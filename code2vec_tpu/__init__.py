@@ -17,13 +17,8 @@ if not _os.environ.get("C2V_HOST_WORKER"):
     import jax as _jax
 
     # Sharding-invariant PRNG: the sharded kernels assume a dropout pattern
-    # that is bit-identical whether the batch lives on one device or a mesh
-    # (newer jax makes this the only behavior; jax < 0.5 defaults the flag
-    # off, which would make GSPMD runs diverge from single-device parity).
-    try:
-        _jax.config.update("jax_threefry_partitionable", True)
-    except Exception:  # flag retired (always-on) in newer jax
-        pass
+    # that is bit-identical whether the batch lives on one device or a mesh.
+    _jax.config.update("jax_threefry_partitionable", True)
 # C2V_HOST_WORKER marks spawned multiprocessing children of the offline
 # data pipeline (data/preprocess.py _worker_pool): pure host-side
 # split/lookup/pack code that must not pay a jax import (seconds + 100s

@@ -408,7 +408,7 @@ def arguments_parser() -> ArgumentParser:
                              "communication overlaps the optimizer "
                              "apply (dense optimizer; dp meshes, or "
                              "tp/cp with --manual_tp_kernels; "
-                             "BENCH_ROOFLINE.md 'Roofline levers')")
+                             "README 'Roofline levers')")
     parser.add_argument("--overlap_bucket_mb", type=float, default=None,
                         metavar="MB",
                         help="target gradient-bucket size for "
@@ -1049,6 +1049,12 @@ def main(argv=None) -> None:
             and "C2V_SERVE_REPLICA" not in os.environ):
         from code2vec_tpu.serving.supervisor import supervisor_main
         sys.exit(supervisor_main(config, argv=list(argv)))
+
+    # Every branch below may compile (the parents above never do); the
+    # persistent cache must be placed before the first jit.
+    from code2vec_tpu.utils.device import configure_compile_cache
+    config.log(f"Compile cache: "
+               f"{configure_compile_cache() or 'off (CPU platform)'}")
 
     # joins the multi-host runtime when a coordinator is configured;
     # no-op on single-process runs (parallel/distributed.py)

@@ -161,9 +161,10 @@ class Config:
     # throughput at java14m scale) with negligible effect on convergence;
     # set "float32" for bit-strict Adam.
     adam_mu_dtype: str = "bfloat16"
-    # Storage dtype for Adam's second moment (nu). bfloat16 shaves
-    # ~3 GB of HBM traffic per flagship step (+10% examples/sec,
-    # BENCH_ROOFLINE.md) and was validated end-to-end: the accuracy
+    # Storage dtype for Adam's second moment (nu). bfloat16 halves the
+    # bytes the memory-bound update reads and writes for it (2 bytes of
+    # 4 per parameter, each way; step-time effect not measured on the
+    # current machine) and was validated end-to-end: the accuracy
     # harness converges to the same test F1 as with f32 nu. nu sets the
     # per-parameter step size through a sqrt, so its rounding is more
     # consequential than mu's — set "float32" (with adam_mu_dtype
@@ -526,8 +527,8 @@ class Config:
     # optimizer only; data-parallel GSPMD meshes, or manual-kernel
     # tp/cp meshes (--manual_tp_kernels — the manual forward runs per
     # shard and the bucket reducers psum each leaf over exactly the
-    # axes it is replicated on). Measured at 2 hosts in
-    # BENCH_ROOFLINE.md "Roofline levers" and BENCH_INPUT.md.
+    # axes it is replicated on). Measured at 2 CPU hosts over gloo in
+    # BENCH_INPUT.md; not measured on the current machine.
     overlap_grad_allreduce: bool = False
     # Target bytes per gradient bucket, in MB (leaves bigger than one
     # bucket get their own).

@@ -59,21 +59,13 @@ def load_library() -> Optional[ctypes.CDLL]:
         lib.c2v_pack_file.restype = i64
         lib.c2v_pack_file.argtypes = [p, ctypes.c_char_p, ctypes.c_char_p,
                                       ctypes.c_char_p, i32, i32]
-        try:
-            lib.c2v_parse_rows.restype = i64
-            lib.c2v_parse_rows.argtypes = [p, ctypes.c_char_p, i64, i32,
-                                           ctypes.POINTER(i32), i64]
-        except AttributeError:
-            pass  # pre-parse_rows build; parse_blob stays available
-        try:
-            lib.c2v_histogram_range.restype = i64
-            lib.c2v_histogram_range.argtypes = [
-                ctypes.c_char_p, i64, i64, ctypes.c_char_p,
-                ctypes.c_char_p, ctypes.c_char_p]
-        except AttributeError:
-            # library built before the histogram entry point existed;
-            # histogram_range() raises and callers fall back to Python
-            pass
+        lib.c2v_parse_rows.restype = i64
+        lib.c2v_parse_rows.argtypes = [p, ctypes.c_char_p, i64, i32,
+                                       ctypes.POINTER(i32), i64]
+        lib.c2v_histogram_range.restype = i64
+        lib.c2v_histogram_range.argtypes = [
+            ctypes.c_char_p, i64, i64, ctypes.c_char_p,
+            ctypes.c_char_p, ctypes.c_char_p]
         _lib = lib
         return _lib
 
@@ -85,10 +77,8 @@ def histogram_range(raw_path: str, start: int, end: int, tokens_out: str,
     map step of the multiprocess histogram build (needs no vocab tables).
     Returns the number of lines consumed."""
     lib = load_library()
-    if lib is None or not hasattr(lib, "c2v_histogram_range"):
-        raise RuntimeError(
-            "libc2vdata.so with c2v_histogram_range not built "
-            "(run `make -C cpp`)")
+    if lib is None:
+        raise RuntimeError("libc2vdata.so not built (run `make -C cpp`)")
     n = lib.c2v_histogram_range(raw_path.encode(), start, end,
                                 tokens_out.encode(), paths_out.encode(),
                                 targets_out.encode())
@@ -98,9 +88,6 @@ def histogram_range(raw_path: str, start: int, end: int, tokens_out: str,
     return n
 
 
-def has_histogram_range() -> bool:
-    lib = load_library()
-    return lib is not None and hasattr(lib, "c2v_histogram_range")
 
 
 def _i32ptr(a: np.ndarray):
@@ -199,9 +186,7 @@ class NativeTables:
         """Parse `n` newline-terminated lines (one bytes blob) straight
         into an `(n, 1 + 3*m)` int32 array in the `.c2vb` interleaved row
         layout — the pack workers write this buffer to disk with no
-        further copy. Requires a libc2vdata.so with `c2v_parse_rows`
-        (raises AttributeError on older builds; callers fall back to
-        `parse_blob` + explicit interleave)."""
+        further copy."""
         m = max_contexts
         rec = np.empty((n, 1 + 3 * m), dtype=np.int32)
         parsed = self._lib.c2v_parse_rows(self._handle, data, len(data), m,

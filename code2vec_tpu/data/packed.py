@@ -208,8 +208,6 @@ def _pack_shard(task) -> dict:
     word_ok, path_ok = ctx["word_ok"], ctx["path_ok"]
     sampling = word_ok is not None
     tables = _pack_worker_native_tables()
-    native_rows = tables is not None and hasattr(tables._lib,
-                                                 "c2v_parse_rows")
     memo: Dict[bytes, tuple] = {}
     memo_cap = preprocess_mod._MEMO_CAP
     # Emission memo: one packed int64 per distinct context
@@ -295,15 +293,7 @@ def _pack_shard(task) -> dict:
         if tables is not None:
             blob = b"\n".join(b" ".join([name] + ctxs)
                               for name, ctxs in zip(names, per_row)) + b"\n"
-            if native_rows:
-                rec = tables.parse_rows_blob(blob, n, m)
-            else:
-                src, pth, tgt, label, _mask = tables.parse_blob(blob, n, m)
-                rec = np.empty((n, 1 + 3 * m), dtype=np.int32)
-                rec[:, 0] = label
-                rec[:, 1:1 + m] = src
-                rec[:, 1 + m:1 + 2 * m] = pth
-                rec[:, 1 + 2 * m:] = tgt
+            rec = tables.parse_rows_blob(blob, n, m)
         else:
             labels = np.fromiter(
                 (target_b2i.get(nm, target_oov) for nm in names),
@@ -400,15 +390,7 @@ def _pack_shard(task) -> dict:
             if not n:
                 return
             blob = b"\n".join(pend_lines) + b"\n"
-            if native_rows:
-                rec = tables.parse_rows_blob(blob, n, m)
-            else:
-                src, pth, tgt, label, _mask = tables.parse_blob(blob, n, m)
-                rec = np.empty((n, 1 + 3 * m), dtype=np.int32)
-                rec[:, 0] = label
-                rec[:, 1:1 + m] = src
-                rec[:, 1 + m:1 + 2 * m] = pth
-                rec[:, 1 + 2 * m:] = tgt
+            rec = tables.parse_rows_blob(blob, n, m)
             seg.write(rec)
             if tgt_seg is not None:
                 tgt_seg.write(b"\n".join(names) + b"\n")

@@ -206,7 +206,7 @@ def _histogram_shard(args) -> Tuple[Counter, Counter, Counter]:
     are bytes either way; the reduce step decodes once."""
     path, start, end = args
     from code2vec_tpu.data import native
-    if native.has_histogram_range():
+    if native.load_library() is not None:
         dump_dir = tempfile.mkdtemp(prefix="c2v_hist_",
                                     dir=os.path.dirname(path) or ".")
         try:

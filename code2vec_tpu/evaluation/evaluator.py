@@ -24,6 +24,7 @@ from code2vec_tpu.evaluation.metrics import (
     TopKAccuracyEvaluationMetric, batch_prediction_info,
 )
 from code2vec_tpu.training.step import device_put_batch
+from code2vec_tpu.utils.device import describe_devices
 
 
 class Evaluator:
@@ -69,6 +70,7 @@ class Evaluator:
                                            code_vectors_path,
                                            code_vectors_sink, prefetch)
         obs.counter("eval_runs_total", "completed evaluation passes").inc()
+        self.config.log(f"Evaluation pass done; {describe_devices(params)}")
         # Last-eval quality gauges: the same scalars the TB eval/ tags
         # carry, visible to a Prometheus scrape between TB flushes.
         for name, value in results.tb_scalars():

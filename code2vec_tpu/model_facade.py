@@ -40,6 +40,7 @@ from code2vec_tpu.training.state import (
 from code2vec_tpu.training.step import (
     EvalOutputs, TrainStepBuilder, device_put_batch,
 )
+from code2vec_tpu.utils.device import describe_devices, shard_layout
 from code2vec_tpu.utils.faults import fault_point
 from code2vec_tpu.vocab import Code2VecVocabs, VocabType
 
@@ -116,7 +117,14 @@ class BucketedPredictMixin:
             return cached[c2v_path]
         packed_path = c2v_path + "b"
         if not os.path.exists(packed_path):
-            self.log(f"Packing {c2v_path} -> {packed_path} (one-time)")
+            # Which loader does the work is otherwise invisible: without
+            # the shared library the Python parser takes over silently.
+            from code2vec_tpu.data import native
+            loader = ("native libc2vdata.so"
+                      if native.load_library() is not None else
+                      "Python parser; libc2vdata.so not built")
+            self.log(f"Packing {c2v_path} -> {packed_path} "
+                     f"(one-time; {loader})")
             pack_c2v(c2v_path, self.vocabs, self.config.max_contexts,
                      out_path=packed_path,
                      num_workers=self.config.preprocess_workers)
@@ -531,9 +539,14 @@ class Code2VecModel(BucketedPredictMixin):
         # per-variable shape/param dump (reference: tensorflow_model.py:59-63)
         for name, p in sorted(self.state.params.items()):
             self.log(f"variable name: {name} -- shape: "
-                     f"{tuple(p.shape)} -- #params: {p.size:,}")
+                     f"{tuple(p.shape)} -- #params: {p.size:,} -- "
+                     f"{shard_layout(p)}")
         self.log(f"Model created: {num_params(self.state):,} parameters "
-                 f"(mesh dp={config.dp} tp={config.tp} cp={config.cp})")
+                 f"(mesh dp={config.dp} tp={config.tp} cp={config.cp}); "
+                 f"{self.describe_devices()}")
+
+    def describe_devices(self) -> str:
+        return describe_devices(self.state.params)
 
     # ------------------------------------------------------------ data
 

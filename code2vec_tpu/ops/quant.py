@@ -3,7 +3,7 @@
 
 The flagship shape is 227-383M params dominated by three embedding
 tables and the ~246K-name target classifier, and every hot op that
-touches them is memory-bandwidth-bound (BENCH_ROOFLINE.md): quantized
+touches them moves far more bytes than it computes on: quantized
 storage moves 1 byte (int8/fp8) or half a byte (int4) per weight instead
 of four through HBM, with the dequant fused into the consuming op —
 gathers multiply the gathered rows by their scales (ops below), the

@@ -3,9 +3,10 @@ the full logit row.
 
 The code2vec prediction head is a (B, V) matmul against a ~246K-row
 target table followed by top-k; at batch 1024 the logits alone are
-~1 GB/batch of HBM traffic written once and read twice (top-k + CE) —
-BENCH_ROOFLINE.md shows the hot ops are bandwidth-bound, so never
-materializing that row is a direct lever. These kernels stream the
+~1 GB/batch of HBM traffic written once and read twice (top-k + CE).
+By their shapes the hot ops move far more bytes than they compute on,
+so never materializing that row is a direct lever (its effect is not
+measured on the current machine). These kernels stream the
 target table in row blocks, compute each block's (B, block) logit slice,
 and fold it into a running `lax.top_k` merge (plus an optional running
 logsumexp for the eval CE), so peak live logits are (B, block) instead

@@ -92,9 +92,9 @@ def initialize(coordinator_address: Optional[str] = None,
         _initialized = True
         return
     # Cloud-TPU-pod heuristic: hostnames present -> try auto-detection.
-    # Best-effort, because single-chip environments (and tunneled dev
-    # setups) can carry TPU_WORKER_HOSTNAMES without a reachable
-    # coordinator; those must keep working single-process.
+    # Best-effort: a failed auto-detection continues single-process
+    # (pinned by tests/test_chaos.py). Whether a pod host that ends up
+    # training alone should instead be fatal is open — ROADMAP S5.
     hostnames = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     if len(hostnames.split(",")) > 1:
         try:

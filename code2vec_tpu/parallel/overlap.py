@@ -4,9 +4,10 @@ optimizer apply.
 The unbucketed GSPMD train step is ONE XLA program: backward, the
 data-parallel gradient all-reduce and the full Adam sweep run as a
 single dispatch, and the all-reduce of the LAST gradient serializes
-ahead of the ENTIRE optimizer apply. BENCH_ROOFLINE.md shows the apply
-already runs at this part's practical HBM bandwidth — the remaining
-lever is keeping the interconnect busy while it runs.
+ahead of the ENTIRE optimizer apply. The apply is a pure streaming
+pass over every parameter and moment — bound by HBM bandwidth, not by
+compute — so the lever is keeping the interconnect busy while it runs
+(neither is measured on the current machine).
 
 This module splits the step into 1 + K dispatches:
 
@@ -224,8 +225,7 @@ def build_overlap_train_step(builder, example_state) -> Callable:
     elif mesh is None:
         backward = jax.jit(full_backward)
     else:
-        from code2vec_tpu.training.step import _shard_map
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             full_backward, mesh=mesh,
             in_specs=(param_specs,) + batch_specs + (P(), P()),
             out_specs=(param_specs, P()),
@@ -257,8 +257,7 @@ def build_overlap_train_step(builder, example_state) -> Callable:
 
         if mesh is None:
             return jax.jit(bucket_backward)
-        from code2vec_tpu.training.step import _shard_map
-        sharded = _shard_map(
+        sharded = jax.shard_map(
             bucket_backward, mesh=mesh,
             in_specs=(param_specs,) + batch_specs + (P(), P()),
             out_specs=(sub_specs, P()) if with_loss else sub_specs,
@@ -278,9 +277,8 @@ def build_overlap_train_step(builder, example_state) -> Callable:
                     out[k] = jax.lax.psum(g, axes) if axes else g
                 return out
 
-            from code2vec_tpu.training.step import _shard_map
-            reducer = _shard_map(reduce, mesh=mesh, in_specs=(specs,),
-                                 out_specs=specs, check_vma=False)
+            reducer = jax.shard_map(reduce, mesh=mesh, in_specs=(specs,),
+                                    out_specs=specs, check_vma=False)
 
         def bucket_step(p_sub, mu_sub, nu_sub, count, rest, g_sub):
             # `count` and `rest` are NOT donated: every bucket reads

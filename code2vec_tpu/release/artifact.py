@@ -23,8 +23,8 @@ export (`--release_scheme int8|fp8_e4m3|fp8_e5m2|int4`): int8 drops the
 three tables ~3.9x at the flagship shape (1 byte/weight + 4 bytes/row),
 fp8 keeps the byte count with a relative error profile, int4 packs two
 weights per byte for another ~2x — which is both the artifact's
-disk/RSS footprint and, because the hot ops are bandwidth-bound
-(BENCH_ROOFLINE.md), the serve step's HBM traffic. Quality deltas per
+disk/RSS footprint and, because the hot ops move far more bytes than
+they compute on, the serve step's HBM traffic. Quality deltas per
 scheme are measured same-run vs fp32 in BENCH_QUANT.md.
 
 Every load validates `kind`/`format`/table dtypes against the declared

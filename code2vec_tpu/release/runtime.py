@@ -45,6 +45,7 @@ from code2vec_tpu.release.artifact import (
     SCHEME_INT8, ReleaseArtifact, load_artifact, table_dim,
 )
 from code2vec_tpu.training.step import EvalOutputs
+from code2vec_tpu.utils.device import describe_devices
 from code2vec_tpu.vocab import Code2VecVocabs
 
 
@@ -408,7 +409,11 @@ class ReleaseModel(BucketedPredictMixin):
             f"{self.artifact.table_bytes() / 1e6:.1f} MB, buckets "
             f"{list(self._context_buckets)}, fingerprint "
             f"{self.artifact.fingerprint[:12]}, aot="
-            f"{'none' if not meta.get('aot') else meta['aot']['platform']}")
+            f"{'none' if not meta.get('aot') else meta['aot']['platform']}"
+            f"; {self.describe_devices()}")
+
+    def describe_devices(self) -> str:
+        return describe_devices(self.params)
 
     @property
     def context_buckets(self) -> Tuple[int, ...]:

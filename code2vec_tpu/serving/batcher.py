@@ -1,8 +1,9 @@
 """Dynamic request batcher: coalesce concurrent predicts into device
 batches under a latency budget, with bucketed context counts.
 
-Why this shape: the device side runs ~41.3K examples/s (BENCH_EVAL.json)
-but only if it is fed BATCHES — a per-request jitted call wastes the
+Why this shape: the device step is sized for a batch of rows (its
+rate is not measured on the current machine) and only earns it when
+fed BATCHES — a per-request jitted call wastes the
 chip on dispatch overhead, and letting every request shape hit pjit
 would recompile per distinct (rows, contexts) pair. So:
 

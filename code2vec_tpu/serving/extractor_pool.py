@@ -2,9 +2,9 @@
 
 The one-shot bridge (extractor_bridge.PathExtractor) pays a full process
 spawn + runtime init per extraction — fine for a REPL, fatal for a
-server (BENCH_EVAL.json: the device side sustains 41.3K examples/s; a
-subprocess fork per request caps the whole service at tens of requests
-per second). This pool keeps N extractor children RESIDENT:
+server (a subprocess fork per request caps the whole service at tens
+of requests per second, whatever the device sustains). This pool keeps
+N extractor children RESIDENT:
 
 - **warm mode**: the native `c2v-extract --server` worker loop (built in
   cpp/; probed once at pool startup). Requests are line-framed over the
@@ -285,7 +285,8 @@ class ExtractorPool:
         _G_SIZE.set(self.size)
         self.log(f"Extractor pool up: {self.size} "
                  f"{'warm --server' if self.warm else 'cold one-shot'} "
-                 f"worker(s)")
+                 f"worker(s)"
+                 + (f" of {self.warm_command[0]}" if self.warm else ""))
 
     # ---------------------------------------------------------- workers
 

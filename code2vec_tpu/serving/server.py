@@ -1064,8 +1064,15 @@ class PredictionServer:
                  f"http://{httpd.server_address[0]}:{self.port} "
                  f"(POST /predict, POST /embed, POST /admin/reload, "
                  f"GET /healthz, GET /metrics"
-                 f"{', SO_REUSEPORT' if reuseport else ''})")
+                 f"{', SO_REUSEPORT' if reuseport else ''})"
+                 f"{self._device_suffix()}")
         return self.port
+
+    def _device_suffix(self) -> str:
+        """Where the serving model's arrays live, for the ready and
+        drain lines (test fakes carry no arrays and say nothing)."""
+        describe = getattr(self.model, "describe_devices", None)
+        return f"; {describe()}" if describe else ""
 
     @staticmethod
     def _inject_trace(body: bytes, trace: RequestTrace) -> bytes:
@@ -1158,7 +1165,8 @@ class PredictionServer:
             except Exception:
                 pass  # teardown must never mask the drain result
         self._drained.set()
-        self.log(f"Drain complete ({'clean' if clean else 'timed out'})")
+        self.log(f"Drain complete ({'clean' if clean else 'timed out'})"
+                 f"{self._device_suffix()}")
         return clean
 
 

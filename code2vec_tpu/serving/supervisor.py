@@ -148,6 +148,40 @@ def child_env(base_env: Dict[str, str]) -> Dict[str, str]:
     return env
 
 
+def exclusive_chips() -> Optional[int]:
+    """How many accelerator chips JAX sees on this host when a chip
+    belongs to ONE process at a time (TPU), else None (CPU, or devices
+    processes can share). Asked in a short-lived child that has exited
+    before the first replica starts: this parent must never open the
+    chip its replicas need. A process pinned to the CPU platform skips
+    the child."""
+    import jax
+    if jax.config.jax_platforms == "cpu":
+        return None
+    probe = subprocess.run(
+        [sys.executable, "-c",
+         "import jax; d = jax.devices(); print(d[0].platform, len(d))"],
+        env=child_env(os.environ), capture_output=True, text=True)
+    if probe.returncode != 0:
+        raise RuntimeError(
+            "cannot open the accelerator the replicas need: "
+            + (probe.stderr.strip().splitlines() or ["no output"])[-1])
+    platform, count = probe.stdout.split()[-2:]
+    return int(count) if platform == "tpu" else None
+
+
+def chip_env(chip: int) -> Dict[str, str]:
+    """Environment that binds one process to TPU chip `chip` of this
+    host (libtpu's one-chip-per-process settings; each process needs
+    its own mesh-controller port)."""
+    port = str(8476 + chip)
+    return {"TPU_VISIBLE_CHIPS": str(chip),
+            "TPU_CHIPS_PER_PROCESS_BOUNDS": "1,1,1",
+            "TPU_PROCESS_BOUNDS": "1,1,1",
+            "TPU_MESH_CONTROLLER_ADDRESS": "localhost:" + port,
+            "TPU_MESH_CONTROLLER_PORT": port}
+
+
 def _free_port(host: str) -> int:
     with socket.socket(socket.AF_INET, socket.SOCK_STREAM) as s:
         s.bind((host, 0))
@@ -164,6 +198,8 @@ class _Replica:
         self.proc: Optional[subprocess.Popen] = None
         self.pipe_r: Optional[int] = None
         self.port: Optional[int] = None
+        # TPU chip this replica is bound to (Supervisor.chips hosts)
+        self.chip: Optional[int] = None
         self.restarts = 0
         self.spawned_at = 0.0
         self.restart_at: Optional[float] = None  # backoff gate
@@ -243,6 +279,10 @@ class Supervisor:
         if self.reuseport and self.port == 0:
             # replicas must all bind ONE concrete port; resolve now
             self.port = _free_port(config.serve_host)
+        # One process per chip: on a TPU host replica i is bound to a
+        # chip of its own through its environment, and more replicas
+        # than chips is a start-up error, not a restart storm.
+        self.chips = exclusive_chips()
         self.replicas = [self._make_replica(i) for i in range(self.n)]
         # /admin/scale: the monitor loop reconciles the live replica set
         # toward `_desired` (spawn up, drain down); indices only ever
@@ -312,6 +352,11 @@ class Supervisor:
                     self.traffic_sample + suffix]
         env = child_env(os.environ)
         env[REPLICA_ENV] = str(replica.index)
+        if self.chips is not None:
+            if replica.chip is None:
+                replica.chip = min(set(range(self.chips)) - {
+                    r.chip for r in self.replicas})
+            env.update(chip_env(replica.chip))
         if self.reuseport:
             cmd += ["--serve_port", str(self.port)]
             env["C2V_SERVE_REUSEPORT"] = "1"
@@ -347,7 +392,8 @@ class Supervisor:
             os.path.join(self.run_dir, RELOAD_TARGET_FILENAME))
         self.log(f"Replica {replica.index} spawned "
                  f"(pid {replica.proc.pid}"
-                 f"{f', port {replica.port}' if replica.port else ''})")
+                 f"{f', port {replica.port}' if replica.port else ''}"
+                 f"{'' if replica.chip is None else f', chip {replica.chip}'})")
 
     def _kill(self, replica: _Replica, sig=signal.SIGKILL) -> None:
         if replica.proc is not None and replica.proc.poll() is None:
@@ -383,6 +429,10 @@ class Supervisor:
         if not (1 <= n <= MAX_REPLICAS):
             raise ValueError(
                 f"replicas must be in [1, {MAX_REPLICAS}] (got {n})")
+        if self.chips is not None and n > self.chips:
+            raise ValueError(
+                f"replicas must be <= {self.chips}, the TPU chips on this "
+                f"host: a chip belongs to one process (got {n})")
         with self._scale_lock:
             self._desired = n
         self.log(f"Scale request: desired replicas -> {n}")
@@ -394,6 +444,8 @@ class Supervisor:
             desired = self._desired
         active = [r for r in self.replicas if not r.draining]
         for _ in range(desired - len(active)):
+            if self.chips is not None and len(self.replicas) >= self.chips:
+                break  # a draining replica still holds its chip
             replica = self._make_replica(self._next_index)
             self._next_index += 1
             self.replicas.append(replica)
@@ -991,6 +1043,12 @@ class Supervisor:
                 signal.signal(sig, handler)
 
     def _run_inner(self) -> int:
+        if self.chips is not None and self.n > self.chips:
+            self.log(f"--replicas {self.n} needs {self.n} TPU chips and "
+                     f"this host has {self.chips}: a chip belongs to one "
+                     f"process at a time. Start at most {self.chips} "
+                     f"replica(s) here.")
+            return 1
         if not self.reuseport:
             self._start_proxy()
         self._start_telemetry()

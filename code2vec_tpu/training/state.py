@@ -49,16 +49,6 @@ def uses_sparse_update(config) -> bool:
                 and getattr(config, "use_sparse_embedding_update", False))
 
 
-# optax renamed safe_int32_increment -> safe_increment; the image may
-# carry either vintage. Resolved at import so an optax with neither
-# name fails HERE with the real attribute error, not as a NoneType call
-# deep inside the jitted update.
-try:
-    _safe_increment = optax.safe_increment
-except AttributeError:
-    _safe_increment = optax.safe_int32_increment
-
-
 def _scale_by_adam_nu_dtype(b1: float, b2: float, eps: float,
                             mu_dtype, nu_dtype) -> optax.GradientTransformation:
     """optax.scale_by_adam with a storage dtype for the SECOND moment as
@@ -77,7 +67,7 @@ def _scale_by_adam_nu_dtype(b1: float, b2: float, eps: float,
 
     def update_fn(updates, state, params=None):
         del params
-        count = _safe_increment(state.count)
+        count = optax.safe_increment(state.count)
         mu = jax.tree.map(
             lambda g, m: b1 * m.astype(g.dtype) + (1.0 - b1) * g,
             updates, state.mu)

@@ -46,8 +46,9 @@ class DevicePrefetcher:
     asynchronously, so the consumer is not stalled — and keeping every
     runtime interaction on one thread avoids serializing the consumer's
     step dispatches against a second thread's transfer calls inside the
-    runtime client (measured 2-3x worse real-data throughput with
-    device_put on the worker thread)."""
+    runtime client (2-3x worse real-data throughput with device_put on
+    the worker thread in a pre-round measurement; not re-measured on
+    the current machine)."""
 
     _SENTINEL = object()
 
@@ -109,11 +110,10 @@ class DevicePrefetcher:
         # batch back: batch N+1's device_put is dispatched before batch
         # N is handed to the step loop, so the N+1 transfer rides under
         # step N's dispatch instead of serializing after it. The
-        # transfer still runs on THIS thread (see the class docstring:
-        # a second runtime-client thread measured 2-3x worse) — only
-        # the dispatch order changes. Costs one extra batch of device
-        # memory and one batch of startup latency; EpochEnd markers
-        # flush the held batch first so ordering is preserved.
+        # transfer still runs on THIS thread (see the class docstring)
+        # — only the dispatch order changes. Costs one extra batch of
+        # device memory and one batch of startup latency; EpochEnd
+        # markers flush the held batch first so ordering is preserved.
         self._thread.start()
         pending = None
         try:

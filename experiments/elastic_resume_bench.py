@@ -32,10 +32,6 @@ import sys
 import time
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=4").strip()
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if REPO_ROOT not in sys.path:
@@ -44,10 +40,7 @@ if REPO_ROOT not in sys.path:
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 4)
-except AttributeError:
-    pass
+jax.config.update("jax_num_cpu_devices", 4)
 
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402

@@ -496,7 +496,8 @@ def _in_backward_section(part: dict) -> str:
         IB_BEGIN,
         "## In-backward bucket completion (2-host A/B)",
         "",
-        "Same harness as the BENCH_ROOFLINE.md overlap section (2 real "
+        "Same harness as the roofline overlap A/B "
+        "(`experiments/overlap_bench.py`; 2 real "
         "jax.distributed processes, gloo, dp=2 mesh), comparing the "
         "bucketed-overlap step with completion AFTER the full backward "
         "vs IN-BACKWARD per-bucket completion "

@@ -312,14 +312,16 @@ def _update_bench_md(result: dict) -> None:
         "see config.py).",
         END,
     ])
-    with open(BENCH_MD) as f:
-        text = f.read()
+    text = ""
+    if os.path.exists(BENCH_MD):
+        with open(BENCH_MD) as f:
+            text = f.read()
     if BEGIN in text:
         head, rest = text.split(BEGIN, 1)
         _, tail = rest.split(END, 1)
         text = head + section + tail
     else:
-        text = text.rstrip() + "\n\n" + section + "\n"
+        text = (text.rstrip() + "\n\n" if text else "") + section + "\n"
     with open(BENCH_MD, "w") as f:
         f.write(text)
 

@@ -32,18 +32,11 @@ import os
 import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=2").strip()
 
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # covered by the XLA_FLAGS fallback above
+jax.config.update("jax_num_cpu_devices", 2)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 import numpy as np  # noqa: E402

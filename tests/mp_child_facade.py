@@ -27,20 +27,11 @@ import os
 import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
-# jax < 0.5 has no jax_num_cpu_devices option; the legacy XLA flag does
-# the same as long as it lands before the backend initializes.
-_flags = os.environ.get("XLA_FLAGS", "")
-if "--xla_force_host_platform_device_count" not in _flags:
-    os.environ["XLA_FLAGS"] = (
-        _flags + " --xla_force_host_platform_device_count=2").strip()
 
 import jax
 
 jax.config.update("jax_platforms", "cpu")
-try:
-    jax.config.update("jax_num_cpu_devices", 2)
-except AttributeError:
-    pass  # covered by the XLA_FLAGS fallback above
+jax.config.update("jax_num_cpu_devices", 2)
 jax.config.update("jax_cpu_collectives_implementation", "gloo")
 
 import numpy as np  # noqa: E402

@@ -100,16 +100,18 @@ def test_sparse_step_exchanges_rows_not_tables():
     dense_text = _train_hlo(sparse=False)
     sparse_text = _train_hlo(sparse=True)
 
-    # The op ITSELF must be a table-shaped all-reduce (`= f32[32,16]{...}
-    # all-reduce(`): some XLA versions print fusion consumers that
-    # mention an all-reduce operand on the same line as a table-shaped
-    # output, which a bare substring test would miscount.
-    table_ar = re.compile(
-        rf"= {re.escape(TOKEN_TABLE_SHARD)}\S* all-reduce\(")
+    # The op ITSELF must produce a table-shaped all-reduce result: the
+    # shape sits in the result type between `= ` and ` all-reduce(`,
+    # alone (`= f32[32,16]{...} all-reduce(`) or as one element of the
+    # tuple XLA's all-reduce combiner builds (`= (f32[16,16]{...},
+    # f32[32,16]{...}) all-reduce(`). Consumers that merely mention an
+    # all-reduce operand (get-tuple-element, fusions) do not match.
+    result_type = re.compile(r"= (.*?) all-reduce\(")
 
     def table_allreduces(text):
         return [ln for ln in _collective_lines(text)
-                if table_ar.search(ln)]
+                if (m := result_type.search(ln))
+                and TOKEN_TABLE_SHARD in m.group(1)]
 
     # the detector must actually detect: dense HAS the table exchange
     assert table_allreduces(dense_text), (

@@ -176,7 +176,7 @@ def test_native_histogram_range_matches_python(tmp_path):
         "solo\n"
         "last f,222,g")                       # unterminated final line
     serial = pp.build_histograms(str(raw))
-    assert native.has_histogram_range()
+    assert native.load_library() is not None
     sharded = pp.build_histograms(str(raw), num_workers=2)
     assert tuple(sharded) == tuple(serial)
 

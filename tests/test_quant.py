@@ -73,7 +73,12 @@ def test_blockwise_matmul_matches_full(v, block, k):
     out = jax.jit(lambda c, t: blockwise_matmul_top_k(c, t, k, block))(
         cv, tbl)
     np.testing.assert_array_equal(np.asarray(fi), np.asarray(out.indices))
-    np.testing.assert_array_equal(np.asarray(fv), np.asarray(out.values))
+    # The MERGE is exact (test_blockwise_tie_breaking_matches pins it
+    # bitwise on shared logits); here each logit comes from a (B, block)
+    # matmul on one side and a (B, V) matmul on the other, and XLA may
+    # sum the 24 products in another order: an ulp or two of f32.
+    np.testing.assert_allclose(np.asarray(fv), np.asarray(out.values),
+                               rtol=1e-6)
     # the streamed logsumexp must agree with the full-row one
     ref_lse = jax.scipy.special.logsumexp(full, axis=-1)
     np.testing.assert_allclose(np.asarray(out.lse), np.asarray(ref_lse),

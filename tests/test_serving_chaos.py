@@ -1354,3 +1354,55 @@ def test_scale_down_prefers_coldest_cache_replica(tmp_path):
         write_metrics(r, 7)
     assert sup._scale_down_victims(sup.replicas, 1) == [r2]
     assert sup._scale_down_victims(sup.replicas, 2) == [r2, r1]
+
+
+def test_supervisor_gives_each_replica_a_chip_or_refuses(tmp_path,
+                                                          monkeypatch):
+    """One process per chip. With the host's chip count injected (a TPU
+    host; the CPU has no such limit): more replicas than chips is ONE
+    start-up line and exit 1 with nothing spawned — not a restart storm
+    — and within the count every replica's environment binds it to a
+    chip of its own."""
+    from code2vec_tpu.serving import supervisor as sup_mod
+
+    monkeypatch.setattr(sup_mod, "exclusive_chips", lambda: 1)
+    sup = sup_mod.Supervisor(_supervisor_config(tmp_path),
+                             child_command=[sys.executable, "-c", "pass"])
+    lines = []
+    sup.log = lines.append
+    assert sup.run() == 1
+    assert all(r.proc is None for r in sup.replicas)
+    assert len(lines) == 1 and "needs 2 TPU chips" in lines[0] \
+        and "has 1" in lines[0]
+
+    monkeypatch.setattr(sup_mod, "exclusive_chips", lambda: 2)
+    script = ("import os, time\n"
+              f"out = os.path.join({str(tmp_path)!r}, 'env' + "
+              "os.environ['C2V_SERVE_REPLICA'])\n"
+              "open(out + '.tmp', 'w').write(' '.join(os.environ.get(k, '-') "
+              "for k in ('TPU_VISIBLE_CHIPS', 'TPU_MESH_CONTROLLER_PORT', "
+              "'TPU_PROCESS_BOUNDS')))\n"
+              "os.rename(out + '.tmp', out)\n"
+              "time.sleep(60)\n")
+    sup = sup_mod.Supervisor(_supervisor_config(tmp_path),
+                             child_command=[sys.executable, "-c", script])
+    sup.log = lines.append
+    try:
+        for replica in sup.replicas:
+            sup._spawn(replica)
+        seen = []
+        for i in range(2):
+            path = tmp_path / f"env{i}"
+            deadline = time.time() + 30
+            while not path.exists() and time.time() < deadline:
+                time.sleep(0.05)
+            seen.append(path.read_text().split())
+        assert [r.chip for r in sup.replicas] == [0, 1]
+        assert seen == [["0", "8476", "1,1,1"], ["1", "8477", "1,1,1"]]
+        with pytest.raises(ValueError, match="chips on this host"):
+            sup.request_scale(3)
+    finally:
+        for replica in sup.replicas:
+            sup._kill(replica)
+            if replica.proc is not None:
+                replica.proc.wait(timeout=10)

@@ -19,7 +19,7 @@ from code2vec_tpu.training.state import (
     TrainState, create_train_state, make_optimizer,
 )
 from code2vec_tpu.training.step import (
-    TrainStepBuilder, _shard_map, device_put_batch,
+    TrainStepBuilder, device_put_batch,
 )
 from jax.sharding import PartitionSpec as P
 
@@ -77,7 +77,7 @@ def test_tp_ops_match_dense():
         vals, idx = tp_ops.tp_top_k(logits_shard, 3, "model")
         return emb, ce, vals, idx
 
-    f = _shard_map(
+    f = jax.shard_map(
         per_shard, mesh=mesh,
         in_specs=(P("model", None), P(), P(None, "model"), P()),
         out_specs=(P(), P(), P(), P()), check_vma=False)
