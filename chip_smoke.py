@@ -476,10 +476,10 @@ def _http(method: str, url: str, body: Optional[bytes] = None,
     req = urllib.request.Request(url, data=body, method=method)
     if body is not None:
         req.add_header("Content-Type", "text/plain")
-        # A cold server compiles one predict step per context bucket on
-        # the first request that needs it, which outlasts the default
-        # 2 s request deadline; the client override is the documented
-        # way to wait for it.
+        # `serve` warms every predict bucket before it listens, so no
+        # request should meet a compile; the generous client deadline
+        # stays so that a cold extractor or a slow first transfer fails
+        # a check with a message and not with a 504.
         req.add_header("X-Deadline-Ms", "280000")
     try:
         with urllib.request.urlopen(req, timeout=timeout) as resp:

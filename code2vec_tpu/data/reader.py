@@ -28,7 +28,6 @@ import hashlib
 import os
 import random
 import struct
-import time
 from typing import Iterable, Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -525,16 +524,14 @@ class PathContextReader:
             yield EpochEnd(epoch)
 
     def _parse_chunk(self, chunk: List[str]) -> RowBatch:
-        t0 = time.perf_counter()
-        raw = parse_context_lines(chunk, self.vocabs, self.config.max_contexts,
-                                  self.estimator_action)
-        keep = row_filter_mask(raw, self.vocabs, self.estimator_action)
-        out = _select_rows(raw, np.nonzero(keep)[0])
-        dur = time.perf_counter() - t0
-        _H_PARSE.observe(dur)
+        with obs.span("data_parse_chunk", hist=_H_PARSE):
+            raw = parse_context_lines(chunk, self.vocabs,
+                                      self.config.max_contexts,
+                                      self.estimator_action)
+            keep = row_filter_mask(raw, self.vocabs, self.estimator_action)
+            out = _select_rows(raw, np.nonzero(keep)[0])
         _C_ROWS_READ.inc(len(chunk))
         _C_ROWS_DROPPED.inc(len(chunk) - out.target_index.shape[0])
-        obs.default_tracer().maybe_record("data_parse_chunk", t0, dur)
         return out
 
     def _parsed_chunks(self, line_iter: Iterator) -> Iterator:

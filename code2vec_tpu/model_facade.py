@@ -54,6 +54,30 @@ class ModelPredictionResults(NamedTuple):
     code_vector: Optional[np.ndarray] = None
 
 
+_STAGE_HELP = (
+    "one stage of a coalesced predict call on the dispatcher thread: "
+    "parse (extractor lines to id arrays; absent on the pre-parsed "
+    "path), assemble (bucket choice, slice, pad), device (transfer "
+    "in, step, fetch of the outputs), render (softmax of the top-k, "
+    "vocabulary look-ups, attention dictionaries)")
+_H_STAGE = {stage: obs.histogram("serving_predict_stage_seconds",
+                                 _STAGE_HELP, stage=stage)
+            for stage in ("parse", "assemble", "device", "render")}
+_FILL_HELP = (
+    "how much of a predict step's padded shape was real work: rows = "
+    "live rows over the padded row shape, contexts = valid contexts "
+    "over padded rows x context bucket")
+_FILL_BUCKETS = (0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7,
+                 0.8, 0.9, 1.0)
+_H_FILL = {dim: obs.histogram("serving_batch_fill_ratio", _FILL_HELP,
+                              buckets=_FILL_BUCKETS, dim=dim)
+           for dim in ("rows", "contexts")}
+
+
+def _stage(stage: str):
+    return obs.span("predict." + stage, hist=_H_STAGE[stage])
+
+
 def _head_dispatch_counter(head: str):
     """Per-head device-batch routing counter. A helper (not a module
     global) because the label value is dynamic; the metric NAME stays a
@@ -327,10 +351,11 @@ class BucketedPredictMixin:
     def _predict_chunk(self, lines: List[str], bs: int,
                        with_code_vectors: bool
                        ) -> List[ModelPredictionResults]:
-        chunk = parse_context_lines(lines, self.vocabs,
-                                    self.config.max_contexts,
-                                    EstimatorAction.Predict,
-                                    keep_strings=True)
+        with _stage("parse"):
+            chunk = parse_context_lines(lines, self.vocabs,
+                                        self.config.max_contexts,
+                                        EstimatorAction.Predict,
+                                        keep_strings=True)
         return self._predict_parsed(chunk, len(lines), bs,
                                     with_code_vectors)
 
@@ -352,30 +377,47 @@ class BucketedPredictMixin:
                         ) -> List[ModelPredictionResults]:
         from code2vec_tpu.data.reader import _pad_rows, slice_contexts
         from code2vec_tpu.serving.batcher import bucket_for
-        # Deepest VALID context column decides the bucket: the slice
-        # below only ever removes all-padding columns. (Slot buffers
-        # keep unclaimed rows' masks zeroed, so pooled reuse cannot
-        # inflate the bucket.)
-        any_valid_col = chunk.context_valid_mask.any(axis=0)
-        deepest = (int(np.nonzero(any_valid_col)[0][-1]) + 1
-                   if any_valid_col.any() else 1)
-        m = bucket_for(deepest, self.context_buckets)
-        chunk = slice_contexts(chunk, m)
-        step, padded_rows, head = self._dispatch_predict_step(n, bs, m)
-        _head_dispatch_counter(head).inc()
-        if chunk.target_index.shape[0] > padded_rows:
-            from code2vec_tpu.data.reader import truncate_rows
-            chunk = truncate_rows(chunk, padded_rows)
-        # Pad the row count to the step's fixed row shape: row count and
-        # context bucket together fully determine the compiled shape.
-        padded = _pad_rows(chunk, padded_rows)
-        arrays = device_put_batch(padded, self.mesh)
-        out = self._call_predict_step(step, arrays)
+        with _stage("assemble"):
+            # Deepest VALID context column decides the bucket: the slice
+            # below only ever removes all-padding columns. (Slot buffers
+            # keep unclaimed rows' masks zeroed, so pooled reuse cannot
+            # inflate the bucket.)
+            any_valid_col = chunk.context_valid_mask.any(axis=0)
+            deepest = (int(np.nonzero(any_valid_col)[0][-1]) + 1
+                       if any_valid_col.any() else 1)
+            m = bucket_for(deepest, self.context_buckets)
+            chunk = slice_contexts(chunk, m)
+            step, padded_rows, head = self._dispatch_predict_step(n, bs, m)
+            _head_dispatch_counter(head).inc()
+            if chunk.target_index.shape[0] > padded_rows:
+                from code2vec_tpu.data.reader import truncate_rows
+                chunk = truncate_rows(chunk, padded_rows)
+            # Pad the row count to the step's fixed row shape: row count
+            # and context bucket together fully determine the compiled
+            # shape.
+            padded = _pad_rows(chunk, padded_rows)
+            live = min(n, padded_rows)
+            _H_FILL["rows"].observe(live / padded_rows)
+            _H_FILL["contexts"].observe(
+                float(chunk.context_valid_mask[:live].sum())
+                / (padded_rows * m))
+        with _stage("device"):
+            arrays = device_put_batch(padded, self.mesh)
+            out = self._call_predict_step(step, arrays)
+            topk_idx = np.asarray(out.topk_indices)[:n]
+            topk_val = np.asarray(out.topk_values)[:n]
+            code_vectors = np.asarray(out.code_vectors)[:n]
+            attention = np.asarray(out.attention)[:n]
+        with _stage("render"):
+            return self._render_predictions(
+                chunk, n, m, topk_idx, topk_val, code_vectors, attention,
+                with_code_vectors)
+
+    def _render_predictions(self, chunk, n: int, m: int, topk_idx,
+                            topk_val, code_vectors, attention,
+                            with_code_vectors: bool
+                            ) -> List[ModelPredictionResults]:
         results: List[ModelPredictionResults] = []
-        topk_idx = np.asarray(out.topk_indices)[:n]
-        topk_val = np.asarray(out.topk_values)[:n]
-        code_vectors = np.asarray(out.code_vectors)[:n]
-        attention = np.asarray(out.attention)[:n]
         # normalize_scores=True in the reference predict graph
         # (tensorflow_model.py:321): softmax over the k values.
         e = np.exp(topk_val - topk_val.max(axis=1, keepdims=True))
@@ -461,7 +503,8 @@ class Code2VecModel(BucketedPredictMixin):
             self.log(f"    {name}: {value}")
         if not config.release:
             self._init_num_of_examples()
-        self.vocabs = Code2VecVocabs.load_or_create(config)
+        with obs.startup_phase("vocab_load"):
+            self.vocabs = Code2VecVocabs.load_or_create(config)
         self.dims = ModelDims.from_config_and_vocabs(config, self.vocabs)
         self.mesh = (make_mesh(MeshPlan.from_config(config))
                      if config.mesh_size > 1 else None)
@@ -470,9 +513,13 @@ class Code2VecModel(BucketedPredictMixin):
             dropout_keep_rate=config.dropout_keep_rate,
             compute_dtype=jnp.dtype(config.compute_dtype))
         self.optimizer = make_optimizer(config)
-        self.state = create_train_state(
-            self.module, self.optimizer, jax.random.PRNGKey(config.seed),
-            mesh=self.mesh, config=config)
+        # timed until the arrays are there (init is dispatched async);
+        # built even when a restore below replaces it
+        with obs.startup_phase("state_init"):
+            self.state = jax.block_until_ready(create_train_state(
+                self.module, self.optimizer,
+                jax.random.PRNGKey(config.seed),
+                mesh=self.mesh, config=config))
         self.builder = TrainStepBuilder(self.module, self.optimizer, config,
                                         mesh=self.mesh)
         # Epoch numbering continues from the loaded artifact on resume
@@ -486,10 +533,10 @@ class Code2VecModel(BucketedPredictMixin):
             # export likewise only reads the params.
             params_only = config.release or bool(config.export_artifact_path)
             report: Dict = {}
-            self.state = ckpt_mod.load_model(config.model_load_path,
-                                             self.state, config=config,
-                                             params_only=params_only,
-                                             report=report)
+            with obs.startup_phase("restore"):
+                self.state = jax.block_until_ready(ckpt_mod.load_model(
+                    config.model_load_path, self.state, config=config,
+                    params_only=params_only, report=report))
             meta = ckpt_mod.load_model_meta(config.model_load_path)
             self.initial_epoch = int(meta.get("epoch", 0))
             mode = report.get("resume_mode", "exact")
@@ -547,6 +594,20 @@ class Code2VecModel(BucketedPredictMixin):
 
     def describe_devices(self) -> str:
         return describe_devices(self.state.params)
+
+    def warmup(self, rows: Optional[int] = None) -> None:
+        """Build + run every (rows, bucket) serve shape once on an
+        all-padding batch (the contract of `ReleaseModel.warmup`):
+        `serve` calls it before it listens, so no request pays a
+        bucket's compile out of its deadline."""
+        from code2vec_tpu.data.reader import slice_contexts
+        rows = int(rows or self.config.serve_batch_size)
+        empty = self.alloc_predict_batch(rows)
+        for m in self.context_buckets:
+            step, _, _ = self._dispatch_predict_step(rows, rows, m)
+            out = self._call_predict_step(step, device_put_batch(
+                slice_contexts(empty, m), self.mesh))
+            jax.block_until_ready(out.topk_indices)
 
     # ------------------------------------------------------------ data
 

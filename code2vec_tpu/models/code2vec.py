@@ -13,6 +13,12 @@ Parameter shapes and initializers follow tensorflow_model.py:204-219 and
 TRANSFORM/ATTENTION use TF's get_variable default (glorot_uniform).
 Parameters are float32; matmuls run in `compute_dtype` (bfloat16 on the
 MXU) with float32 accumulation.
+
+`jax.named_scope`s name the step's parts for the profiler's op view:
+`embed_gather`, `transform`, `attention` (ops/attention.py), `logits_ce`
+(with the loss in training/step.py); a backward op carries its forward
+scope as `transpose(jvp(<scope>))`. Scopes are metadata: the compiled
+program does not change (tests/test_model.py).
 """
 
 from __future__ import annotations
@@ -136,12 +142,16 @@ class Code2VecModule(nn.Module):
 
         reference: tensorflow_model.py:237-251.
         """
-        src = jnp.take(self.token_embedding, source_token_indices, axis=0)
-        pth = jnp.take(self.path_embedding, path_indices, axis=0)
-        tgt = jnp.take(self.token_embedding, target_token_indices, axis=0)
+        with jax.named_scope("embed_gather"):
+            src = jnp.take(self.token_embedding, source_token_indices,
+                           axis=0)
+            pth = jnp.take(self.path_embedding, path_indices, axis=0)
+            tgt = jnp.take(self.token_embedding, target_token_indices,
+                           axis=0)
         return self.transform_gathered(src, pth, tgt,
                                        deterministic=deterministic)
 
+    @jax.named_scope("transform")
     def transform_gathered(
         self,
         source_rows: jax.Array,            # (B, M, token_dim) f32
@@ -193,6 +203,7 @@ class Code2VecModule(nn.Module):
             axis_name=self.context_axis_name)
         return code_vectors.astype(jnp.float32), attention
 
+    @jax.named_scope("logits_ce")
     def logits_from_code_vectors(self, code_vectors: jax.Array) -> jax.Array:
         """(B, target_vocab) float32 — the replicated (non-TP) classifier.
 

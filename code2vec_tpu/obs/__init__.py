@@ -12,15 +12,19 @@ One import surface for every instrumented layer:
 - Metrics (`obs.metrics`): process-wide registry of counters/gauges/
   fixed-bucket histograms; Prometheus text + TB scalar export.
 - Tracing (`obs.tracer`): `span(name)` wall-time spans into a ring
-  buffer; Chrome trace-event JSON export (Perfetto-loadable),
-  complementing the device-side `jax.profiler` trace.
+  buffer; Chrome trace-event JSON export (Perfetto-loadable). Where
+  jax is imported the same span is also the annotation `c2v.<name>`
+  in a running `jax.profiler` trace, on the device trace's clock, and
+  jax's compile events feed `jax_compile_seconds` (and, for a span
+  that asks, `jax_compiles_during`).
 - Exporters (`obs.exporters`): atomic Prometheus snapshot file
   (`--metrics_file`), localhost HTTP `/metrics` (`--metrics_port`),
   atomic JSON heartbeat (`--heartbeat_file`), and a dump of every
   registered metric into TensorBoard at log boundaries.
 
-Everything is stdlib-only and safe to import from any layer (no jax, no
-circular deps): the data-reader worker threads, the checkpoint commit
+Everything is stdlib-only and safe to import from any layer (jax is
+never imported from here, only used where it already is; no circular
+deps): the data-reader worker threads, the checkpoint commit
 path, and the serving bridge all record into the same registry.
 """
 
@@ -33,12 +37,16 @@ from code2vec_tpu.obs.metrics import (
     default_registry,
 )
 from code2vec_tpu.obs.reqtrace import RequestTrace
-from code2vec_tpu.obs.tracer import SpanTracer, default_tracer, span
+from code2vec_tpu.obs.tracer import (
+    SpanTracer, compiles_during, default_tracer, log_compiles_from_now, span,
+    startup_phase,
+)
 
 __all__ = [
     "Counter", "FlightRecorder", "Gauge", "Histogram", "MetricsRegistry",
     "RequestTrace", "SpanTracer",
     "DEFAULT_BUCKETS", "counter", "gauge", "histogram", "span",
+    "startup_phase", "log_compiles_from_now", "compiles_during",
     "default_registry", "default_flight_recorder", "default_tracer",
     "exporters", "flight", "metrics", "reqtrace", "tracer",
 ]

@@ -25,6 +25,7 @@ import jax
 import jax.numpy as jnp
 
 
+@jax.named_scope("attention")
 def masked_single_query_attention(
     transformed: jax.Array,       # (B, M_local, D) already tanh(ctx @ W)
     attention_param: jax.Array,   # (D,)

@@ -73,6 +73,7 @@ def _fold_lse(run_max: jax.Array, run_sum: jax.Array,
     return new_max, run_sum
 
 
+@jax.named_scope("head_topk")
 def blockwise_top_k_from_logits(logits: jax.Array, k: int,
                                 block_cols: int
                                 ) -> Tuple[jax.Array, jax.Array]:
@@ -97,6 +98,7 @@ def blockwise_top_k_from_logits(logits: jax.Array, k: int,
     return vals, idx
 
 
+@jax.named_scope("head_topk")
 def blockwise_matmul_top_k(
     code_vectors: jax.Array,          # (B, D) f32
     target_table: jax.Array,          # (V, D) f32 — or int8 with `scales`

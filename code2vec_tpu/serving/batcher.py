@@ -51,6 +51,7 @@ from concurrent.futures import Future
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 from code2vec_tpu import obs
+from code2vec_tpu.obs import reqtrace, tracer
 from code2vec_tpu.serving.admission import (
     Deadline, DeadlineExceeded, DeadlineInfeasible, expired_counter,
 )
@@ -59,16 +60,30 @@ _H_BATCH_ROWS = obs.histogram(
     "serving_batch_rows",
     "rows per dispatched device batch (coalescing effectiveness)",
     buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256, 512, 1024))
-_H_BATCH_WAIT = obs.histogram(
-    "serving_batch_wait_seconds",
-    "request submit to device-batch dispatch (coalescing delay)")
 _H_DEVICE = obs.histogram(
     "serving_device_seconds",
     "one coalesced model call: parse + pad + device step + unpack")
+_DISPATCHER_HELP = (
+    "time a dispatcher thread spent in one state, observed on leaving "
+    "it: idle (nothing pending), delay (work pending: waiting out the "
+    "window, for rows or for a parse), dispatch (inside the coalesced "
+    "model call and its fan-out). dispatch over wall time is the busy "
+    "share of the thread every request passes through")
+_H_STATE = {state: obs.histogram("serving_dispatcher_seconds",
+                                 _DISPATCHER_HELP, state=state)
+            for state in ("idle", "delay", "dispatch")}
+
+
+_H_COMPILES = obs.compiles_during("serve.dispatch")
+
+
+def _state(state: str):
+    return obs.span("serve." + state, hist=_H_STATE[state],
+                    compiles=_H_COMPILES if state == "dispatch" else None)
+
+
 _C_BATCHES = obs.counter("serving_batches_total",
                          "device batches dispatched by the batcher")
-_C_ROWS = obs.counter("serving_batch_rows_total",
-                      "method rows pushed through the batcher")
 _G_INFLIGHT = obs.gauge(
     "serving_batch_inflight_steps",
     "device steps currently in flight (continuous batching)")
@@ -282,7 +297,8 @@ class DynamicBatcher:
             batch = self._collect()
             if batch is None:
                 return
-            self._dispatch(batch)
+            with _state("dispatch"):
+                self._dispatch(batch)
 
     def _collect(self) -> Optional[List[_Pending]]:
         """Block until a batch is due: rows >= cap, oldest item older
@@ -313,12 +329,14 @@ class DynamicBatcher:
                         wait = min(wait, remaining - p95, remaining)
                     if wait <= 0:
                         return self._take_locked()
-                    self._cond.wait(timeout=wait)
+                    with _state("delay"):
+                        self._cond.wait(timeout=wait)
                 elif self._draining:
                     self._closed = True
                     return None
                 else:
-                    self._cond.wait()
+                    with _state("idle"):
+                        self._cond.wait()
 
     def _expire_locked(self) -> None:
         alive: List[_Pending] = []
@@ -381,7 +399,6 @@ class DynamicBatcher:
         all_lines: List[str] = []
         for item in batch:
             wait = t_dispatch - item.t_submit
-            _H_BATCH_WAIT.observe(wait)
             if item.phases is not None:
                 item.phases["batch_wait"] = wait
             if item.trace is not None:
@@ -390,10 +407,10 @@ class DynamicBatcher:
         _C_BATCHES.inc()
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
-        _C_ROWS.inc(len(all_lines))
         _H_BATCH_ROWS.observe(len(all_lines))
         try:
-            results = self.predict_fn(all_lines)
+            with tracer.collect() as stages:
+                results = self.predict_fn(all_lines)
             if len(results) != len(all_lines):
                 raise RuntimeError(
                     f"predict_fn returned {len(results)} results for "
@@ -412,7 +429,7 @@ class DynamicBatcher:
                             if i.bucket is not None), default=None)
         self.device_times.record(batch_bucket, dur)
         self._record_batch_spans(batch, batch_id, batch_bucket,
-                                 len(all_lines), t_dispatch, dur)
+                                 len(all_lines), t_dispatch, dur, stages)
         off = 0
         for item in batch:
             n = len(item.lines)
@@ -424,25 +441,30 @@ class DynamicBatcher:
 
     def _record_batch_spans(self, batch: List[_Pending], batch_id: int,
                             bucket: Optional[int], rows: int,
-                            t_dispatch: float, dur: float) -> None:
+                            t_dispatch: float, dur: float,
+                            stages=()) -> None:
         _record_batch_spans(batch, batch_id, bucket, rows, t_dispatch,
-                            dur)
+                            dur, stages)
 
 
 def _record_batch_spans(batch: List[_Pending], batch_id: int,
                         bucket: Optional[int], rows: int,
-                        t_dispatch: float, dur: float) -> None:
+                        t_dispatch: float, dur: float,
+                        stages=()) -> None:
     """Fan the coalesced device call into the member traces: ONE
     shared batch span id is stamped into every member request's
     trace (the batch node N request trees share), each member's
-    `device` span hangs under it, and the process tracer records the
-    batch exactly once — tagged with every member trace id so the
-    bulk Chrome trace links batch to requests."""
+    `device` span hangs under it with the model call's stages
+    (`stages`: what `tracer.collect` gathered around the call, the
+    facade's predict.parse / .assemble / .device / .render) as its
+    children, and the process tracer records the batch exactly once —
+    tagged with every member trace id so the bulk Chrome trace links
+    batch to requests."""
     traced = [item for item in batch if item.trace is not None]
     if not traced:
         return
-    from code2vec_tpu.obs import reqtrace, tracer
     batch_span_id = reqtrace.mint_span_id()
+    device_span_id = reqtrace.mint_span_id() if stages else None
     members = [item.trace.trace_id for item in traced]
     attrs = {"batch_id": batch_id, "rows": rows,
              "requests": len(batch)}
@@ -460,7 +482,12 @@ def _record_batch_spans(batch: List[_Pending], batch_id: int,
                             attrs=span_attrs,
                             forward=False)
         item.trace.add_span("device", t_dispatch, dur,
+                            span_id=device_span_id,
                             parent_id=batch_span_id)
+        for name, start, seconds in stages:
+            # the ring already has each stage once, from the span itself
+            item.trace.add_span(name, start, seconds,
+                                parent_id=device_span_id, forward=False)
     tracer.default_tracer().maybe_record(
         "serving_batch", t_dispatch, dur, span_id=batch_span_id,
         attrs=dict(attrs, member_trace_ids=members))
@@ -785,7 +812,8 @@ class ContinuousBatcher:
             if slot is None:
                 return
             try:
-                self._run_slot(slot)
+                with _state("dispatch"):
+                    self._run_slot(slot)
             finally:
                 self._release_buffer(slot.buffer, slot.rows)
                 with self._cond:
@@ -803,7 +831,8 @@ class ContinuousBatcher:
                 if slot is None:
                     if self._draining:
                         return None
-                    self._cond.wait()
+                    with _state("idle"):
+                        self._cond.wait()
                     continue
                 self._expire_head_locked(slot)
                 if all(i.settled for i in slot.items) \
@@ -823,7 +852,8 @@ class ContinuousBatcher:
                         [time.perf_counter(), bucket, slot])
                     _G_INFLIGHT.set(self._inflight)
                     return slot
-                self._cond.wait(timeout=wait if wait > 0 else None)
+                with _state("delay"):
+                    self._cond.wait(timeout=wait if wait > 0 else None)
 
     def _release_buffer_nolock_queue(self, slot: _Slot) -> None:
         # called with the lock held for a fully-expired slot: return
@@ -842,7 +872,6 @@ class ContinuousBatcher:
             return
         for item in live:
             wait = t_dispatch - item.t_submit
-            _H_BATCH_WAIT.observe(wait)
             if item.phases is not None:
                 item.phases["batch_wait"] = wait
             if item.trace is not None:
@@ -851,34 +880,34 @@ class ContinuousBatcher:
         _C_BATCHES.inc()
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
-        _C_ROWS.inc(rows_live)
         _H_BATCH_ROWS.observe(rows_live)
         use_rows = slot.kind == "rows" and len(slot.fps) == 1
         try:
-            if use_rows:
-                try:
-                    results = self.backend.predict_rows(
-                        slot.buffer, slot.rows, next(iter(slot.fps)))
-                except StaleParse:
-                    use_rows = False
-                else:
-                    if len(results) < slot.rows:
+            with tracer.collect() as stages:
+                if use_rows:
+                    try:
+                        results = self.backend.predict_rows(
+                            slot.buffer, slot.rows, next(iter(slot.fps)))
+                    except StaleParse:
+                        use_rows = False
+                    else:
+                        if len(results) < slot.rows:
+                            raise RuntimeError(
+                                f"predict_rows returned {len(results)} "
+                                f"results for {slot.rows} rows")
+                if not use_rows:
+                    # lines fallback: plain lines slot, a rows slot that
+                    # straddled a hot-swap (mixed parse fingerprints or
+                    # StaleParse), — re-parse under the CURRENT model so
+                    # the batch answers with one fingerprint
+                    all_lines = [l for i in live for l in i.lines]
+                    fn = (self.backend.predict_lines
+                          if self.backend is not None else self.predict_fn)
+                    results = fn(all_lines)
+                    if len(results) != len(all_lines):
                         raise RuntimeError(
-                            f"predict_rows returned {len(results)} "
-                            f"results for {slot.rows} rows")
-            if not use_rows:
-                # lines fallback: plain lines slot, a rows slot that
-                # straddled a hot-swap (mixed parse fingerprints or
-                # StaleParse), — re-parse under the CURRENT model so
-                # the batch answers with one fingerprint
-                all_lines = [l for i in live for l in i.lines]
-                fn = (self.backend.predict_lines
-                      if self.backend is not None else self.predict_fn)
-                results = fn(all_lines)
-                if len(results) != len(all_lines):
-                    raise RuntimeError(
-                        f"predict_fn returned {len(results)} results "
-                        f"for {len(all_lines)} lines")
+                            f"predict_fn returned {len(results)} results "
+                            f"for {len(all_lines)} lines")
         except BaseException as e:  # noqa: BLE001 — futures must settle
             for item in live:
                 if item.future.set_running_or_notify_cancel():
@@ -890,7 +919,7 @@ class ContinuousBatcher:
                             if i.bucket is not None), default=None)
         self.device_times.record(batch_bucket, dur)
         _record_batch_spans(live, batch_id, batch_bucket, rows_live,
-                            t_dispatch, dur)
+                            t_dispatch, dur, stages)
         if use_rows:
             for (off, n), item in zip(slot.offsets, slot.items):
                 if item.settled:

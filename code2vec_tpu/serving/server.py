@@ -1297,7 +1297,16 @@ def serve_main(config, model=None, *, stop: Optional[threading.Event]
         # live `fleet trace` stitching and a crash both see recent
         # spans) and finally at shutdown
         obs.default_tracer().enable()
+    warmup = getattr(model, "warmup", None)
+    if warmup is not None:
+        # every predict bucket compiled (or loaded from the compile
+        # cache) and run once BEFORE the port opens: a cold bucket's
+        # compile outlasts the default request deadline
+        with obs.startup_phase("serve_warm") as warm:
+            warmup()
+        config.log(f"Predict buckets warmed in {warm.seconds:.2f}s")
     server.start()
+    obs.log_compiles_from_now(config.log)
 
     hb_stop = threading.Event()
 
@@ -1337,6 +1346,7 @@ def serve_main(config, model=None, *, stop: Optional[threading.Event]
     finally:
         clean = server.drain()
         hb_stop.set()
+        obs.log_compiles_from_now(None)
         if install_signals:
             signal.signal(signal.SIGTERM, prev_term)
             signal.signal(signal.SIGINT, prev_int)

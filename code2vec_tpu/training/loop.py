@@ -204,6 +204,7 @@ class Trainer:
             "train_step_dispatch_seconds",
             "host-side dispatch of the jitted train step (async: device "
             "execution overlaps; sync time is train_loss_sync_seconds)")
+        h_compiles = obs.compiles_during("step_dispatch")
         h_loss_sync = reg.histogram(
             "train_loss_sync_seconds",
             "blocking device fetch of a window's losses")
@@ -377,11 +378,9 @@ class Trainer:
             nonlocal pending_losses, last_avg_loss, trace_active
             if not pending_losses:
                 return np.empty((0,)), 0.0
-            t0 = time.perf_counter()
-            fetched = jax.device_get(pending_losses)
-            sync_s = time.perf_counter() - t0
-            h_loss_sync.observe(sync_s)
-            tracer.maybe_record("loss_sync", t0, sync_s)
+            with obs.span("loss_sync", hist=h_loss_sync) as sync:
+                fetched = jax.device_get(pending_losses)
+            sync_s = sync.seconds
             pending_losses = []
             losses = np.asarray(fetched, dtype=np.float64)
             last_avg_loss = float(losses.mean())
@@ -427,12 +426,14 @@ class Trainer:
         try:
             batch_iter = iter(prefetcher)
             while True:
-                t_wait = time.perf_counter()
+                # the histogram takes a wait only once it turned out to
+                # be a batch's (below): input_wait reads it
                 try:
-                    item = next(batch_iter)
+                    with obs.span("data_wait") as wait:
+                        item = next(batch_iter)
                 except StopIteration:
                     break
-                wait_s = time.perf_counter() - t_wait
+                wait_s = wait.seconds
                 if isinstance(item, EpochEnd):
                     # Per-batch sentinel over the partial window the epoch
                     # boundary is about to discard (see drain_losses).
@@ -486,21 +487,31 @@ class Trainer:
                 batches_since_eval += 1
                 h_data_wait.observe(wait_s)
                 win_data_wait += wait_s
-                tracer.maybe_record("data_wait", t_wait, wait_s)
                 if self.profile_dir and batch_num == 10:
                     jax.profiler.start_trace(self.profile_dir)
+                    tracer.mark_profiler_start()
                     trace_active = True
-                t_disp = time.perf_counter()
-                state, loss = self.train_step(state, *arrays, rng)
-                disp_s = time.perf_counter() - t_disp
-                h_dispatch.observe(disp_s)
-                win_dispatch += disp_s
-                tracer.maybe_record("step_dispatch", t_disp, disp_s)
                 if batch_num == 1:
-                    log(f"First train step dispatched in {disp_s:.2f}s "
-                        f"(trace + compile, or a compile-cache load); "
+                    # start-up's last phase: the call (trace, lower,
+                    # compile or cache load) until its result is ready
+                    with obs.startup_phase("first_step") as first:
+                        with obs.span("step_dispatch", hist=h_dispatch,
+                                      compiles=h_compiles) as disp:
+                            state, loss = self.train_step(state, *arrays,
+                                                          rng)
+                        jax.block_until_ready(loss)
+                    log(f"First train step dispatched in "
+                        f"{disp.seconds:.2f}s (trace + compile, or a "
+                        f"compile-cache load), ready after "
+                        f"{first.seconds:.2f}s; "
                         f"batch {tuple(arrays[0].shape)}: "
                         f"{shard_layout(arrays[0])}")
+                    obs.log_compiles_from_now(log)
+                else:
+                    with obs.span("step_dispatch", hist=h_dispatch,
+                                  compiles=h_compiles) as disp:
+                        state, loss = self.train_step(state, *arrays, rng)
+                win_dispatch += disp.seconds
                 c_batches.inc()
                 pending_losses.append(loss)
                 if preemption_agreed(batch_num):
@@ -573,15 +584,6 @@ class Trainer:
                     g_throughput.set(throughput)
                     g_epoch.set(epoch)
                     g_rss.set(current_rss_bytes())
-                    reg.gauge("train_window_data_wait_seconds",
-                              "data wait total over the last log window"
-                              ).set(win_data_wait)
-                    reg.gauge("train_window_dispatch_seconds",
-                              "dispatch total over the last log window"
-                              ).set(win_dispatch)
-                    reg.gauge("train_window_loss_sync_seconds",
-                              "loss sync at the last log boundary"
-                              ).set(sync_s)
                     # "Is the step loop input-bound at N hosts?" as ONE
                     # number: the share of the window's wall time the
                     # host spent blocked waiting for input. ~0 = device-
@@ -633,6 +635,7 @@ class Trainer:
                 trace_active = False
             if watcher is not None:
                 watcher.uninstall()
+            obs.log_compiles_from_now(None)
             # Flush+close the TB event file HERE, not after the loop: a
             # crash (or the NaN-halt raise) must not lose the tail of the
             # event stream. Same for the final heartbeat/snapshot — the

@@ -7,7 +7,6 @@ from __future__ import annotations
 
 import queue
 import threading
-import time
 from typing import Iterable, Iterator, Optional
 
 from code2vec_tpu import obs
@@ -21,12 +20,19 @@ from code2vec_tpu.training.step import (
 _H_PACK = obs.histogram(
     "prefetch_pack_seconds",
     "host packing of one batch's fused transfer buffer (worker thread)")
+_H_READ = obs.histogram(
+    "prefetch_read_seconds",
+    "the worker's next() on the reader for one batch: packed-corpus "
+    "slice, shuffle, filter (worker thread)")
+_H_BUSY = obs.histogram(
+    "prefetch_busy_seconds",
+    "read + pack of one batch: what the one worker thread does apart "
+    "from waiting on a full queue; its sum over wall time is the "
+    "feed's busy share (at 1 the step starts to wait)")
 _H_DEVICE_PUT = obs.histogram(
     "prefetch_device_put_seconds",
     "host-side cost of dispatching one batch's device transfer "
     "(consumer thread; the transfer itself is async)")
-_C_BATCHES = obs.counter("prefetch_batches_total",
-                         "batches staged by the prefetch worker")
 _G_DEPTH = obs.gauge(
     "prefetch_queue_depth",
     "ready batches queued ahead of the consumer at its last take "
@@ -80,24 +86,32 @@ class DevicePrefetcher:
     def _worker(self):
         try:
             pack = fused_path_applies(self.mesh)
-            for batch in self.batches:
+            batches = iter(self.batches)
+            while True:
+                try:
+                    with obs.span("prefetch_read") as read:
+                        batch = next(batches)
+                except StopIteration:
+                    break
                 if isinstance(batch, EpochEnd):
                     item = batch
-                elif pack:
-                    # the packed buffer is all the consumer needs unless
-                    # it asked for the host batch too — don't pin both
-                    t0 = time.perf_counter()
-                    packed = pack_batch_host(batch)
-                    dur = time.perf_counter() - t0
-                    _H_PACK.observe(dur)
-                    obs.default_tracer().maybe_record("prefetch_pack",
-                                                      t0, dur)
-                    _C_BATCHES.inc()
-                    item = (batch if self.keep_host_batch else None,
-                            packed)
                 else:
-                    _C_BATCHES.inc()
-                    item = (batch, None)
+                    # an epoch marker's read is no batch's: only these
+                    # count (one observation a batch)
+                    _H_READ.observe(read.seconds)
+                    busy = read.seconds
+                    packed = None
+                    if pack:
+                        # the packed buffer is all the consumer needs
+                        # unless it asked for the host batch too —
+                        # don't pin both
+                        with obs.span("prefetch_pack",
+                                      hist=_H_PACK) as packing:
+                            packed = pack_batch_host(batch)
+                        busy += packing.seconds
+                    _H_BUSY.observe(busy)
+                    item = (batch if self.keep_host_batch or not pack
+                            else None, packed)
                 if not self._put(item):
                     return
         except BaseException as e:  # propagate to consumer
@@ -133,12 +147,9 @@ class DevicePrefetcher:
                     continue
                 _G_DEPTH.set(self._queue.qsize())
                 batch, packed = item
-                t0 = time.perf_counter()
-                arrays = device_put_batch(batch, self.mesh, packed=packed)
-                dur = time.perf_counter() - t0
-                _H_DEVICE_PUT.observe(dur)
-                obs.default_tracer().maybe_record("prefetch_device_put",
-                                                  t0, dur)
+                with obs.span("prefetch_device_put", hist=_H_DEVICE_PUT):
+                    arrays = device_put_batch(batch, self.mesh,
+                                              packed=packed)
                 staged = (arrays, batch if self.keep_host_batch else None)
                 if not self.double_buffer:
                     yield staged

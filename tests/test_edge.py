@@ -403,10 +403,15 @@ def test_shared_fleet_view_derives_candidates_and_view():
         assert fleet["role"] == "fleet-router"
         assert fleet["router"] == "router-7"
         assert fleet["view_age_s"] is not None
-        # metrics re-merge: the listener's counter survives alongside
-        # this process's own registry
+        # metrics re-merge: the listener's counter (2) survives
+        # alongside this process's own registry, whose own count of the
+        # same series (left by whichever tests ran here before) adds
+        from code2vec_tpu import obs
+        own = obs.default_registry().collect().get(
+            "fleet_swap_total", {}).get((("outcome", "committed"),))
         merged = view.merged_fleet_metrics()
-        assert 'fleet_swap_total{outcome="committed"} 2' in merged
+        assert ('fleet_swap_total{outcome="committed"} '
+                f'{2 + int(own.value if own else 0)}') in merged
         with pytest.raises(ValueError):
             SharedFleetView(_router_test_config(), "no-port", "r",
                             log=lambda m: None)

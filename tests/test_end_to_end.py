@@ -10,6 +10,7 @@ import random
 import numpy as np
 import pytest
 
+from code2vec_tpu import obs
 from code2vec_tpu.config import Config
 from code2vec_tpu.model_facade import Code2VecModel
 from code2vec_tpu.vocab import VocabType
@@ -125,6 +126,51 @@ def test_train_eval_save_load_predict(tmp_path, use_packed):
     assert ("tok0", "path0", "tok0") in preds[0].attention_per_context
     # name|alpha should be the top prediction for tok0/tok1 contexts
     assert preds[0].topk_predicted_words[0] == "name|alpha"
+
+
+def test_startup_phases_are_each_timed_once(tmp_path):
+    """`startup_phase_seconds{phase}`: a `Code2VecModel` start times its
+    dictionary load and its initial state once each, the trainer its
+    first step once (call until the result is ready), and `restore`
+    exists only under --load, where the initial state is still built."""
+    prefix = _make_synthetic_dataset(tmp_path, n_rows=48)
+    save_path = str(tmp_path / "model" / "saved_model")
+    common = dict(max_contexts=8, compute_dtype="float32", verbose_mode=0)
+
+    def phases():
+        names = [e["name"] for e in tracer.chrome_trace()["traceEvents"]
+                 if e["name"].startswith("startup.")]
+        return {n[len("startup."):]: names.count(n) for n in set(names)}
+
+    def seconds(phase):
+        return obs.gauge("startup_phase_seconds", phase=phase).value
+
+    tracer = obs.default_tracer()
+    tracer.clear()
+    tracer.enable()
+    try:
+        obs.gauge("startup_phase_seconds", phase="restore").set(0.0)
+        model = Code2VecModel(Config(
+            train_data_path_prefix=prefix, model_save_path=save_path,
+            train_batch_size=16, num_train_epochs=2,
+            num_batches_to_log_progress=1000, save_every_epochs=1000,
+            **common))
+        assert phases() == {"vocab_load": 1, "state_init": 1}
+        model.train()
+        assert phases() == {"vocab_load": 1, "state_init": 1,
+                            "first_step": 1}
+        assert seconds("restore") == 0.0
+        for phase in ("vocab_load", "state_init", "first_step"):
+            assert seconds(phase) > 0.0, phase
+        Code2VecModel(Config(model_load_path=save_path,
+                             test_data_path=prefix + ".val.c2v",
+                             test_batch_size=16, **common))
+        assert phases() == {"vocab_load": 2, "state_init": 2,
+                            "first_step": 1, "restore": 1}
+        assert seconds("restore") > 0.0
+    finally:
+        tracer.disable()
+        tracer.clear()
 
 
 def test_release_roundtrip(tmp_path):

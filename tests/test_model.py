@@ -120,3 +120,93 @@ def test_padded_to_rounds_up():
     assert (p.token_vocab_size, p.path_vocab_size, p.target_vocab_size) == (12, 12, 8)
     assert p.real_target_vocab_size == 7
     assert p.has_padded_targets
+
+
+# ------------------------------------------------- scope names in the step
+
+_SCOPES = {
+    False: ("embed_gather", "transform", "attention", "logits_ce",
+            "transpose(jvp(Code2VecModule))", "adam_token", "adam_path",
+            "adam_target", "adam_dense"),
+    # the touched-rows step gathers outside the differentiated function
+    # and updates the two tables row-wise under the same two names
+    True: ("embed_gather", "transform", "attention", "logits_ce",
+           "adam_token", "adam_path", "adam_dense"),
+}
+
+
+def _lower_toy_train_step(sparse: bool):
+    from code2vec_tpu.config import Config
+    from code2vec_tpu.training.state import (
+        create_train_state, make_optimizer,
+    )
+    from code2vec_tpu.training.step import TrainStepBuilder
+    b, m = 8, 8
+    dims = ModelDims(token_vocab_size=64, path_vocab_size=32,
+                     target_vocab_size=32, token_dim=16, path_dim=16)
+    config = Config(train_data_path_prefix="unused", train_batch_size=b,
+                    max_contexts=m, use_sparse_embedding_update=sparse)
+    module = Code2VecModule(dims=dims)
+    optimizer = make_optimizer(config)
+    state = create_train_state(module, optimizer, jax.random.PRNGKey(0),
+                               config=config)
+    step = TrainStepBuilder(module, optimizer, config).make_train_step(state)
+    ids = np.arange(b * m, dtype=np.int32).reshape(b, m) % 16
+    return step.lower(state, ids, ids, ids, np.ones((b, m), np.float32),
+                      np.ones((b,), np.int32), np.ones((b,), bool),
+                      jax.random.PRNGKey(1))
+
+
+@pytest.mark.parametrize("sparse", [False, True])
+def test_scope_names_are_in_the_step_and_change_nothing_else(monkeypatch,
+                                                             sparse):
+    """`jax.named_scope` names every part of the train step for the
+    profiler's op view and is metadata only: the same step lowered with
+    every scope switched off is the same program once locations are
+    stripped (which is also what the compile-cache key is taken from)."""
+    from jax._src import source_info_util
+    scoped = _lower_toy_train_step(sparse)
+    named = scoped.as_text(debug_info=True)
+    for scope in _SCOPES[sparse]:
+        assert scope in named, scope
+    # the table-shaped gradient scatter is the gather's own transpose:
+    # no backward is written by hand, it carries the forward's scope
+    if not sparse:
+        assert any("transpose(jvp(Code2VecModule))" in line
+                   and "embed_gather" in line
+                   for line in named.splitlines())
+    manager = source_info_util.ExtendNameStackContextManager
+    monkeypatch.setattr(manager, "__enter__", lambda self: None)
+    monkeypatch.setattr(manager, "__exit__", lambda self, *exc: None)
+    bare = _lower_toy_train_step(sparse)
+    assert "adam_token" not in bare.as_text(debug_info=True)
+    assert bare.as_text() == scoped.as_text()
+
+
+def test_scoped_adam_update_is_one_update_of_the_whole_tree():
+    """Adam run once per table under its own scope gives bit for bit
+    what one `optimizer.update` over the whole tree gives, state
+    structure included (a checkpoint restores either way)."""
+    import optax
+    from code2vec_tpu.training.step import scoped_adam_update
+    rng = np.random.default_rng(0)
+    shapes = {"token_embedding": (6, 4), "path_embedding": (5, 4),
+              "target_embedding": (7, 12), "transform": (12, 12),
+              "attention": (12, 1)}
+    params = {k: jnp.asarray(rng.normal(size=s), jnp.float32)
+              for k, s in shapes.items()}
+    optimizer = optax.adam(1e-2, mu_dtype=jnp.bfloat16)
+    whole_state, split_state = optimizer.init(params), optimizer.init(params)
+    whole, split = params, params
+    for _ in range(3):
+        grads = {k: jnp.asarray(rng.normal(size=s), jnp.float32)
+                 for k, s in shapes.items()}
+        updates, whole_state = optimizer.update(grads, whole_state, whole)
+        whole = optax.apply_updates(whole, updates)
+        split, split_state = scoped_adam_update(optimizer, grads,
+                                                split_state, split)
+    assert jax.tree.structure(split_state) == jax.tree.structure(whole_state)
+    for a, b in zip(jax.tree.leaves((whole, whole_state)),
+                    jax.tree.leaves((split, split_state))):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

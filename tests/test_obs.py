@@ -160,6 +160,161 @@ def test_tracer_ring_buffer_bounded_and_exports_chrome_trace(tmp_path):
     assert doc["otherData"]["trace_epoch_unix_s"] > 0
 
 
+def test_span_has_three_sinks_and_each_only_when_it_is_on(tmp_path):
+    """One `obs.span`: the histogram always, the ring only when armed,
+    and (jax is imported in this process) the annotation `c2v.<name>`
+    in whatever profiler session runs, on the session's own clock."""
+    import glob
+    import jax
+    reg = MetricsRegistry()
+    tracer = SpanTracer()
+    h = reg.histogram("three_seconds", buckets=(10.0,))
+    with span("three_sinks", hist=h, tracer=tracer):
+        pass
+    assert h.count == 1 and len(tracer) == 0
+    tracer.enable()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 1       # what the benchmark's serve trace uses
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    tracer.mark_profiler_start()
+    try:
+        with span("three_sinks", hist=h, tracer=tracer) as inside:
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    with span("three_sinks", hist=h, tracer=tracer):
+        pass                            # after the session: not in its file
+    assert h.count == 3 and len(tracer) == 2
+    [path] = glob.glob(str(tmp_path / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    data = jax.profiler.ProfileData.from_file(path)
+    found = [e for plane in data.planes for line in plane.lines
+             for e in line.events if e.name == "c2v.three_sinks"]
+    assert len(found) == 1
+    assert found[0].duration_ns == pytest.approx(inside.seconds * 1e9,
+                                                 rel=0.5, abs=2e5)
+    # the two files of one run can be laid over each other: the export
+    # says where on its axis the profiler session began
+    other = tracer.chrome_trace()["otherData"]
+    assert other["trace_epoch_perf_counter_s"] > 0
+    [session] = other["profiler_sessions"]
+    ring = [e for e in tracer.chrome_trace()["traceEvents"]
+            if e.get("name") == "three_sinks"]
+    assert 0 <= session["ts"] <= ring[0]["ts"]
+    assert abs(ring[0]["ts"] - session["ts"]
+               - found[0].start_ns / 1e3) < 5e4     # 50 ms, in us
+
+
+def test_host_worker_opens_a_span_and_imports_no_jax():
+    """Router agents and host workers run with C2V_HOST_WORKER=1 and
+    must stay jax-free: `obs` looks jax up in sys.modules only."""
+    import subprocess
+    import sys
+    code = (
+        "import sys\n"
+        "from code2vec_tpu import obs\n"
+        "from code2vec_tpu.obs import tracer\n"
+        "h = obs.histogram('worker_seconds')\n"
+        "obs.default_tracer().enable()\n"
+        "with tracer.collect() as got:\n"
+        "    with obs.span('in_worker', hist=h):\n"
+        "        pass\n"
+        "assert h.count == 1 and len(obs.default_tracer()) == 1\n"
+        "assert [g[0] for g in got] == ['in_worker']\n"
+        "assert tracer._ANNOTATION is None\n"
+        "print('jax' in sys.modules)\n")
+    env = dict(os.environ, C2V_HOST_WORKER="1",
+               PYTHONPATH=os.path.dirname(os.path.dirname(
+                   os.path.abspath(__file__))))
+    out = subprocess.run([sys.executable, "-c", code], env=env,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip() == "False"
+
+
+def test_compile_listener_counts_a_fresh_jit_once():
+    """`jax_compile_seconds{stage}`: one observation a compile event, so
+    the count inside a window is the number of compiles there; a second
+    call of the same shape adds nothing, another shape adds one."""
+    import jax
+    import jax.numpy as jnp
+    with span("registers_the_listener"):
+        pass
+    hist = {stage: obs.histogram("jax_compile_seconds", stage=stage)
+            for stage in ("trace", "lower", "backend")}
+    total = obs.counter("jax_compile_seconds_total")
+    lines = []
+    obs.log_compiles_from_now(lines.append)
+    try:
+        x = jnp.ones((3, 5))            # made before the counts are read
+        before = {k: h.count for k, h in hist.items()}
+        spent = total.value
+
+        @jax.jit
+        def fresh_for_the_listener(a):
+            return jnp.tanh(a) @ a.T    # jnp.tanh: a jit traced inside
+
+        fresh_for_the_listener(x).block_until_ready()
+        once = {k: h.count - before[k] for k, h in hist.items()}
+        assert once == {"trace": 1, "lower": 1, "backend": 1}
+        assert total.value > spent
+        fresh_for_the_listener(x + 1.0).block_until_ready()
+        assert hist["backend"].count - before["backend"] <= 2   # the add
+        seen = hist["backend"].count
+        fresh_for_the_listener(x).block_until_ready()
+        assert hist["backend"].count == seen
+        assert any("fresh_for_the_listener" in ln for ln in lines)
+        n_lines = len(lines)
+    finally:
+        obs.log_compiles_from_now(None)
+    fresh_for_the_listener(jnp.ones((2, 2))).block_until_ready()
+    assert len(lines) == n_lines        # start-up compiles are not logged
+
+
+def test_span_counts_the_compiles_inside_it():
+    """`jax_compiles_during{span}`: one observation a span, 1 around a
+    fresh jit and 0 around its second call, so a window's sum is its
+    compiles and a sound window still has something to read (0)."""
+    import jax
+    import jax.numpy as jnp
+    hist = obs.compiles_during("a_step_for_the_test")
+    x = jnp.ones((4, 3))
+
+    @jax.jit
+    def fresh_inside_a_span(a):
+        return a * 2.0 - 1.0
+
+    with span("a_step_for_the_test", compiles=hist):
+        fresh_inside_a_span(x).block_until_ready()
+    assert (hist.count, hist.sum) == (1, 1.0)
+    with span("a_step_for_the_test", compiles=hist):
+        fresh_inside_a_span(x).block_until_ready()
+    assert (hist.count, hist.sum) == (2, 1.0)
+
+
+def test_collect_hands_over_this_threads_spans_only():
+    from code2vec_tpu.obs import tracer as tracer_mod
+    done = threading.Event()
+
+    def elsewhere():
+        with span("other_thread"):
+            pass
+        done.set()
+    with tracer_mod.collect() as got:
+        with span("outer"):
+            with span("inner"):
+                pass
+        t = threading.Thread(target=elsewhere)
+        t.start()
+        t.join()
+    with span("after"):
+        pass
+    assert done.is_set()
+    assert [name for name, _, _ in got] == ["inner", "outer"]
+    assert all(seconds >= 0 and start > 0 for _, start, seconds in got)
+
+
 def test_span_records_on_exception():
     tracer = SpanTracer()
     tracer.enable()
@@ -531,6 +686,37 @@ def test_train_loop_emits_heartbeat_snapshot_tb_and_trace(tiny_config,
     assert "loss_sync" in names
 
     assert saves == [1]                # the loop itself behaved normally
+
+
+def test_prefetch_worker_times_its_read_and_its_busy_share():
+    """The feed's headroom: the worker observes `prefetch_read_seconds`
+    (its next() on the reader) and `prefetch_busy_seconds` (read + pack)
+    once a batch, not for an epoch marker and not while it waits on a
+    full queue."""
+    import time
+    from code2vec_tpu.utils.prefetch import DevicePrefetcher
+    hist = {n: obs.histogram(f"prefetch_{n}_seconds")
+            for n in ("read", "pack", "busy")}
+    before = {n: (h.count, h.sum) for n, h in hist.items()}
+
+    def slow_reader():
+        for _ in range(5):
+            time.sleep(0.01)
+            yield _fake_batch()
+        time.sleep(0.05)                # the marker's read is no batch's
+        yield EpochEnd(1)
+
+    got = []
+    for item in DevicePrefetcher(slow_reader(), mesh=None, depth=1):
+        time.sleep(0.02)                # a slow consumer: the queue fills
+        got.append(item)
+    assert len(got) == 6 and isinstance(got[-1], EpochEnd)
+    count = {n: hist[n].count - before[n][0] for n in hist}
+    spent = {n: hist[n].sum - before[n][1] for n in hist}
+    assert count == {"read": 5, "pack": 5, "busy": 5}
+    assert 0.05 * 0.9 <= spent["read"] < 0.05 + 0.04
+    assert spent["busy"] == pytest.approx(spent["read"] + spent["pack"],
+                                          abs=1e-6)
 
 
 def test_train_loop_with_obs_disabled_writes_nothing(tiny_config, tmp_path):

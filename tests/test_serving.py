@@ -317,6 +317,45 @@ def test_batcher_flushes_on_delay_and_preserves_order():
     batcher.drain()
 
 
+@pytest.mark.parametrize("kind,workers", [("classic", 1), ("continuous", 2)])
+def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
+                                                       workers):
+    """`serving_dispatcher_seconds{state}`: idle, delay and dispatch are
+    observed on leaving each state and together account for every
+    dispatcher thread's life; dispatch over the wall time is the busy
+    share of the thread(s) every request passes through."""
+    from code2vec_tpu.obs.metrics import Histogram
+    from code2vec_tpu.serving import batcher as batcher_mod
+    states = {s: Histogram() for s in ("idle", "delay", "dispatch")}
+    monkeypatch.setattr(batcher_mod, "_H_STATE", states)
+
+    def predict_fn(lines):
+        time.sleep(0.02)
+        return [l.upper() for l in lines]
+
+    t0 = time.perf_counter()
+    if kind == "classic":
+        batcher = batcher_mod.DynamicBatcher(predict_fn, max_batch_rows=8,
+                                             max_delay_s=0.03)
+    else:
+        batcher = batcher_mod.ContinuousBatcher(
+            predict_fn, max_batch_rows=8, max_delay_s=0.03,
+            inflight_steps=workers)
+    for i in range(6):
+        assert batcher.submit([f"a{i}", f"b{i}"]).result(timeout=10) \
+            == [f"A{i}", f"B{i}"]
+        time.sleep(0.01 * i)            # some idle time between requests
+    batcher.drain(timeout=10)
+    wall = time.perf_counter() - t0
+    n = batcher.batches_dispatched
+    assert n == 6 and states["dispatch"].count == n
+    assert states["dispatch"].sum == pytest.approx(0.02 * n, rel=0.5)
+    assert states["delay"].sum >= 0.03 * n * 0.8     # each waited its window
+    assert states["idle"].count >= 1
+    covered = sum(h.sum for h in states.values())
+    assert covered == pytest.approx(wall * workers, rel=0.1, abs=0.05)
+
+
 def test_batcher_error_propagates_and_drain_refuses():
     from code2vec_tpu.serving.batcher import DynamicBatcher
 
@@ -622,6 +661,34 @@ def test_predict_accepts_lazy_iterable(served_model):
 # ------------------------------------------------------------- http
 
 
+def test_serve_main_warms_every_bucket_before_it_listens(served_model):
+    """`serve` runs every (rows, bucket) predict shape once before the
+    port opens and reports it as the start-up phase `serve_warm`: no
+    request then pays a bucket's compile out of its deadline."""
+    import dataclasses
+    from code2vec_tpu.serving.server import serve_main
+    logged = []
+    config = dataclasses.replace(served_model.config, serve_port=0)
+    config.log = logged.append
+    gauge = obs.gauge("startup_phase_seconds", phase="serve_warm")
+    gauge.set(0.0)
+    stop = threading.Event()
+    stop.set()                  # come up, warm, listen, drain
+    assert serve_main(config, model=served_model, stop=stop,
+                      install_signals=False) == 0
+    assert gauge.value > 0.0
+    warmed = [i for i, m in enumerate(logged) if "buckets warmed" in m]
+    listening = [i for i, m in enumerate(logged) if "listening on" in m]
+    assert warmed and listening and warmed[0] < listening[0]
+    rows = config.serve_batch_size
+    assert {(rows, m) for m in served_model.context_buckets} \
+        <= set(served_model._predict_steps)
+    compiled = served_model.predict_compile_count()
+    line = "warm|probe " + " ".join(["tok0,path0,tok0"] * 3)
+    served_model.predict([line], batch_size=rows, with_code_vectors=True)
+    assert served_model.predict_compile_count() == compiled
+
+
 @pytest.fixture()
 def server(served_model, fake_extractor):
     from code2vec_tpu.serving.server import PredictionServer
@@ -865,6 +932,43 @@ def test_trace_id_minted_and_debug_tree_names_every_phase(traced_server):
         "class T { int traced(int n) { return n; } }")
     assert "trace" not in json.loads(body2)
     assert headers2["X-Trace-Id"] != trace_id  # fresh id per request
+
+
+def test_predict_stages_hang_under_the_device_span_and_fill_it(
+        traced_server):
+    """The coalesced model call's stages (the facade's obs.spans, which
+    also feed `serving_predict_stage_seconds{stage}`) are children of
+    the batch's `device` span in the request tree and account for it."""
+    stages = ("parse", "assemble", "device", "render")
+    hists = {s: obs.histogram("serving_predict_stage_seconds", stage=s)
+             for s in stages}
+    fill = {d: obs.histogram("serving_batch_fill_ratio", dim=d)
+            for d in ("rows", "contexts")}
+    before = {s: h.count for s, h in hists.items()}
+    fill_before = {d: (h.count, h.sum) for d, h in fill.items()}
+    status, body, _ = _post_full(
+        traced_server.port, "predict",
+        "class T { int staged(int n) { return n; } }",
+        query="?debug=trace")
+    assert status == 200
+    spans = json.loads(body)["trace"]["spans"]
+    [device] = [s for s in spans if s["name"] == "device"]
+    children = [s for s in spans if s["parent_id"] == device["span_id"]]
+    assert [c["name"] for c in children] == [
+        "predict." + s for s in stages]
+    inside = sum(c["duration_ms"] for c in children)
+    assert inside <= device["duration_ms"] + 0.01
+    assert inside >= device["duration_ms"] - 2.0    # breaker, fan-out
+    for c in children:
+        assert c["start_ms"] >= device["start_ms"] - 0.01
+    assert {s: h.count - before[s] for s, h in hists.items()} == dict.fromkeys(
+        stages, 1)
+    rows = traced_server.config.serve_batch_size
+    count, total = fill_before["rows"]
+    assert fill["rows"].count == count + 1
+    assert fill["rows"].sum - total == pytest.approx(1.0 / rows)
+    assert 0.0 < fill["contexts"].sum - fill_before["contexts"][1] \
+        <= 1.0 / rows
 
 
 def test_inbound_traceparent_honored_and_echoed(traced_server):
