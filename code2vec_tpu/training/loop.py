@@ -31,7 +31,9 @@ import numpy as np
 from code2vec_tpu import obs
 from code2vec_tpu.obs import exporters as obs_exporters
 from code2vec_tpu.data.reader import EpochEnd
+from code2vec_tpu.ops.embed import live_block_ratio
 from code2vec_tpu.training.state import TrainState
+from code2vec_tpu.training.step import gathers_live_rows
 from code2vec_tpu.utils.device import describe_devices, shard_layout
 from code2vec_tpu.utils.prefetch import DevicePrefetcher
 
@@ -243,9 +245,25 @@ class Trainer:
         win_data_wait = 0.0        # host-side step-time breakdown,
         win_dispatch = 0.0         # accumulated over the log window
         last_avg_loss = float("nan")
+        observe = None
+        if gathers_live_rows(config, self.mesh):
+            h_live = reg.histogram(
+                "train_context_blocks_live_ratio",
+                "share of a batch's (rows, contexts) block grid that the "
+                "train step's embedding lookup gathers (ops/embed.py): "
+                "the blocks some row of its group reaches into, rows "
+                "ordered by depth on each chip",
+                buckets=(0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0))
+            chips = (1 if self.mesh is None
+                     else len(self.mesh.local_devices))
+
+            def observe(batch):
+                h_live.observe(live_block_ratio(batch.context_valid_mask,
+                                                chips))
         prefetcher = DevicePrefetcher(
             batches, self.mesh, depth=config.prefetch_batches,
-            double_buffer=getattr(config, "prefetch_double_buffer", False))
+            double_buffer=getattr(config, "prefetch_double_buffer", False),
+            observe=observe)
         watcher = None
         if getattr(config, "save_on_preemption", True):
             watcher = PreemptionWatcher(log).install()

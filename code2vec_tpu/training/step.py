@@ -34,6 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from code2vec_tpu.models.code2vec import Code2VecModule
 from code2vec_tpu.ops.attention import masked_single_query_attention
+from code2vec_tpu.ops.embed import context_depth, embed_live_rows
 from code2vec_tpu.ops import sharded as tp_ops
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.parallel.mesh import AXIS_CTX, AXIS_DATA, AXIS_MODEL
@@ -117,6 +118,38 @@ def scoped_adam_update(optimizer: optax.GradientTransformation, grads,
         is_leaf=lambda n: isinstance(n, dict) and set(n) <= keys)
 
 
+def gathers_live_rows(config, mesh: Optional[Mesh]) -> bool:
+    """Whether `make_train_step` builds the dense step around the
+    live-rows lookup (ops/embed.py). It runs chip by chip (each chip's
+    rows ordered among themselves, each chip's table-shaped gradient its
+    own, one all-reduce a table): left to GSPMD, a loop's scatter into a
+    replicated table would be reduced across chips in every iteration.
+    So it needs whole tables on every chip: no mesh, or a data-only one;
+    tp/cp meshes keep `jnp.take`, and the sparse and the overlapped step
+    never see it."""
+    if uses_sparse_update(config) or getattr(
+            config, "overlap_grad_allreduce", False):
+        return False
+    return mesh is None or _data_only(mesh)
+
+
+def _data_only(mesh: Mesh) -> bool:
+    """Every chip holds whole tables and a slice of the batch's rows."""
+    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
+    return shape.get(AXIS_MODEL, 1) == 1 and shape.get(AXIS_CTX, 1) == 1
+
+
+def _order_rows_by_depth(src, pth, tgt, mask, labels, valid):
+    """The batch's rows by depth (ops/embed.py context_depth), deepest
+    first, so that the live contexts form a staircase of blocks; the
+    depths come last. The loss is a sum over rows: nothing is ordered
+    back."""
+    depth = context_depth(mask)
+    order = jnp.argsort(-depth, stable=True)
+    return tuple(jnp.take(x, order, axis=0)
+                 for x in (src, pth, tgt, mask, labels, valid, depth))
+
+
 class TrainStepBuilder:
     """Builds the jitted train/eval callables for a module + optimizer +
     mesh. `mesh=None` means single-device jit."""
@@ -180,7 +213,15 @@ class TrainStepBuilder:
         jit: donated state, mesh shardings when a mesh is present. Single
         source of the train-step sharding contract for all four builders."""
         if self.mesh is None:
-            return jax.jit(fn, donate_argnums=0)
+            # The state's own (single-device) sharding, said out loud:
+            # left unsaid, jit keys its compile on which arguments
+            # happen to be committed to their device, and a state that is
+            # partly so (restored or re-seeded parameters beside fresh
+            # moments) compiles the step a second time when its own,
+            # wholly committed, output comes back in.
+            one = getattr(jax.tree.leaves(example_state)[0], "sharding", None)
+            return jax.jit(fn, donate_argnums=0, in_shardings=one,
+                           out_shardings=one)
         state_sh = mesh_lib.shardings(self.mesh, state_spec_tree(example_state))
         batch_sh = tuple(NamedSharding(self.mesh, s) for s in _batch_spec_tuple())
         scalar_sh = NamedSharding(self.mesh, P())
@@ -200,14 +241,47 @@ class TrainStepBuilder:
 
     def _make_gspmd_train_step(self, example_state: TrainState) -> Callable:
         module, optimizer = self.module, self.optimizer
+        live_rows = gathers_live_rows(self.config, self.mesh)
+        order_rows, embed = _order_rows_by_depth, embed_live_rows
+        if live_rows and self.mesh is not None:
+            rows, ids = P(AXIS_DATA), P(AXIS_DATA, None)
+            order_rows = jax.shard_map(
+                order_rows, mesh=self.mesh,
+                in_specs=(ids, ids, ids, ids, rows, rows),
+                out_specs=(ids, ids, ids, ids, rows, rows, rows),
+                check_vma=False)
+
+            def embed(table, id_arrays, depth, dtype):
+                return jax.shard_map(
+                    lambda t, i, d: embed_live_rows(t, i, d, dtype),
+                    mesh=self.mesh,
+                    in_specs=(P(), (ids,) * len(id_arrays), rows),
+                    out_specs=(P(AXIS_DATA, None, None),) * len(id_arrays),
+                    check_vma=False)(table, id_arrays, depth)
 
         def train_step(state: TrainState, src, pth, tgt, mask, labels, valid, rng):
             dropout_rng = jax.random.fold_in(rng, state.step)
+            if live_rows:
+                src, pth, tgt, mask, labels, valid, depth = order_rows(
+                    src, pth, tgt, mask, labels, valid)
 
             def loss_fn(params):
+                if not live_rows:
+                    logits, _, _ = module.apply(
+                        {"params": params}, src, pth, tgt, mask,
+                        deterministic=False, rngs={"dropout": dropout_rng})
+                    return self._loss_from_logits(logits, labels, valid)
+                with jax.named_scope("embed_gather"):
+                    src_rows, tgt_rows = embed(
+                        params["token_embedding"], (src, tgt), depth,
+                        module.compute_dtype)
+                    path_rows, = embed(
+                        params["path_embedding"], (pth,), depth,
+                        module.compute_dtype)
                 logits, _, _ = module.apply(
-                    {"params": params}, src, pth, tgt, mask,
-                    deterministic=False, rngs={"dropout": dropout_rng})
+                    {"params": params}, src_rows, path_rows, tgt_rows, mask,
+                    deterministic=False, rngs={"dropout": dropout_rng},
+                    method=Code2VecModule.apply_from_rows)
                 return self._loss_from_logits(logits, labels, valid)
 
             loss, grads = jax.value_and_grad(loss_fn)(state.params)
@@ -700,9 +774,7 @@ def fused_path_applies(mesh: Optional[Mesh]) -> bool:
         return True  # local arrays — correct on any process count
     if jax.process_count() > 1:
         return False  # global batch assembly (distributed.py) owns this
-    shape = dict(zip(mesh.axis_names, mesh.devices.shape))
-    return (shape.get(mesh_lib.AXIS_MODEL, 1) == 1
-            and shape.get(mesh_lib.AXIS_CTX, 1) == 1)
+    return _data_only(mesh)
 
 
 def device_put_batch(batch, mesh: Optional[Mesh], packed=None):

@@ -7,7 +7,7 @@ from __future__ import annotations
 
 import queue
 import threading
-from typing import Iterable, Iterator, Optional
+from typing import Callable, Iterable, Iterator, Optional
 
 from code2vec_tpu import obs
 from code2vec_tpu.data.reader import EpochEnd
@@ -26,7 +26,8 @@ _H_READ = obs.histogram(
     "slice, shuffle, filter (worker thread)")
 _H_BUSY = obs.histogram(
     "prefetch_busy_seconds",
-    "read + pack of one batch: what the one worker thread does apart "
+    "read + pack (+ the trainer's per-batch counters) of one batch: "
+    "what the one worker thread does apart "
     "from waiting on a full queue; its sum over wall time is the "
     "feed's busy share (at 1 the step starts to wait)")
 _H_DEVICE_PUT = obs.histogram(
@@ -60,8 +61,12 @@ class DevicePrefetcher:
 
     def __init__(self, batches: Iterable, mesh, depth: int = 4,
                  keep_host_batch: bool = False,
-                 double_buffer: bool = False):
+                 double_buffer: bool = False,
+                 observe: Optional[Callable] = None):
         self.batches = batches
+        # called with every host batch on the worker thread (counters
+        # that read the batch itself); its time counts as busy
+        self.observe = observe
         self.mesh = mesh
         self.depth = max(1, depth)
         self.keep_host_batch = keep_host_batch
@@ -100,6 +105,10 @@ class DevicePrefetcher:
                     # count (one observation a batch)
                     _H_READ.observe(read.seconds)
                     busy = read.seconds
+                    if self.observe is not None:
+                        with obs.span("prefetch_observe") as observing:
+                            self.observe(batch)
+                        busy += observing.seconds
                     packed = None
                     if pack:
                         # the packed buffer is all the consumer needs

@@ -10,6 +10,7 @@ planner's size/order laws and the config guard rails.
 """
 
 import dataclasses
+from unittest import mock
 
 import jax
 import jax.numpy as jnp
@@ -31,6 +32,7 @@ def _build(overlap, mesh=None, *, dropout_keep=1.0, bucket_mb=0.003,
            nu_dtype="bfloat16", in_backward=False):
     from code2vec_tpu.models.code2vec import Code2VecModule, ModelDims
     from code2vec_tpu.training.state import create_train_state, make_optimizer
+    from code2vec_tpu.training import step as step_mod
     from code2vec_tpu.training.step import TrainStepBuilder
     config = Config(train_data_path_prefix="<t>", train_batch_size=8,
                     max_contexts=6, compute_dtype="float32",
@@ -47,8 +49,14 @@ def _build(overlap, mesh=None, *, dropout_keep=1.0, bucket_mb=0.003,
     opt = make_optimizer(config)
     state = create_train_state(module, opt, jax.random.PRNGKey(0),
                                mesh=mesh, config=config)
-    step = TrainStepBuilder(module, opt, config,
-                            mesh=mesh).make_train_step(state)
+    # The overlapped step differentiates the module over `jnp.take`; its
+    # bit-level reference is the monolithic step over the same lookup,
+    # not the live-rows one (ops/embed.py sums a table row's gradient in
+    # another order; tests/test_embed_live.py holds the two together).
+    with mock.patch.object(step_mod, "gathers_live_rows",
+                           lambda config, mesh: False):
+        step = TrainStepBuilder(module, opt, config,
+                                mesh=mesh).make_train_step(state)
     return step, state
 
 
