@@ -81,6 +81,19 @@ def arguments_parser() -> ArgumentParser:
                              "'32,64,128'; max_contexts is always "
                              "appended) — bounds the number of pjit "
                              "compilations serving can trigger")
+    parser.add_argument("--model_config", default=None, metavar="FILE",
+                        help="a model-configuration file (JSON, the "
+                             "published config.json keys plus the share "
+                             "held here) naming a model of models/ other "
+                             "than code2vec: today the hybrid state-space "
+                             "/ latent-expert language model "
+                             "(models/hybrid_lm.py), served for scoring "
+                             "on POST /score")
+    parser.add_argument("--serve_token_budget", type=int, default=None,
+                        metavar="N",
+                        help="most tokens (rows x padded length) in one "
+                             "scoring step of a --model_config model, "
+                             "and so its longest request (default 8192)")
     parser.add_argument("--serve_cache_entries", type=int, default=None,
                         metavar="N",
                         help="LRU prediction-cache capacity keyed by "
@@ -807,6 +820,8 @@ def config_from_args(argv=None) -> Config:
                                       "serve_continuous",
                                       "serve_inflight_steps",
                                       "serve_buckets",
+                                      "model_config",
+                                      "serve_token_budget",
                                       "serve_cache_entries",
                                       "extractor_pool_size",
                                       "serve_drain_timeout_s",
@@ -1097,6 +1112,18 @@ def main(argv=None) -> None:
         if config.predict:
             from code2vec_tpu.serving.interactive import InteractivePredictor
             InteractivePredictor(config, model).predict()
+        if config.serve:
+            from code2vec_tpu.serving.server import serve_main
+            sys.exit(serve_main(config, model))
+        return
+
+    if config.model_config:
+        # a model of models/ behind the one serving interface
+        # (lm_facade.py); it trains nothing and predicts no method name
+        from code2vec_tpu.lm_facade import ScoringModel
+        model = ScoringModel(config)
+        if config.is_saving:
+            model.save()
         if config.serve:
             from code2vec_tpu.serving.server import serve_main
             sys.exit(serve_main(config, model))

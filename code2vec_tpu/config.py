@@ -259,6 +259,13 @@ class Config:
     # trigger is bounded by len(buckets) instead of one per request
     # shape.
     serve_buckets: str = "32,64,128"
+    # A model of models/ other than code2vec, named by its
+    # model-configuration file (cli --model_config; lm_facade.py). Its
+    # length buckets come from that file; a scoring step holds at most
+    # serve_token_budget tokens (rows x padded length), which is also
+    # the longest request it takes.
+    model_config: Optional[str] = None
+    serve_token_budget: int = 8192
     # LRU prediction-cache capacity (entries), keyed by normalized
     # method-body hash (serving/cache.py). 0 disables.
     serve_cache_entries: int = 4096
@@ -800,9 +807,15 @@ class Config:
 
     def verify(self) -> None:
         # reference: config.py:232-239, plus mesh-shape checks.
+        if self.model_config and not os.path.isfile(self.model_config):
+            raise ValueError(
+                f"Model configuration `{self.model_config}` does not exist.")
+        if self.serve_token_budget < 1:
+            raise ValueError("serve_token_budget must be >= 1.")
         if (not self.is_training and not self.is_loading
                 and not self.serve_artifact and not self.index_out
                 and not self.corpus
+                and not (self.model_config and self.is_saving)
                 and not (self.fleet and self.fleet_models)
                 and not (self.fleet and self.fleet_trace_id)):
             raise ValueError(

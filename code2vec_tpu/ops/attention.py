@@ -68,3 +68,80 @@ def masked_single_query_attention(
     if axis_name is not None:
         code_vectors = jax.lax.psum(code_vectors, axis_name)
     return code_vectors, attention
+
+
+@jax.named_scope("attn")
+def causal_gqa_attention(q: jax.Array,      # (b, l, q_heads, d)
+                         k: jax.Array,      # (b, l, kv_heads, d)
+                         v: jax.Array,      # (b, l, kv_heads, d)
+                         block: int = 512) -> jax.Array:
+    """Causal softmax(q k^T / sqrt(d)) v with grouped queries (query head
+    n reads key/value head n // (q_heads / kv_heads)), float32 out.
+
+    Computed block by block with a running max and sum, so the (l, l)
+    scores never exist whole: a query block visits only the key blocks
+    at or before it. Operands keep their dtype (bfloat16 on the MXU),
+    scores, softmax and accumulator are float32. Right padding is safe:
+    a position never reads one after it.
+    """
+    bsz, length, hq, d = q.shape
+    hkv = k.shape[2]
+    rep = hq // hkv
+    block = min(block, length)
+    pad = (-length) % block
+    if pad:
+        q, k, v = (jnp.pad(t, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                   for t in (q, k, v))
+    nb = (length + pad) // block
+    q = q.reshape(bsz, nb, block, hkv, rep, d)
+    k = k.reshape(bsz, nb, block, hkv, d)
+    v = v.reshape(bsz, nb, block, hkv, d)
+    scale = 1.0 / (d ** 0.5)
+    within = jnp.arange(block)
+    f32 = jnp.float32
+
+    def query_block(i):
+        qi = q[:, i]                                     # (b, q, h, r, d)
+
+        def key_block(j, carry):
+            top, total, acc = carry
+            s = jnp.einsum("bqhrd,bkhd->bhrqk", qi, k[:, j],
+                           preferred_element_type=f32) * scale
+            seen = (i * block + within)[:, None] >= (j * block + within)
+            s = jnp.where(seen, s, -jnp.inf)
+            new_top = jnp.maximum(top, jnp.max(s, axis=-1))
+            p = jnp.exp(s - new_top[..., None])
+            fade = jnp.exp(top - new_top)
+            total = total * fade + jnp.sum(p, axis=-1)
+            acc = acc * fade[..., None] + jnp.einsum(
+                "bhrqk,bkhd->bhrqd", p.astype(v.dtype), v[:, j],
+                preferred_element_type=f32)
+            return new_top, total, acc
+        init = (jnp.full((bsz, hkv, rep, block), -jnp.inf, f32),
+                jnp.zeros((bsz, hkv, rep, block), f32),
+                jnp.zeros((bsz, hkv, rep, block, d), f32))
+        # the diagonal block comes first, so the running max is finite
+        # from the first step on; then the blocks before it
+        top, total, acc = key_block(i, init)
+        top, total, acc = jax.lax.fori_loop(0, i, key_block,
+                                            (top, total, acc))
+        return acc / total[..., None]                    # (b, h, r, q, d)
+    out = jax.lax.map(query_block, jnp.arange(nb))       # (nb, b, h, r, q, d)
+    out = jnp.transpose(out, (1, 0, 4, 2, 3, 5))         # (b, nb, q, h, r, d)
+    return out.reshape(bsz, nb * block, hq, d)[:, :length]
+
+
+def causal_gqa_attention_plain(q, k, v) -> jax.Array:
+    """The same attention with the whole (l, l) score matrix, float32,
+    "highest": what the blockwise form is tested against."""
+    f32 = jnp.float32
+    hi = jax.lax.Precision.HIGHEST
+    bsz, length, hq, d = q.shape
+    rep = hq // k.shape[2]
+    k = jnp.repeat(k.astype(f32), rep, axis=2)
+    v = jnp.repeat(v.astype(f32), rep, axis=2)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(f32), k,
+                   precision=hi) / (d ** 0.5)
+    causal = jnp.tril(jnp.ones((length, length), bool))
+    p = jax.nn.softmax(jnp.where(causal, s, -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v, precision=hi)

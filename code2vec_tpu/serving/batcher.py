@@ -199,12 +199,22 @@ class DynamicBatcher:
     def __init__(self, predict_fn: Callable[[List[str]], List],
                  max_batch_rows: int = 64, max_delay_s: float = 0.01,
                  buckets: Optional[Sequence[int]] = None,
-                 tenancy=None):
+                 tenancy=None,
+                 bucket_of: Optional[Callable[[object], int]] = None,
+                 max_batch_tokens: Optional[int] = None):
         self.predict_fn = predict_fn
         self.max_batch_rows = max(1, int(max_batch_rows))
         self.max_delay_s = max(0.0, float(max_delay_s))
         self.tenancy = tenancy
         self._dwrr_state: dict = {}
+        # A model whose rows are not extractor lines says how one row
+        # buckets (`bucket_of(row)`: a token sequence by its length) and
+        # may cap a batch's padded size: rows x deepest bucket <=
+        # max_batch_tokens (a step of a sequence model costs by tokens,
+        # not by rows).
+        self._bucket_fn = bucket_of
+        self.max_batch_tokens = (None if max_batch_tokens is None
+                                 else max(1, int(max_batch_tokens)))
         # Context-bucket list (model.context_buckets) for per-bucket
         # device-time estimates; None = one global estimate (the
         # standalone/unit-test construction).
@@ -230,6 +240,8 @@ class DynamicBatcher:
         real contexts."""
         if self.buckets is None:
             return None
+        if self._bucket_fn is not None:
+            return max(self._bucket_fn(line) for line in lines)
         deepest = max((len(line.split()) - 1 for line in lines),
                       default=1)
         return bucket_for(max(deepest, 1), self.buckets)
@@ -313,7 +325,11 @@ class DynamicBatcher:
                     if not self._pending:
                         continue
                     if (self._draining
-                            or self._pending_rows >= self.max_batch_rows):
+                            or self._pending_rows >= self.max_batch_rows
+                            or not self._fits(
+                                self._pending_rows + 1,
+                                max((i.bucket or 0)
+                                    for i in self._pending))):
                         return self._take_locked()
                     age = time.perf_counter() - self._pending[0].t_submit
                     wait = self.max_delay_s - age
@@ -369,15 +385,23 @@ class DynamicBatcher:
                 self._pending_rows -= sum(len(i.lines) for i in take)
                 return take
         take: List[_Pending] = []
-        rows = 0
+        rows = deepest = 0
         while self._pending:
             nxt = self._pending[0]
-            if take and rows + len(nxt.lines) > self.max_batch_rows:
+            n = rows + len(nxt.lines)
+            if take and (n > self.max_batch_rows or not self._fits(
+                    n, max(deepest, nxt.bucket or 0))):
                 break
             take.append(self._pending.pop(0))
-            rows += len(nxt.lines)
+            rows, deepest = n, max(deepest, nxt.bucket or 0)
         self._pending_rows -= rows
         return take
+
+    def _fits(self, rows: int, bucket: int) -> bool:
+        """Whether `rows` rows padded to `bucket` stay inside the token
+        budget (always, where the model set none)."""
+        return (self.max_batch_tokens is None
+                or rows * bucket <= self.max_batch_tokens)
 
     def _dispatch(self, batch: List[_Pending]) -> None:
         t_dispatch = time.perf_counter()
@@ -601,6 +625,7 @@ class ContinuousBatcher:
     # -------------------------------------------------------------- API
 
     _bucket_of = DynamicBatcher._bucket_of
+    _bucket_fn = None       # rows are extractor lines (no token budget)
 
     def _tenant_cap_hit_locked(self, slot: "_Slot",
                                tenant: Optional[str], n: int) -> bool:

@@ -236,8 +236,18 @@ class PredictionServer:
         self._model_lock = threading.Lock()
         self._model_ref: Tuple[object, str] = (model,
                                                model.model_fingerprint())
-        self.pool = ExtractorPool(
-            self.config, size=self.config.extractor_pool_size, log=self.log)
+        # The model says which endpoints it answers and whether its
+        # requests pass the extractor: code2vec's facades answer
+        # /predict, /embed and /neighbors from source code; a
+        # --model_config model (lm_facade.py) answers /score from token
+        # ids and starts no extractor.
+        self.endpoints = tuple(getattr(
+            model, "served_endpoints", ("predict", "embed", "neighbors")))
+        self.pool = None
+        if getattr(model, "uses_extractor", True):
+            self.pool = ExtractorPool(
+                self.config, size=self.config.extractor_pool_size,
+                log=self.log)
         # with_code_vectors=True: /predict and /embed rows coalesce into
         # the SAME batches (a per-endpoint batcher would halve fill);
         # the step computes vectors anyway, the flag only materializes
@@ -252,7 +262,11 @@ class PredictionServer:
             max_delay_s=self.config.serve_max_delay_ms / 1000.0,
             buckets=model.context_buckets,
             tenancy=self.tenancy)
-        if getattr(self.config, "serve_continuous", False):
+        # how a row buckets and what a batch may hold, where the model's
+        # rows are not extractor lines (batcher.DynamicBatcher)
+        own = getattr(model, "batcher_options", dict)()
+        batcher_kw.update(own)
+        if getattr(self.config, "serve_continuous", False) and not own:
             # --serve_continuous: slot-reservation dispatcher + the
             # zero-copy parse-into-slot path (batcher.ContinuousBatcher)
             self.batcher = ContinuousBatcher(
@@ -393,9 +407,13 @@ class PredictionServer:
         self.device_breaker.check()
         model, fp = self._model_ref
         try:
-            results = model.predict(
-                lines, batch_size=self.config.serve_batch_size,
-                with_code_vectors=True)
+            score = getattr(model, "score_batch", None)
+            if score is not None:
+                results = score(lines)
+            else:
+                results = model.predict(
+                    lines, batch_size=self.config.serve_batch_size,
+                    with_code_vectors=True)
         except BaseException:
             self.device_breaker.record(ok=False)
             raise
@@ -542,6 +560,10 @@ class PredictionServer:
                 tenant: Optional[str] = None) -> bytes:
         if trace is None:
             trace = RequestTrace()
+        if endpoint not in self.endpoints:
+            raise _HTTPError(
+                404, f"this model does not serve /{endpoint} (it serves "
+                     f"{', '.join('/' + e for e in self.endpoints)})")
         if not code.strip():
             raise _HTTPError(400, "empty request body")
         knobs: Dict = {}
@@ -556,6 +578,9 @@ class PredictionServer:
                 raise _HTTPError(503, str(e))
             knobs = self._neighbor_knobs(params)
             knobs["index"] = self.retrieval.fingerprint
+        if endpoint == "score":
+            knobs = {"return_routing": bool(
+                (params or {}).get("return_routing"))}
         model, fp = self._model_ref
         # ONE normalization pass per request: the same bytes feed the
         # cache probe here and the hot-swap re-key below.
@@ -575,10 +600,14 @@ class PredictionServer:
         t_admit = time.perf_counter()
         worked = True
         try:
-            lines, hash_to_string = self._extract(code, deadline, phases,
-                                                  trace=trace)
-            if self.traffic is not None:
-                self.traffic.record(lines)
+            if endpoint == "score":
+                lines, hash_to_string = [self._score_request(
+                    model, params)], {}
+            else:
+                lines, hash_to_string = self._extract(
+                    code, deadline, phases, trace=trace)
+                if self.traffic is not None:
+                    self.traffic.record(lines)
             future = self.batcher.submit(lines, phases=phases,
                                          deadline=deadline, trace=trace,
                                          tenant=tenant)
@@ -628,6 +657,20 @@ class PredictionServer:
             self.admission.finish(
                 (time.perf_counter() - t_admit) if worked else -1.0,
                 tenant=tenant)
+
+    @staticmethod
+    def _score_request(model, params: Optional[Dict]):
+        """The body of POST /score, `{"ids": [...], "top_k": 10}`, as the
+        model's own request object."""
+        params = params or {}
+        if "ids" not in params:
+            raise _HTTPError(400, 'JSON body must be {"ids": [...], '
+                                  '"top_k": N}')
+        try:
+            return model.validate(params["ids"],
+                                  params.get("top_k", model.top_k))
+        except ValueError as e:
+            raise _HTTPError(400, str(e))
 
     def _extract(self, code: str, deadline: Optional[Deadline],
                  phases: Dict[str, float],
@@ -680,6 +723,21 @@ class PredictionServer:
     def _render(self, endpoint: str, raw, hash_to_string,
                 fingerprint: str, knobs: Optional[Dict] = None,
                 trace: Optional[RequestTrace] = None) -> dict:
+        if endpoint == "score":
+            # one token sequence a request: the top-k next-token logits
+            # at its last position, probabilities over the rows held
+            [r] = raw
+            out = {"model": "hybrid_lm", "model_fingerprint": fingerprint,
+                   "tokens": r.tokens,
+                   "top": [{"id": int(i), "logit": float(v),
+                            "probability": float(p)}
+                           for i, v, p in zip(r.token_ids, r.logits,
+                                              r.probabilities)]}
+            if (knobs or {}).get("return_routing"):
+                # the router's choice at the last position, by expert
+                # layer: what a comparison with a reference reads
+                out["routing_last"] = r.routing_last.tolist()
+            return out
         if endpoint == "embed":
             # embedding_fingerprint is the embedding-SPACE identity —
             # the same field /neighbors stamps — so a client holding
@@ -782,8 +840,9 @@ class PredictionServer:
             },
             # kept at top level too: deploy tooling from PR 8 reads it
             "model_fingerprint": self.model_fingerprint,
-            "extractor_pool": {"size": self.pool.size,
-                               "warm": self.pool.warm},
+            "extractor_pool": ({"size": self.pool.size,
+                                "warm": self.pool.warm}
+                               if self.pool is not None else None),
             "batcher": {"max_batch_rows": self.batcher.max_batch_rows,
                         "max_delay_ms":
                             self.batcher.max_delay_s * 1000.0,
@@ -912,7 +971,8 @@ class PredictionServer:
                 if path == "/admin/dump":
                     self._admin_dump()
                     return
-                if endpoint not in ("predict", "embed", "neighbors"):
+                if endpoint not in ("predict", "embed", "neighbors",
+                                    "score"):
                     self._error(404, f"no such endpoint: {path}")
                     return
                 # Inbound W3C traceparent joins the caller's distributed
@@ -962,7 +1022,7 @@ class PredictionServer:
                             "Content-Length", 0))
                         raw = self.rfile.read(length)
                         code_text, params = server._decode_body(
-                            raw, self.headers)
+                            raw, self.headers, endpoint)
                     except _HTTPError as e:
                         _requests_counter(endpoint, str(e.code)).inc()
                         self._error(e.code, str(e),
@@ -1090,11 +1150,22 @@ class PredictionServer:
         return json.dumps(payload, sort_keys=True).encode() + b"\n"
 
     @staticmethod
-    def _decode_body(raw: bytes, headers) -> Tuple[str, Optional[Dict]]:
+    def _decode_body(raw: bytes, headers, endpoint: str = ""
+                     ) -> Tuple[str, Optional[Dict]]:
         """(code, extra params). JSON bodies may carry per-request
         knobs beside "code" (today: /neighbors' `k` and `nprobe`);
-        plain-text bodies have none."""
+        plain-text bodies have none. A /score body is JSON whatever its
+        content type says: the text is the cache's key, the parsed
+        object the parameters."""
         text = raw.decode("utf-8", errors="replace")
+        if endpoint == "score":
+            try:
+                payload = json.loads(text)
+            except json.JSONDecodeError as e:
+                raise _HTTPError(400, f"bad JSON body: {e}")
+            if not isinstance(payload, dict):
+                raise _HTTPError(400, "JSON body must be an object")
+            return text, payload
         ctype = (headers.get("Content-Type") or "").split(";")[0].strip()
         if ctype == "application/json":
             try:
@@ -1157,7 +1228,8 @@ class PredictionServer:
         self.batcher.drain(timeout=max(deadline - time.monotonic(), 1.0))
         if self.traffic is not None:
             self.traffic.flush()
-        self.pool.close()
+        if self.pool is not None:
+            self.pool.close()
         if self._httpd is not None:
             try:
                 self._httpd.shutdown()

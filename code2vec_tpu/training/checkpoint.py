@@ -1219,3 +1219,77 @@ def release_model(model_load_path: str, model_save_path: Optional[str],
     state = load_model(model_load_path, state_like, params_only=True)
     out = model_save_path or model_load_path
     return save_model(out, state, vocabs, config, released=True)
+
+
+# ------------------------------------------------- parameters-only artifacts
+
+PARAMS_FORMAT = "params-v1"
+_PARAMS_DIR = "params"
+
+
+def save_params(model_save_path: str, params: dict, meta: dict) -> str:
+    """Save a flat dict of parameter arrays, and nothing else, as an
+    artifact `--load` accepts: one `.npy` file a leaf under `params/`
+    (bfloat16 as its 16 bits), and the meta file with the leaf table,
+    written last inside a staging directory that is then renamed into
+    place (whole or absent, as `save_model` commits).
+
+    Leaf by leaf, device to host to disk: the host never holds more
+    than one leaf, so a model that fills the chip can be saved."""
+    base = _abs(model_save_path)
+    staging = f"{base}{STAGING_INFIX}{os.getpid()}"
+    if os.path.isdir(staging):
+        shutil.rmtree(staging)
+    os.makedirs(os.path.join(staging, _PARAMS_DIR))
+    table = {}
+    with obs.span("checkpoint_save"):
+        for name, leaf in params.items():
+            host = np.asarray(leaf)
+            dtype = str(leaf.dtype)
+            if dtype == "bfloat16":
+                host = host.view(np.uint16)
+            np.save(os.path.join(staging, _PARAMS_DIR, name + ".npy"), host)
+            table[name] = {"shape": list(leaf.shape), "dtype": dtype}
+            del host
+        with open(os.path.join(staging, _META_NAME), "w") as f:
+            json.dump(dict(meta, format=PARAMS_FORMAT, released=True,
+                           leaves=table), f)
+    _commit_staging(staging, base)
+    return base
+
+
+def restore_params(model_load_path: str, abstract: dict) -> dict:
+    """Restore a `save_params` artifact STRAIGHT into place: each leaf is
+    read from its file and put on the device before the next is opened.
+    No initial state is built first and nothing is held twice, so the
+    device's peak is the parameters themselves (`load_model` restores
+    into a whole fresh state: two states at the peak, which a model that
+    fills the chip cannot afford). `abstract` maps each leaf's name to
+    its expected shape and dtype; a leaf that is absent or differs is an
+    error that names it."""
+    import ml_dtypes
+    base = _abs(model_load_path)
+    meta = load_model_meta(base)
+    if meta.get("format") != PARAMS_FORMAT:
+        raise ValueError(f"{base} is not a parameters-only artifact "
+                         f"(format {meta.get('format')!r})")
+    table = meta["leaves"]
+    missing = sorted(set(abstract) - set(table))
+    if missing:
+        raise ValueError(f"{base} lacks the leaves {missing[:5]} "
+                         f"({len(missing)} in all)")
+    out = {}
+    for name, want in abstract.items():
+        got = table[name]
+        if (tuple(got["shape"]) != tuple(want.shape)
+                or got["dtype"] != str(want.dtype)):
+            raise ValueError(
+                f"{base}: leaf {name} is {got['dtype']}{got['shape']}, "
+                f"the model wants {want.dtype}{list(want.shape)}")
+        host = np.load(os.path.join(base, _PARAMS_DIR, name + ".npy"),
+                       mmap_mode="r")
+        if got["dtype"] == "bfloat16":
+            host = host.view(ml_dtypes.bfloat16)
+        out[name] = jax.block_until_ready(jax.device_put(host))
+        del host
+    return out

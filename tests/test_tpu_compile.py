@@ -1,0 +1,55 @@
+"""Compiles for a DESCRIBED v5e chip (no chip attached): what the chip's
+compiler refuses costs no chip time. The one file that describes the
+topology (only one process at a time may load the TPU's library), and it
+does so inside a fixture, never while a module is imported."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tokens", [512, 8192])
+def test_grouped_expert_matmuls_compile_at_published_widths(
+        one_chip, monkeypatch, tokens):
+    """The expert layer's two grouped matmuls at the widths
+    `nemotron3-super-ep4` runs (128 experts held, latent 1024, width
+    2688, 22 a token), on the kernel the TPU branch picks."""
+    from code2vec_tpu.ops import moe
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    rows, held, lat, width = tokens * 22, 128, 1024, 2688
+
+    def both(x, w1, w2, sizes):
+        hidden = moe.grouped_matmul(x, w1, sizes, jnp.bfloat16)
+        return moe.grouped_matmul(hidden, w2, sizes, jnp.float32)
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(both).lower(
+        shape((rows, lat), jnp.bfloat16),
+        shape((held, lat, width), jnp.bfloat16),
+        shape((held, width, lat), jnp.bfloat16),
+        shape((held,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2 and "ragged-dot" not in text
+
+
+def test_tiles_divide_the_published_widths():
+    from code2vec_tpu.ops.moe import _tile
+    assert (_tile(1024), _tile(2688), _tile(4096), _tile(100)) == (
+        1024, 896, 1024, 0)
