@@ -57,19 +57,13 @@ def arguments_parser() -> ArgumentParser:
                         help="rows per coalesced serving device batch "
                              "(also the padded row count of every "
                              "compiled predict shape; default 64)")
-    parser.add_argument("--serve_max_delay_ms", type=float, default=None,
-                        metavar="MS",
-                        help="max milliseconds a request waits for "
-                             "batch-mates before dispatching anyway "
-                             "(default 10; 0 = no coalescing)")
     parser.add_argument("--serve_continuous", action="store_true",
                         default=None,
                         help="continuous batching: admit arriving rows "
                              "into the next device step of an already-"
                              "forming slot (zero-copy parse into the "
                              "slot buffer; a row arriving while a step "
-                             "is on device rides the NEXT step instead "
-                             "of opening a fresh delay window)")
+                             "is on device rides the NEXT step)")
     parser.add_argument("--serve_inflight_steps", type=int, default=None,
                         metavar="N",
                         help="device steps the continuous batcher may "
@@ -816,7 +810,6 @@ def config_from_args(argv=None) -> Config:
                                       "save_barrier_timeout_s",
                                       "serve_port", "serve_host",
                                       "serve_batch_size",
-                                      "serve_max_delay_ms",
                                       "serve_continuous",
                                       "serve_inflight_steps",
                                       "serve_buckets",

@@ -229,23 +229,20 @@ class Config:
     # own external exposure/TLS.
     serve_port: int = 8800
     serve_host: str = "127.0.0.1"
-    # Rows per coalesced device batch: the dynamic batcher dispatches
-    # when this many method rows are pending (or the delay below
-    # expires). Also the padded row count of every compiled predict
-    # shape — smaller than test_batch_size because serving favors
-    # latency over peak throughput.
+    # Rows per coalesced device batch: the most the batcher cuts into
+    # one model call from what piled up behind the call in flight (a
+    # free dispatcher dispatches at once, serving/batcher.py). Also the
+    # padded row count of every compiled predict shape — smaller than
+    # test_batch_size because serving favors latency over peak
+    # throughput.
     serve_batch_size: int = 64
-    # Max milliseconds a request waits for batch-mates before the
-    # batcher dispatches anyway: the latency price of coalescing on an
-    # idle server (a busy server fills batches and never waits).
-    serve_max_delay_ms: float = 10.0
     # Continuous batching (serving/batcher.py ContinuousBatcher): admit
     # newly-arrived rows into the next device step of an already-forming
     # slot instead of collect-then-dispatch — a row arriving while a
-    # step is on device rides the NEXT step rather than opening a fresh
-    # delay window — and parse extractor output straight into the
-    # slot's padded (rows, contexts) buffer (zero-copy request path).
-    # An idle server behaves exactly like the classic batcher.
+    # step is on device rides the NEXT step — and parse extractor
+    # output straight into the slot's padded (rows, contexts) buffer
+    # (zero-copy request path). Both batchers follow one dispatch rule
+    # (serving/batcher.py): a free dispatcher dispatches at once.
     serve_continuous: bool = False
     # Device steps the continuous batcher may keep in flight at once
     # (worker threads; step N+1 launches as soon as step N's dispatch
@@ -868,10 +865,6 @@ class Config:
                 "serve_port must be in [0, 65535] (0 picks a free port).")
         if self.serve_batch_size < 1:
             raise ValueError("serve_batch_size must be >= 1.")
-        if self.serve_max_delay_ms < 0:
-            raise ValueError(
-                "serve_max_delay_ms must be >= 0 (0 = dispatch "
-                "immediately, no coalescing).")
         if self.serve_cache_entries < 0:
             raise ValueError(
                 "serve_cache_entries must be >= 0 (0 disables the "

@@ -8,11 +8,17 @@ chip on dispatch overhead, and letting every request shape hit pjit
 would recompile per distinct (rows, contexts) pair. So:
 
 - Requests (groups of extracted method lines) enqueue; a single
-  dispatcher thread collects until either `max_batch_rows` rows are
-  pending or the OLDEST request has waited `max_delay_s`, then runs one
-  model call over the coalesced rows. A lone request on an idle server
-  therefore pays at most `max_delay_s` extra latency; a busy server
-  fills batches and pays none.
+  dispatcher thread runs one model call over the coalesced rows.
+  THE DISPATCH RULE, which both batchers of this module follow: a
+  dispatcher that is free and has a live request pending cuts a batch
+  at once. The model call in flight is the only batching window: what
+  arrives behind it piles up and is cut together (inside the row cap
+  and the token budget) the moment it returns, so batches grow with the
+  backlog by themselves and a lone request on an idle server waits for
+  nothing. `serving_batch_cut_idle_ratio` says, once a dispatched
+  batch, which of the two it was: 1 when the batch's oldest request
+  was submitted with no call in flight and none ran before it was cut,
+  0 when it was cut behind a call.
 - The model call itself buckets the context axis (model_facade.predict
   `context_buckets`): rows are padded to the smallest configured bucket
   that fits their deepest valid context, so the number of compiled
@@ -32,11 +38,9 @@ Deadline propagation (serving/admission.py): `submit()` takes the
 request's Deadline. A request whose remaining budget cannot cover its
 context bucket's observed p95 device time is REFUSED up front
 (`DeadlineInfeasible`, an honest 503 shed — coalescing it would only
-burn a device slot on a guaranteed 504); a request that expires while
-waiting for batch-mates settles as `DeadlineExceeded` (504) and never
-reaches the device; and a request running out of coalescing slack
-(remaining budget approaching its bucket's p95) forces an early
-dispatch instead of waiting out the full delay budget. Per-bucket
+burn a device slot on a guaranteed 504); a request that expires
+behind the call in flight settles as `DeadlineExceeded` (504) and never
+reaches the device. Per-bucket
 device times come from a small rolling window of dispatched-batch
 durations — no estimate, no refusal (a cold batcher never sheds on a
 bogus p95).
@@ -65,10 +69,11 @@ _H_DEVICE = obs.histogram(
     "one coalesced model call: parse + pad + device step + unpack")
 _DISPATCHER_HELP = (
     "time a dispatcher thread spent in one state, observed on leaving "
-    "it: idle (nothing pending), delay (work pending: waiting out the "
-    "window, for rows or for a parse), dispatch (inside the coalesced "
-    "model call and its fan-out). dispatch over wall time is the busy "
-    "share of the thread every request passes through")
+    "it: idle (nothing pending), delay (continuous batcher only: the "
+    "head slot is pending and a parse is still writing into it), "
+    "dispatch (inside the coalesced model call and its fan-out). "
+    "dispatch over wall time is the busy share of the thread every "
+    "request passes through")
 _H_STATE = {state: obs.histogram("serving_dispatcher_seconds",
                                  _DISPATCHER_HELP, state=state)
             for state in ("idle", "delay", "dispatch")}
@@ -89,8 +94,24 @@ _G_INFLIGHT = obs.gauge(
     "device steps currently in flight (continuous batching)")
 _C_RIDES = obs.counter(
     "serving_batch_inflight_rides_total",
-    "admissions that rode an in-flight dispatch window instead of "
-    "opening a fresh delay window (continuous batching)")
+    "admissions that arrived while a step was in flight and ride the "
+    "next one (continuous batching)")
+_H_CUT_IDLE = obs.histogram(
+    "serving_batch_cut_idle_ratio",
+    "one observation a dispatched batch: 1 when its oldest request "
+    "found the dispatcher free and went straight through, 0 when it "
+    "was cut behind a model call. The mean is the share of batches "
+    "that a fixed coalescing delay would have held back",
+    buckets=(0.0, 1.0))
+
+
+def _cut_idle(items, t_free: float, in_flight: int = 0) -> float:
+    """What `serving_batch_cut_idle_ratio` observes for a batch that is
+    being cut: 1.0 when no model call is in flight (`in_flight`: the
+    continuous batcher's other workers) and the oldest of `items` was
+    submitted after the last one returned (`t_free`), else 0.0."""
+    return float(in_flight == 0
+                 and min(i.t_submit for i in items) >= t_free)
 
 
 def parse_buckets(spec, max_contexts: int, cp: int = 1) -> Tuple[int, ...]:
@@ -197,14 +218,13 @@ class DynamicBatcher:
     """
 
     def __init__(self, predict_fn: Callable[[List[str]], List],
-                 max_batch_rows: int = 64, max_delay_s: float = 0.01,
+                 max_batch_rows: int = 64,
                  buckets: Optional[Sequence[int]] = None,
                  tenancy=None,
                  bucket_of: Optional[Callable[[object], int]] = None,
                  max_batch_tokens: Optional[int] = None):
         self.predict_fn = predict_fn
         self.max_batch_rows = max(1, int(max_batch_rows))
-        self.max_delay_s = max(0.0, float(max_delay_s))
         self.tenancy = tenancy
         self._dwrr_state: dict = {}
         # A model whose rows are not extractor lines says how one row
@@ -226,6 +246,7 @@ class DynamicBatcher:
         self._draining = False
         self._closed = False
         self.batches_dispatched = 0
+        self._t_free = 0.0      # when the last model call returned
         self._thread = threading.Thread(target=self._run,
                                         name="serving-batcher", daemon=True)
         self._thread.start()
@@ -311,42 +332,20 @@ class DynamicBatcher:
                 return
             with _state("dispatch"):
                 self._dispatch(batch)
+            self._t_free = time.perf_counter()
 
     def _collect(self) -> Optional[List[_Pending]]:
-        """Block until a batch is due: rows >= cap, oldest item older
-        than max_delay_s, any pending item out of coalescing slack
-        (its remaining deadline budget is down to its bucket's p95
-        device time), or draining (flush everything). Expired items are
-        settled as 504 here, before they can occupy a device slot."""
+        """Block until a live request is pending and cut a batch at
+        once (the dispatch rule, module docstring): this thread being
+        here means no model call is in flight. Expired items are
+        settled as 504 here, before they can occupy a device slot;
+        draining flushes what is pending and then ends the thread."""
         with self._cond:
             while True:
                 if self._pending:
                     self._expire_locked()
-                    if not self._pending:
-                        continue
-                    if (self._draining
-                            or self._pending_rows >= self.max_batch_rows
-                            or not self._fits(
-                                self._pending_rows + 1,
-                                max((i.bucket or 0)
-                                    for i in self._pending))):
+                    if self._pending:
                         return self._take_locked()
-                    age = time.perf_counter() - self._pending[0].t_submit
-                    wait = self.max_delay_s - age
-                    for item in self._pending:
-                        if item.deadline is None \
-                                or not item.deadline.bounded:
-                            continue
-                        remaining = item.deadline.remaining()
-                        p95 = self.device_times.p95(item.bucket) or 0.0
-                        # slack = budget left after the device call;
-                        # once it's gone, waiting for batch-mates turns
-                        # a servable request into a 504.
-                        wait = min(wait, remaining - p95, remaining)
-                    if wait <= 0:
-                        return self._take_locked()
-                    with _state("delay"):
-                        self._cond.wait(timeout=wait)
                 elif self._draining:
                     self._closed = True
                     return None
@@ -362,8 +361,8 @@ class DynamicBatcher:
                 expired_counter("batch_wait").inc()
                 if item.future.set_running_or_notify_cancel():
                     item.future.set_exception(DeadlineExceeded(
-                        "request deadline expired while waiting for "
-                        "batch-mates"))
+                        "request deadline expired behind the model "
+                        "call in flight"))
             else:
                 alive.append(item)
         self._pending = alive
@@ -429,6 +428,7 @@ class DynamicBatcher:
                 item.trace.add_span("batch_wait", item.t_submit, wait)
             all_lines.extend(item.lines)
         _C_BATCHES.inc()
+        _H_CUT_IDLE.observe(_cut_idle(batch, self._t_free))
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
         _H_BATCH_ROWS.observe(len(all_lines))
@@ -536,7 +536,7 @@ class _Slot:
     tracked by `pending_writes`)."""
 
     __slots__ = ("kind", "items", "offsets", "rows", "buffer",
-                 "pending_writes", "sealed", "chained", "t_open", "fps")
+                 "pending_writes", "sealed", "cut_idle", "fps")
 
     def __init__(self, kind: str, buffer=None):
         self.kind = kind              # "rows" (zero-copy) | "lines"
@@ -546,8 +546,7 @@ class _Slot:
         self.buffer = buffer
         self.pending_writes = 0
         self.sealed = False
-        self.chained = False
-        self.t_open = time.perf_counter()
+        self.cut_idle = 0.0           # _cut_idle(), set when a worker takes it
         self.fps: set = set()         # model fingerprints seen at parse
 
 
@@ -555,26 +554,24 @@ class ContinuousBatcher:
     """Slot-reservation dispatcher: continuous batching for the serve
     path (--serve_continuous).
 
-    The collect-then-dispatch DynamicBatcher holds every batch until it
-    fills or ages out, so a row arriving just after a dispatch starts a
-    FRESH delay window behind a device step it cannot join. Here the
-    next batch is always forming: `submit()` reserves rows in the tail
-    slot under the lock, parses the extractor lines straight into the
-    slot's padded (rows, contexts) buffer OUTSIDE the lock (zero-copy:
+    The collect-then-dispatch DynamicBatcher parses a batch's lines
+    inside its one model call. Here the next batch is always forming:
+    `submit()` reserves rows in the tail slot under the lock, parses
+    the extractor lines straight into the slot's padded (rows,
+    contexts) buffer OUTSIDE the lock (zero-copy:
     reader.parse_context_lines(out=...) — no per-request RowBatch
     between extractor_pool and the device step), and up to
-    `inflight_steps` worker threads launch a device step as soon as the
-    previous one's dispatch returns. A slot any of whose rows arrived
-    while a step was on device is CHAINED: it dispatches the moment a
-    worker frees (riding step N+1) instead of waiting out max_delay_s.
-    An idle server degrades exactly to the classic behavior — one slot,
-    one delay window, byte-identical responses for a serial client.
+    `inflight_steps` worker threads follow the module's dispatch rule:
+    the head slot is due as soon as a worker is free and no parse is
+    still writing into it (`serve.delay` is the wait for that parse). A
+    row that arrives while every worker is inside a step rides the
+    next one with whatever else arrived behind it. A serial client
+    gets byte-identical responses from both batchers.
 
     Admission control is re-expressed against the in-flight step's ETA:
     a bounded-deadline request is refused (`DeadlineInfeasible`) when
     `remaining < eta + p95(bucket)` where eta is 0 if a worker is free,
-    else the soonest in-flight step's expected completion; the
-    slack-aware early dispatch uses the same per-bucket p95s. Cold
+    else the soonest in-flight step's expected completion. Cold
     tracker => no refusal, as in the classic batcher.
 
     `backend` is the model adapter (serving/server.py) with:
@@ -592,7 +589,7 @@ class ContinuousBatcher:
 
     def __init__(self, predict_fn: Optional[Callable[[List[str]], List]]
                  = None,
-                 max_batch_rows: int = 64, max_delay_s: float = 0.01,
+                 max_batch_rows: int = 64,
                  buckets: Optional[Sequence[int]] = None,
                  inflight_steps: int = 2, backend=None, tenancy=None):
         if predict_fn is None and backend is None:
@@ -602,7 +599,6 @@ class ContinuousBatcher:
         self.backend = backend
         self.tenancy = tenancy
         self.max_batch_rows = max(1, int(max_batch_rows))
-        self.max_delay_s = max(0.0, float(max_delay_s))
         self.buckets = tuple(buckets) if buckets else None
         self.inflight_steps = max(1, int(inflight_steps))
         self.device_times = _DeviceTimeTracker()
@@ -615,6 +611,7 @@ class ContinuousBatcher:
         self._draining = False
         self.batches_dispatched = 0
         self.rides = 0
+        self._t_free = 0.0      # when the last model call returned
         self._workers = [
             threading.Thread(target=self._worker,
                              name=f"serving-batcher-{i}", daemon=True)
@@ -707,11 +704,8 @@ class ContinuousBatcher:
             slot.rows += n
             if slot.rows >= self.max_batch_rows:
                 slot.sealed = True
-            if self._inflight > 0 and not slot.chained:
-                # this row arrived while a step was on device: the slot
-                # rides the next step instead of a fresh delay window
-                slot.chained = True
             if self._inflight > 0:
+                # this row arrived while a step was on device
                 self.rides += 1
                 _C_RIDES.inc()
             if kind == "rows":
@@ -800,20 +794,6 @@ class ContinuousBatcher:
             if len(self._pool) < self._pool_cap:
                 self._pool.append(buffer)
 
-    def _due_wait_locked(self, slot: _Slot) -> float:
-        """Seconds until the head slot is due (<= 0: dispatch now)."""
-        if self._draining or slot.sealed or slot.chained:
-            return 0.0
-        wait = self.max_delay_s - (time.perf_counter() - slot.t_open)
-        for item in slot.items:
-            if item.deadline is None or not item.deadline.bounded \
-                    or item.settled:
-                continue
-            remaining = item.deadline.remaining()
-            p95 = self.device_times.p95(item.bucket) or 0.0
-            wait = min(wait, remaining - p95, remaining)
-        return wait
-
     def _expire_head_locked(self, slot: _Slot) -> None:
         if slot.pending_writes:
             return   # a parse is writing; next pass catches expiries
@@ -828,8 +808,8 @@ class ContinuousBatcher:
                 slot.buffer.example_valid[off:off + n] = False
             if item.future.set_running_or_notify_cancel():
                 item.future.set_exception(DeadlineExceeded(
-                    "request deadline expired while waiting for "
-                    "batch-mates"))
+                    "request deadline expired behind the model "
+                    "call in flight"))
 
     def _worker(self) -> None:
         while True:
@@ -843,6 +823,7 @@ class ContinuousBatcher:
                 self._release_buffer(slot.buffer, slot.rows)
                 with self._cond:
                     self._inflight -= 1
+                    self._t_free = time.perf_counter()
                     self._inflight_meta = [
                         m for m in self._inflight_meta
                         if m[2] is not slot]
@@ -865,10 +846,14 @@ class ContinuousBatcher:
                     self._slots.popleft()
                     self._release_buffer_nolock_queue(slot)
                     continue
-                wait = self._due_wait_locked(slot)
-                if wait <= 0 and slot.pending_writes == 0:
+                if slot.pending_writes == 0:
+                    # the dispatch rule: this worker is free, the head
+                    # slot holds a live request and nothing is writing
                     self._slots.popleft()
                     slot.sealed = True
+                    slot.cut_idle = _cut_idle(
+                        [i for i in slot.items if not i.settled],
+                        self._t_free, self._inflight)
                     self._inflight += 1
                     bucket = max((i.bucket for i in slot.items
                                   if i.bucket is not None
@@ -878,7 +863,7 @@ class ContinuousBatcher:
                     _G_INFLIGHT.set(self._inflight)
                     return slot
                 with _state("delay"):
-                    self._cond.wait(timeout=wait if wait > 0 else None)
+                    self._cond.wait()
 
     def _release_buffer_nolock_queue(self, slot: _Slot) -> None:
         # called with the lock held for a fully-expired slot: return
@@ -903,6 +888,7 @@ class ContinuousBatcher:
                 item.trace.add_span("batch_wait", item.t_submit, wait)
         rows_live = sum(len(i.lines) for i in live)
         _C_BATCHES.inc()
+        _H_CUT_IDLE.observe(slot.cut_idle)
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
         _H_BATCH_ROWS.observe(rows_live)

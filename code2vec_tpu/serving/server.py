@@ -259,7 +259,6 @@ class PredictionServer:
         self.tenancy = TenantPolicy.from_config(self.config)
         batcher_kw = dict(
             max_batch_rows=self.config.serve_batch_size,
-            max_delay_s=self.config.serve_max_delay_ms / 1000.0,
             buckets=model.context_buckets,
             tenancy=self.tenancy)
         # how a row buckets and what a batch may hold, where the model's
@@ -844,8 +843,6 @@ class PredictionServer:
                                 "warm": self.pool.warm}
                                if self.pool is not None else None),
             "batcher": {"max_batch_rows": self.batcher.max_batch_rows,
-                        "max_delay_ms":
-                            self.batcher.max_delay_s * 1000.0,
                         "batches_dispatched":
                             self.batcher.batches_dispatched,
                         "continuous":

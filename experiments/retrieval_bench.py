@@ -99,7 +99,6 @@ def build_model(corpus: str):
         verbose_mode=0,
         test_batch_size=EMBED_BATCH,
         serve_batch_size=16,
-        serve_max_delay_ms=5.0,
         extractor_pool_size=2,
         serve_cache_entries=0,      # /neighbors latency = the full path
         embed_shard_rows=1024,

@@ -68,7 +68,6 @@ N_CLASSES = 24          # distinct request bodies in the corpus
 REQUESTS_PER_CLIENT = 24
 CLIENT_COUNTS = (4, 8)
 SERVE_BATCH = 16
-SERVE_DELAY_MS = 5.0
 BUCKETS = "32,64,128"
 VOCAB = 20_000
 
@@ -93,7 +92,6 @@ def build_model():
         compute_dtype="float32",
         verbose_mode=0,
         serve_batch_size=SERVE_BATCH,
-        serve_max_delay_ms=SERVE_DELAY_MS,
         serve_buckets=BUCKETS,
         extractor_pool_size=2,
     )
@@ -422,7 +420,7 @@ def run_overload_scenario(model, log) -> dict:
     serial_wall = time.perf_counter() - t0
     capacity_rps = n_probe / serial_wall * model.config.extractor_pool_size
     # the uncontended tail at HALF capacity through the same open loop:
-    # includes the batcher's coalescing delay and normal pool handoff,
+    # includes the wait behind model calls in flight and normal pool handoff,
     # i.e. what a healthy, non-overloaded server actually serves
     records = _wrap_server_latency(server)
     open_loop_multiproc(port, capacity_rps * 0.5, 3.0)
@@ -529,7 +527,7 @@ def run_kill_replica_scenario(model, prefix: str, log) -> dict:
         sys.executable, "-m", "code2vec_tpu.cli", "serve",
         "--data", prefix, "--load", save_base,
         "--serve_batch_size", str(SERVE_BATCH),
-        "--serve_buckets", BUCKETS, "--serve_max_delay_ms", "5",
+        "--serve_buckets", BUCKETS,
         "--serve_cache_entries", "0", "--extractor_pool_size", "2",
         "--serve_heartbeat_interval", "1", "-v", "0"]
     sup = Supervisor(config, child_command=child_command)
@@ -871,8 +869,7 @@ def mixed_main() -> None:
       --serve_continuous — two servers over the SAME model, and every
       body is sent to BOTH servers back-to-back in per-slot shuffled
       order (exact per-body pairing). Bar: continuous p50 < classic
-      p50 — a late single row rides the in-flight step's successor
-      instead of opening a fresh delay window behind a bulk batch.
+      p50 — a late single row rides the in-flight step's successor.
     - uncontended: one serial client, single-method bodies, cache off —
       the no-contention tax of the slot-reservation machinery. Bar:
       continuous p50 regresses < 2% vs classic.
@@ -1092,7 +1089,6 @@ def mixed_main() -> None:
     out = {
         "bench": "serving_mixed",
         "serve_batch_size": SERVE_BATCH,
-        "serve_max_delay_ms": SERVE_DELAY_MS,
         "buckets": list(model.context_buckets),
         "pjit_compilations_serving": compiled,
         "pjit_compilations_bound": len(model.context_buckets),
@@ -1151,7 +1147,7 @@ def fleet_main() -> None:
         sys.executable, "-m", "code2vec_tpu.cli", "serve",
         "--data", prefix, "--load", save_base,
         "--serve_batch_size", str(SERVE_BATCH),
-        "--serve_buckets", BUCKETS, "--serve_max_delay_ms", "5",
+        "--serve_buckets", BUCKETS,
         "--serve_cache_entries", "0", "--extractor_pool_size", "2",
         "--serve_heartbeat_interval", "1", "-v", "0",
         "--serve_port", "0", "--serve_telemetry_port", "0"]
@@ -1340,7 +1336,7 @@ def edge_main() -> None:
         sys.executable, "-m", "code2vec_tpu.cli", "serve",
         "--data", prefix, "--load", save_base,
         "--serve_batch_size", str(SERVE_BATCH),
-        "--serve_buckets", BUCKETS, "--serve_max_delay_ms", "5",
+        "--serve_buckets", BUCKETS,
         "--serve_cache_entries", "4096", "--extractor_pool_size", "2",
         "--serve_heartbeat_interval", "1", "-v", "0",
         "--serve_port", "0", "--serve_telemetry_port", "0"]
@@ -1683,7 +1679,7 @@ def slo_main() -> None:
         sys.executable, "-m", "code2vec_tpu.cli", "serve",
         "--data", prefix, "--load", save_base,
         "--serve_batch_size", str(SERVE_BATCH),
-        "--serve_buckets", BUCKETS, "--serve_max_delay_ms", "5",
+        "--serve_buckets", BUCKETS,
         "--serve_cache_entries", "0", "--extractor_pool_size", "2",
         "--serve_heartbeat_interval", "1", "-v", "0",
         "--serve_port", "0", "--serve_telemetry_port", "0"]
@@ -2313,7 +2309,7 @@ def run_tenant_fleet_drill(model, log) -> dict:
         sys.executable, "-m", "code2vec_tpu.cli", "serve",
         "--data", prefix, "--load", save_base,
         "--serve_batch_size", "4",
-        "--serve_buckets", BUCKETS, "--serve_max_delay_ms", "5",
+        "--serve_buckets", BUCKETS,
         "--serve_cache_entries", "0", "--extractor_pool_size", "2",
         "--serve_heartbeat_interval", "1", "-v", "0",
         "--serve_tenants", "hot=1,beta=1,cold=1",
@@ -2542,7 +2538,6 @@ def main() -> None:
         "corpus_classes": len(sources),
         "requests_per_client": REQUESTS_PER_CLIENT,
         "serve_batch_size": SERVE_BATCH,
-        "serve_max_delay_ms": SERVE_DELAY_MS,
         "buckets": list(model.context_buckets),
         "pjit_compilations_serving": compiled,
         "pjit_compilations_bound": len(model.context_buckets),

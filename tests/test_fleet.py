@@ -702,7 +702,7 @@ def _write_json(tmp_path, name, payload):
 def _replica_overrides(**extra):
     overrides = dict(
         serve_host="127.0.0.1", max_contexts=16, serve_batch_size=4,
-        serve_buckets="4,8", serve_max_delay_ms=2.0,
+        serve_buckets="4,8",
         serve_cache_entries=0, extractor_pool_size=1,
         serve_drain_timeout_s=5.0, serve_heartbeat_interval_s=0.2,
         serve_deadline_ms=3000.0)

@@ -236,7 +236,7 @@ def test_batcher_keeps_the_token_budget():
         seen.append([len(r) for r in rows])
         return rows
     batcher = DynamicBatcher(
-        predict, max_batch_rows=64, max_delay_s=0.05, buckets=buckets,
+        predict, max_batch_rows=64, buckets=buckets,
         bucket_of=lambda r: bucket_for(len(r), buckets),
         max_batch_tokens=512)
     try:
@@ -271,8 +271,7 @@ def served(tmp_path_factory):
     first = ScoringModel(config_from_args(
         common + ["--save", str(work / "ck" / "saved")]))
     saved = first.save()
-    config = config_from_args(["serve", "--load", saved,
-                               "--serve_max_delay_ms", "20"] + common)
+    config = config_from_args(["serve", "--load", saved] + common)
     model = ScoringModel(config)
     model.warmup()
     server = PredictionServer(model, config)

@@ -129,7 +129,6 @@ def _serving_config(tmp_path, **overrides) -> Config:
         verbose_mode=0,
         serve_batch_size=4,
         serve_buckets="4,8",
-        serve_max_delay_ms=5.0,
         serve_cache_entries=16,
         extractor_pool_size=1,
         num_batches_to_log_progress=1000,
@@ -278,16 +277,56 @@ def test_pool_deterministic_rejection_not_retried(fake_extractor,
 # ---------------------------------------------------------- batcher
 
 
+class _HeldCall:
+    """A predict_fn whose FIRST call blocks until `release()`. What is
+    submitted meanwhile piles up behind it: the model call in flight is
+    the only batching window the dispatch rule has, so this is how a
+    test forces batch-mates."""
+
+    def __init__(self, fn=lambda lines: [l.upper() for l in lines]):
+        self.fn = fn
+        self.calls = []
+        self.entered = threading.Event()
+        self._go = threading.Event()
+
+    def __call__(self, lines):
+        self.calls.append(list(lines))
+        if len(self.calls) == 1:
+            self.entered.set()
+            assert self._go.wait(10), "the held call was never released"
+        return self.fn(lines)
+
+    def hold(self, batcher, **kwargs):
+        """Submit the request whose call is held; returns its future
+        once the dispatcher is inside `predict_fn`."""
+        future = batcher.submit(["hold"], **kwargs)
+        assert self.entered.wait(10)
+        return future
+
+    def release(self):
+        self._go.set()
+
+
+def _make_batcher(kind, predict_fn, **kwargs):
+    """Either batcher over a plain `predict_fn`; the continuous one with
+    a single worker, so that one held call holds the whole dispatcher
+    as it does in the classic batcher."""
+    from code2vec_tpu.serving import batcher as batcher_mod
+    if kind == "classic":
+        return batcher_mod.DynamicBatcher(predict_fn, **kwargs)
+    return batcher_mod.ContinuousBatcher(predict_fn, inflight_steps=1,
+                                         **kwargs)
+
+
+BATCHERS = ("classic", "continuous")
+
+
 def test_batcher_coalesces_concurrent_requests():
     from code2vec_tpu.serving.batcher import DynamicBatcher
-    calls = []
-
-    def predict_fn(lines):
-        calls.append(list(lines))
-        return [f"r:{l}" for l in lines]
-
-    batcher = DynamicBatcher(predict_fn, max_batch_rows=4,
-                             max_delay_s=2.0)
+    predict_fn = _HeldCall(lambda lines: [f"r:{l}" for l in lines])
+    calls = predict_fn.calls
+    batcher = DynamicBatcher(predict_fn, max_batch_rows=4)
+    held = predict_fn.hold(batcher)
     futures = []
 
     def submit(i):
@@ -299,21 +338,37 @@ def test_batcher_coalesces_concurrent_requests():
         t.start()
     for t in threads:
         t.join()
+    predict_fn.release()
+    assert held.result(timeout=10) == ["r:hold"]
     results = [f.result(timeout=10) for f in futures]
     assert sorted(r[0] for r in results) == [f"r:line{i}"
                                              for i in range(4)]
-    # 4 rows hit max_batch_rows -> ONE device batch, not four
-    assert batcher.batches_dispatched == 1
-    assert sorted(len(c) for c in calls) == [4]
+    # the 4 rows that piled up behind the held call -> ONE device
+    # batch, not four
+    assert batcher.batches_dispatched == 2
+    assert [len(c) for c in calls] == [1, 4]
     batcher.drain()
 
 
 def test_batcher_flushes_on_delay_and_preserves_order():
     from code2vec_tpu.serving.batcher import DynamicBatcher
+    # alone on an idle batcher: flushed at once, no batch-mates needed
     batcher = DynamicBatcher(lambda lines: [l.upper() for l in lines],
-                             max_batch_rows=100, max_delay_s=0.02)
+                             max_batch_rows=100)
     f = batcher.submit(["a", "b", "c"])
     assert f.result(timeout=10) == ["A", "B", "C"]
+    batcher.drain()
+    # behind a held call: one batch, rows in submit order
+    predict_fn = _HeldCall()
+    batcher = DynamicBatcher(predict_fn, max_batch_rows=100)
+    held = predict_fn.hold(batcher)
+    futures = [batcher.submit(["a", "b", "c"]), batcher.submit(["d"]),
+               batcher.submit(["e", "f"])]
+    predict_fn.release()
+    assert held.result(timeout=10) == ["HOLD"]
+    assert [f.result(timeout=10) for f in futures] \
+        == [["A", "B", "C"], ["D"], ["E", "F"]]
+    assert predict_fn.calls == [["hold"], ["a", "b", "c", "d", "e", "f"]]
     batcher.drain()
 
 
@@ -323,7 +378,9 @@ def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
     """`serving_dispatcher_seconds{state}`: idle, delay and dispatch are
     observed on leaving each state and together account for every
     dispatcher thread's life; dispatch over the wall time is the busy
-    share of the thread(s) every request passes through."""
+    share of the thread(s) every request passes through. A free
+    dispatcher dispatches: with no parse to wait for, nothing is ever
+    spent in `delay`."""
     from code2vec_tpu.obs.metrics import Histogram
     from code2vec_tpu.serving import batcher as batcher_mod
     states = {s: Histogram() for s in ("idle", "delay", "dispatch")}
@@ -335,12 +392,10 @@ def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
 
     t0 = time.perf_counter()
     if kind == "classic":
-        batcher = batcher_mod.DynamicBatcher(predict_fn, max_batch_rows=8,
-                                             max_delay_s=0.03)
+        batcher = batcher_mod.DynamicBatcher(predict_fn, max_batch_rows=8)
     else:
         batcher = batcher_mod.ContinuousBatcher(
-            predict_fn, max_batch_rows=8, max_delay_s=0.03,
-            inflight_steps=workers)
+            predict_fn, max_batch_rows=8, inflight_steps=workers)
     for i in range(6):
         assert batcher.submit([f"a{i}", f"b{i}"]).result(timeout=10) \
             == [f"A{i}", f"B{i}"]
@@ -350,7 +405,7 @@ def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
     n = batcher.batches_dispatched
     assert n == 6 and states["dispatch"].count == n
     assert states["dispatch"].sum == pytest.approx(0.02 * n, rel=0.5)
-    assert states["delay"].sum >= 0.03 * n * 0.8     # each waited its window
+    assert states["delay"].count == 0     # nobody waits out a window
     assert states["idle"].count >= 1
     covered = sum(h.sum for h in states.values())
     assert covered == pytest.approx(wall * workers, rel=0.1, abs=0.05)
@@ -362,7 +417,7 @@ def test_batcher_error_propagates_and_drain_refuses():
     def boom(lines):
         raise RuntimeError("device on fire")
 
-    batcher = DynamicBatcher(boom, max_batch_rows=2, max_delay_s=0.01)
+    batcher = DynamicBatcher(boom, max_batch_rows=2)
     f = batcher.submit(["x"])
     with pytest.raises(RuntimeError, match="device on fire"):
         f.result(timeout=10)
@@ -398,9 +453,10 @@ def test_batch_span_attrs_shared_and_thread_count_stable():
     from code2vec_tpu.obs.reqtrace import RequestTrace
     from code2vec_tpu.serving.batcher import DynamicBatcher
     before = threading.active_count()
-    batcher = DynamicBatcher(lambda lines: [l for l in lines],
-                             max_batch_rows=3, max_delay_s=2.0)
+    predict_fn = _HeldCall(lambda lines: [l for l in lines])
+    batcher = DynamicBatcher(predict_fn, max_batch_rows=3)
     assert threading.active_count() == before + 1
+    held = predict_fn.hold(batcher)
     traces = [RequestTrace() for _ in range(3)]
     futures = []
 
@@ -413,9 +469,10 @@ def test_batch_span_attrs_shared_and_thread_count_stable():
         t.start()
     for t in threads:
         t.join()
-    for f in list(futures):
+    predict_fn.release()
+    for f in [held] + list(futures):
         f.result(timeout=10)
-    assert batcher.batches_dispatched == 1
+    assert batcher.batches_dispatched == 2      # the held call, the three
     batch_attrs = [attrs for tr in traces
                    for (name, _, _, _, _, attrs) in tr._spans
                    if name == "batch"]
@@ -431,8 +488,7 @@ def test_batch_span_attrs_shared_and_thread_count_stable():
 
 def test_continuous_row_rides_step_n_plus_1():
     """A row admitted while step N is on device rides step N+1 the
-    moment the worker frees — never a fresh max_delay_s window, never
-    step N+2 when a slot is free."""
+    moment the worker frees — never step N+2 when a slot is free."""
     from code2vec_tpu.serving.batcher import ContinuousBatcher
     calls = []
 
@@ -442,8 +498,8 @@ def test_continuous_row_rides_step_n_plus_1():
         return [l.upper() for l in lines]
 
     batcher = ContinuousBatcher(predict, max_batch_rows=4,
-                                max_delay_s=2.0, inflight_steps=1)
-    # four rows fill the slot -> step N dispatches immediately
+                                inflight_steps=1)
+    # the worker is free -> step N dispatches immediately
     f1 = batcher.submit(["a1", "a2", "a3", "a4"])
     time.sleep(0.1)                      # step N is on device now
     t0 = time.perf_counter()
@@ -452,7 +508,7 @@ def test_continuous_row_rides_step_n_plus_1():
     assert f2.result(timeout=10) == ["B"]
     waited = time.perf_counter() - t0
     # rode step N+1 (~0.15s left of N + 0.25s of N+1) instead of
-    # opening a fresh 2s delay window or waiting for step N+2
+    # waiting for step N+2
     assert waited < 1.0, waited
     assert batcher.batches_dispatched == 2
     assert calls == [["a1", "a2", "a3", "a4"], ["b"]]
@@ -476,7 +532,7 @@ def test_continuous_refusal_against_inflight_eta():
         return list(lines)
 
     batcher = ContinuousBatcher(predict, max_batch_rows=1,
-                                max_delay_s=0.0, inflight_steps=1)
+                                inflight_steps=1)
     for _ in range(4):
         batcher.device_times.record(None, 0.5)   # p95 = 0.5s
     f1 = batcher.submit(["x"])                   # occupies the worker
@@ -501,10 +557,17 @@ def test_continuous_refusal_against_inflight_eta():
 
 def test_continuous_drain_flushes_partial_slot():
     from code2vec_tpu.serving.batcher import ContinuousBatcher
-    batcher = ContinuousBatcher(lambda lines: [l * 2 for l in lines],
-                                max_batch_rows=100, max_delay_s=30.0)
-    f = batcher.submit(["q"])
-    batcher.drain(timeout=10)
+    predict_fn = _HeldCall(lambda lines: [l * 2 for l in lines])
+    batcher = ContinuousBatcher(predict_fn, max_batch_rows=100,
+                                inflight_steps=1)
+    held = predict_fn.hold(batcher)
+    f = batcher.submit(["q"])           # a partial slot behind the call
+    drainer = threading.Thread(target=batcher.drain, args=(10,))
+    drainer.start()                     # intake stops while it forms
+    predict_fn.release()
+    drainer.join(timeout=15)
+    assert not drainer.is_alive()
+    assert held.result(timeout=1) == ["holdhold"]
     assert f.result(timeout=1) == ["qq"]
     f2 = batcher.submit(["z"])
     with pytest.raises(RuntimeError, match="draining"):
@@ -582,8 +645,8 @@ def test_continuous_stale_parse_falls_back_to_lines_path():
             calls["lines"] += 1
             return [f"fpNEW:{ln}" for ln in lines]
 
-    b = ContinuousBatcher(max_batch_rows=4, max_delay_s=0.005,
-                          backend=_Backend(), inflight_steps=1)
+    b = ContinuousBatcher(max_batch_rows=4, backend=_Backend(),
+                          inflight_steps=1)
     try:
         futs = [b.submit([f"l{i}"]) for i in range(2)]
         out = [f.result(timeout=5) for f in futs]
@@ -592,6 +655,119 @@ def test_continuous_stale_parse_falls_back_to_lines_path():
     assert calls["rows"] >= 1, "rows path never attempted"
     assert calls["lines"] >= 1, "StaleParse did not fall back to lines"
     assert out == [["fpNEW:l0"], ["fpNEW:l1"]]
+
+
+# ------------------------- the dispatch rule, shared by both batchers
+
+
+@pytest.mark.parametrize("kind", BATCHERS)
+def test_lone_request_on_idle_batcher_dispatches_at_once(kind):
+    """A free dispatcher dispatches: a lone request reaches `predict_fn`
+    without waiting for batch-mates or a window, before any second
+    request exists."""
+    predict_fn = _HeldCall()
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=64)
+    try:
+        phases = {}
+        first = batcher.submit(["solo"], phases=phases)
+        assert predict_fn.entered.wait(10)      # inside the model call
+        assert predict_fn.calls == [["solo"]]   # ... with nothing else
+        predict_fn.release()
+        assert first.result(timeout=10) == ["SOLO"]
+        assert phases["batch_wait"] < 0.05
+    finally:
+        predict_fn.release()
+        batcher.drain(timeout=10)
+
+
+@pytest.mark.parametrize("kind,budget", [("classic", None),
+                                         ("continuous", None),
+                                         ("classic", 512)])
+def test_requests_behind_a_held_call_are_cut_as_one_batch(kind, budget):
+    """The call in flight is the only batching window: what was
+    submitted behind it is cut the moment it returns, in submit order,
+    inside the row cap and (classic batcher, a model that sets one) the
+    token budget."""
+    from code2vec_tpu.serving.batcher import bucket_for
+    predict_fn = _HeldCall(lambda lines: list(lines))
+    kwargs = {}
+    if budget is None:
+        sent = [[f"r{i}"] for i in range(6)]
+        want = [["r0", "r1", "r2", "r3"], ["r4", "r5"]]     # 4-row cap
+    else:
+        buckets = (128, 256)
+        kwargs = dict(buckets=buckets, max_batch_tokens=budget,
+                      bucket_of=lambda r: bucket_for(len(r), buckets))
+        sent = [["x" * 100], ["y" * 100], ["z" * 200], ["w" * 100]]
+        # 3 rows x the 256 bucket would pass 512 tokens; 2 x 256 fits
+        want = [["x" * 100, "y" * 100], ["z" * 200, "w" * 100]]
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=4, **kwargs)
+    try:
+        held = predict_fn.hold(batcher)
+        futures = [batcher.submit(lines) for lines in sent]
+        assert predict_fn.calls == [["hold"]]   # nothing passes the call
+        predict_fn.release()
+        assert held.result(timeout=10) == ["hold"]
+        assert [f.result(timeout=10) for f in futures] == sent
+    finally:
+        predict_fn.release()
+        batcher.drain(timeout=10)
+    assert predict_fn.calls[1:] == want
+    assert batcher.batches_dispatched == 3
+
+
+@pytest.mark.parametrize("kind", BATCHERS)
+def test_request_expiring_behind_a_held_call_settles_504(kind):
+    """Deadlines are not weakened: a request whose budget runs out
+    while it waits behind the call in flight settles as
+    DeadlineExceeded and never reaches `predict_fn`."""
+    from code2vec_tpu.serving.admission import Deadline, DeadlineExceeded
+    predict_fn = _HeldCall()
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=8)
+    try:
+        held = predict_fn.hold(batcher)
+        doomed = batcher.submit(["late"], deadline=Deadline(0.05))
+        alive = batcher.submit(["fine"], deadline=Deadline(30.0))
+        time.sleep(0.15)                        # the budget runs out
+        predict_fn.release()
+        assert held.result(timeout=10) == ["HOLD"]
+        with pytest.raises(DeadlineExceeded):
+            doomed.result(timeout=10)
+        assert alive.result(timeout=10) == ["FINE"]
+    finally:
+        predict_fn.release()
+        batcher.drain(timeout=10)
+    assert predict_fn.calls == [["hold"], ["fine"]]
+
+
+@pytest.mark.parametrize("kind", BATCHERS)
+def test_cut_idle_ratio_tells_straight_through_from_behind_a_call(
+        monkeypatch, kind):
+    """`serving_batch_cut_idle_ratio`, one observation a dispatched
+    batch: 1.0 when the batch's oldest request found the dispatcher
+    free, 0.0 when it was cut behind a model call."""
+    from code2vec_tpu.obs.metrics import Histogram
+    from code2vec_tpu.serving import batcher as batcher_mod
+    cut_idle = Histogram(buckets=(0.0, 1.0))
+    monkeypatch.setattr(batcher_mod, "_H_CUT_IDLE", cut_idle)
+    predict_fn = _HeldCall()
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=8)
+    try:
+        held = predict_fn.hold(batcher)
+        assert (cut_idle.sum, cut_idle.count) == (1.0, 1)
+        behind = [batcher.submit([f"b{i}"]) for i in range(3)]
+        predict_fn.release()
+        for f in [held] + behind:
+            f.result(timeout=10)
+        assert (cut_idle.sum, cut_idle.count) == (1.0, 2)
+        # once the dispatcher is back from the call and free again,
+        # the next one goes straight through
+        time.sleep(0.05)
+        assert batcher.submit(["again"]).result(timeout=10) == ["AGAIN"]
+        assert (cut_idle.sum, cut_idle.count) == (2.0, 3)
+    finally:
+        predict_fn.release()
+        batcher.drain(timeout=10)
 
 
 def test_parse_buckets_and_bucket_for():
@@ -763,11 +939,18 @@ def test_http_end_to_end(server):
     assert _post(server.port, "predict", "CRASH_ALWAYS f(")[0] == 503
 
 
-def test_http_coalesces_concurrent_requests(server):
+def test_http_coalesces_concurrent_requests(server, monkeypatch):
     before = server.batcher.batches_dispatched
     codes = [f"class A{i} {{ int f{i}(int n) {{ return n; }} }}"
              for i in range(4)]
     results = [None] * 4
+    # the model call in flight is the batching window: hold one
+    held = _HeldCall(server.batcher.predict_fn)
+    monkeypatch.setattr(server.batcher, "predict_fn", held)
+    holder = threading.Thread(target=_post, args=(
+        server.port, "predict", "class H { int held() { return 0; } }"))
+    holder.start()
+    assert held.entered.wait(30)
 
     def post(i):
         results[i] = _post(server.port, "predict", codes[i])
@@ -775,14 +958,20 @@ def test_http_coalesces_concurrent_requests(server):
     threads = [threading.Thread(target=post, args=(i,)) for i in range(4)]
     for t in threads:
         t.start()
-    for t in threads:
+    deadline = time.perf_counter() + 30
+    while len(server.batcher._pending) < 4:
+        assert time.perf_counter() < deadline
+        time.sleep(0.005)
+    held.release()
+    for t in threads + [holder]:
         t.join()
     assert all(r[0] == 200 for r in results)
     for i, (_, body) in enumerate(results):
         assert json.loads(body)["methods"][0]["original_name"] == f"f{i}"
-    # 4 single-method requests, serve_batch_size=4, 5ms delay window:
+    # 4 single-method requests behind the held call, serve_batch_size=4:
     # strictly fewer device batches than requests proves coalescing
     assert server.batcher.batches_dispatched - before < 4
+    assert [len(c) for c in held.calls] == [1, 4]
 
 
 def test_cache_hit_is_byte_equal_and_normalized(server):
@@ -1129,15 +1318,19 @@ def test_serve_cli_flags_parse():
     config = config_from_args([
         "serve", "--load", "/tmp/nonexistent-model", "--serve_port", "0",
         "--serve_batch_size", "32", "--serve_buckets", "16,32",
-        "--serve_max_delay_ms", "2.5", "--serve_cache_entries", "128",
-        "--extractor_pool_size", "3"])
+        "--serve_cache_entries", "128", "--extractor_pool_size", "3"])
     assert config.serve is True
     assert config.serve_port == 0
     assert config.serve_batch_size == 32
     assert config.serve_buckets == "16,32"
-    assert config.serve_max_delay_ms == 2.5
     assert config.serve_cache_entries == 128
     assert config.extractor_pool_size == 3
     # --serve flag form equals the subcommand form
     config2 = config_from_args(["--serve", "--load", "/tmp/x"])
     assert config2.serve is True
+    # the coalescing delay is gone (a free dispatcher dispatches): the
+    # flag is rejected, not ignored, and Config has no such field
+    with pytest.raises(SystemExit):
+        config_from_args(["serve", "--load", "/tmp/x",
+                          "--serve_max_delay_ms", "2.5"])
+    assert not hasattr(config2, "serve_max_delay_ms")

@@ -34,7 +34,9 @@ import pytest
 from code2vec_tpu import obs
 from code2vec_tpu.utils import faults
 
-from test_serving import FAKE_EXTRACTOR, _counter_value, _serving_config
+from test_serving import (
+    FAKE_EXTRACTOR, _HeldCall, _counter_value, _serving_config,
+)
 
 pytestmark = [pytest.mark.serving, pytest.mark.serving_chaos]
 
@@ -281,15 +283,15 @@ def test_admission_estimated_wait_sheds_doomed_requests():
 def test_batcher_refuses_infeasible_deadline_and_expires_waiters():
     """The batcher's two deadline duties: refuse a request whose budget
     cannot cover its bucket's observed p95 device time (503 shed, no
-    device slot), and settle a request that expires while coalescing as
-    504 before dispatch."""
+    device slot), and settle a request that expires behind the model
+    call in flight as 504 before dispatch."""
     from code2vec_tpu.serving.admission import (
         Deadline, DeadlineExceeded, DeadlineInfeasible,
     )
     from code2vec_tpu.serving.batcher import DynamicBatcher
 
     batcher = DynamicBatcher(lambda lines: [l for l in lines],
-                             max_batch_rows=64, max_delay_s=5.0)
+                             max_batch_rows=64)
     try:
         # seed the p95 estimate: 0.5s device calls
         for _ in range(4):
@@ -297,25 +299,32 @@ def test_batcher_refuses_infeasible_deadline_and_expires_waiters():
         f = batcher.submit(["line a,b,c"], deadline=Deadline(0.1))
         with pytest.raises(DeadlineInfeasible):
             f.result(timeout=5)
-        # feasible budget but a 5s coalescing window: the deadline
-        # forces early dispatch instead of a 504 (slack-aware collect)
+        # a feasible budget on a free dispatcher: dispatched at once,
+        # nothing waits for batch-mates, so no 504
         t0 = time.perf_counter()
         f2 = batcher.submit(["line a,b,c"], deadline=Deadline(1.0))
         assert f2.result(timeout=5) == ["line a,b,c"]
         assert time.perf_counter() - t0 < 2.0
     finally:
         batcher.drain()
-    # expiry while waiting for batch-mates -> 504 without dispatch
-    batcher2 = DynamicBatcher(lambda lines: [l for l in lines],
-                              max_batch_rows=64, max_delay_s=10.0)
+    # expiry while waiting behind a held model call -> 504 without
+    # dispatch
+    held_call = _HeldCall(lambda lines: [l for l in lines])
+    batcher2 = DynamicBatcher(held_call, max_batch_rows=64)
     try:
+        first = held_call.hold(batcher2)
         t0 = time.perf_counter()
         f3 = batcher2.submit(["line a,b,c"], deadline=Deadline(0.05))
+        time.sleep(0.1)
+        held_call.release()
         with pytest.raises(DeadlineExceeded):
             f3.result(timeout=5)
         assert time.perf_counter() - t0 < 2.0
-        assert batcher2.batches_dispatched == 0
+        assert first.result(timeout=5) == ["hold"]
+        assert batcher2.batches_dispatched == 1      # the held call only
+        assert held_call.calls == [["hold"]]
     finally:
+        held_call.release()
         batcher2.drain()
 
 
@@ -1006,7 +1015,6 @@ def _write_child_overrides(tmp_path, fake_extractor, **extra):
         max_contexts=16,
         serve_batch_size=4,
         serve_buckets="4,8",
-        serve_max_delay_ms=2.0,
         serve_cache_entries=0,
         extractor_pool_size=1,
         serve_drain_timeout_s=5.0,

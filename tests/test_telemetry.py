@@ -241,7 +241,7 @@ def test_supervisor_merged_metrics_equal_replica_sum_and_fleet(
     monkeypatch.setenv("C2V_SERVE_FORCE_PROXY", "1")
     overrides = dict(
         serve_host="127.0.0.1", max_contexts=16, serve_batch_size=4,
-        serve_buckets="4,8", serve_max_delay_ms=2.0,
+        serve_buckets="4,8",
         serve_cache_entries=0, extractor_pool_size=1,
         serve_drain_timeout_s=5.0, serve_heartbeat_interval_s=0.2)
     overrides_path = tmp_path / "child-config.json"

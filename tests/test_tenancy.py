@@ -414,8 +414,8 @@ def test_classic_batcher_dwrr_under_two_tenants():
         seen.append(list(lines))
         return [f"r:{ln}" for ln in lines]
 
-    b = DynamicBatcher(max_batch_rows=4, max_delay_s=0.01,
-                       predict_fn=predict, tenancy=pol)
+    b = DynamicBatcher(max_batch_rows=4, predict_fn=predict,
+                       tenancy=pol)
     try:
         warm = b.submit(["warm"], tenant="a")
         _time.sleep(0.2)  # dispatcher is now blocked inside predict
