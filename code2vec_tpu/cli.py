@@ -406,28 +406,6 @@ def arguments_parser() -> ArgumentParser:
                              "export, or all-MIPS for artifacts "
                              "without one; 0 = exact-only bit-for-bit; "
                              "requires --serve_mips_nprobe > 0)")
-    parser.add_argument("--overlap_allreduce",
-                        dest="overlap_grad_allreduce",
-                        action="store_true", default=None,
-                        help="bucketed async gradient all-reduce: "
-                             "split the train step into backward + "
-                             "per-bucket all-reduce+Adam dispatches so "
-                             "communication overlaps the optimizer "
-                             "apply (dense optimizer; dp meshes, or "
-                             "tp/cp with --manual_tp_kernels; "
-                             "README 'Roofline levers')")
-    parser.add_argument("--overlap_bucket_mb", type=float, default=None,
-                        metavar="MB",
-                        help="target gradient-bucket size for "
-                             "--overlap_allreduce (default 32)")
-    parser.add_argument("--overlap_in_backward",
-                        action="store_true", default=None,
-                        help="in-backward bucket completion for "
-                             "--overlap_allreduce: split the backward "
-                             "itself by bucket so bucket i's "
-                             "all-reduce+apply dispatches while bucket "
-                             "i+1's backward runs (costs one forward "
-                             "per extra bucket; BENCH_INPUT.md A/B)")
     parser.add_argument("--no_aot", action="store_true",
                         help="skip the jax.export AOT lowerings in the "
                              "exported artifact (consumers then always "
@@ -644,14 +622,6 @@ def arguments_parser() -> ArgumentParser:
                              "single pack; build/grow it with the "
                              "`corpus` subcommand (README 'Training at "
                              "pod scale')")
-    parser.add_argument("--prefetch_double_buffer",
-                        action="store_true", default=None,
-                        help="double-buffer device transfers: issue "
-                             "batch N+1's device_put before handing "
-                             "batch N to the step loop, overlapping "
-                             "the transfer with step dispatch (one "
-                             "extra batch of device memory; watch "
-                             "train_input_bound_fraction)")
     parser.add_argument("--gspmd", action="store_true",
                         help="disable the manual shard_map TP kernels and "
                              "rely on GSPMD sharding propagation")
@@ -867,10 +837,6 @@ def config_from_args(argv=None) -> Config:
                                       "serve_mips_nprobe",
                                       "serve_mips_nlist",
                                       "serve_mips_crossover",
-                                      "overlap_grad_allreduce",
-                                      "overlap_bucket_mb",
-                                      "overlap_in_backward",
-                                      "prefetch_double_buffer",
                                       "train_corpus_manifest",
                                       "topk_block_size",
                                       "embed_out", "embed_dtype",

@@ -195,12 +195,6 @@ class Config:
     preprocess_workers: int = 0
     # Number of batches the host pipeline keeps in flight ahead of device.
     prefetch_batches: int = 4
-    # Double-buffer device transfers (utils/prefetch.py): issue the
-    # device_put for batch N+1 before handing batch N to the step loop,
-    # so the N+1 transfer overlaps step N's dispatch instead of
-    # serializing after it. One extra batch of device memory; the
-    # train_input_bound_fraction gauge reads whether it pays off.
-    prefetch_double_buffer: bool = False
     # When set, a jax.profiler trace of train batches 10-20 is written
     # here (viewable in TensorBoard / Perfetto).
     profile_dir: Optional[str] = None
@@ -523,29 +517,6 @@ class Config:
     # crossover row count. Requires serve_mips_nprobe > 0 to take
     # effect (there is no MIPS head to dispatch to otherwise).
     serve_mips_crossover: int = -1
-    # Overlap the gradient all-reduce with the optimizer apply
-    # (parallel/overlap.py): the train step splits into backward (no
-    # cross-host reduce) + per-bucket all-reduce+Adam jits dispatched
-    # back to back, so bucket i's apply overlaps bucket i+1's reduce
-    # and the host never blocks on one monolithic step chain. Dense
-    # optimizer only; data-parallel GSPMD meshes, or manual-kernel
-    # tp/cp meshes (--manual_tp_kernels — the manual forward runs per
-    # shard and the bucket reducers psum each leaf over exactly the
-    # axes it is replicated on). Measured at 2 CPU hosts over gloo in
-    # BENCH_INPUT.md; not measured on the current machine.
-    overlap_grad_allreduce: bool = False
-    # Target bytes per gradient bucket, in MB (leaves bigger than one
-    # bucket get their own).
-    overlap_bucket_mb: float = 32.0
-    # True in-backward bucket completion (parallel/overlap.py): split
-    # the backward itself by bucket so bucket i's all-reduce + Adam
-    # apply dispatches while bucket i+1's backward is still running,
-    # instead of overlapping only the post-backward reduce chain.
-    # Costs one extra forward per bucket beyond the first (no
-    # cross-bucket activation reuse at the jit seam) — the
-    # input-bench A/B (BENCH_INPUT.md) records whether the overlap
-    # buys more than the recompute. Requires overlap_grad_allreduce.
-    overlap_in_backward: bool = False
     # Also AOT-export (jax.export) the bucketed serve functions into
     # the artifact, one per (serve_batch_size, context bucket) shape,
     # so a serving replica cold-starts from deserialized lowerings
@@ -1155,30 +1126,10 @@ class Config:
             raise ValueError(
                 "serve_inflight_steps must be >= 1 (device steps the "
                 "continuous batcher may keep in flight).")
-        if self.overlap_bucket_mb <= 0:
-            raise ValueError("overlap_bucket_mb must be > 0.")
-        if self.overlap_grad_allreduce and self.use_sparse_embedding_update:
-            raise ValueError(
-                "overlap_grad_allreduce is incompatible with "
-                "--sparse_embedding_update: the sparse path already "
-                "exchanges (ids, rows) lists instead of table-shaped "
-                "gradients.")
-        if (self.overlap_grad_allreduce and (self.tp > 1 or self.cp > 1)
-                and not self.use_manual_tp_kernels):
-            raise ValueError(
-                "overlap_grad_allreduce on a tp/cp-sharded mesh requires "
-                "--manual_tp_kernels: the split backward runs the forward "
-                "per shard, which only the manual-kernel path does under "
-                "tp/cp sharding (GSPMD tp/cp keeps the stock fused step).")
         if self.train_corpus_manifest and not self.use_packed_data:
             raise ValueError(
                 "--train_corpus_manifest requires packed data: the "
                 "manifest lists .c2vb shards (drop --no_packed_data).")
-        if self.overlap_in_backward and not self.overlap_grad_allreduce:
-            raise ValueError(
-                "overlap_in_backward requires overlap_grad_allreduce: "
-                "in-backward completion is a scheduling mode of the "
-                "bucketed overlap step.")
         if self.export_artifact_path and not self.is_loading:
             raise ValueError(
                 "export (--artifact_out) requires --load: the artifact "

@@ -224,15 +224,6 @@ class Trainer:
         g_epoch = reg.gauge("train_epoch", "current epoch number")
         g_rss = reg.gauge("process_rss_bytes", "current resident set size")
 
-        # Overlapped train step (parallel/overlap.py): the composite
-        # announces its bucket plan once — the operator reading the log
-        # knows whether the dispatch histogram covers one program or
-        # 1 + K (and the bench A/B can assert which arm it measured).
-        overlap_desc = getattr(self.train_step, "overlap_description",
-                               None)
-        if overlap_desc:
-            log(f"Overlapped train step active: {overlap_desc}")
-
         batch_num = 0              # batches this run
         trace_active = False       # profiler trace in flight
         epoch = self.initial_epoch
@@ -262,7 +253,6 @@ class Trainer:
                                                 chips))
         prefetcher = DevicePrefetcher(
             batches, self.mesh, depth=config.prefetch_batches,
-            double_buffer=getattr(config, "prefetch_double_buffer", False),
             observe=observe)
         watcher = None
         if getattr(config, "save_on_preemption", True):
@@ -606,9 +596,8 @@ class Trainer:
                     # number: the share of the window's wall time the
                     # host spent blocked waiting for input. ~0 = device-
                     # bound (scaling out hosts buys nothing on input);
-                    # approaching 1 = feed-bound (shard the corpus /
-                    # enable --prefetch_double_buffer before buying
-                    # more compute).
+                    # approaching 1 = feed-bound (more corpus shards,
+                    # more feeding hosts, before buying more compute).
                     reg.gauge("train_input_bound_fraction",
                               "fraction of the last log window the step "
                               "loop spent blocked on input data"

@@ -6,16 +6,30 @@ TPU-first replacement for the reference's per-batch `sess.run` boundary
 here one jitted function with donated state performs
 forward/backward/Adam-update on device; the host only feeds int32 batches.
 
-Two sharding strategies (both over parallel/mesh.py's 3-axis mesh):
+Four train steps, each ONE jitted program over parallel/mesh.py's
+3-axis mesh. `make_train_step` picks by two facts and nothing else:
+manual or not (the mesh and `use_manual_tp_kernels`), sparse or not (the
+opt-state's type). The cell of `BENCHMARK.json`, or the queued cell
+(`ROADMAP.md` B1), that owns each:
 
-1. **GSPMD** (default): jit with NamedSharding-annotated inputs/outputs —
-   the scaling-book recipe: annotate, let XLA insert the collectives.
-2. **Manual shard_map** (`use_manual_tp_kernels` with tp>1 or cp>1):
-   explicit collectives — vocab-parallel embedding gathers, psum-logsumexp
-   cross-entropy over row-sharded logits (ops/sharded.py), psum(max/sumexp)
-   context-parallel attention softmax (ops/attention.py), gradient psums
-   derived from each leaf's storage replication
-   (parallel.mesh.replicated_axes_for_spec).
+1. **GSPMD, dense Adam** (no mesh, `--dp`, or tp/cp under `--gspmd`):
+   jit with NamedSharding-annotated inputs/outputs — the scaling-book
+   recipe: annotate, let XLA insert the collectives. Where every chip
+   holds whole tables it runs the live-rows lookup (ops/embed.py), chip
+   by chip under shard_map. All four train cells (`java14m.train_dp4`
+   and the three `*.train_hostfed*`).
+2. **GSPMD, touched-rows Adam**: gathers outside the differentiated
+   function, (ids, grad rows) in place of table-shaped gradients.
+   Queued: `java14m.train_dp4_sparse` (B1, B2).
+3. **Manual shard_map, dense Adam** (`use_manual_tp_kernels` with tp>1
+   or cp>1): explicit collectives — vocab-parallel embedding gathers,
+   psum-logsumexp cross-entropy over row-sharded logits (ops/sharded.py),
+   psum(max/sumexp) context-parallel attention softmax
+   (ops/attention.py), gradient psums derived from each leaf's storage
+   replication (parallel.mesh.replicated_axes_for_spec). Queued:
+   `java14m.train_tp4` (B1), which also decides it against 1 on one mesh.
+4. **Manual shard_map, touched-rows Adam**: 3's forward, the rows
+   all-gathered over data/ctx. No cell queued: it waits for 2 and 3.
 
 Loss definition matches tensorflow_model.py:225-229: sum of sparse softmax
 CE over the batch divided by batch size.
@@ -125,10 +139,8 @@ def gathers_live_rows(config, mesh: Optional[Mesh]) -> bool:
     own, one all-reduce a table): left to GSPMD, a loop's scatter into a
     replicated table would be reduced across chips in every iteration.
     So it needs whole tables on every chip: no mesh, or a data-only one;
-    tp/cp meshes keep `jnp.take`, and the sparse and the overlapped step
-    never see it."""
-    if uses_sparse_update(config) or getattr(
-            config, "overlap_grad_allreduce", False):
+    tp/cp meshes keep `jnp.take`, and the sparse step never sees it."""
+    if uses_sparse_update(config):
         return False
     return mesh is None or _data_only(mesh)
 
@@ -178,20 +190,6 @@ class TrainStepBuilder:
                 f"but config.use_sparse_embedding_update="
                 f"{self.config.use_sparse_embedding_update}; pass the same "
                 f"config to create_train_state and TrainStepBuilder.")
-        if getattr(self.config, "overlap_grad_allreduce", False) \
-                and not sparse:
-            # Bucketed async all-reduce overlap (parallel/overlap.py):
-            # backward + K per-bucket reduce+apply dispatches instead
-            # of one monolithic program. Covers the dense GSPMD
-            # data-parallel case AND the manual-kernel tp/cp path (the
-            # builder's _manual_encode/_manual_ce supply the per-shard
-            # backward; the per-leaf reducers psum over exactly each
-            # leaf's replicated axes). Sparse stays monolithic — it
-            # exchanges rows, not tables.
-            from code2vec_tpu.parallel.overlap import (
-                build_overlap_train_step,
-            )
-            return build_overlap_train_step(self, example_state)
         if self.manual:
             if sparse:
                 return self._make_manual_sparse_train_step(example_state)
@@ -211,7 +209,7 @@ class TrainStepBuilder:
     def _jit_train_step(self, fn, example_state: TrainState) -> Callable:
         """Stage a (state, *batch, rng) -> (state, loss) callable through
         jit: donated state, mesh shardings when a mesh is present. Single
-        source of the train-step sharding contract for all four builders."""
+        source of the train-step sharding contract for all four steps."""
         if self.mesh is None:
             # The state's own (single-device) sharding, said out loud:
             # left unsaid, jit keys its compile on which arguments

@@ -1,7 +1,9 @@
-"""Host->device double-buffering: overlap input parsing/packing with step
-execution (the reference gets this from tf.data's internal C++ threads,
-path_context_reader.py:150; here an explicit background thread feeds a
-bounded queue of ready-to-transfer batches)."""
+"""The one feed path: a background thread reads and packs batches into a
+bounded queue while the device steps, and the consumer transfers each as
+it takes it (the reference gets this from tf.data's internal C++
+threads, path_context_reader.py:150). The trainer and the evaluator both
+iterate a `DevicePrefetcher`; its contract is pinned in
+tests/test_prefetch.py."""
 
 from __future__ import annotations
 
@@ -61,7 +63,6 @@ class DevicePrefetcher:
 
     def __init__(self, batches: Iterable, mesh, depth: int = 4,
                  keep_host_batch: bool = False,
-                 double_buffer: bool = False,
                  observe: Optional[Callable] = None):
         self.batches = batches
         # called with every host batch on the worker thread (counters
@@ -70,7 +71,6 @@ class DevicePrefetcher:
         self.mesh = mesh
         self.depth = max(1, depth)
         self.keep_host_batch = keep_host_batch
-        self.double_buffer = double_buffer
         self._queue: queue.Queue = queue.Queue(maxsize=self.depth)
         self._error: Optional[BaseException] = None
         self._stop = threading.Event()
@@ -129,29 +129,15 @@ class DevicePrefetcher:
             self._put(self._SENTINEL)
 
     def __iter__(self) -> Iterator:
-        # Double-buffering (`double_buffer=True`) holds ONE transferred
-        # batch back: batch N+1's device_put is dispatched before batch
-        # N is handed to the step loop, so the N+1 transfer rides under
-        # step N's dispatch instead of serializing after it. The
-        # transfer still runs on THIS thread (see the class docstring)
-        # — only the dispatch order changes. Costs one extra batch of
-        # device memory and one batch of startup latency; EpochEnd
-        # markers flush the held batch first so ordering is preserved.
         self._thread.start()
-        pending = None
         try:
             while True:
                 item = self._queue.get()
                 if item is self._SENTINEL:
                     if self._error is not None:
                         raise self._error
-                    if pending is not None:
-                        yield pending
                     return
                 if isinstance(item, EpochEnd):
-                    if pending is not None:
-                        out, pending = pending, None
-                        yield out
                     yield item
                     continue
                 _G_DEPTH.set(self._queue.qsize())
@@ -159,14 +145,7 @@ class DevicePrefetcher:
                 with obs.span("prefetch_device_put", hist=_H_DEVICE_PUT):
                     arrays = device_put_batch(batch, self.mesh,
                                               packed=packed)
-                staged = (arrays, batch if self.keep_host_batch else None)
-                if not self.double_buffer:
-                    yield staged
-                elif pending is None:
-                    pending = staged  # prime: hold batch 0, put batch 1
-                else:
-                    out, pending = pending, staged
-                    yield out
+                yield arrays, (batch if self.keep_host_batch else None)
         finally:
             # consumer stopped (normally, by exception, or abandoned):
             # release the worker so it can exit and drop the reader

@@ -1,48 +1,32 @@
-"""Pod-scale input-pipeline bench: multi-shard manifests, double-
-buffered device puts, and the in-backward overlap A/B.
+"""Pod-scale input-pipeline bench: multi-shard manifests against
+simulated hosts. CPU-only and self-contained (synthetic corpus packed
+on the fly).
 
-Two parts, both CPU-only and self-contained (synthetic corpus packed
-on the fly):
-
-**Part A — input grid (one process, simulated hosts).** Builds ONE
-synthetic row set, packs it three ways (a single `.c2vb`, a 4-shard
-manifest, a 16-shard manifest — identical global row spaces), then for
-every (hosts H in 1/2/4) x (shards S in 1/4/16) x (double-buffer
-off/on) arm drives H independent reader+DevicePrefetcher stacks in
-lock-step against a fixed-cost jitted step, exactly the Trainer's
-consume path (queue get -> device put -> async step dispatch ->
-windowed loss sync). Per arm it records steps/s and the data-wait
-share (host time blocked in the prefetcher / wall — the window
-quantity `train_input_bound_fraction` gauges in production). "Hosts"
-are simulated in one process: the point is reader/manifest scaling
-laws and dispatch-order effects, not NIC bandwidth — every host stack
+**Input grid (one process, simulated hosts).** Builds ONE synthetic row
+set, packs it three ways (a single `.c2vb`, a 4-shard manifest, a
+16-shard manifest — identical global row spaces), then for every
+(hosts H in 1/2/4) x (shards S in 1/4/16) arm drives H independent
+reader+DevicePrefetcher stacks in lock-step against a fixed-cost
+jitted step, exactly the Trainer's consume path (queue get -> device
+put -> async step dispatch -> windowed loss sync). Per arm it records
+steps/s and the data-wait share (host time blocked in the prefetcher /
+wall — the window quantity `train_input_bound_fraction` gauges in
+production). "Hosts" are simulated in one process: the point is
+reader/manifest scaling laws, not NIC bandwidth — every host stack
 still pays its real pack, transfer and GIL costs.
 
-**Part B — in-backward overlap A/B (2 real processes).** The
-overlap_bench.py harness (jax.distributed, gloo, dp=2 mesh, 1 CPU
-device each) timing the bucketed-overlap step WITHOUT vs WITH
-`overlap_in_backward` — per-bucket backward so bucket i's
-all-reduce+apply dispatches while bucket i+1's backward runs, at the
-cost of one extra forward per bucket. On a CPU/gloo harness the extra
-forwards are expected to dominate (compute-bound, near-free
-collectives); the honest verdict either way is recorded in
-BENCH_INPUT.md — the flag targets interconnect-bound pods.
-
-Output: experiments/results/input.json + BENCH_INPUT.md (both marker
-sections rewritten in place). Run via scripts/run_input_bench.sh.
+Output: experiments/results/input.json + BENCH_INPUT.md (the marker
+section rewritten in place). Run via scripts/run_input_bench.sh.
 
 Usage:
     python experiments/input_bench.py [--rows N] [--global_batch B]
-        [--epochs E] [--steps N] [--skip_grid] [--skip_in_backward]
-    python experiments/input_bench.py --child RANK PORT OUT  (internal)
+        [--epochs E]
 """
 
 from __future__ import annotations
 
 import json
 import os
-import socket
-import subprocess
 import sys
 import time
 
@@ -54,10 +38,8 @@ OUT_PATH = os.path.join(REPO, "experiments", "results", "input.json")
 BENCH_MD = os.path.join(REPO, "BENCH_INPUT.md")
 GRID_BEGIN = "<!-- input-grid:begin -->"
 GRID_END = "<!-- input-grid:end -->"
-IB_BEGIN = "<!-- in-backward:begin -->"
-IB_END = "<!-- in-backward:end -->"
 
-# Part A corpus shape: small vocab (pack cost stays in parse, as with
+# Corpus shape: small vocab (pack cost stays in parse, as with
 # real data), wide-ish rows so the per-batch transfer buffer is tens of
 # KB, and a step sized to a few ms on one CPU so the host-side
 # pipeline effects are visible against it.
@@ -68,16 +50,8 @@ WINDOW = 8
 HOSTS_GRID = (1, 2, 4)
 SHARDS_GRID = (1, 4, 16)
 
-# Part B model shape (mirrors overlap_bench.py's "medium synthetic"):
-# gradients in the tens of MB per step over gloo.
-IB_TOKEN_VOCAB = 30_000
-IB_PATH_VOCAB = 20_000
-IB_TARGET_VOCAB = 5_000
-IB_DIM = 96
-IB_CONTEXTS = 32
 
-
-# ----------------------------------------------------- Part A: corpus
+# ------------------------------------------------------------- corpus
 
 
 def _build_corpus(tmp: str, rows: int):
@@ -155,8 +129,8 @@ def _make_step():
 
 
 def _run_grid_arm(vocabs, single: str, manifests: dict, hosts: int,
-                  shards: int, double_buffer: bool, global_batch: int,
-                  epochs: int, seed: int = 7) -> dict:
+                  shards: int, global_batch: int, epochs: int,
+                  seed: int = 7) -> dict:
     import jax
 
     from code2vec_tpu.data.packed import PackedDataset, ShardedCorpus
@@ -176,8 +150,7 @@ def _run_grid_arm(vocabs, single: str, manifests: dict, hosts: int,
         return ds.iter_batches(batch, EstimatorAction.Train,
                                num_epochs=epochs, seed=seed)
 
-    stacks = [iter(DevicePrefetcher(reader(h), None, depth=4,
-                                    double_buffer=double_buffer))
+    stacks = [iter(DevicePrefetcher(reader(h), None, depth=4))
               for h in range(hosts)]
     # warm the jit caches (unpack + step) outside the timed region
     firsts = [next(s) for s in stacks]
@@ -215,7 +188,6 @@ def _run_grid_arm(vocabs, single: str, manifests: dict, hosts: int,
     wall = time.perf_counter() - t_arm
     return {
         "hosts": hosts, "shards": shards,
-        "double_buffer": double_buffer,
         "steps": steps_done,
         "wall_s": round(wall, 3),
         "steps_per_s": round(steps_done / wall, 2),
@@ -235,179 +207,24 @@ def run_grid(rows: int, global_batch: int, epochs: int,
     grid = []
     for hosts in HOSTS_GRID:
         for shards in SHARDS_GRID:
-            for db in (False, True):
-                # best-of-N: one process simulating H hosts is at the
-                # mercy of the OS scheduler; the best run is the one
-                # with the least unrelated interference
-                runs = [_run_grid_arm(vocabs, single, manifests, hosts,
-                                      shards, db, global_batch, epochs)
-                        for _ in range(repeats)]
-                arm = max(runs, key=lambda r: r["steps_per_s"])
-                grid.append(arm)
-                print(f"hosts={hosts} shards={shards:2d} "
-                      f"double_buffer={int(db)}: "
-                      f"{arm['steps_per_s']} st/s, data-wait share "
-                      f"{arm['data_wait_share']} "
-                      f"(best of {repeats})", flush=True)
+            # best-of-N: one process simulating H hosts is at the
+            # mercy of the OS scheduler; the best run is the one
+            # with the least unrelated interference
+            runs = [_run_grid_arm(vocabs, single, manifests, hosts,
+                                  shards, global_batch, epochs)
+                    for _ in range(repeats)]
+            arm = max(runs, key=lambda r: r["steps_per_s"])
+            grid.append(arm)
+            print(f"hosts={hosts} shards={shards:2d}: "
+                  f"{arm['steps_per_s']} st/s, data-wait share "
+                  f"{arm['data_wait_share']} "
+                  f"(best of {repeats})", flush=True)
     return {"rows": rows, "contexts": CONTEXTS,
             "global_batch": global_batch, "epochs": epochs,
             "repeats": repeats,
             "vocab": {"tokens": TOKENS, "paths": PATHS,
                       "targets": TARGETS},
             "grid": grid}
-
-
-# ----------------------------------- Part B: in-backward overlap A/B
-
-
-def child_main(rank: int, port: str, out_path: str, steps: int,
-               batch: int, bucket_mb: float) -> None:
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    import jax
-    jax.config.update("jax_platforms", "cpu")
-    jax.config.update("jax_cpu_collectives_implementation", "gloo")
-
-    import numpy as np
-
-    from code2vec_tpu.config import Config
-    from code2vec_tpu.data.reader import RowBatch
-    from code2vec_tpu.models.code2vec import Code2VecModule, ModelDims
-    from code2vec_tpu.parallel import distributed
-    from code2vec_tpu.parallel.mesh import MeshPlan, make_mesh
-    from code2vec_tpu.training.state import (
-        create_train_state, make_optimizer,
-    )
-    from code2vec_tpu.training.step import (
-        TrainStepBuilder, device_put_batch,
-    )
-    import jax.numpy as jnp
-
-    distributed.initialize(coordinator_address=f"localhost:{port}",
-                           num_processes=2, process_id=rank)
-    assert jax.process_count() == 2
-    mesh = make_mesh(MeshPlan(dp=2))
-
-    dims = ModelDims(token_vocab_size=IB_TOKEN_VOCAB,
-                     path_vocab_size=IB_PATH_VOCAB,
-                     target_vocab_size=IB_TARGET_VOCAB,
-                     token_dim=IB_DIM, path_dim=IB_DIM)
-    rng = np.random.default_rng(23 + rank)
-    local_rows = batch // 2
-    local = RowBatch(
-        source_token_indices=rng.integers(
-            2, IB_TOKEN_VOCAB, (local_rows, IB_CONTEXTS)).astype(np.int32),
-        path_indices=rng.integers(
-            2, IB_PATH_VOCAB, (local_rows, IB_CONTEXTS)).astype(np.int32),
-        target_token_indices=rng.integers(
-            2, IB_TOKEN_VOCAB, (local_rows, IB_CONTEXTS)).astype(np.int32),
-        context_valid_mask=np.ones((local_rows, IB_CONTEXTS), np.float32),
-        target_index=rng.integers(2, IB_TARGET_VOCAB,
-                                  (local_rows,)).astype(np.int32),
-        example_valid=np.ones((local_rows,), bool),
-        target_strings=None)
-    arrays = device_put_batch(local, mesh)
-    key = jax.random.PRNGKey(3)
-
-    def run_arm(in_backward: bool) -> dict:
-        config = Config(train_data_path_prefix="<bench>",
-                        train_batch_size=batch, max_contexts=IB_CONTEXTS,
-                        compute_dtype="float32", dp=2,
-                        overlap_grad_allreduce=True,
-                        overlap_in_backward=in_backward,
-                        overlap_bucket_mb=bucket_mb, verbose_mode=0)
-        module = Code2VecModule(dims=dims, compute_dtype=jnp.float32,
-                                dropout_keep_rate=config.dropout_keep_rate)
-        opt = make_optimizer(config)
-        state = create_train_state(module, opt, jax.random.PRNGKey(0),
-                                   mesh=mesh, config=config)
-        step = TrainStepBuilder(module, opt, config,
-                                mesh=mesh).make_train_step(state)
-        pending = []
-        for _ in range(3):
-            state, loss = step(state, *arrays, key)
-            pending.append(loss)
-        jax.device_get(pending)
-
-        dispatch_s, sync_s = [], []
-        pending = []
-        t_arm = time.perf_counter()
-        for i in range(steps):
-            t0 = time.perf_counter()
-            state, loss = step(state, *arrays, key)
-            dispatch_s.append(time.perf_counter() - t0)
-            pending.append(loss)
-            if (i + 1) % 5 == 0:
-                t0 = time.perf_counter()
-                losses = jax.device_get(pending)
-                sync_s.append(time.perf_counter() - t0)
-                pending = []
-                assert all(np.isfinite(losses)), losses
-        if pending:
-            jax.device_get(pending)
-        wall = time.perf_counter() - t_arm
-        return {
-            "in_backward": in_backward,
-            "buckets": getattr(step, "overlap_buckets", 1),
-            "steps": steps,
-            "wall_s": round(wall, 3),
-            "steps_per_s": round(steps / wall, 3),
-            "dispatch_sum_s": round(sum(dispatch_s), 3),
-            "loss_sync_sum_s": round(sum(sync_s), 3),
-            "host_stall_sum_s": round(sum(dispatch_s) + sum(sync_s), 3),
-        }
-
-    after = run_arm(False)
-    within = run_arm(True)
-    result = {"rank": rank, "after_backward": after,
-              "in_backward": within}
-    with open(out_path, "w") as f:
-        json.dump(result, f, indent=2)
-    print(f"child {rank}: after-backward {after['steps_per_s']} st/s vs "
-          f"in-backward {within['steps_per_s']} st/s "
-          f"({within['buckets']} buckets)", flush=True)
-
-
-def _free_port() -> int:
-    with socket.socket() as s:
-        s.bind(("127.0.0.1", 0))
-        return s.getsockname()[1]
-
-
-def run_in_backward(steps: int, batch: int, bucket_mb: float) -> dict:
-    import tempfile
-
-    port = _free_port()
-    tmp = tempfile.mkdtemp(prefix="c2v-inbackward-")
-    outs = [os.path.join(tmp, f"host{r}.json") for r in (0, 1)]
-    procs = []
-    for r in (0, 1):
-        cmd = [sys.executable, os.path.abspath(__file__),
-               "--child", str(r), str(port), outs[r],
-               "--steps", str(steps), "--batch", str(batch),
-               "--bucket_mb", str(bucket_mb)]
-        procs.append(subprocess.Popen(
-            cmd, env=dict(os.environ, JAX_PLATFORMS="cpu")))
-    rcs = [proc.wait(timeout=900) for proc in procs]
-    if any(rcs):
-        raise SystemExit(f"in-backward child rc(s) {rcs}")
-    hosts = []
-    for out in outs:
-        with open(out) as f:
-            hosts.append(json.load(f))
-    after = hosts[0]["after_backward"]
-    within = hosts[0]["in_backward"]
-    return {
-        "topology": "2 processes x 1 CPU device, gloo collectives, "
-                    "dp=2 mesh",
-        "model": {"token_vocab": IB_TOKEN_VOCAB,
-                  "path_vocab": IB_PATH_VOCAB,
-                  "target_vocab": IB_TARGET_VOCAB, "dim": IB_DIM,
-                  "contexts": IB_CONTEXTS, "batch": batch},
-        "bucket_mb": bucket_mb,
-        "hosts": hosts,
-        "speedup_steps_per_s": round(
-            within["steps_per_s"] / after["steps_per_s"], 3),
-    }
 
 
 # ------------------------------------------------------------ output
@@ -424,7 +241,7 @@ def _replace_section(text: str, begin: str, end: str,
 
 def _grid_section(part: dict) -> str:
     rows = [GRID_BEGIN,
-            "## Input grid: shards x simulated hosts x double-buffer",
+            "## Input grid: shards x simulated hosts",
             "",
             "Produced by `scripts/run_input_bench.sh` -> "
             "`experiments/input_bench.py` -> "
@@ -438,105 +255,42 @@ def _grid_section(part: dict) -> str:
             "fixed-cost jitted step. `data-wait share` is host time "
             "blocked on the input stacks / wall — the quantity "
             "`train_input_bound_fraction` gauges in production. Hosts "
-            "are simulated in ONE process (reader scaling laws and "
-            "dispatch-order effects, not NIC bandwidth).",
+            "are simulated in ONE process (reader scaling laws, not "
+            "NIC bandwidth).",
             "",
-            "| hosts | shards | double-buffer | steps/s | "
-            "data-wait share |",
-            "|---|---|---|---|---|"]
+            "| hosts | shards | steps/s | data-wait share |",
+            "|---|---|---|---|"]
     for arm in part["grid"]:
         rows.append(
             f"| {arm['hosts']} | {arm['shards']} | "
-            f"{'on' if arm['double_buffer'] else 'off'} | "
             f"{arm['steps_per_s']} | {arm['data_wait_share']} |")
-    by = {(a["hosts"], a["shards"], a["double_buffer"]): a
-          for a in part["grid"]}
-    base = by[(1, 1, False)]
+    by = {(a["hosts"], a["shards"]): a for a in part["grid"]}
+    base = by[(1, 1)]
     notes = ["", "Reading the grid:"]
     for shards in SHARDS_GRID[1:]:
-        arm = by[(1, shards, False)]
+        arm = by[(1, shards)]
         notes.append(
             f"- {shards}-shard manifest at 1 host: "
             f"{arm['steps_per_s']} vs {base['steps_per_s']} st/s "
             f"single-shard "
             f"({arm['steps_per_s'] / base['steps_per_s']:.2f}x) — the "
             "manifest view adds no read-path cost.")
-    for hosts in HOSTS_GRID[1:]:
-        off = sum(by[(hosts, s, False)]["data_wait_share"]
-                  for s in SHARDS_GRID) / len(SHARDS_GRID)
-        on = sum(by[(hosts, s, True)]["data_wait_share"]
-                 for s in SHARDS_GRID) / len(SHARDS_GRID)
-        notes.append(
-            f"- double-buffer at {hosts} hosts (mean over shard "
-            f"counts): data-wait share {off:.4f} -> {on:.4f} "
-            f"({'-' if off >= on else '+'}{abs(off - on):.4f}).")
     rows += notes + [GRID_END]
     return "\n".join(rows)
 
 
-def _in_backward_section(part: dict) -> str:
-    after = part["hosts"][0]["after_backward"]
-    within = part["hosts"][0]["in_backward"]
-    speed = part["speedup_steps_per_s"]
-    if speed >= 1.02:
-        verdict = (f"in-backward completion WINS here: {speed}x "
-                   "steps/s.")
-    elif speed > 0.98:
-        verdict = (f"a wash on this harness ({speed}x steps/s).")
-    else:
-        verdict = (
-            f"HONEST NEGATIVE on this harness: {speed}x steps/s — the "
-            "per-bucket backward re-runs one forward per bucket, and "
-            "on a CPU/gloo pair the collectives it hides are nearly "
-            "free while the extra forwards are not. The flag targets "
-            "interconnect-bound pods where the hidden all-reduce "
-            "dwarfs a recomputed forward; the parity tests "
-            "(tests/test_overlap.py) pin correctness either way.")
-    return "\n".join([
-        IB_BEGIN,
-        "## In-backward bucket completion (2-host A/B)",
-        "",
-        "Same harness as the roofline overlap A/B "
-        "(`experiments/overlap_bench.py`; 2 real "
-        "jax.distributed processes, gloo, dp=2 mesh), comparing the "
-        "bucketed-overlap step with completion AFTER the full backward "
-        "vs IN-BACKWARD per-bucket completion "
-        "(`--overlap_in_backward`: bucket i's all-reduce+apply "
-        "dispatches while bucket i+1's backward runs, one extra "
-        "forward per bucket).",
-        "",
-        "| arm | steps/s | host dispatch sum | host stall total |",
-        "|---|---|---|---|",
-        f"| after-backward ({after['buckets']} buckets) | "
-        f"{after['steps_per_s']} | {after['dispatch_sum_s']}s | "
-        f"{after['host_stall_sum_s']}s |",
-        f"| in-backward ({within['buckets']} buckets) | "
-        f"{within['steps_per_s']} | {within['dispatch_sum_s']}s | "
-        f"{within['host_stall_sum_s']}s |",
-        "",
-        f"Verdict: {verdict}",
-        IB_END,
-    ])
-
-
 HEADER = """# BENCH_INPUT: pod-scale input pipeline
 
-Measurements for the multi-shard corpus manifest reader, the
-double-buffered device-put prefetcher, and in-backward collective
-overlap. Regenerate with `scripts/run_input_bench.sh` (sections below
-are rewritten in place between their markers).
+Measurements for the multi-shard corpus manifest reader. Regenerate
+with `scripts/run_input_bench.sh` (the section below is rewritten in
+place between its markers).
 """
 
 
 def _update_bench_md(result: dict) -> None:
     text = open(BENCH_MD).read() if os.path.exists(BENCH_MD) else HEADER
-    if "grid" in result:
-        text = _replace_section(text, GRID_BEGIN, GRID_END,
-                                _grid_section(result["grid"]))
-    if "in_backward" in result:
-        text = _replace_section(
-            text, IB_BEGIN, IB_END,
-            _in_backward_section(result["in_backward"]))
+    text = _replace_section(text, GRID_BEGIN, GRID_END,
+                            _grid_section(result["grid"]))
     with open(BENCH_MD, "w") as f:
         f.write(text)
 
@@ -544,61 +298,23 @@ def _update_bench_md(result: dict) -> None:
 def main(argv=None) -> None:
     import argparse
     p = argparse.ArgumentParser()
-    p.add_argument("--child", nargs=3, metavar=("RANK", "PORT", "OUT"))
     p.add_argument("--rows", type=int, default=8192)
     p.add_argument("--global_batch", type=int, default=128)
     p.add_argument("--epochs", type=int, default=2)
-    p.add_argument("--steps", type=int, default=15)
-    p.add_argument("--batch", type=int, default=256)
-    p.add_argument("--bucket_mb", type=float, default=8.0)
-    p.add_argument("--skip_grid", action="store_true")
-    p.add_argument("--skip_in_backward", action="store_true")
     args = p.parse_args(argv)
 
-    if args.child:
-        rank, port, out = args.child
-        child_main(int(rank), port, out, args.steps, args.batch,
-                   args.bucket_mb)
-        return
-
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
-    result = {"bench": "input_pipeline"}
-    if not args.skip_grid:
-        result["grid"] = run_grid(args.rows, args.global_batch,
-                                  args.epochs)
-    if not args.skip_in_backward:
-        result["in_backward"] = run_in_backward(args.steps, args.batch,
-                                                args.bucket_mb)
+    result = {"bench": "input_pipeline",
+              "grid": run_grid(args.rows, args.global_batch, args.epochs)}
 
     os.makedirs(os.path.dirname(OUT_PATH), exist_ok=True)
-    prior = {}
-    if os.path.exists(OUT_PATH):
-        try:
-            with open(OUT_PATH) as f:
-                prior = json.load(f)
-        except (OSError, ValueError):
-            prior = {}
-    prior.update(result)
     with open(OUT_PATH, "w") as f:
-        json.dump(prior, f, indent=2)
+        json.dump(result, f, indent=2)
         f.write("\n")
     _update_bench_md(result)
-    summary = {}
-    if "grid" in result:
-        by = {(a["hosts"], a["shards"], a["double_buffer"]): a
-              for a in result["grid"]["grid"]}
-        summary["multi_shard_1host_ratio"] = round(
-            by[(1, 4, False)]["steps_per_s"]
-            / by[(1, 1, False)]["steps_per_s"], 3)
-        shard_n = len(SHARDS_GRID)
-        summary["double_buffer_wait_delta_2hosts"] = round(
-            sum(by[(2, s, False)]["data_wait_share"]
-                - by[(2, s, True)]["data_wait_share"]
-                for s in SHARDS_GRID) / shard_n, 4)
-    if "in_backward" in result:
-        summary["in_backward_speedup"] = \
-            result["in_backward"]["speedup_steps_per_s"]
-    print(json.dumps(summary))
+    by = {(a["hosts"], a["shards"]): a for a in result["grid"]["grid"]}
+    print(json.dumps({"multi_shard_1host_ratio": round(
+        by[(1, 4)]["steps_per_s"] / by[(1, 1)]["steps_per_s"], 3)}))
     print(f"Wrote {OUT_PATH} and BENCH_INPUT.md")
 
 

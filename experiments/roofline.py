@@ -340,21 +340,8 @@ def _write_report(r: dict) -> None:
         "file).",
         "",
     ]
-    # Preserve the marker-delimited overlap-A/B section the 2-host
-    # bench owns (experiments/overlap_bench.py): a single-chip roofline
-    # rerun must not silently drop the multi-host measurement.
-    path = os.path.join(REPO, "BENCH_ROOFLINE.md")
-    overlap_section = ""
-    if os.path.exists(path):
-        with open(path) as f:
-            old = f.read()
-        begin, end = "<!-- overlap-bench:begin -->", "<!-- overlap-bench:end -->"
-        if begin in old and end in old:
-            overlap_section = ("\n" + begin
-                               + old.split(begin, 1)[1].split(end, 1)[0]
-                               + end + "\n")
-    with open(path, "w") as f:
-        f.write("\n".join(lines) + overlap_section)
+    with open(os.path.join(REPO, "BENCH_ROOFLINE.md"), "w") as f:
+        f.write("\n".join(lines))
 
 
 if __name__ == "__main__":

@@ -1,17 +1,12 @@
 #!/usr/bin/env bash
-# Run the pod-scale input-pipeline benches with a hard timeout and
-# crash diagnostics, matching scripts/run_roofline_bench.sh:
+# Run the pod-scale input-pipeline bench with a hard timeout and
+# crash diagnostics:
 #
-#   1. the input grid (simulated hosts x shards x double-buffer) plus
-#      the 2-host in-backward overlap A/B
+#   1. the input grid (simulated hosts x shards)
 #      (experiments/input_bench.py -> experiments/results/input.json
-#       + the BENCH_INPUT.md sections);
+#       + the BENCH_INPUT.md section);
 #   2. the fast multi-shard reader suite (tests/test_sharded_corpus.py
 #      — the cursor-law pins the bench numbers rest on).
-#
-# The in-backward A/B drives a real 2-process jax.distributed pair —
-# a collectives bug tends to surface as a HANG, so the run is
-# wall-clock bounded and failures dump any metrics snapshots.
 #
 # Usage: scripts/run_input_bench.sh [extra args passed to the bench]
 set -u -o pipefail
@@ -22,20 +17,19 @@ RUN_DIR="$(mktemp -d "${TMPDIR:-/tmp}/c2v-input.XXXXXX")"
 LOG="$RUN_DIR/bench.log"
 export C2V_CHAOS_DIAG_DIR="$RUN_DIR"
 
-# Wall-clock backstops: the grid is 18 arms x best-of-3 short runs
-# (~3 min on a dev CPU); the 2-process A/B compiles four overlap
-# programs (~3 min). The timeouts catch a gloo hang, not a slow run.
+# Wall-clock backstops: the grid is 9 arms x best-of-3 short runs
+# (~2 min on a dev CPU). The timeouts catch a hang, not a slow run.
 BENCH_BUDGET=900
 TEST_BUDGET=300
 rc=0
 
-echo "=== input grid + in-backward A/B (budget ${BENCH_BUDGET}s) ==="
+echo "=== input grid (budget ${BENCH_BUDGET}s) ==="
 timeout -k 20 "$BENCH_BUDGET" \
     env JAX_PLATFORMS=cpu python experiments/input_bench.py "$@" \
     2>&1 | tee "$LOG"
 bench_rc=${PIPESTATUS[0]}
 if [ "$bench_rc" -eq 124 ] || [ "$bench_rc" -eq 137 ]; then
-    echo "BENCH TIMED OUT (rc=$bench_rc): likely a collective hang" \
+    echo "BENCH TIMED OUT (rc=$bench_rc)" \
         | tee -a "$LOG"
 fi
 [ "$bench_rc" -ne 0 ] && rc=$bench_rc

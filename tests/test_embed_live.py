@@ -244,18 +244,16 @@ def test_the_other_steps_lower_to_what_they_lower_to_without_the_op(
     assert lower() == with_op
 
 
-@pytest.mark.parametrize("plan,sparse,overlap,want", [
-    (None, False, False, True),
-    (MeshPlan(dp=4, tp=1, cp=1), False, False, True),
-    (MeshPlan(dp=2, tp=2, cp=1), False, False, False),
-    (MeshPlan(dp=2, tp=1, cp=2), False, False, False),
-    (None, True, False, False),
-    (None, False, True, False),
+@pytest.mark.parametrize("plan,sparse,want", [
+    (None, False, True),
+    (MeshPlan(dp=4, tp=1, cp=1), False, True),
+    (MeshPlan(dp=2, tp=2, cp=1), False, False),
+    (MeshPlan(dp=2, tp=1, cp=2), False, False),
+    (None, True, False),
 ])
-def test_which_steps_gather_live_rows(plan, sparse, overlap, want):
+def test_which_steps_gather_live_rows(plan, sparse, want):
     config = Config(train_data_path_prefix="unused",
-                    use_sparse_embedding_update=sparse,
-                    overlap_grad_allreduce=overlap)
+                    use_sparse_embedding_update=sparse)
     mesh = make_mesh(plan) if plan else None
     assert step_mod.gathers_live_rows(config, mesh) is want
 
