@@ -25,23 +25,10 @@ import jax
 import jax.numpy as jnp
 
 
-@jax.named_scope("attention")
-def masked_single_query_attention(
-    transformed: jax.Array,       # (B, M_local, D) already tanh(ctx @ W)
-    attention_param: jax.Array,   # (D,)
-    context_valid_mask: jax.Array,  # (B, M_local) float {0,1}
-    axis_name: Optional[str] = None,
-) -> Tuple[jax.Array, jax.Array]:
-    """Returns (code_vectors (B, D), attention_weights (B, M_local)).
-
-    Softmax runs in float32 regardless of the compute dtype. When
-    `axis_name` names a mesh axis over which the context dimension is
-    sharded, the max/sum-exp/weighted-sum reductions are combined across
-    shards with pmax/psum so the result equals the unsharded computation.
-    """
-    scores = jnp.einsum(
-        "bmd,d->bm", transformed, attention_param.astype(transformed.dtype),
-        preferred_element_type=jnp.float32)           # (B, M)
+def masked_softmax(scores: jax.Array, context_valid_mask: jax.Array,
+                   axis_name: Optional[str] = None) -> jax.Array:
+    """Softmax over the contexts of float32 `(B, M_local)` scores, the
+    invalid ones at weight exactly 0 (a row with none: all 0)."""
     # Additive log-mask (reference: tensorflow_model.py:256-258). Where the
     # mask is 0 this is -inf; jnp.where keeps the gradient clean.
     neg_inf = jnp.asarray(-jnp.inf, dtype=scores.dtype)
@@ -60,7 +47,27 @@ def masked_single_query_attention(
     denom = jnp.sum(unnorm, axis=1, keepdims=True)           # (B, 1)
     if axis_name is not None:
         denom = jax.lax.psum(denom, axis_name)
-    attention = unnorm / jnp.maximum(denom, 1e-30)           # (B, M)
+    return unnorm / jnp.maximum(denom, 1e-30)                # (B, M)
+
+
+@jax.named_scope("attention")
+def masked_single_query_attention(
+    transformed: jax.Array,       # (B, M_local, D) already tanh(ctx @ W)
+    attention_param: jax.Array,   # (D,)
+    context_valid_mask: jax.Array,  # (B, M_local) float {0,1}
+    axis_name: Optional[str] = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """Returns (code_vectors (B, D), attention_weights (B, M_local)).
+
+    Softmax runs in float32 regardless of the compute dtype. When
+    `axis_name` names a mesh axis over which the context dimension is
+    sharded, the max/sum-exp/weighted-sum reductions are combined across
+    shards with pmax/psum so the result equals the unsharded computation.
+    """
+    scores = jnp.einsum(
+        "bmd,d->bm", transformed, attention_param.astype(transformed.dtype),
+        preferred_element_type=jnp.float32)           # (B, M)
+    attention = masked_softmax(scores, context_valid_mask, axis_name)
 
     code_vectors = jnp.einsum(
         "bm,bmd->bd", attention.astype(transformed.dtype), transformed,

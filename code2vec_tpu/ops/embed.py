@@ -7,12 +7,16 @@ weight exactly 0 (ops/attention.py), gradient row exactly 0.0.
 `embed_live_rows` does the row work of the live entries only, those up
 to each row's `depth` (its deepest valid context), under static shapes:
 
-- forward: the grid is covered with static blocks of `BLOCK_ROWS` x
-  `BLOCK_CONTEXTS`; a device-side `fori_loop` whose trip count is the
-  number of blocks some row reaches into gathers those, the others stay
-  zero. With the rows ordered by depth the live blocks form a staircase
-  under the batch's own counts; any other order is still correct and
-  only skips less.
+- the grid is covered with static blocks of `BLOCK_ROWS` x
+  `BLOCK_CONTEXTS`; the blocks some row reaches into are live. With the
+  rows ordered by depth they form a staircase under the batch's own
+  counts; any other order is still correct and only skips less.
+- forward: a device-side `fori_loop` whose trip count is the number of
+  live blocks gathers live block `t` of the schedule (`live_slots`)
+  into slot `t` of a COMPACT `(slots, entries, width)` buffer, the
+  other slots stay zero. A flat slot is whole tiles where a `(64, 50)`
+  corner of the grid is not (200 = 8 x 25), and what consumes the rows
+  (ops/encode_live.py) runs over the filled slots and no others.
 - backward: the ids are sorted once, the dead ones last, and one sorted
   scatter-add takes the shortest of `SCATTER_SIZES` static prefixes of
   the list that holds every live entry (`_embed_bwd` says why not a loop).
@@ -31,6 +35,9 @@ import numpy as np
 BLOCK_ROWS = 64
 BLOCK_CONTEXTS = 50
 SCATTER_SIZES = 8
+# Slots the dense chain over the lookup's output (ops/encode_live.py)
+# takes in one trip of its loops (PR 29).
+SLOT_CHUNK = 4
 
 
 def context_depth(context_valid_mask: jax.Array) -> jax.Array:
@@ -46,73 +53,101 @@ def _grid(rows: int, contexts: int) -> Tuple[int, int]:
     return -(-rows // BLOCK_ROWS), -(-contexts // BLOCK_CONTEXTS)
 
 
-def _schedule(depth: jax.Array, contexts: int):
-    """The live blocks first, as flat indices into the (groups,
-    context blocks) grid, and how many they are. `depth` holds whole
-    groups of rows."""
+def slot_count(rows: int, contexts: int) -> int:
+    """Slots of the compact buffer of a `(rows, contexts)` grid: one a
+    block, rounded up to whole `SLOT_CHUNK`s."""
+    groups, across = _grid(rows, contexts)
+    return -(-groups * across // SLOT_CHUNK) * SLOT_CHUNK
+
+
+def live_slots(depth: jax.Array, contexts: int):
+    """The schedule of a batch's blocks: `order[t]` is the flat index,
+    in the (groups, context blocks) grid, of the block that slot `t` of
+    a compact buffer holds, the live blocks (those some row of their
+    group reaches into) first; and how many are live."""
     groups, across = _grid(depth.shape[0], contexts)
-    reach = jnp.max(depth.reshape(groups, BLOCK_ROWS), axis=1)
+    reach = jnp.max(jnp.pad(depth, (0, groups * BLOCK_ROWS - depth.shape[0])
+                            ).reshape(groups, BLOCK_ROWS), axis=1)
     live = (jnp.arange(across, dtype=jnp.int32)[None, :] * BLOCK_CONTEXTS
             < reach[:, None]).reshape(-1)
     order = jnp.argsort(jnp.logical_not(live), stable=True)
     return order.astype(jnp.int32), jnp.sum(live, dtype=jnp.int32)
 
 
-def _pad_grid(x: jax.Array) -> jax.Array:
-    """Pad the two leading axes up to whole blocks (static; a no-op when
-    the grid already divides)."""
-    groups, across = _grid(x.shape[0], x.shape[1])
-    pad = [(0, groups * BLOCK_ROWS - x.shape[0]),
-           (0, across * BLOCK_CONTEXTS - x.shape[1])]
-    if not any(p[1] for p in pad):
-        return x
-    return jnp.pad(x, pad + [(0, 0)] * (x.ndim - 2))
+def to_slots(grid: jax.Array, order: jax.Array) -> jax.Array:
+    """(rows, contexts) values -> (slots, BLOCK_CONTEXTS * BLOCK_ROWS):
+    slot `t` holds block `order[t]`, context-major (entry `c *
+    BLOCK_ROWS + r` is context `c` of the block's row `r`, so that a
+    slot of a `(slots, entries, width)` buffer splits into whole
+    `(BLOCK_ROWS, width)` tiles, one a context). Entries past the
+    grid's edge and the slots past the last block read 0."""
+    groups, across = _grid(*grid.shape)
+    padded = jnp.pad(grid, ((0, groups * BLOCK_ROWS - grid.shape[0]),
+                            (0, across * BLOCK_CONTEXTS - grid.shape[1])))
+    blocks = padded.reshape(groups, BLOCK_ROWS, across, BLOCK_CONTEXTS)
+    blocks = blocks.transpose(0, 2, 3, 1).reshape(groups * across, -1)
+    slots = jnp.take(blocks, order, axis=0)
+    return jnp.pad(slots, ((0, slot_count(*grid.shape) - groups * across),
+                           (0, 0)))
+
+
+def to_grid(slots: jax.Array, order: jax.Array, rows: int,
+            contexts: int) -> jax.Array:
+    """`to_slots` undone: (slots, entries) values -> (rows, contexts)."""
+    groups, across = _grid(rows, contexts)
+    blocks = jnp.zeros((groups * across,) + slots.shape[1:], slots.dtype
+                       ).at[order].set(slots[:groups * across],
+                                       unique_indices=True)
+    blocks = blocks.reshape(groups, across, BLOCK_CONTEXTS, BLOCK_ROWS)
+    return blocks.transpose(0, 3, 1, 2).reshape(
+        groups * BLOCK_ROWS, across * BLOCK_CONTEXTS)[:rows, :contexts]
+
+
+def _slot_ids(ids, depth):
+    """Each id array by slot, and which entries lie under their row's
+    depth (none of a dead block, by the schedule)."""
+    contexts = ids[0].shape[1]
+    order, count = live_slots(depth, contexts)
+    below = to_slots(
+        jnp.arange(contexts, dtype=jnp.int32)[None, :] < depth[:, None],
+        order)
+    return tuple(to_slots(i, order) for i in ids), below, count
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
 def embed_live_rows(table: jax.Array, ids: Tuple[jax.Array, ...],
                     depth: jax.Array, dtype) -> Tuple[jax.Array, ...]:
     """`tuple(jnp.take(table, i, axis=0).astype(dtype) for i in ids)` on
-    every entry `[b, m]` with `m < depth[b]`, zero on every other. `ids`
-    are `(B, M)` int arrays of ONE table, so their gradient rows land in
-    one table-shaped float gradient."""
+    every entry `[b, m]` with `m < depth[b]`, zero on every other, by
+    slot: `(slot_count, entries, width)` in the layout of `to_slots`
+    under the schedule `live_slots(depth, contexts)`. `ids` are `(B, M)`
+    int arrays of ONE table, so their gradient rows land in one
+    table-shaped float gradient."""
     outs, _ = _embed_fwd(table, ids, depth, dtype)
     return outs
 
 
 def _embed_fwd(table, ids, depth, dtype):
-    rows, contexts = ids[0].shape
-    groups, across = _grid(rows, contexts)
-    reach = jnp.pad(depth, (0, groups * BLOCK_ROWS - rows))
-    order, count = _schedule(reach, contexts)
-    padded_ids = tuple(_pad_grid(i) for i in ids)
-    width = table.shape[1]
-    position = jnp.arange(BLOCK_CONTEXTS, dtype=jnp.int32)[None, :]
+    slot_ids, below, count = _slot_ids(ids, depth)
 
-    def gather_block(t, outs):
-        block = order[t]
-        r0 = (block // across) * BLOCK_ROWS
-        c0 = (block % across) * BLOCK_CONTEXTS
-        below = (c0 + position
-                 < jax.lax.dynamic_slice(reach, (r0,), (BLOCK_ROWS,))[:, None])
-        new = []
-        for block_ids, out in zip(padded_ids, outs):
-            got = jnp.take(table, jax.lax.dynamic_slice(
-                block_ids, (r0, c0), (BLOCK_ROWS, BLOCK_CONTEXTS)), axis=0)
-            # Zero past each row's depth by a PRODUCT, not a select: XLA
-            # moves the cast through a select and a gather onto the
-            # table and hoists it out of the loop, a pass over every
-            # row of the table (2 ms and 0.5 GB at java14m); it cannot
-            # move it through a product with the loop's own mask.
-            got = (got * below[:, :, None].astype(got.dtype)).astype(dtype)
-            new.append(jax.lax.dynamic_update_slice(out, got, (r0, c0, 0)))
-        return tuple(new)
+    def gather_slot(t, outs):
+        # Zero past each row's depth by a PRODUCT, not a select: XLA
+        # moves the cast through a select and a gather onto the table
+        # and hoists it out of the loop, a pass over every row of the
+        # table (2 ms and 0.5 GB at java14m); it cannot move it through
+        # a product with the loop's own mask.
+        under = below[t][:, None].astype(table.dtype)
+        return tuple(
+            jax.lax.dynamic_update_slice(
+                out, (jnp.take(table, i[t], axis=0) * under
+                      ).astype(dtype)[None], (t, 0, 0))
+            for i, out in zip(slot_ids, outs))
 
     outs = jax.lax.fori_loop(
-        0, count, gather_block,
-        tuple(jnp.zeros(i.shape + (width,), dtype) for i in padded_ids))
-    outs = tuple(out[:rows, :contexts] for out in outs)
-    return outs, (table, ids, depth)
+        0, count, gather_slot,
+        tuple(jnp.zeros(below.shape + (table.shape[1],), dtype)
+              for _ in ids))
+    return outs, (table, slot_ids, below)
 
 
 def _embed_bwd(dtype, residuals, cotangents):
@@ -125,14 +160,13 @@ def _embed_bwd(dtype, residuals, cotangents):
     25). So the ids are sorted once, as there, with the dead entries
     behind a key past the table's end (dropped), and ONE sorted scatter
     adds a prefix of the list: the shortest of `SCATTER_SIZES` static
-    lengths that holds every live entry."""
-    table, ids, depth = residuals
-    contexts = ids[0].shape[1]
+    lengths that holds every live entry. The cotangents come by slot, as
+    the outputs went, so a sorted key's `source` is a compact row."""
+    table, slot_ids, below = residuals
     width = table.shape[1]
-    live = jnp.arange(contexts, dtype=jnp.int32)[None, :] < depth[:, None]
     past_end = table.shape[0]
     keys = jnp.concatenate(
-        [jnp.where(live, i, past_end).reshape(-1) for i in ids])
+        [jnp.where(below, i, past_end).reshape(-1) for i in slot_ids])
     keys, source = jax.lax.sort_key_val(
         keys, jnp.arange(keys.shape[0], dtype=jnp.int32))
     updates = jnp.concatenate([ct.reshape(-1, width) for ct in cotangents])
@@ -146,7 +180,7 @@ def _embed_bwd(dtype, residuals, cotangents):
                 mode="drop")
         return scatter
 
-    entries = len(ids) * jnp.sum(depth)
+    entries = len(slot_ids) * jnp.sum(below, dtype=jnp.int32)
     grad = jax.lax.switch(
         jnp.maximum(entries - 1, 0) // step,
         [scatter_prefix(min((n + 1) * step, keys.shape[0]))
@@ -157,19 +191,24 @@ def _embed_bwd(dtype, residuals, cotangents):
 embed_live_rows.defvjp(_embed_fwd, _embed_bwd)
 
 
-def live_block_ratio(context_valid_mask: np.ndarray, chips: int = 1) -> float:
-    """Host-side (numpy) count of what `embed_live_rows`'s forward
-    gathers of a batch whose rows the step orders by depth: live blocks
-    over all blocks of the grid. With `chips` > 1 the batch's rows are
-    `chips` equal slices, each ordered and gathered on its own chip."""
+def live_block_ratio(context_valid_mask: np.ndarray, chips: int = 1,
+                     chunk: int = 1) -> float:
+    """Host-side (numpy) count of what the step runs over of a batch
+    whose rows it orders by depth: live blocks, in whole chunks of
+    `chunk`, over all blocks of the grid. `embed_live_rows`'s forward
+    gathers block by block (`chunk` 1), the dense chain
+    (ops/encode_live.py) runs `SLOT_CHUNK` slots a trip. With `chips` >
+    1 the batch's rows are `chips` equal slices, each ordered and run
+    on its own chip."""
     mask = np.asarray(context_valid_mask) > 0
     contexts = mask.shape[1]
     depth = np.where(mask.any(axis=1),
                      contexts - np.argmax(mask[:, ::-1], axis=1), 0)
-    live = blocks = 0
+    run = blocks = 0
     for rows in np.split(depth, chips):
         groups, across = _grid(len(rows), contexts)
         deepest = -np.sort(-rows)[::BLOCK_ROWS]
-        live += int((-(-deepest // BLOCK_CONTEXTS)).sum())
+        live = int((-(-deepest // BLOCK_CONTEXTS)).sum())
+        run += -(-live // chunk) * chunk
         blocks += groups * across
-    return live / blocks
+    return run / blocks

@@ -31,7 +31,7 @@ import numpy as np
 from code2vec_tpu import obs
 from code2vec_tpu.obs import exporters as obs_exporters
 from code2vec_tpu.data.reader import EpochEnd
-from code2vec_tpu.ops.embed import live_block_ratio
+from code2vec_tpu.ops import embed
 from code2vec_tpu.training.state import TrainState
 from code2vec_tpu.training.step import gathers_live_rows
 from code2vec_tpu.utils.device import describe_devices, shard_layout
@@ -238,19 +238,27 @@ class Trainer:
         last_avg_loss = float("nan")
         observe = None
         if gathers_live_rows(config, self.mesh):
+            tenths = (0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0)
             h_live = reg.histogram(
                 "train_context_blocks_live_ratio",
                 "share of a batch's (rows, contexts) block grid that the "
                 "train step's embedding lookup gathers (ops/embed.py): "
                 "the blocks some row of its group reaches into, rows "
-                "ordered by depth on each chip",
-                buckets=(0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8, 0.9, 1.0))
+                "ordered by depth on each chip", buckets=tenths)
+            h_run = reg.histogram(
+                "train_dense_blocks_run_ratio",
+                "share of a batch's (rows, contexts) block grid that the "
+                "train step's dense chain runs over "
+                "(ops/encode_live.py): the live blocks in whole chunks "
+                "of slots, each chip's apart", buckets=tenths)
             chips = (1 if self.mesh is None
                      else len(self.mesh.local_devices))
 
             def observe(batch):
-                h_live.observe(live_block_ratio(batch.context_valid_mask,
-                                                chips))
+                mask = batch.context_valid_mask
+                h_live.observe(embed.live_block_ratio(mask, chips))
+                h_run.observe(embed.live_block_ratio(
+                    mask, chips, chunk=embed.SLOT_CHUNK))
         prefetcher = DevicePrefetcher(
             batches, self.mesh, depth=config.prefetch_batches,
             observe=observe)

@@ -15,7 +15,8 @@ opt-state's type). The cell of `BENCHMARK.json`, or the queued cell
 1. **GSPMD, dense Adam** (no mesh, `--dp`, or tp/cp under `--gspmd`):
    jit with NamedSharding-annotated inputs/outputs — the scaling-book
    recipe: annotate, let XLA insert the collectives. Where every chip
-   holds whole tables it runs the live-rows lookup (ops/embed.py), chip
+   holds whole tables it runs the live-rows lookup (ops/embed.py) and
+   the dense chain over the slots it fills (ops/encode_live.py), chip
    by chip under shard_map. All four train cells (`java14m.train_dp4`
    and the three `*.train_hostfed*`).
 2. **GSPMD, touched-rows Adam**: gathers outside the differentiated
@@ -49,6 +50,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from code2vec_tpu.models.code2vec import Code2VecModule
 from code2vec_tpu.ops.attention import masked_single_query_attention
 from code2vec_tpu.ops.embed import context_depth, embed_live_rows
+from code2vec_tpu.ops.encode_live import encode_live_blocks
 from code2vec_tpu.ops import sharded as tp_ops
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.parallel.mesh import AXIS_CTX, AXIS_DATA, AXIS_MODEL
@@ -132,9 +134,16 @@ def scoped_adam_update(optimizer: optax.GradientTransformation, grads,
         is_leaf=lambda n: isinstance(n, dict) and set(n) <= keys)
 
 
+# What `_encode_live_rows` reads of the parameters (the head's table is
+# the logits' alone).
+_ENCODER_PARAMS = ("token_embedding", "path_embedding", "transform",
+                   "attention")
+
+
 def gathers_live_rows(config, mesh: Optional[Mesh]) -> bool:
     """Whether `make_train_step` builds the dense step around the
-    live-rows lookup (ops/embed.py). It runs chip by chip (each chip's
+    live-rows lookup (ops/embed.py) and the chain over its slots
+    (ops/encode_live.py). It runs chip by chip (each chip's
     rows ordered among themselves, each chip's table-shaped gradient its
     own, one all-reduce a table): left to GSPMD, a loop's scatter into a
     replicated table would be reduced across chips in every iteration.
@@ -237,10 +246,31 @@ class TrainStepBuilder:
         # train batches are always full so this equals the mean.
         return jnp.sum(ce) / labels.shape[0]
 
+    def _encode_live_rows(self, params, src, pth, tgt, mask, depth, key,
+                          axis_name: Optional[str] = None):
+        """One chip's rows to code vectors over their live blocks only:
+        the lookups by slot (ops/embed.py), then the dense chain over
+        the slots they filled (ops/encode_live.py). Under `shard_map`
+        over `axis_name` each chip's staircase is its own, and so is
+        its dropout mask; the transpose sums each parameter's gradient
+        across the chips once."""
+        dtype = self.module.compute_dtype
+        if axis_name is not None:
+            key = jax.random.fold_in(key, jax.lax.axis_index(axis_name))
+        with jax.named_scope("embed_gather"):
+            src_rows, tgt_rows = embed_live_rows(
+                params["token_embedding"], (src, tgt), depth, dtype)
+            path_rows, = embed_live_rows(
+                params["path_embedding"], (pth,), depth, dtype)
+        return encode_live_blocks(
+            (src_rows, path_rows, tgt_rows), params["transform"],
+            params["attention"][:, 0], mask, depth, key,
+            self.module.dropout_keep_rate)
+
     def _make_gspmd_train_step(self, example_state: TrainState) -> Callable:
         module, optimizer = self.module, self.optimizer
         live_rows = gathers_live_rows(self.config, self.mesh)
-        order_rows, embed = _order_rows_by_depth, embed_live_rows
+        order_rows, encode = _order_rows_by_depth, self._encode_live_rows
         if live_rows and self.mesh is not None:
             rows, ids = P(AXIS_DATA), P(AXIS_DATA, None)
             order_rows = jax.shard_map(
@@ -248,14 +278,11 @@ class TrainStepBuilder:
                 in_specs=(ids, ids, ids, ids, rows, rows),
                 out_specs=(ids, ids, ids, ids, rows, rows, rows),
                 check_vma=False)
-
-            def embed(table, id_arrays, depth, dtype):
-                return jax.shard_map(
-                    lambda t, i, d: embed_live_rows(t, i, d, dtype),
-                    mesh=self.mesh,
-                    in_specs=(P(), (ids,) * len(id_arrays), rows),
-                    out_specs=(P(AXIS_DATA, None, None),) * len(id_arrays),
-                    check_vma=False)(table, id_arrays, depth)
+            encode = jax.shard_map(
+                functools.partial(encode, axis_name=AXIS_DATA),
+                mesh=self.mesh,
+                in_specs=(P(), ids, ids, ids, ids, rows, P()),
+                out_specs=ids, check_vma=False)
 
         def train_step(state: TrainState, src, pth, tgt, mask, labels, valid, rng):
             dropout_rng = jax.random.fold_in(rng, state.step)
@@ -269,17 +296,12 @@ class TrainStepBuilder:
                         {"params": params}, src, pth, tgt, mask,
                         deterministic=False, rngs={"dropout": dropout_rng})
                     return self._loss_from_logits(logits, labels, valid)
-                with jax.named_scope("embed_gather"):
-                    src_rows, tgt_rows = embed(
-                        params["token_embedding"], (src, tgt), depth,
-                        module.compute_dtype)
-                    path_rows, = embed(
-                        params["path_embedding"], (pth,), depth,
-                        module.compute_dtype)
-                logits, _, _ = module.apply(
-                    {"params": params}, src_rows, path_rows, tgt_rows, mask,
-                    deterministic=False, rngs={"dropout": dropout_rng},
-                    method=Code2VecModule.apply_from_rows)
+                code_vectors = encode(
+                    {k: params[k] for k in _ENCODER_PARAMS},
+                    src, pth, tgt, mask, depth, dropout_rng)
+                logits = module.apply(
+                    {"params": params}, code_vectors,
+                    method=Code2VecModule.logits_from_code_vectors)
                 return self._loss_from_logits(logits, labels, valid)
 
             loss, grads = jax.value_and_grad(loss_fn)(state.params)

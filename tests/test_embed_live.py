@@ -1,22 +1,30 @@
-"""The live-rows embedding lookup (ops/embed.py) and the dense train
-step built around it (training/step.py).
+"""The live-rows embedding lookup (ops/embed.py), the dense chain over
+the slots it fills (ops/encode_live.py) and the dense train step built
+around both (training/step.py).
 
-The op is held against `jnp.take` on every live entry, forward and
-VJP, at toy widths with toy block sizes; the step against the step it
-replaced (the `jnp.take` path a mesh still takes), over three updates
-with the batch's rows in shuffled order."""
+The lookup is held against `jnp.take` on every live entry, forward and
+VJP, at toy widths with toy block sizes; the chain against
+`transform_gathered` + `masked_single_query_attention` over the whole
+grid; the step against the step it replaced (the `jnp.take` path a
+mesh still takes), over three updates with the batch's rows in
+shuffled order."""
+
+import functools
 
 import numpy as np
 import pytest
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from code2vec_tpu import obs
 from code2vec_tpu.config import Config
 from code2vec_tpu.data.reader import EpochEnd, RowBatch
 from code2vec_tpu.models.code2vec import Code2VecModule, ModelDims
 from code2vec_tpu.ops import embed
-from code2vec_tpu.parallel.mesh import MeshPlan, make_mesh
+from code2vec_tpu.ops.attention import masked_single_query_attention
+from code2vec_tpu.ops.encode_live import encode_live_blocks
+from code2vec_tpu.parallel.mesh import AXIS_DATA, MeshPlan, make_mesh
 from code2vec_tpu.training import step as step_mod
 from code2vec_tpu.training.loop import Trainer
 from code2vec_tpu.training.state import create_train_state, make_optimizer
@@ -31,6 +39,7 @@ def toy_blocks(monkeypatch):
     monkeypatch.setattr(embed, "BLOCK_ROWS", ROWS)
     monkeypatch.setattr(embed, "BLOCK_CONTEXTS", CONTEXTS)
     monkeypatch.setattr(embed, "SCATTER_SIZES", 3)
+    monkeypatch.setattr(embed, "SLOT_CHUNK", 2)
 
 
 def _prefix(counts, m):
@@ -69,6 +78,14 @@ def _case(name):
     return mask, table, ids, depth, live, rng
 
 
+def _on_grid(by_slot, depth, shape):
+    """A `(slots, entries, width)` output of the lookup back on the
+    `(B, M, width)` grid."""
+    order, _ = embed.live_slots(depth, shape[1])
+    return jax.vmap(lambda v: embed.to_grid(v, order, *shape),
+                    in_axes=2, out_axes=2)(by_slot)
+
+
 def _numpy_depth(mask):
     return np.array([max([j + 1 for j in range(len(r)) if r[j] > 0],
                          default=0) for r in mask])
@@ -83,7 +100,9 @@ def test_forward_is_take_on_every_live_entry_and_zero_elsewhere(name):
     assert len(outs) == len(ids)
     for got, i in zip(outs, ids):
         want = np.asarray(jnp.take(table, i, axis=0))
-        got = np.asarray(got)
+        assert got.shape == (embed.slot_count(*mask.shape),
+                             ROWS * CONTEXTS, WIDTH)
+        got = np.asarray(_on_grid(got, depth, mask.shape))
         assert got.shape == want.shape and np.isfinite(got).all()
         np.testing.assert_array_equal(got[live], want[live])
         assert (got[~live] == 0).all()
@@ -101,7 +120,8 @@ def test_vjp_is_takes_on_cotangents_that_vanish_off_the_mask(name):
 
     def through_op(t):
         outs = embed.embed_live_rows(t, ids, depth, jnp.float32)
-        return sum(jnp.sum(o * w) for o, w in zip(outs, weights))
+        return sum(jnp.sum(_on_grid(o, depth, mask.shape) * w)
+                   for o, w in zip(outs, weights))
 
     def through_take(t):
         return sum(jnp.sum(jnp.take(t, i, axis=0) * w)
@@ -119,7 +139,8 @@ def test_the_output_takes_the_compute_dtype_and_the_gradient_the_tables():
         t, ids, depth, jnp.bfloat16), table)
     assert all(o.dtype == jnp.bfloat16 for o in outs)
     want = np.asarray(jnp.take(table, ids[0], axis=0).astype(jnp.bfloat16))
-    np.testing.assert_array_equal(np.asarray(outs[0])[live], want[live])
+    np.testing.assert_array_equal(
+        np.asarray(_on_grid(outs[0], depth, live.shape))[live], want[live])
     grad, = vjp(tuple(jnp.ones_like(o) for o in outs))
     assert grad.dtype == table.dtype and grad.shape == table.shape
 
@@ -141,11 +162,165 @@ def test_live_block_ratio_counts_what_the_forward_gathers(name):
         _numpy_live_ratio(mask))
     # and the device-side schedule of the same rows, ordered, agrees
     groups, across = embed._grid(*mask.shape)
-    ordered = jnp.pad(jnp.sort(depth)[::-1],
-                      (0, groups * ROWS - mask.shape[0]))
-    _, count = embed._schedule(ordered, mask.shape[1])
+    _, count = embed.live_slots(jnp.sort(depth)[::-1], mask.shape[1])
     assert int(count) / (groups * across) == pytest.approx(
         embed.live_block_ratio(mask))
+
+
+# ----------------------------------------------------------- the chain
+
+CHAIN_DIMS = ModelDims(token_vocab_size=VOCAB, path_vocab_size=VOCAB,
+                       target_vocab_size=5, token_dim=WIDTH, path_dim=WIDTH)
+
+
+def _by_slot(grid_rows, depth):
+    """`(B, M, width)` rows as the lookup hands them over."""
+    order, _ = embed.live_slots(depth, grid_rows.shape[1])
+    return jax.vmap(lambda v: embed.to_slots(v, order),
+                    in_axes=2, out_axes=2)(grid_rows)
+
+
+def _chain_case(mask, seed=0):
+    """For a mask: three grids of rows that vanish past each row's
+    depth (as the lookup's do), the two parameters and a cotangent for
+    the code vectors."""
+    rng = np.random.default_rng(seed)
+    depth = embed.context_depth(jnp.asarray(mask))
+    under = (np.arange(mask.shape[1])[None, :]
+             < np.asarray(depth)[:, None])[:, :, None]
+    rows = tuple(jnp.asarray((rng.normal(size=mask.shape + (WIDTH,))
+                              * under).astype(np.float32))
+                 for _ in range(3))
+    transform = jnp.asarray(
+        rng.normal(size=(3 * WIDTH, 3 * WIDTH)).astype(np.float32) * 0.3)
+    attention = jnp.asarray(rng.normal(size=(3 * WIDTH,)).astype(np.float32))
+    weigh = jnp.asarray(
+        rng.normal(size=(mask.shape[0], 3 * WIDTH)).astype(np.float32))
+    return jnp.asarray(mask), depth, rows, transform, attention, weigh
+
+
+def _over_the_grid(rows, transform, attention, mask, dropout=None, keep=1.0):
+    """`transform_gathered` + `masked_single_query_attention` over the
+    whole grid; with `dropout` (a `(B, M, 3d)` mask) the same chain by
+    hand under THAT mask."""
+    if dropout is None:
+        module = Code2VecModule(dims=CHAIN_DIMS, dropout_keep_rate=1.0,
+                                compute_dtype=jnp.float32)
+        params = {"transform": transform, "attention": attention[:, None],
+                  "token_embedding": jnp.zeros((VOCAB, WIDTH)),
+                  "path_embedding": jnp.zeros((VOCAB, WIDTH)),
+                  "target_embedding": jnp.zeros((5, 3 * WIDTH))}
+        transformed = module.apply(
+            {"params": params}, *rows, deterministic=True,
+            method=Code2VecModule.transform_gathered)
+    else:
+        ctx = jnp.where(dropout, jnp.concatenate(rows, axis=-1) / keep, 0.0)
+        transformed = jnp.tanh(ctx @ transform)
+    return masked_single_query_attention(transformed, attention, mask)[0]
+
+
+@pytest.mark.parametrize("name", sorted(MASKS))
+def test_the_chain_over_the_slots_is_the_chain_over_the_grid(name):
+    """Keep 1.0: code vectors and the gradients to the rows, `transform`
+    and `attention` equal those of the module's chain over the whole
+    grid, within float32 summation order."""
+    mask, depth, rows, transform, attention, weigh = _chain_case(
+        MASKS[name], seed=sorted(MASKS).index(name))
+
+    def live(rows, transform, attention):
+        code = encode_live_blocks(
+            tuple(_by_slot(r, depth) for r in rows), transform, attention,
+            mask, depth, jax.random.key(0), 1.0)
+        return jnp.sum(code * weigh), code
+
+    def grid(rows, transform, attention):
+        code = _over_the_grid(rows, transform, attention, mask)
+        return jnp.sum(code * weigh), code
+    (_, got_code), got = jax.jit(jax.value_and_grad(
+        live, argnums=(0, 1, 2), has_aux=True))(rows, transform, attention)
+    (_, want_code), want = jax.jit(jax.value_and_grad(
+        grid, argnums=(0, 1, 2), has_aux=True))(rows, transform, attention)
+    assert got_code.shape == want_code.shape
+    assert got_code.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got_code), np.asarray(want_code),
+                               rtol=1e-5, atol=1e-6)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-5)
+    # a slot no context is valid in gets gradient exactly 0.0
+    for g in got[0]:
+        assert (np.asarray(g)[np.asarray(mask) == 0] == 0.0).all()
+
+
+def _dropout_case():
+    """A batch large enough to count a share in: 32 rows x 12 contexts,
+    rows already by depth, a hole, an all-padding row."""
+    counts = np.sort(np.random.default_rng(0).integers(0, 13, 32))[::-1].copy()
+    counts[0], counts[-1] = 12, 0
+    mask = _prefix(counts, 12)
+    mask[0, 3] = 0.0
+    return _chain_case(mask)
+
+
+def _with_dropout(key, keep=0.75):
+    """Code vectors and gradients of the chain at `keep`, the rows'
+    gradient on the grid."""
+    mask, depth, rows, transform, attention, weigh = _dropout_case()
+
+    def live(rows, transform, attention):
+        code = encode_live_blocks(
+            tuple(_by_slot(r, depth) for r in rows), transform, attention,
+            mask, depth, key, keep)
+        return jnp.sum(code * weigh), code
+    (_, code), grads = jax.jit(jax.value_and_grad(
+        live, argnums=(0, 1, 2), has_aux=True))(rows, transform, attention)
+    return code, grads
+
+
+def test_dropout_keeps_three_quarters_of_the_live_elements():
+    """Keep 0.75: of the elements a valid context holds, the share whose
+    gradient is not 0 is 0.75 within sampling error (8,000 elements:
+    sigma 0.005); every other element's gradient is exactly 0.0; and the
+    backward drew the forward's mask: the chain by hand over the grid
+    under the mask the gradient shows gives the same code vectors and
+    the same parameter gradients."""
+    mask, depth, rows, transform, attention, weigh = _dropout_case()
+    code, (row_grads, transform_grad, attention_grad) = _with_dropout(
+        jax.random.key(3, impl="rbg"))
+    grad = np.concatenate([np.asarray(g) for g in row_grads], axis=-1)
+    valid = np.asarray(mask) > 0
+    assert valid.sum() * 3 * WIDTH > 4000
+    assert abs((grad[valid] != 0).mean() - 0.75) < 0.02
+    assert (grad[~valid] == 0.0).all()
+
+    def by_hand(transform, attention):
+        got = _over_the_grid(rows, transform, attention, mask,
+                             dropout=jnp.asarray(grad != 0), keep=0.75)
+        return jnp.sum(got * weigh), got
+    (_, want_code), want = jax.value_and_grad(
+        by_hand, argnums=(0, 1), has_aux=True)(transform, attention)
+    np.testing.assert_allclose(np.asarray(code), np.asarray(want_code),
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(transform_grad),
+                               np.asarray(want[0]), rtol=1e-4, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(attention_grad),
+                               np.asarray(want[1]), rtol=1e-4, atol=1e-5)
+
+
+def test_two_steps_draw_two_masks_and_one_key_draws_one():
+    key = jax.random.key(3, impl="rbg")
+    first, (first_grads, _, _) = _with_dropout(jax.random.fold_in(key, 0))
+    again, (again_grads, _, _) = _with_dropout(jax.random.fold_in(key, 0))
+    second, (second_grads, _, _) = _with_dropout(jax.random.fold_in(key, 1))
+    np.testing.assert_array_equal(np.asarray(first), np.asarray(again))
+    kept = [np.concatenate([np.asarray(g) != 0 for g in grads], axis=-1)
+            for grads in (first_grads, again_grads, second_grads)]
+    np.testing.assert_array_equal(kept[0], kept[1])
+    valid = np.asarray(_dropout_case()[0]) > 0
+    # two independent masks at keep 0.75 agree on 0.75^2 + 0.25^2 = 0.625
+    assert abs((kept[0] == kept[2])[valid].mean() - 0.625) < 0.03
+    assert not np.allclose(np.asarray(first), np.asarray(second))
 
 
 # ------------------------------------------------------------ the step
@@ -219,18 +394,35 @@ def test_three_steps_equal_the_take_steps_on_shuffled_rows(monkeypatch):
 
 
 def _refuse(*args, **kwargs):
-    raise AssertionError("embed_live_rows is the dense train step's alone")
+    raise AssertionError("the live-rows ops are the dense train step's alone")
 
 
-@pytest.mark.parametrize("which", ["eval", "predict_k1", "sparse_train"])
+@pytest.mark.parametrize("which", ["eval", "predict_k1", "sparse_train",
+                                   "manual_train"])
 def test_the_other_steps_lower_to_what_they_lower_to_without_the_op(
         monkeypatch, which):
-    """The eval and predict steps (and the sparse train step) never
-    reach the op: with it taken away they lower to the same program."""
-    overrides = ({"use_sparse_embedding_update": True}
-                 if which == "sparse_train" else {})
+    """The eval and predict steps (and the sparse and the manual train
+    steps) never reach the ops: with them taken away they lower to the
+    same program."""
+    overrides = {"sparse_train": {"use_sparse_embedding_update": True},
+                 "manual_train": {"dp": 2, "tp": 2,
+                                  "use_manual_tp_kernels": True}
+                 }.get(which, {})
 
     def lower():
+        if which == "manual_train":
+            config, _, _ = _toy(**overrides)
+            module = Code2VecModule(dims=DIMS, dropout_keep_rate=1.0,
+                                    compute_dtype=jnp.float32)
+            optimizer = make_optimizer(config)
+            mesh = make_mesh(MeshPlan(dp=2, tp=2, cp=1))
+            state = create_train_state(
+                module, optimizer, jax.random.PRNGKey(0), mesh=mesh,
+                config=config)
+            builder = TrainStepBuilder(module, optimizer, config, mesh=mesh)
+            assert builder.manual
+            return builder.make_train_step(state).lower(
+                state, *_toy_batch(), jax.random.PRNGKey(1)).as_text()
         _, builder, state = _toy(**overrides)
         batch = _toy_batch()
         if which == "sparse_train":
@@ -241,6 +433,7 @@ def test_the_other_steps_lower_to_what_they_lower_to_without_the_op(
             state.params, *batch).as_text()
     with_op = lower()
     monkeypatch.setattr(step_mod, "embed_live_rows", _refuse)
+    monkeypatch.setattr(step_mod, "encode_live_blocks", _refuse)
     assert lower() == with_op
 
 
@@ -294,6 +487,76 @@ def test_a_data_mesh_runs_the_lookup_chip_by_chip(dp):
             np.testing.assert_allclose(
                 np.asarray(mesh_state.params[key]), np.asarray(want),
                 rtol=1e-4, atol=2e-5, err_msg=f"step {n + 1} {key}")
+
+
+def test_two_chips_draw_two_masks():
+    """Under a data mesh each chip folds its index into the step's key:
+    two chips given the same rows return other code vectors at keep
+    0.75, and the same ones where the index is not folded in."""
+    half = _toy_batch(seed=3)
+    src, pth, tgt, mask = (np.concatenate([a, a]) for a in half[:4])
+    depth = embed.context_depth(jnp.asarray(mask))
+    mesh = make_mesh(MeshPlan(dp=2, tp=1, cp=1))
+    _, builder, state = _toy(keep=0.75)
+    params = {k: state.params[k] for k in step_mod._ENCODER_PARAMS}
+    rows, ids = P(AXIS_DATA), P(AXIS_DATA, None)
+
+    def codes(axis_name):
+        return np.asarray(jax.jit(jax.shard_map(
+            functools.partial(builder._encode_live_rows,
+                              axis_name=axis_name),
+            mesh=mesh, in_specs=(P(), ids, ids, ids, ids, rows, P()),
+            out_specs=ids, check_vma=False))(
+                params, src, pth, tgt, mask, depth,
+                jax.random.key(5, impl="rbg")))
+    same = codes(None)
+    np.testing.assert_array_equal(same[:B], same[B:])
+    apart = codes(AXIS_DATA)
+    live = np.asarray(half[3]).any(axis=1)
+    assert live.sum() > 2
+    assert (np.abs(apart[:B] - apart[B:])[live].max(axis=1) > 1e-4).all()
+
+
+def test_the_trainer_observes_what_the_dense_chain_runs_over(tiny_config):
+    """`train_dense_blocks_run_ratio` reads, once a batch, the live
+    blocks in whole `SLOT_CHUNK`s over all blocks: at or a little above
+    `train_context_blocks_live_ratio`."""
+    tiny_config.verbose_mode = 0
+    masks = [MASKS[name] for name in ("prefix", "hole", "every_row_full",
+                                      "nothing_live")]
+
+    def stream():
+        for mask in masks:
+            ids = np.ones(mask.shape, np.int32)
+            yield RowBatch(ids, ids, ids, mask,
+                           np.ones((mask.shape[0],), np.int32),
+                           np.ones((mask.shape[0],), bool))
+        yield EpochEnd(1)
+
+    class State:
+        step = np.zeros((), np.int32)
+    hist = obs.default_registry().histogram("train_dense_blocks_run_ratio")
+    count, total = hist.count, hist.sum
+    Trainer(tiny_config, lambda state, *args: (state, np.float32(1.0))
+            ).train(State(), stream(), rng=np.zeros((2,), np.uint32))
+    assert hist.count - count == len(masks)
+
+    def run_ratio(mask):
+        groups, across = embed._grid(*mask.shape)
+        live = round(_numpy_live_ratio(mask) * groups * across)
+        return -(-live // 2) * 2 / (groups * across)      # SLOT_CHUNK 2
+    assert hist.sum - total == pytest.approx(sum(map(run_ratio, masks)))
+    for mask in masks:
+        _, count_on_device = embed.live_slots(
+            jnp.sort(embed.context_depth(jnp.asarray(mask)))[::-1],
+            mask.shape[1])
+        assert embed.live_block_ratio(mask, chunk=2) == pytest.approx(
+            run_ratio(mask))
+        assert (embed.live_block_ratio(mask)
+                <= embed.live_block_ratio(mask, chunk=2)
+                < embed.live_block_ratio(mask) + 2 / 6)
+        assert -(-int(count_on_device) // 2) * 2 == round(
+            run_ratio(mask) * np.prod(embed._grid(*mask.shape)))
 
 
 def test_the_trainer_observes_each_batchs_live_ratio(tiny_config):

@@ -125,10 +125,13 @@ def test_padded_to_rounds_up():
 # ------------------------------------------------- scope names in the step
 
 _SCOPES = {
-    False: ("embed_gather", "transform", "attention", "logits_ce",
-            "transpose(jvp(Code2VecModule.apply_from_rows))", "adam_token",
-            "adam_path",
-            "adam_target", "adam_dense"),
+    # the dense step runs the chain over the live blocks
+    # (ops/encode_live.py): its loops' bodies carry the chain's names,
+    # forward and transposed, as a scope sum of a trace reads them
+    False: ("embed_gather", "/transform/", "/attention/", "logits_ce",
+            "/transpose(jvp(transform))/", "/transpose(jvp(attention))/",
+            "transpose(jvp(Code2VecModule.logits_from_code_vectors))",
+            "adam_token", "adam_path", "adam_target", "adam_dense"),
     # the touched-rows step gathers outside the differentiated function
     # and updates the two tables row-wise under the same two names
     True: ("embed_gather", "transform", "attention", "logits_ce",
