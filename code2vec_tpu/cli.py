@@ -79,10 +79,13 @@ def arguments_parser() -> ArgumentParser:
                         help="a model-configuration file (JSON, the "
                              "published config.json keys plus the share "
                              "held here) naming a model of models/ other "
-                             "than code2vec: today the hybrid state-space "
-                             "/ latent-expert language model "
-                             "(models/hybrid_lm.py), served for scoring "
-                             "on POST /score")
+                             "than code2vec, chosen by the file's own "
+                             "model_type: the hybrid state-space / "
+                             "latent-expert language model "
+                             "(models/hybrid_lm.py) or the latent-"
+                             "attention / gated-expert one "
+                             "(models/latent_moe_lm.py), served for "
+                             "scoring on POST /score")
     parser.add_argument("--serve_token_budget", type=int, default=None,
                         metavar="N",
                         help="most tokens (rows x padded length) in one "

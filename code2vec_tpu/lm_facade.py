@@ -15,6 +15,21 @@ A batch is `(rows, length)` padded on the right: `length` one of a few
 buckets, `rows` a power of two, `rows x length <= serve_token_budget`.
 One jitted step a shape, every shape compiled by `warmup()`.
 
+The model's module is chosen by the configuration file's own
+`model_type` (`MODEL_MODULES`); what a module gives the facade is
+`LMConfig.from_dict`, `leaf_specs` and `lm_score_step`. A module that
+also has `init_cache` and `ctx_register_step` can keep CONTEXTS on the
+device, where the file's `serve.context_cache` gives the slots: a
+context is registered once (`register_context`: chunk by chunk under
+one compiled shape, into a free or the least recently used slot,
+serving/context_cache.py), and a request may then name it: its tokens
+are scored as the continuation of the context's. The cache arrays are
+read by scoring steps and updated in place (donated) by registration
+chunks; `_cache_lock` makes "look the rows' slots up, dispatch the
+step" one act against "dispatch a chunk, take the new arrays", so a step
+never holds arrays a chunk gave away, and a slot taken after a step's
+lookup is overwritten only behind that step on the device.
+
 `--load` restores a parameters-only artifact leaf by leaf straight into
 place (training/checkpoint.py `restore_params`); without it the
 parameters are initialised from `--seed`, and `--save` writes them.
@@ -25,6 +40,7 @@ from __future__ import annotations
 import hashlib
 import json
 import os
+import threading
 from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
@@ -33,8 +49,11 @@ import numpy as np
 from code2vec_tpu import obs
 from code2vec_tpu.config import Config
 from code2vec_tpu.model_facade import _H_FILL, _stage
-from code2vec_tpu.models import hybrid_lm
+from code2vec_tpu.models import hybrid_lm, latent_moe_lm
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
+from code2vec_tpu.serving.context_cache import (
+    ContextSlots, chunks, context_id,
+)
 from code2vec_tpu.training import checkpoint as ckpt_mod
 from code2vec_tpu.utils.device import describe_devices
 
@@ -67,9 +86,39 @@ _C_EXPERTS_HIT = obs.counter(
     "steps and expert layers: whose weights a step had to read")
 
 
+_H_REGISTER = obs.histogram(
+    "context_register_seconds",
+    "wall time of one context registration, all its chunks",
+    buckets=(0.01, 0.03, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0, 30.0))
+_H_REGISTER_CHUNK = obs.histogram(
+    "context_register_chunk_seconds",
+    "wall time of one registration chunk, dispatch to ready",
+    buckets=(0.003, 0.01, 0.03, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0))
+_C_UNKNOWN = obs.counter(
+    "score_unknown_context_total",
+    "score requests that named a context that is unknown or evicted")
+
+_C_KEYS = obs.counter(
+    "score_latents_read_total",
+    "latents a scoring step's rows attend in one layer: each row's "
+    "cached context tokens and its own real tokens")
+_C_PAIRS = obs.counter(
+    "score_attended_pairs_total",
+    "(query, visible key) pairs of a scoring step's rows in one layer: "
+    "q x cached + q (q + 1) / 2 a row of q real tokens")
+
+# the configuration file's `model_type` -> the module that runs it
+MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm}
+
+
+class UnknownContext(LookupError):
+    """A request named a context that is not (or no longer) held."""
+
+
 class ScoreRequest(NamedTuple):
     ids: np.ndarray         # (length,) int32
     top_k: int
+    context: Optional[str] = None   # a registered context's id
 
 
 class ScoreResult(NamedTuple):
@@ -79,6 +128,10 @@ class ScoreResult(NamedTuple):
     tokens: int
     routing_last: np.ndarray    # (expert layers, k): the router's choice
     #                             at the last position
+    context_tokens: int = 0     # tokens of the context read before them
+    unknown_context: Optional[str] = None   # set INSTEAD of an answer:
+    #                             the context went between the request's
+    #                             admission and its step
 
 
 def row_counts(bucket: int, budget: int) -> Tuple[int, ...]:
@@ -101,7 +154,15 @@ class ScoringModel:
         self.log = config.log
         with open(config.model_config) as f:
             raw = json.load(f)
-        self.lm = hybrid_lm.LMConfig.from_dict(raw, config.model_config)
+        kind = raw.get("model_type", "nemotron_h")
+        if kind not in MODEL_MODULES:
+            raise ValueError(
+                f"{config.model_config}: model_type {kind!r} is none of "
+                f"{', '.join(MODEL_MODULES)}")
+        self.module = MODEL_MODULES[kind]
+        self.model_name = self.module.__name__.rsplit(".", 1)[-1]
+        self.lm = self.module.LMConfig.from_dict(raw, config.model_config)
+        specs = self.module.leaf_specs(self.lm)
         serve = raw.get("serve", {})
         self.token_budget = int(config.serve_token_budget)
         self.top_k = int(config.top_k_words_considered_during_prediction)
@@ -119,15 +180,36 @@ class ScoringModel:
             with obs.startup_phase("restore"):
                 self.params = ckpt_mod.restore_params(
                     config.model_load_path,
-                    hybrid_lm.abstract_params(self.lm))
+                    hybrid_lm.abstract_leaves(specs))
             self.log(f"Loaded model weights from {config.model_load_path}")
         else:
             with obs.startup_phase("state_init"):
                 self.params = jax.block_until_ready(
-                    hybrid_lm.init_params(self.lm, config.seed))
+                    hybrid_lm.init_leaves(self.lm, specs, config.seed))
         self._predict_steps: Dict[Tuple[int, int], object] = {}
         self._fingerprint: Optional[str] = None
-        self.log(f"Model created: {hybrid_lm.num_params(self.lm):,} "
+        self.contexts: Optional[ContextSlots] = None
+        held = serve.get("context_cache")
+        if held and hasattr(self.module, "init_cache"):
+            self.contexts = ContextSlots(held["slots"],
+                                         held["tokens_per_slot"])
+            self.register_chunk = int(held["register_chunk"])
+            if self.contexts.capacity % self.register_chunk:
+                raise ValueError(
+                    f"{config.model_config}: tokens_per_slot must be a "
+                    f"multiple of register_chunk")
+            self.cache = self.module.init_cache(
+                self.lm, self.contexts.slots, self.contexts.capacity)
+            self._cache_lock = threading.Lock()
+            self._register_lock = threading.Lock()
+            self._register_step = None
+            self.served_endpoints = ("score", "contexts")
+            self.log(f"Context cache: {self.contexts.slots} slots x "
+                     f"{self.contexts.capacity} tokens x {self.lm.layers} "
+                     f"layers x {self.lm.cache_width} values = "
+                     f"{sum(a.nbytes for a in self.cache):,} bytes; "
+                     f"registration in chunks of {self.register_chunk}")
+        self.log(f"Model created: {hybrid_lm.count_leaves(specs):,} "
                  f"parameters; {self.describe_devices()}")
 
     # ------------------------------------------------------ the contract
@@ -167,6 +249,12 @@ class ScoringModel:
         those first where the device cannot hold two sets)."""
         self.params = params
         self._fingerprint = None
+        if self.contexts is not None:
+            # latents of the old weights answer nothing: every context
+            # goes, and has to be registered again
+            with self._register_lock, self._cache_lock:
+                self.contexts = ContextSlots(self.contexts.slots,
+                                             self.contexts.capacity)
 
     def save(self, model_save_path: Optional[str] = None) -> str:
         path = ckpt_mod.save_params(
@@ -188,43 +276,145 @@ class ScoringModel:
         key = (rows, length)
         step = self._predict_steps.get(key)
         if step is None:
-            cfg, k = self.lm, self.top_k
+            cfg, k, module = self.lm, self.top_k, self.module
             block = min(4096, cfg.vocab_rows)
 
-            def lm_score_step(params, ids, lengths):
-                return hybrid_lm.lm_score_step(cfg, k, block, params, ids,
-                                               lengths)
-            step = self._predict_steps[key] = jax.jit(lm_score_step)
+            if self.contexts is None:
+                def lm_score_step(params, ids, lengths):
+                    return module.lm_score_step(cfg, k, block, params, ids,
+                                                lengths)
+                step = jax.jit(lm_score_step)
+            else:
+                def ctx_score_step(params, ids, lengths, cache, slot, held):
+                    return module.lm_score_step(cfg, k, block, params, ids,
+                                                lengths, cache, slot, held)
+                step = jax.jit(ctx_score_step)
+            self._predict_steps[key] = step
             self.log(f"Compiling scoring step for shape (rows={rows}, "
                      f"length={length}) [{len(self._predict_steps)} of "
                      f"{len(self.shapes())}]")
         return step
 
+    def _run_step(self, rows: int, length: int, ids: np.ndarray,
+                  lengths: np.ndarray, contexts: Sequence[Optional[str]] = ()):
+        """Dispatch one scoring step, row i after context `contexts[i]`;
+        the answer is fetched by the caller. -> (the step's outputs, the
+        tokens each row's context holds, {row: its context's id} for
+        the rows whose context is gone: they run as padding, `lengths`
+        zeroed IN PLACE for them). With a cache the slots are looked up
+        and the step dispatched under the cache's lock (module
+        docstring)."""
+        step = self._step(rows, length)
+        held = np.zeros((rows,), np.int32)
+        gone: Dict[int, str] = {}
+        if self.contexts is None:
+            return step(self.params, ids, lengths), held, gone
+        slot = np.zeros((rows,), np.int32)
+        with self._cache_lock:
+            for i, context in enumerate(contexts):
+                if context is None:
+                    continue
+                found = self.contexts.lookup(context)
+                if found is None:
+                    gone[i], lengths[i] = context, 0
+                else:
+                    slot[i], held[i] = found
+            return (step(self.params, ids, lengths, self.cache, slot, held),
+                    held, gone)
+
     def warmup(self, rows: Optional[int] = None) -> None:
-        """Compile and run every (rows, length) shape once, so that no
-        request pays a compile out of its deadline."""
+        """Compile and run every (rows, length) shape once (and the one
+        registration chunk), so that no request pays a compile out of
+        its deadline."""
         for n, length in self.shapes():
-            out = self._step(n, length)(
-                self.params, np.zeros((n, length), np.int32),
+            out, _, _ = self._run_step(
+                n, length, np.zeros((n, length), np.int32),
                 np.ones((n,), np.int32))
             jax.block_until_ready(out.topk_values)
+        if self.contexts is not None:
+            # a chunk of no real token into slot 0: what it writes there
+            # lies behind the length of whatever the slot holds
+            self._register_chunk(np.zeros((self.register_chunk,), np.int32),
+                                 0, 0, self.contexts.capacity
+                                 - self.register_chunk)
 
-    def validate(self, ids: Sequence[int], top_k: int) -> ScoreRequest:
-        """A request's ids as an array, or ValueError saying what is
-        wrong with them."""
+    def _token_ids(self, ids: Sequence[int], most: int) -> np.ndarray:
         try:
             arr = np.asarray(ids, dtype=np.int64)
         except (TypeError, ValueError, OverflowError):
             raise ValueError("ids must be a list of integers")
-        if arr.ndim != 1 or not 1 <= arr.size <= self.token_budget:
-            raise ValueError(f"ids must hold 1 to {self.token_budget} "
-                             f"token ids")
+        if arr.ndim != 1 or not 1 <= arr.size <= most:
+            raise ValueError(f"ids must hold 1 to {most} token ids")
         if arr.min() < 0 or arr.max() >= self.lm.vocab_rows:
             raise ValueError(f"token ids must lie in "
                              f"[0, {self.lm.vocab_rows})")
+        return arr.astype(np.int32)
+
+    def validate(self, ids: Sequence[int], top_k: int,
+                 context: Optional[str] = None) -> ScoreRequest:
+        """A request's ids as an array, or ValueError saying what is
+        wrong with them; UnknownContext for a context that is not
+        held."""
+        arr = self._token_ids(ids, self.token_budget)
         if not 1 <= int(top_k) <= self.top_k:
             raise ValueError(f"top_k must lie in [1, {self.top_k}]")
-        return ScoreRequest(arr.astype(np.int32), int(top_k))
+        if context is not None:
+            if self.contexts is None:
+                raise ValueError("this model keeps no contexts: send the "
+                                 "whole sequence as ids")
+            if self.contexts.lookup(str(context)) is None:
+                _C_UNKNOWN.inc()
+                raise UnknownContext(
+                    f"context {context!r} is unknown or evicted: register "
+                    f"it again (POST /contexts)")
+            context = str(context)
+        return ScoreRequest(arr, int(top_k), context)
+
+    # ----------------------------------------------------------- contexts
+
+    def _register_chunk(self, ids: np.ndarray, real: int, slot: int,
+                        start: int) -> None:
+        if self._register_step is None:
+            cfg, module = self.lm, self.module
+
+            def ctx_register_step(params, cache, ids, length, slot, start):
+                return module.ctx_register_step(cfg, params, cache, ids,
+                                                length, slot, start)
+            self._register_step = jax.jit(ctx_register_step,
+                                          donate_argnums=(1,))
+        with obs.span("context.register.chunk", hist=_H_REGISTER_CHUNK):
+            with self._cache_lock:
+                self.cache = self._register_step(
+                    self.params, self.cache, ids, np.int32(real),
+                    np.int32(slot), np.int32(start))
+            jax.block_until_ready(self.cache)
+
+    def register_context(self, ids: Sequence[int]) -> Dict:
+        """Run a context's tokens through the model once and keep their
+        latents in a cache slot. -> {"context": its id, "tokens",
+        "evicted": the id that lost the slot, or None, "held": whether
+        it was there already}. Scoring steps run between the chunks."""
+        if self.contexts is None:
+            raise ValueError("this model keeps no contexts")
+        arr = self._token_ids(ids, self.contexts.capacity)
+        context = context_id(arr)
+        with self._register_lock, obs.span("context.register",
+                                           hist=_H_REGISTER):
+            if self.contexts.lookup(context) is not None:
+                return {"context": context, "tokens": int(arr.size),
+                        "evicted": None, "held": True}
+            slot, evicted = self.contexts.acquire()
+            try:
+                for start, real in chunks(arr.size, self.register_chunk):
+                    part = np.zeros((self.register_chunk,), np.int32)
+                    part[:real] = arr[start:start + real]
+                    self._register_chunk(part, real, slot, start)
+            except BaseException:
+                self.contexts.release(slot)
+                raise
+            self.contexts.commit(slot, context, arr.size)
+        return {"context": context, "tokens": int(arr.size),
+                "evicted": evicted, "held": False}
 
     def score_batch(self, requests: Sequence[ScoreRequest]
                     ) -> List[ScoreResult]:
@@ -255,11 +445,17 @@ class ScoringModel:
                 ids[i, :len(r.ids)] = r.ids
                 lengths[i] = len(r.ids)
             _H_FILL["rows"].observe(n / rows)
-            _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
         with _stage("device"):
-            got = self._step(rows, length)(self.params, ids, lengths)
+            got, held, gone = self._run_step(
+                rows, length, ids, lengths, [r.context for r in requests])
             values, indices, lse, stats = jax.device_get(
                 (got.topk_values, got.topk_indices, got.lse, got.stats))
+        _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
+        if self.contexts is not None:
+            q = lengths.astype(np.int64)
+            _C_KEYS.inc(int((held + q).sum()))
+            _C_PAIRS.inc(int((q * held + q * (q + 1) // 2).sum()))
+            _C_UNKNOWN.inc(len(gone))
         with _stage("render"):
             self._observe_router(stats)
             results = []
@@ -268,7 +464,7 @@ class ScoringModel:
                 results.append(ScoreResult(
                     indices[i, :k], values[i, :k],
                     np.exp(values[i, :k] - lse[i]), int(lengths[i]),
-                    stats.chosen_last[i]))
+                    stats.chosen_last[i], int(held[i]), gone.get(i)))
             return results
 
     @staticmethod

@@ -201,14 +201,18 @@ def leaf_specs(cfg: LMConfig) -> List[Leaf]:
     return out
 
 
-def num_params(cfg: LMConfig) -> int:
+def count_leaves(specs: List[Leaf]) -> int:
     n = 0
-    for leaf in leaf_specs(cfg):
+    for leaf in specs:
         size = 1
         for s in leaf.shape:
             size *= s
         n += size
     return n
+
+
+def num_params(cfg: LMConfig) -> int:
+    return count_leaves(leaf_specs(cfg))
 
 
 def init_leaf(cfg: LMConfig, leaf: Leaf, key) -> jax.Array:
@@ -243,19 +247,27 @@ def init_leaf(cfg: LMConfig, leaf: Leaf, key) -> jax.Array:
     raise ValueError(f"unknown initializer {leaf.init!r}")
 
 
-def init_params(cfg: LMConfig, seed: int) -> Dict[str, jax.Array]:
+def init_leaves(cfg, specs: List[Leaf], seed: int) -> Dict[str, jax.Array]:
     """Leaf by leaf on the device, so that nothing larger than the
     largest leaf exists beside the parameters."""
     root = jax.random.PRNGKey(seed)
     make = jax.jit(init_leaf, static_argnums=(0, 1))
     return {leaf.name: make(cfg, leaf, jax.random.fold_in(root, i))
-            for i, leaf in enumerate(leaf_specs(cfg))}
+            for i, leaf in enumerate(specs)}
+
+
+def abstract_leaves(specs: List[Leaf]) -> Dict[str, jax.ShapeDtypeStruct]:
+    return {leaf.name: jax.ShapeDtypeStruct(leaf.shape,
+                                            jnp.dtype(leaf.dtype))
+            for leaf in specs}
+
+
+def init_params(cfg: LMConfig, seed: int) -> Dict[str, jax.Array]:
+    return init_leaves(cfg, leaf_specs(cfg), seed)
 
 
 def abstract_params(cfg: LMConfig) -> Dict[str, jax.ShapeDtypeStruct]:
-    return {leaf.name: jax.ShapeDtypeStruct(leaf.shape,
-                                            jnp.dtype(leaf.dtype))
-            for leaf in leaf_specs(cfg)}
+    return abstract_leaves(leaf_specs(cfg))
 
 
 # ---------------------------------------------------------------- the layers

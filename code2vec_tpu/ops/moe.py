@@ -20,13 +20,18 @@ long on the chip (PERF.md, PR 26); elsewhere it is `lax.ragged_dot`.
 The buffer is `tokens * k` rows, the most the router can send here, so
 its shape is static; rows past the last group cost no matmul time.
 
+An expert is either the two-matrix `W2 relu(W1 x)^2` or, given a gate
+matrix, the gated `W2 (silu(Wg x) * W1 x)`: a third grouped matmul over
+the same sorted rows. `gated_mlp` is the same gated unit as one plain
+MLP (a shared expert, a dense layer).
+
 `experts_loop` is the plain form, one expert after the other with a
 dense mask, float32: what the grouped form is tested against.
 """
 
 from __future__ import annotations
 
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -92,9 +97,11 @@ def experts_grouped(latent: jax.Array,      # (T, latent_dim)
                     w2: jax.Array,          # (held, width, latent_dim)
                     first: int,
                     token_real: jax.Array,  # (T,) bool: not padding
+                    w_gate: Optional[jax.Array] = None,  # as w1: gated
                     ) -> Tuple[jax.Array, ExpertStats]:
-    """(T, latent_dim) float32: sum_i w_i W2_i relu(W1_i l)^2 over the
-    chosen experts held here; and the router's load on them."""
+    """(T, latent_dim) float32: sum_i w_i W2_i relu(W1_i l)^2 (with
+    `w_gate`: sum_i w_i W2_i (silu(Wg_i l) * W1_i l)) over the chosen
+    experts held here; and the router's load on them."""
     tokens, k = routed.experts.shape
     held = w1.shape[0]
     local = routed.experts - first
@@ -107,8 +114,13 @@ def experts_grouped(latent: jax.Array,      # (T, latent_dim)
     edges = jnp.searchsorted(key[order], jnp.arange(held + 1), side="left")
     load = (edges[1:] - edges[:-1]).astype(jnp.int32)
     x = jnp.take(latent, order // k, axis=0)            # (T * k, latent)
-    hidden = grouped_matmul(x, w1, load, latent.dtype)
-    hidden = relu2(hidden.astype(jnp.float32)).astype(latent.dtype)
+    if w_gate is None:
+        hidden = grouped_matmul(x, w1, load, latent.dtype)
+        hidden = relu2(hidden.astype(jnp.float32)).astype(latent.dtype)
+    else:
+        gate = grouped_matmul(x, w_gate, load, jnp.float32)
+        hidden = (jax.nn.silu(gate) * grouped_matmul(
+            x, w1, load, jnp.float32)).astype(latent.dtype)
     y = grouped_matmul(hidden, w2, load, jnp.float32)
     # rows past the last group are whatever the kernel left there: a
     # zero weight does not undo a NaN, so they are masked outright
@@ -124,7 +136,20 @@ def experts_grouped(latent: jax.Array,      # (T, latent_dim)
     return out, stats
 
 
-def experts_loop(latent, routed: Routed, w1, w2, first: int) -> jax.Array:
+def gated_mlp(x: jax.Array, gate: jax.Array, up: jax.Array,
+              down: jax.Array, out_dtype=jnp.float32) -> jax.Array:
+    """down(silu(gate x) * up x): operands in the weights' type,
+    accumulation and the gate's product float32."""
+    x = x.astype(gate.dtype)
+    f32 = jnp.float32
+    hidden = (jax.nn.silu(jnp.dot(x, gate, preferred_element_type=f32))
+              * jnp.dot(x, up, preferred_element_type=f32))
+    return jnp.dot(hidden.astype(down.dtype), down,
+                   preferred_element_type=f32).astype(out_dtype)
+
+
+def experts_loop(latent, routed: Routed, w1, w2, first: int,
+                 w_gate=None) -> jax.Array:
     """One held expert after the other over every token, masked by the
     router's choice; float32, "highest"."""
     f32 = jnp.float32
@@ -134,6 +159,8 @@ def experts_loop(latent, routed: Routed, w1, w2, first: int) -> jax.Array:
     for e in range(w1.shape[0]):
         w = jnp.sum(jnp.where(routed.experts == first + e,
                               routed.weights, 0.0), axis=-1)   # (T,)
-        h = relu2(jnp.dot(latent, w1[e].astype(f32), precision=hi))
+        h = jnp.dot(latent, w1[e].astype(f32), precision=hi)
+        h = (relu2(h) if w_gate is None else h * jax.nn.silu(
+            jnp.dot(latent, w_gate[e].astype(f32), precision=hi)))
         out = out + w[:, None] * jnp.dot(h, w2[e].astype(f32), precision=hi)
     return out

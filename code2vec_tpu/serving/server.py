@@ -577,10 +577,17 @@ class PredictionServer:
                 raise _HTTPError(503, str(e))
             knobs = self._neighbor_knobs(params)
             knobs["index"] = self.retrieval.fingerprint
+        model, fp = self._model_ref
+        if endpoint == "contexts":
+            return self._register_context(model, params, deadline, trace,
+                                          tenant)
+        score_request = None
         if endpoint == "score":
             knobs = {"return_routing": bool(
                 (params or {}).get("return_routing"))}
-        model, fp = self._model_ref
+            # before the cache probe: an answer cached while its context
+            # was held must not outlive the context
+            score_request = self._score_request(model, params)
         # ONE normalization pass per request: the same bytes feed the
         # cache probe here and the hot-swap re-key below.
         normalized = normalize_source(code)
@@ -600,8 +607,7 @@ class PredictionServer:
         worked = True
         try:
             if endpoint == "score":
-                lines, hash_to_string = [self._score_request(
-                    model, params)], {}
+                lines, hash_to_string = [score_request], {}
             else:
                 lines, hash_to_string = self._extract(
                     code, deadline, phases, trace=trace)
@@ -667,9 +673,39 @@ class PredictionServer:
                                   '"top_k": N}')
         try:
             return model.validate(params["ids"],
-                                  params.get("top_k", model.top_k))
+                                  params.get("top_k", model.top_k),
+                                  params.get("context"))
         except ValueError as e:
             raise _HTTPError(400, str(e))
+        except LookupError as e:        # a context that is not held
+            raise _HTTPError(404, str(e.args[0]))
+
+    def _register_context(self, model, params: Optional[Dict],
+                          deadline: Optional[Deadline],
+                          trace: RequestTrace,
+                          tenant: Optional[str]) -> bytes:
+        """POST /contexts, `{"ids": [...]}`: the model runs the tokens
+        once and keeps their state on the device; the answer names the
+        context for later /score requests. It passes admission like any
+        request, not the batcher and not the response cache: the model
+        interleaves its chunks with scoring steps itself."""
+        if "ids" not in (params or {}):
+            raise _HTTPError(400, 'JSON body must be {"ids": [...]}')
+        with trace.span("admission"):
+            self.admission.admit(deadline, tenant=tenant)
+        t_admit = time.perf_counter()
+        try:
+            with trace.span("register"):
+                out = model.register_context(params["ids"])
+        except ValueError as e:
+            raise _HTTPError(400, str(e))
+        except LookupError as e:        # every slot is being filled
+            raise _HTTPError(503, str(e.args[0]))
+        finally:
+            self.admission.finish(time.perf_counter() - t_admit,
+                                  tenant=tenant)
+        out["model_fingerprint"] = self._model_ref[1]
+        return json.dumps(out, sort_keys=True).encode() + b"\n"
 
     def _extract(self, code: str, deadline: Optional[Deadline],
                  phases: Dict[str, float],
@@ -726,8 +762,14 @@ class PredictionServer:
             # one token sequence a request: the top-k next-token logits
             # at its last position, probabilities over the rows held
             [r] = raw
-            out = {"model": "hybrid_lm", "model_fingerprint": fingerprint,
-                   "tokens": r.tokens,
+            if r.unknown_context is not None:
+                raise _HTTPError(
+                    404, f"context {r.unknown_context!r} was evicted "
+                         f"before this request's step: register it again "
+                         f"(POST /contexts)")
+            out = {"model": self._model_ref[0].model_name,
+                   "model_fingerprint": fingerprint,
+                   "tokens": r.tokens, "context_tokens": r.context_tokens,
                    "top": [{"id": int(i), "logit": float(v),
                             "probability": float(p)}
                            for i, v, p in zip(r.token_ids, r.logits,
@@ -969,7 +1011,7 @@ class PredictionServer:
                     self._admin_dump()
                     return
                 if endpoint not in ("predict", "embed", "neighbors",
-                                    "score"):
+                                    "score", "contexts"):
                     self._error(404, f"no such endpoint: {path}")
                     return
                 # Inbound W3C traceparent joins the caller's distributed
@@ -1155,7 +1197,7 @@ class PredictionServer:
         content type says: the text is the cache's key, the parsed
         object the parameters."""
         text = raw.decode("utf-8", errors="replace")
-        if endpoint == "score":
+        if endpoint in ("score", "contexts"):
             try:
                 payload = json.loads(text)
             except json.JSONDecodeError as e:
@@ -1406,15 +1448,21 @@ def serve_main(config, model=None, *, stop: Optional[threading.Event]
             fault_point("replica_heartbeat")
             _publish()
 
+    ticker = None
     if config.heartbeat_file or config.metrics_file:
         _publish()
-        threading.Thread(target=_heartbeat_loop, name="serving-heartbeat",
-                         daemon=True).start()
+        ticker = threading.Thread(target=_heartbeat_loop,
+                                  name="serving-heartbeat", daemon=True)
+        ticker.start()
     try:
         stop.wait()
     finally:
         clean = server.drain()
         hb_stop.set()
+        if ticker is not None:
+            # a tick caught mid-write must not land its "draining" after
+            # the final heartbeat below
+            ticker.join(timeout=5.0)
         obs.log_compiles_from_now(None)
         if install_signals:
             signal.signal(signal.SIGTERM, prev_term)

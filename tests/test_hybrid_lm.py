@@ -313,7 +313,10 @@ def test_served_answers_are_the_references_top_k(served):
     ({"ids": list(range(100)) * 6}, 400, "1 to 512"),
     ({"ids": [1, 2], "top_k": 99}, 400, "top_k"),
     ({"tokens": [1]}, 400, "ids"),
-], ids=["id_outside_slice", "empty", "over_budget", "top_k", "no_ids"])
+    ({"ids": [1, 2], "context": "feedfeedfeedfeed"}, 400,
+     "keeps no contexts"),
+], ids=["id_outside_slice", "empty", "over_budget", "top_k", "no_ids",
+        "context_without_a_cache"])
 def test_score_refuses_what_it_cannot_answer(served, body, status, says):
     server, _ = served
     got, raw, _ = server.handle_request("score", json.dumps(body),
@@ -324,6 +327,9 @@ def test_score_refuses_what_it_cannot_answer(served, body, status, says):
 def test_other_routes_say_what_the_model_serves(served):
     server, _ = served
     status, raw, _ = server.handle_request("predict", "class A {}")
+    assert status == 404 and "/score" in raw.decode()
+    status, raw, _ = server.handle_request("contexts", '{"ids": [1]}',
+                                           params={"ids": [1]})
     assert status == 404 and "/score" in raw.decode()
     assert server.healthz()["extractor_pool"] is None
     assert server.healthz()["buckets"] == [128, 256, 512]
