@@ -33,7 +33,9 @@ from code2vec_tpu.obs import exporters as obs_exporters
 from code2vec_tpu.data.reader import EpochEnd
 from code2vec_tpu.ops import embed
 from code2vec_tpu.training.state import TrainState
-from code2vec_tpu.training.step import gathers_live_rows
+from code2vec_tpu.training.step import (
+    async_collective_count, gathers_live_rows,
+)
 from code2vec_tpu.utils.device import describe_devices, shard_layout
 from code2vec_tpu.utils.prefetch import DevicePrefetcher
 
@@ -223,6 +225,11 @@ class Trainer:
             "window throughput at the last log boundary")
         g_epoch = reg.gauge("train_epoch", "current epoch number")
         g_rss = reg.gauge("process_rss_bytes", "current resident set size")
+        g_async = reg.gauge(
+            "train_step_async_collectives",
+            "collectives of the compiled train step that carry an "
+            "asynchronous start (training/step.py "
+            "async_collective_count), read once after the first step")
 
         batch_num = 0              # batches this run
         trace_active = False       # profiler trace in flight
@@ -516,10 +523,17 @@ class Trainer:
                             state, loss = self.train_step(state, *arrays,
                                                           rng)
                         jax.block_until_ready(loss)
+                        # read off the executable that call compiled
+                        n_async = async_collective_count(
+                            self.train_step, state, *arrays, rng)
+                        if n_async is not None:
+                            g_async.set(n_async)
+                    said_async = ("" if n_async is None else
+                                  f"{n_async} asynchronous collective(s); ")
                     log(f"First train step dispatched in "
                         f"{disp.seconds:.2f}s (trace + compile, or a "
                         f"compile-cache load), ready after "
-                        f"{first.seconds:.2f}s; "
+                        f"{first.seconds:.2f}s; {said_async}"
                         f"batch {tuple(arrays[0].shape)}: "
                         f"{shard_layout(arrays[0])}")
                     obs.log_compiles_from_now(log)
