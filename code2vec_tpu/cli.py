@@ -82,9 +82,11 @@ def arguments_parser() -> ArgumentParser:
                              "than code2vec, chosen by the file's own "
                              "model_type: the hybrid state-space / "
                              "latent-expert language model "
-                             "(models/hybrid_lm.py) or the latent-"
+                             "(models/hybrid_lm.py), the latent-"
                              "attention / gated-expert one "
-                             "(models/latent_moe_lm.py), served for "
+                             "(models/latent_moe_lm.py) or the grouped-"
+                             "query / selected-key / softmax-expert one "
+                             "(models/sparse_gqa_moe_lm.py), served for "
                              "scoring on POST /score")
     parser.add_argument("--serve_token_budget", type=int, default=None,
                         metavar="N",

@@ -19,7 +19,11 @@ The model's module is chosen by the configuration file's own
 `model_type` (`MODEL_MODULES`); what a module gives the facade is
 `LMConfig.from_dict`, `leaf_specs` and `lm_score_step`. A module that
 also has `init_cache` and `ctx_register_step` can keep CONTEXTS on the
-device, where the file's `serve.context_cache` gives the slots: a
+device, where the file's `serve.context_cache` gives the slots. The
+cache is the module's own: a tuple with one entry a layer, each entry an
+array or a tuple of arrays (two kinds of state a token); the facade
+holds it, hands it to the steps, donates it to registration and counts
+its bytes, and reads nothing inside it. A
 context is registered once (`register_context`: chunk by chunk under
 one compiled shape, into a free or the least recently used slot,
 serving/context_cache.py), and a request may then name it: its tokens
@@ -49,7 +53,10 @@ import numpy as np
 from code2vec_tpu import obs
 from code2vec_tpu.config import Config
 from code2vec_tpu.model_facade import _H_FILL, _stage
-from code2vec_tpu.models import hybrid_lm, latent_moe_lm
+from code2vec_tpu.models import (
+    hybrid_lm, latent_moe_lm, lm_common, sparse_gqa_moe_lm,
+)
+from code2vec_tpu.ops.sparse_attn import unpack_bits
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
 from code2vec_tpu.serving.context_cache import (
     ContextSlots, chunks, context_id,
@@ -107,8 +114,32 @@ _C_PAIRS = obs.counter(
     "(query, visible key) pairs of a scoring step's rows in one layer: "
     "q x cached + q (q + 1) / 2 a row of q real tokens")
 
+_C_INDEX_PAIRS = obs.counter(
+    "score_index_pairs_scored_total",
+    "(query, visible key) pairs the indexer of a selecting attention "
+    "scored, summed over a scoring step's rows, real queries and layers")
+_C_KEYS_VISIBLE = obs.counter(
+    "score_keys_visible_total",
+    "keys a real query may see, summed over a scoring step's rows, "
+    "queries and layers (what the selection chooses among)")
+_C_KEYS_SELECTED = obs.counter(
+    "score_keys_selected_total",
+    "keys the selection kept (the step's own count, fetched with the "
+    "answer), summed over a scoring step's rows, real queries and layers")
+
 # the configuration file's `model_type` -> the module that runs it
-MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm}
+MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm,
+                 "KeyeVL2": sparse_gqa_moe_lm}
+
+
+def selected_positions(words: np.ndarray, capacity: int, held: int
+                       ) -> np.ndarray:
+    """A row's `StepStats.selected_last` of one layer as positions of
+    the sequence context ++ question, ascending: the packed row counts
+    the slot's `capacity` positions, then the row's own tokens, which
+    stand behind the `held` the context has."""
+    at = unpack_bits(words)
+    return np.where(at < capacity, at, at - capacity + held).astype(np.int32)
 
 
 class UnknownContext(LookupError):
@@ -132,6 +163,10 @@ class ScoreResult(NamedTuple):
     unknown_context: Optional[str] = None   # set INSTEAD of an answer:
     #                             the context went between the request's
     #                             admission and its step
+    selected_last: Optional[np.ndarray] = None  # (layers, words) uint32:
+    #                             the keys the last position attended,
+    #                             packed (`selected_positions`); None for
+    #                             a model whose attention selects nothing
 
 
 def row_counts(bucket: int, budget: int) -> Tuple[int, ...]:
@@ -180,12 +215,18 @@ class ScoringModel:
             with obs.startup_phase("restore"):
                 self.params = ckpt_mod.restore_params(
                     config.model_load_path,
-                    hybrid_lm.abstract_leaves(specs))
+                    lm_common.abstract_leaves(specs))
             self.log(f"Loaded model weights from {config.model_load_path}")
         else:
             with obs.startup_phase("state_init"):
                 self.params = jax.block_until_ready(
-                    hybrid_lm.init_leaves(self.lm, specs, config.seed))
+                    lm_common.init_leaves(self.lm, specs, config.seed))
+        if hasattr(self.module, "ATTEND_FORM"):
+            self._c_attend_form = obs.counter(
+                "sparse_attend_steps_total",
+                "scoring steps by the form of attention over the selected "
+                "keys that their shape picked",
+                form=self.module.ATTEND_FORM)
         self._predict_steps: Dict[Tuple[int, int], object] = {}
         self._fingerprint: Optional[str] = None
         self.contexts: Optional[ContextSlots] = None
@@ -207,9 +248,10 @@ class ScoringModel:
             self.log(f"Context cache: {self.contexts.slots} slots x "
                      f"{self.contexts.capacity} tokens x {self.lm.layers} "
                      f"layers x {self.lm.cache_width} values = "
-                     f"{sum(a.nbytes for a in self.cache):,} bytes; "
+                     f"{sum(a.nbytes for a in jax.tree.leaves(self.cache)):,}"
+                     f" bytes; "
                      f"registration in chunks of {self.register_chunk}")
-        self.log(f"Model created: {hybrid_lm.count_leaves(specs):,} "
+        self.log(f"Model created: {lm_common.count_leaves(specs):,} "
                  f"parameters; {self.describe_devices()}")
 
     # ------------------------------------------------------ the contract
@@ -416,6 +458,13 @@ class ScoringModel:
         return {"context": context, "tokens": int(arr.size),
                 "evicted": evicted, "held": False}
 
+    def selected_positions(self, result: ScoreResult) -> List[np.ndarray]:
+        """By layer, the positions of context ++ question that the
+        request's last position attended."""
+        return [selected_positions(words, self.contexts.capacity,
+                                   result.context_tokens)
+                for words in result.selected_last]
+
     def score_batch(self, requests: Sequence[ScoreRequest]
                     ) -> List[ScoreResult]:
         """One result a request, in order. The batcher hands over what
@@ -453,9 +502,18 @@ class ScoringModel:
         _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
         if self.contexts is not None:
             q = lengths.astype(np.int64)
+            pairs = int((q * held + q * (q + 1) // 2).sum())
             _C_KEYS.inc(int((held + q).sum()))
-            _C_PAIRS.inc(int((q * held + q * (q + 1) // 2).sum()))
+            _C_PAIRS.inc(pairs)
             _C_UNKNOWN.inc(len(gone))
+            if stats.selected_keys is not None:
+                # every visible key of a query is index-scored, so the
+                # two counts are one number while the indexer prunes none
+                layers = len(stats.selected_keys)
+                _C_INDEX_PAIRS.inc(pairs * layers)
+                _C_KEYS_VISIBLE.inc(pairs * layers)
+                _C_KEYS_SELECTED.inc(int(stats.selected_keys.sum()))
+                self._c_attend_form.inc()
         with _stage("render"):
             self._observe_router(stats)
             results = []
@@ -464,7 +522,9 @@ class ScoringModel:
                 results.append(ScoreResult(
                     indices[i, :k], values[i, :k],
                     np.exp(values[i, :k] - lse[i]), int(lengths[i]),
-                    stats.chosen_last[i], int(held[i]), gone.get(i)))
+                    stats.chosen_last[i], int(held[i]), gone.get(i),
+                    None if stats.selected_last is None
+                    else stats.selected_last[i]))
             return results
 
     @staticmethod

@@ -43,11 +43,15 @@ from __future__ import annotations
 
 import dataclasses
 import json
-from typing import Dict, List, NamedTuple, Tuple
+from typing import Dict, List
 
 import jax
 import jax.numpy as jnp
 
+from code2vec_tpu.models.lm_common import (  # noqa: F401  (re-exported)
+    Leaf, ScoreOutputs, StepStats, _matmul, abstract_leaves, count_leaves,
+    init_leaf, init_leaves, layer_params, layer_prefix, rms_norm,
+)
 from code2vec_tpu.ops import moe, ssd
 from code2vec_tpu.ops.attention import causal_gqa_attention
 from code2vec_tpu.ops.topk import blockwise_matmul_top_k
@@ -137,13 +141,6 @@ class LMConfig:
         return self.d_inner + 2 * self.n_groups * self.ssm_state_size
 
 
-class Leaf(NamedTuple):
-    name: str
-    shape: Tuple[int, ...]
-    dtype: str          # "bfloat16" for matrices, "float32" for the rest
-    init: str           # normal | ones | zeros | a_log | dt_bias | bias
-
-
 def layer_leaf_specs(cfg: LMConfig, kind: str) -> List[Leaf]:
     """One layer's leaves, names without the `layers.<nn>.` prefix."""
     h = cfg.hidden_size
@@ -185,10 +182,6 @@ def layer_leaf_specs(cfg: LMConfig, kind: str) -> List[Leaf]:
     return out
 
 
-def layer_prefix(index: int) -> str:
-    return f"layers.{index:02d}."
-
-
 def leaf_specs(cfg: LMConfig) -> List[Leaf]:
     """Every leaf of the model, in forward order."""
     h = cfg.hidden_size
@@ -201,65 +194,8 @@ def leaf_specs(cfg: LMConfig) -> List[Leaf]:
     return out
 
 
-def count_leaves(specs: List[Leaf]) -> int:
-    n = 0
-    for leaf in specs:
-        size = 1
-        for s in leaf.shape:
-            size *= s
-        n += size
-    return n
-
-
 def num_params(cfg: LMConfig) -> int:
     return count_leaves(leaf_specs(cfg))
-
-
-def init_leaf(cfg: LMConfig, leaf: Leaf, key) -> jax.Array:
-    """The program's own initializer of one leaf: normal(0, 0.02) for
-    projections and embeddings, `A` in [1, 16], `dt` log-uniform in
-    [time_step_min, time_step_max] (floor time_step_floor) through the
-    inverse softplus, ones for norms and `D`, a small non-zero
-    correction bias."""
-    dtype = jnp.dtype(leaf.dtype)
-    if leaf.init == "normal":
-        return (0.02 * jax.random.normal(key, leaf.shape, jnp.float32)
-                ).astype(dtype)
-    if leaf.init == "conv":
-        bound = 1.0 / (cfg.conv_kernel ** 0.5)
-        return jax.random.uniform(key, leaf.shape, jnp.float32,
-                                  -bound, bound)
-    if leaf.init == "ones":
-        return jnp.ones(leaf.shape, dtype)
-    if leaf.init == "zeros":
-        return jnp.zeros(leaf.shape, dtype)
-    if leaf.init == "a_log":
-        return jnp.log(jax.random.uniform(key, leaf.shape, jnp.float32,
-                                          1.0, 16.0))
-    if leaf.init == "dt_bias":
-        lo, hi = jnp.log(cfg.time_step_min), jnp.log(cfg.time_step_max)
-        dt = jnp.exp(jax.random.uniform(key, leaf.shape, jnp.float32,
-                                        lo, hi))
-        dt = jnp.maximum(dt, cfg.time_step_floor)
-        return dt + jnp.log(-jnp.expm1(-dt))        # inverse softplus
-    if leaf.init == "bias":
-        return 0.01 * jax.random.normal(key, leaf.shape, jnp.float32)
-    raise ValueError(f"unknown initializer {leaf.init!r}")
-
-
-def init_leaves(cfg, specs: List[Leaf], seed: int) -> Dict[str, jax.Array]:
-    """Leaf by leaf on the device, so that nothing larger than the
-    largest leaf exists beside the parameters."""
-    root = jax.random.PRNGKey(seed)
-    make = jax.jit(init_leaf, static_argnums=(0, 1))
-    return {leaf.name: make(cfg, leaf, jax.random.fold_in(root, i))
-            for i, leaf in enumerate(specs)}
-
-
-def abstract_leaves(specs: List[Leaf]) -> Dict[str, jax.ShapeDtypeStruct]:
-    return {leaf.name: jax.ShapeDtypeStruct(leaf.shape,
-                                            jnp.dtype(leaf.dtype))
-            for leaf in specs}
 
 
 def init_params(cfg: LMConfig, seed: int) -> Dict[str, jax.Array]:
@@ -271,18 +207,6 @@ def abstract_params(cfg: LMConfig) -> Dict[str, jax.ShapeDtypeStruct]:
 
 
 # ---------------------------------------------------------------- the layers
-
-def rms_norm(x: jax.Array, weight: jax.Array, eps: float) -> jax.Array:
-    """Float32 in and out; the caller casts."""
-    x = x.astype(jnp.float32)
-    return (x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-            * weight.astype(jnp.float32))
-
-
-def _matmul(x: jax.Array, w: jax.Array, out_dtype=jnp.bfloat16) -> jax.Array:
-    return jnp.dot(x.astype(w.dtype), w,
-                   preferred_element_type=jnp.float32).astype(out_dtype)
-
 
 def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
     """Depthwise, causal: y[t] = sum_j w[:, j] x[t - (K-1) + j] + b, as a
@@ -362,22 +286,6 @@ def expert_mixer(cfg: LMConfig, p: Dict[str, jax.Array], u: jax.Array,
     return out, stats, routed.experts.reshape(bsz, length, -1)
 
 
-class StepStats(NamedTuple):
-    """What the router did in one step, per expert layer."""
-    load: jax.Array             # (expert layers, held) int32
-    unserved_tokens: jax.Array  # (expert layers,) int32
-    real_tokens: jax.Array      # () int32
-    chosen_last: jax.Array      # (rows, expert layers, k) int32: each
-    #                             row's choice at its last real position
-
-
-def layer_params(params: Dict[str, jax.Array], index: int
-                 ) -> Dict[str, jax.Array]:
-    prefix = layer_prefix(index)
-    return {k[len(prefix):]: v for k, v in params.items()
-            if k.startswith(prefix)}
-
-
 def hidden_states(cfg: LMConfig, params: Dict[str, jax.Array],
                   ids: jax.Array, lengths: jax.Array):
     """ids (rows, length) int32 padded on the right, lengths (rows,) ->
@@ -416,13 +324,6 @@ def hidden_states(cfg: LMConfig, params: Dict[str, jax.Array],
         chosen_last=(jnp.stack(chosen, axis=1) if chosen
                      else jnp.zeros((rows, 0, k), jnp.int32)))
     return h_last, stats
-
-
-class ScoreOutputs(NamedTuple):
-    topk_values: jax.Array      # (rows, k) float32 logits
-    topk_indices: jax.Array     # (rows, k) int32, ids over the rows held
-    lse: jax.Array              # (rows,) float32 logsumexp over the slice
-    stats: StepStats
 
 
 def lm_score_step(cfg: LMConfig, top_k: int, block_rows: int,

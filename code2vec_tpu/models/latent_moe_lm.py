@@ -50,7 +50,7 @@ from typing import Dict, List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-from code2vec_tpu.models.hybrid_lm import (
+from code2vec_tpu.models.lm_common import (
     Leaf, ScoreOutputs, StepStats, _matmul, layer_params, layer_prefix,
     rms_norm,
 )

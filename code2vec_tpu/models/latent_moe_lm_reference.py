@@ -22,7 +22,7 @@ from typing import Dict, Tuple
 import jax
 import jax.numpy as jnp
 
-from code2vec_tpu.models.hybrid_lm import layer_params
+from code2vec_tpu.models.lm_common import layer_params
 from code2vec_tpu.models.latent_moe_lm import LMConfig
 from code2vec_tpu.ops import moe
 

@@ -1,13 +1,15 @@
 """A routed expert layer that is told which experts it holds.
 
-The router scores every token against ALL experts of the model (sigmoid
-scores, a correction bias that only steers the choice, the `k` largest
-chosen, weights normalised over the chosen and scaled), as the published
-layer does. This chip holds experts `[first, first + held)`: it computes,
-for every token, the sum over the chosen experts IT HOLDS and leaves out
-what the absent ones would add. With expert parallelism that partial sum
-is what each chip brings to the combine; on one chip the layer runs
-without its exchange, and nothing stands in for the absent chips.
+The router scores every token against ALL experts of the model, in one
+of the two published forms: sigmoid scores, a correction bias that only
+steers the choice, the `k` largest chosen, weights normalised over the
+chosen and scaled; or a softmax over all experts, its `k` largest,
+renormalised over the chosen. This chip holds experts `[first, first +
+held)`: it computes, for every token, the sum over the chosen experts IT
+HOLDS and leaves out what the absent ones would add. With expert
+parallelism that partial sum is what each chip brings to the combine; on
+one chip the layer runs without its exchange, and nothing stands in for
+the absent chips.
 
 Dispatch drops no token: every (token, chosen expert) pair whose expert
 is held becomes one row of a buffer sorted by expert; a grouped matmul
@@ -51,14 +53,19 @@ class ExpertStats(NamedTuple):
 @jax.named_scope("moe_route")
 def route(u: jax.Array,             # (T, hidden)
           router: jax.Array,        # (hidden, experts)
-          bias: jax.Array,          # (experts,) float32 correction bias
-          k: int, scaling: float) -> Routed:
-    """s = sigmoid(u W_r) in float32, the matmul too; the k largest of
-    s + bias; w_i = scaling * s_i / sum over the chosen."""
-    s = jax.nn.sigmoid(jnp.dot(
-        u.astype(jnp.float32), router.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(s + bias.astype(jnp.float32), k)
+          bias: Optional[jax.Array],    # (experts,) float32, or None
+          k: int, scaling: float = 1.0, softmax: bool = False) -> Routed:
+    """The router in float32, the matmul too. Sigmoid form: s =
+    sigmoid(u W_r); the k largest of s + bias (the correction bias
+    steers the choice only); w_i = scaling * s_i / sum over the chosen.
+    Softmax form: s = softmax(u W_r) over ALL experts, the k largest of
+    it (ties to the lower expert), the same renormalisation."""
+    logits = jnp.dot(u.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    s = (jax.nn.softmax(logits, axis=-1) if softmax
+         else jax.nn.sigmoid(logits))
+    steer = s if bias is None else s + bias.astype(jnp.float32)
+    _, chosen = jax.lax.top_k(steer, k)
     picked = jnp.take_along_axis(s, chosen, axis=-1)
     weights = scaling * picked / jnp.sum(picked, axis=-1, keepdims=True)
     return Routed(chosen.astype(jnp.int32), weights)

@@ -583,8 +583,8 @@ class PredictionServer:
                                           tenant)
         score_request = None
         if endpoint == "score":
-            knobs = {"return_routing": bool(
-                (params or {}).get("return_routing"))}
+            knobs = {name: bool((params or {}).get(name))
+                     for name in ("return_routing", "return_selected")}
             # before the cache probe: an answer cached while its context
             # was held must not outlive the context
             score_request = self._score_request(model, params)
@@ -778,6 +778,13 @@ class PredictionServer:
                 # the router's choice at the last position, by expert
                 # layer: what a comparison with a reference reads
                 out["routing_last"] = r.routing_last.tolist()
+            if ((knobs or {}).get("return_selected")
+                    and r.selected_last is not None):
+                # the key positions (of context ++ question) the last
+                # position attended, by layer, where attention selects
+                out["selected_last"] = [
+                    at.tolist() for at in
+                    self._model_ref[0].selected_positions(r)]
             return out
         if endpoint == "embed":
             # embedding_fingerprint is the embedding-SPACE identity —
