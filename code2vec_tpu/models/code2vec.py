@@ -16,9 +16,11 @@ MXU) with float32 accumulation.
 
 `jax.named_scope`s name the step's parts for the profiler's op view:
 `embed_gather`, `transform`, `attention` (ops/attention.py), `logits_ce`
-(with the loss in training/step.py); a backward op carries its forward
-scope as `transpose(jvp(<scope>))`. Scopes are metadata: the compiled
-program does not change (tests/test_model.py).
+(the classifier here for eval and predict; in the GSPMD train steps the
+classifier with its loss and BOTH directions, ops/head_ce.py); a
+backward op carries its forward scope as `transpose(jvp(<scope>))`.
+Scopes are metadata: the compiled program does not change
+(tests/test_model.py).
 """
 
 from __future__ import annotations
@@ -221,18 +223,16 @@ class Code2VecModule(nn.Module):
                                logits, -jnp.inf)
         return logits
 
-    def apply_from_rows(self, source_rows, path_rows, target_rows,
-                        context_valid_mask, deterministic: bool = True):
-        """Full forward from pre-gathered embedding rows (sparse-update
-        train path): (logits, code_vectors f32, attention)."""
+    def encode_from_rows(self, source_rows, path_rows, target_rows,
+                         context_valid_mask, deterministic: bool = True):
+        """`encode` from pre-gathered embedding rows (sparse-update
+        train path): (code_vectors f32, attention)."""
         transformed = self.transform_gathered(
             source_rows, path_rows, target_rows, deterministic=deterministic)
         code_vectors, attention = masked_single_query_attention(
             transformed, self.attention[:, 0], context_valid_mask,
             axis_name=self.context_axis_name)
-        code_vectors = code_vectors.astype(jnp.float32)
-        logits = self.logits_from_code_vectors(code_vectors)
-        return logits, code_vectors, attention
+        return code_vectors.astype(jnp.float32), attention
 
     def __call__(self, source_token_indices, path_indices, target_token_indices,
                  context_valid_mask, deterministic: bool = True):

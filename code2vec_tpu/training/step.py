@@ -39,7 +39,11 @@ opt-state's type). The cell of `BENCHMARK.json`, or the queued cell
    all-gathered over data/ctx. No cell queued: it waits for 2 and 3.
 
 Loss definition matches tensorflow_model.py:225-229: sum of sparse softmax
-CE over the batch divided by batch size.
+CE over the batch divided by batch size. Steps 1 and 2 hold whole rows
+of logits on a chip and take it from the head's own VJP
+(ops/head_ce.py `head_cross_entropy`: three passes over the float32
+logits where autodiff of the einsum and optax's cross-entropy makes
+four); steps 3 and 4 hold vocabulary shards and keep `tp_softmax_ce`.
 """
 
 from __future__ import annotations
@@ -58,6 +62,7 @@ from code2vec_tpu.models.code2vec import Code2VecModule
 from code2vec_tpu.ops.attention import masked_single_query_attention
 from code2vec_tpu.ops.embed import context_depth, embed_live_rows
 from code2vec_tpu.ops.encode_live import encode_live_blocks
+from code2vec_tpu.ops.head_ce import head_cross_entropy
 from code2vec_tpu.ops import sharded as tp_ops
 from code2vec_tpu.parallel import mesh as mesh_lib
 from code2vec_tpu.parallel.mesh import AXIS_CTX, AXIS_DATA, AXIS_MODEL
@@ -338,13 +343,16 @@ class TrainStepBuilder:
             donate_argnums=0,
             compiler_options=train_step_compiler_options(self.mesh) or None)
 
-    @jax.named_scope("logits_ce")
-    def _loss_from_logits(self, logits, labels, valid):
-        ce = optax.softmax_cross_entropy_with_integer_labels(logits, labels)
-        ce = ce * valid.astype(jnp.float32)
-        # reference: sum CE / batch_size (tensorflow_model.py:226-229);
-        # train batches are always full so this equals the mean.
-        return jnp.sum(ce) / labels.shape[0]
+    def _head_loss(self, params, code_vectors, labels, valid):
+        """The GSPMD train steps' loss from whole rows of logits
+        (ops/head_ce.py). reference: sum CE / batch_size
+        (tensorflow_model.py:226-229); train batches are always full so
+        this equals the mean."""
+        return head_cross_entropy(
+            code_vectors, params["target_embedding"], labels,
+            valid.astype(jnp.float32) / labels.shape[0],
+            self.module.dims.real_target_vocab_size,
+            self.module.compute_dtype, self.mesh)
 
     def _encode_live_rows(self, params, src, pth, tgt, mask, depth, key,
                           axis_name: Optional[str] = None):
@@ -393,18 +401,16 @@ class TrainStepBuilder:
                     src, pth, tgt, mask, labels, valid)
 
             def loss_fn(params):
-                if not live_rows:
-                    logits, _, _ = module.apply(
+                if live_rows:
+                    code_vectors = encode(
+                        {k: params[k] for k in _ENCODER_PARAMS},
+                        src, pth, tgt, mask, depth, dropout_rng)
+                else:
+                    code_vectors, _ = module.apply(
                         {"params": params}, src, pth, tgt, mask,
-                        deterministic=False, rngs={"dropout": dropout_rng})
-                    return self._loss_from_logits(logits, labels, valid)
-                code_vectors = encode(
-                    {k: params[k] for k in _ENCODER_PARAMS},
-                    src, pth, tgt, mask, depth, dropout_rng)
-                logits = module.apply(
-                    {"params": params}, code_vectors,
-                    method=Code2VecModule.logits_from_code_vectors)
-                return self._loss_from_logits(logits, labels, valid)
+                        deterministic=False, rngs={"dropout": dropout_rng},
+                        method=Code2VecModule.encode)
+                return self._head_loss(params, code_vectors, labels, valid)
 
             loss, grads = jax.value_and_grad(loss_fn)(state.params)
             params, opt_state = scoped_adam_update(
@@ -435,11 +441,12 @@ class TrainStepBuilder:
             def loss_fn(dense_params, src_rows, path_rows, tgt_rows):
                 full = dict(dense_params, token_embedding=tok_table,
                             path_embedding=path_table)
-                logits, _, _ = module.apply(
+                code_vectors, _ = module.apply(
                     {"params": full}, src_rows, path_rows, tgt_rows, mask,
                     deterministic=False, rngs={"dropout": dropout_rng},
-                    method=Code2VecModule.apply_from_rows)
-                return self._loss_from_logits(logits, labels, valid)
+                    method=Code2VecModule.encode_from_rows)
+                return self._head_loss(dense_params, code_vectors, labels,
+                                       valid)
 
             loss, grads = jax.value_and_grad(loss_fn, argnums=(0, 1, 2, 3))(
                 dense_params, src_rows, path_rows, tgt_rows)

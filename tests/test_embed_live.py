@@ -30,6 +30,8 @@ from code2vec_tpu.training.loop import Trainer
 from code2vec_tpu.training.state import create_train_state, make_optimizer
 from code2vec_tpu.training.step import TrainStepBuilder
 
+from test_head_ce import _optax_form
+
 ROWS, CONTEXTS = 4, 3           # toy block: 4 rows x 3 contexts
 VOCAB, WIDTH = 40, 8
 
@@ -393,14 +395,62 @@ def test_three_steps_equal_the_take_steps_on_shuffled_rows(monkeypatch):
     assert int(state.step) == 3
 
 
+def _optax_head(*args):
+    """The head as autodiff of the einsum's logits under optax's
+    cross-entropy (what `head_cross_entropy` replaced), whatever the
+    mesh."""
+    return _optax_form(*args[:6])
+
+
+@pytest.mark.parametrize("step", ["live_rows", "take", "sparse"])
+def test_three_steps_equal_the_steps_on_the_optax_head(monkeypatch, step):
+    """The head's own VJP (ops/head_ce.py) against autodiff of the optax
+    form, float32, dropout keep 1.0: loss, Adam's first moment and the
+    parameters of three updates, in each step that holds whole rows of
+    logits."""
+    overrides = ({"use_sparse_embedding_update": True}
+                 if step == "sparse" else {})
+    if step == "take":
+        monkeypatch.setattr(step_mod, "gathers_live_rows", lambda c, m: False)
+    _, builder, state = _toy(**overrides)
+    own_step = builder.make_train_step(state)
+    monkeypatch.setattr(step_mod, "head_cross_entropy", _optax_head)
+    _, optax_builder, optax_state = _toy(**overrides)
+    optax_step = optax_builder.make_train_step(optax_state)
+    for n in range(3):
+        batch, rng = _toy_batch(seed=n), jax.random.PRNGKey(n)
+        state, loss = own_step(state, *batch, rng)
+        optax_state, optax_loss = optax_step(optax_state, *batch, rng)
+        np.testing.assert_allclose(float(loss), float(optax_loss), rtol=1e-6)
+        pairs = [(state.params, optax_state.params)]
+        if step != "sparse":
+            pairs.append((_first_moment(state), _first_moment(optax_state)))
+        for got, want in pairs:
+            for key in want:
+                np.testing.assert_allclose(
+                    np.asarray(got[key]), np.asarray(want[key]),
+                    rtol=1e-4, atol=2e-5, err_msg=f"step {n + 1} {key}")
+    assert int(state.step) == 3
+
+
 def _refuse(*args, **kwargs):
-    raise AssertionError("the live-rows ops are the dense train step's alone")
+    raise AssertionError("an op of the GSPMD train steps alone")
 
 
-@pytest.mark.parametrize("which", ["eval", "predict_k1", "sparse_train",
-                                   "manual_train"])
+# which steps never reach which ops: the live-rows lookup and chain are
+# the dense GSPMD train step's, the head's own VJP the two GSPMD train
+# steps' (the sparse one holds whole rows of logits too)
+_LIVE_ROWS_OPS = ("embed_live_rows", "encode_live_blocks")
+_HEAD_OP = ("head_cross_entropy",)
+
+
+@pytest.mark.parametrize("which,ops", [
+    ("eval", _LIVE_ROWS_OPS), ("predict_k1", _LIVE_ROWS_OPS),
+    ("sparse_train", _LIVE_ROWS_OPS), ("manual_train", _LIVE_ROWS_OPS),
+    ("eval", _HEAD_OP), ("predict_k1", _HEAD_OP), ("manual_train", _HEAD_OP),
+])
 def test_the_other_steps_lower_to_what_they_lower_to_without_the_op(
-        monkeypatch, which):
+        monkeypatch, which, ops):
     """The eval and predict steps (and the sparse and the manual train
     steps) never reach the ops: with them taken away they lower to the
     same program."""
@@ -432,8 +482,8 @@ def test_the_other_steps_lower_to_what_they_lower_to_without_the_op(
         return builder.make_eval_step(state, k=k).lower(
             state.params, *batch).as_text()
     with_op = lower()
-    monkeypatch.setattr(step_mod, "embed_live_rows", _refuse)
-    monkeypatch.setattr(step_mod, "encode_live_blocks", _refuse)
+    for op in ops:
+        monkeypatch.setattr(step_mod, op, _refuse)
     assert lower() == with_op
 
 

@@ -127,14 +127,17 @@ def test_padded_to_rounds_up():
 _SCOPES = {
     # the dense step runs the chain over the live blocks
     # (ops/encode_live.py): its loops' bodies carry the chain's names,
-    # forward and transposed, as a scope sum of a trace reads them
-    False: ("embed_gather", "/transform/", "/attention/", "logits_ce",
+    # forward and transposed, as a scope sum of a trace reads them;
+    # the head's own VJP names its forward and its backward
+    # `logits_ce` alike (ops/head_ce.py)
+    False: ("embed_gather", "/transform/", "/attention/",
             "/transpose(jvp(transform))/", "/transpose(jvp(attention))/",
-            "transpose(jvp(Code2VecModule.logits_from_code_vectors))",
+            "/jvp(head_ce)/logits_ce/", "/transpose(jvp(head_ce))/logits_ce/",
             "adam_token", "adam_path", "adam_target", "adam_dense"),
     # the touched-rows step gathers outside the differentiated function
     # and updates the two tables row-wise under the same two names
-    True: ("embed_gather", "transform", "attention", "logits_ce",
+    True: ("embed_gather", "transform", "attention",
+           "/jvp(head_ce)/logits_ce/", "/transpose(jvp(head_ce))/logits_ce/",
            "adam_token", "adam_path", "adam_dense"),
 }
 

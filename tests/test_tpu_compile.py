@@ -49,6 +49,29 @@ def test_grouped_expert_matmuls_compile_at_published_widths(
     assert text.count("tpu_custom_call") >= 2 and "ragged-dot" not in text
 
 
+@pytest.mark.parametrize("rows,target_rows", [
+    (1024, 261245), (1024, 522490),
+    (2048, 261245),     # `--batch_size 2048`: two blocks of rows
+])
+def test_the_heads_pass_b_kernel_compiles_at_published_widths(
+        one_chip, rows, target_rows):
+    """Pass B of the train head (ops/head_ce.py) at the widths java14m
+    and java14m-ctx500 run: 1,024 rows of float32 logits over a target
+    table whose rows no tile divides, 384 wide. Lowered for the TPU the
+    op picks the kernel, and the chip's compiler takes its blocks."""
+    from code2vec_tpu.ops import head_ce
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda logits, row_max, table: head_ce._exp_sums_on_a_chip(
+            logits, row_max, table, jnp.bfloat16)).lower(
+                shape((rows, target_rows), jnp.float32),
+                shape((rows,), jnp.float32),
+                shape((target_rows, 384), jnp.float32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_tiles_divide_the_published_widths():
     from code2vec_tpu.ops.moe import _tile
     assert (_tile(1024), _tile(2688), _tile(4096), _tile(100)) == (
