@@ -1,7 +1,8 @@
 """The reader kinds of per-layer metrics. A metric is a file of its own,
 `layer_metrics/<metric>.json`, that names one of these kinds under
 `reader` with its arguments under `args`; adding a metric of an existing
-kind adds a file and an entry in BENCHMARK.json and edits nothing. A
+kind adds a file and APPENDS an entry to BENCHMARK.json's `per_layer`
+(entries are found by name, never by place) and edits nothing. A
 reader that finds nothing to read returns None, and the harness leaves
 the metric out of the line.
 """
@@ -67,13 +68,16 @@ def trace_program_time(m: Measured, program: str, scale: float = 1.0):
     return None if got is None else got["seconds_per_run"] * scale
 
 
-def trace_op_time(m: Measured, ops: str, program: str,
+def trace_op_time(m: Measured, program: str, ops: str,
+                  opcodes: Optional[str] = None,
                   exposed_only: bool = False, scale: float = 1.0):
-    """Per run of `program`: device time inside operations named `ops`;
-    with `exposed_only`, only while nothing else runs on that device."""
+    """Per run of `program`: device time inside the operations whose
+    NAME matches `ops` or whose OPCODE matches `opcodes`, an
+    asynchronous one from its start to its done; with `exposed_only`,
+    only while nothing else runs on that device."""
     if m.trace is None:
         return None
-    got = trace_reduce.op_time(m.trace, ops, program, exposed_only)
+    got = trace_reduce.op_time(m.trace, ops, program, exposed_only, opcodes)
     return None if got is None else got["seconds_per_run"] * scale
 
 

@@ -34,7 +34,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchmarks import common, loadgen, readers, trace_reduce
+from benchmarks import common, loadgen, readers
 from benchmarks.runners import serve_lm
 from benchmarks.runners.serve import TRACE_WINDOW_S, summarize
 
@@ -42,14 +42,6 @@ PROGRAM = r"^jit_ctx_score_step\("
 SCOPES = ("mla_attend", "moe_experts")
 # the grouped-matmul custom calls carry no scope of their own
 KERNELS = {"moe_experts": r"^(ragged-dot|gmm)"}
-# The per-layer metrics this runner kind brings. Their files are under
-# layer_metrics/; BENCHMARK.json cannot list them yet (PERF.md section 7:
-# an entry may only be appended, and test_benchmark_cut_idle.py holds the
-# last place for another). A traced run prints as a note each one that
-# BENCHMARK.json does not list for the cell, read as a listed one is.
-UNLISTED = ("mla_attend_roofline.serve", "moe_gated_experts_roofline.serve",
-            "ctx_score_step_device_ms.serve", "latent_cache_fill_pct.serve",
-            "context_register_ms.setup")
 
 
 # ------------------------------------------------------------- the traffic
@@ -267,21 +259,6 @@ def roofline_facts(cell: common.Cell, device_kind: str, trace_dir: str,
     return out
 
 
-def read_unlisted(m: readers.Measured) -> Dict[str, float]:
-    """Those of `UNLISTED` that have something to read, but for the ones
-    `readers.read_all` already reads for the cell."""
-    listed = {metric["name"] for metric in m.cell.per_layer()}
-    out = {}
-    for name in UNLISTED:
-        if name in listed:
-            continue
-        spec = m.cell.layer_metric_spec(name)
-        value = readers.KINDS[spec["reader"]](m, **spec.get("args", {}))
-        if value is not None:
-            out[name] = float(value)
-    return out
-
-
 # -------------------------------------------------------------- the checks
 
 def pick_checked(seed: int, arrivals: List[Dict], pool: Dict,
@@ -445,12 +422,6 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
             trace_dir, late_ms=got["late_ms"], facts=facts)
         dev.update(traced["device"])
         values, breakdown = traced["values"], traced["breakdown"]
-        unlisted = read_unlisted(readers.Measured(
-            cell, device["kind"], drove["registry"], drove["window_s"],
-            trace_reduce.load_xplane(trace_dir), facts=facts))
-        for name, value in unlisted.items():
-            unit = cell.layer_metric_spec(name)["unit"]
-            print(f"note {name}: {value!r} {unit}", flush=True)
         for key in ("mla_attend", "moe_gated_experts"):
             if key + "_roofline" in facts:
                 common.say(f"{key}: {facts[key + '_ms_per_layer']:.3f} ms a "

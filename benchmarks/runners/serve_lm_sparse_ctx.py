@@ -8,10 +8,9 @@ From `runners/serve_lm_ctx.py`, unchanged: the traffic (`make_pool`,
 `write_bodies`), the drive, the picking of checked requests and the
 facts of a window. Written here: what names the model. That file names
 `reference_glm` inside `ServingCtx.__init__`, `seed_weights`,
-`check_answers` and `roofline_facts`, and `read_unlisted` reads its own
-module's `UNLISTED`, so those five (and `run`, which calls them) are
-this model's copies (PERF.md section 7 lists them for the `benchmark`
-issue that gives the runner kind a model hook).
+`check_answers` and `roofline_facts`, so those four (and `run`, which
+calls them) are this model's copies (PERF.md section 7 lists them for
+the `benchmark` issue that gives the runner kind a model hook).
 
 `correct`: once the window has closed and the program's arrays are
 freed, `checked_requests` of the requests it finished, over at least
@@ -37,7 +36,7 @@ from typing import Dict, List
 
 import numpy as np
 
-from benchmarks import common, loadgen, readers, trace_reduce
+from benchmarks import common, loadgen, readers
 from benchmarks.runners import serve_lm, serve_lm_ctx
 from benchmarks.runners.serve import TRACE_WINDOW_S, summarize
 from benchmarks.runners.serve_lm_ctx import (
@@ -47,12 +46,6 @@ from benchmarks.runners.serve_lm_ctx import (
 PROGRAM = serve_lm_ctx.PROGRAM
 SCOPES = ("index_score", "index_select", "sparse_attend", "moe_experts")
 KERNELS = serve_lm_ctx.KERNELS
-# As `serve_lm_ctx.UNLISTED`: files under layer_metrics/ that
-# BENCHMARK.json cannot list yet; a traced run prints them as notes.
-UNLISTED = ("index_select_roofline.serve", "sparse_attend_roofline.serve",
-            "keys_selected_pct.serve", "moe_gated_experts_roofline.serve",
-            "ctx_score_step_device_ms.serve", "latent_cache_fill_pct.serve",
-            "context_register_ms.setup")
 
 
 # ------------------------------------------------------------- the program
@@ -353,12 +346,6 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
             trace_dir, late_ms=got["late_ms"], facts=facts)
         dev.update(traced["device"])
         values, breakdown = traced["values"], traced["breakdown"]
-        measured = readers.Measured(
-            cell, device["kind"], drove["registry"], drove["window_s"],
-            trace_reduce.load_xplane(trace_dir), facts=facts)
-        for name, value in unlisted(measured).items():
-            unit = cell.layer_metric_spec(name)["unit"]
-            print(f"note {name}: {value!r} {unit}", flush=True)
         for key in ("index_select", "sparse_attend", "moe_gated_experts"):
             if key + "_roofline" in facts:
                 common.say(f"{key}: {facts[key + '_ms_per_layer']:.3f} ms a "
@@ -375,18 +362,3 @@ def run(cell: common.Cell, seed: int, seconds: float, trace: bool,
                 checks)
     return result
 
-
-def unlisted(m: readers.Measured) -> Dict[str, float]:
-    """Those of `UNLISTED` that have something to read, but for the ones
-    `readers.read_all` already reads for the cell
-    (`serve_lm_ctx.read_unlisted` reads its own module's list)."""
-    listed = {metric["name"] for metric in m.cell.per_layer()}
-    out = {}
-    for name in UNLISTED:
-        if name in listed:
-            continue
-        spec = m.cell.layer_metric_spec(name)
-        value = readers.KINDS[spec["reader"]](m, **spec.get("args", {}))
-        if value is not None:
-            out[name] = float(value)
-    return out

@@ -3,26 +3,20 @@ path, and a temporary copy of the benchmark to which toy configurations,
 traffic mixes, cells and a per-layer metric are ADDED as new files and
 new BENCHMARK.json entries, no existing file edited.
 
-Why three files. The tier-1 command runs `-n 6 --dist loadfile`: whole
-files are handed to six workers, files with most tests first, and a
-worker gets its next file when it has two tests left. One test of the
-standing tree, tests/test_edge.py::
-test_shared_fleet_view_derives_candidates_and_view, fails whenever
-test_fleet.py or test_pipeline.py ran earlier in its process (they leave
-`fleet_swap_total{outcome="committed"}` in the process-wide metrics
-registry, which that test then reads as 2 + theirs). In the standing
-tree the worker that ran both is free again within a second of the
-moment test_edge.py is handed out, and which worker takes it is a coin
-toss: six new files of other sizes tipped it the wrong way (PR 22 and
-the first PR 23 were refused over it). So the tests of the benchmark
-are cut into exactly three files of 19 or 20 tests, long tests first:
-they sort between test_extractor.py (21 tests) and test_cs_extractor.py
-(18), the three workers that are free in the run's first twelve seconds
-(the only ones that can have run test_pipeline.py or test_fleet.py by
-then) take one each and stay busy for three quarters of a minute, and
-test_edge.py (17 tests) goes to a worker that has run neither. Keep the
-counts at 19 or 20 and add no fourth file here until test_edge.py or
-the registry is repaired (PERF.md, Open questions)."""
+How a PR adds a per-layer metric: a file `layer_metrics/<metric>.json`
+and an entry APPENDED to `per_layer` (`make_toy_root` does exactly that
+with `device_put_ms.train`). The harness and every test here find an
+entry by its NAME; none asserts a place, so a later entry breaks
+nothing (until PR 36 three tests pinned the last place to PR 27's
+metric and no other kind of PR could list one).
+
+The files are cut by subject (benchmarks/README.md, "The tests"). They
+were once held to three files of 19 or 20 tests, because the tier-1
+command hands whole files to six workers and tests/test_edge.py::
+test_shared_fleet_view_derives_candidates_and_view failed on a worker
+that had run test_fleet.py or test_pipeline.py before it; since PR 24
+that test takes the difference of the process-wide counter, and the
+sizes of these files constrain nothing."""
 
 from __future__ import annotations
 

@@ -32,8 +32,8 @@ class Window:
 def test_the_metric_is_listed_for_exactly_the_two_serve_cells():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
-    entry = bench["per_layer"][-1]                  # appended, last
-    assert entry["name"] == METRIC
+    # found by its name: entries are appended, and none holds a place
+    entry = next(m for m in bench["per_layer"] if m["name"] == METRIC)
     assert tuple(entry["workloads"]) == SERVE_CELLS
     assert entry["layer"] == "serving host"
     assert entry["moves"] == "request_p50_ms"

@@ -20,6 +20,10 @@ from benchmarks import reference_glm, reference_lm, roofline_glm  # noqa: E402
 from benchmarks.runners import serve_lm_ctx  # noqa: E402
 
 CELL = "glm47-flash-pp8.serve_score_ctx_open"
+# the per-layer metrics the runner kind `serve_lm_ctx` brought (PR 31)
+BROUGHT = ("mla_attend_roofline.serve", "moe_gated_experts_roofline.serve",
+           "ctx_score_step_device_ms.serve", "latent_cache_fill_pct.serve",
+           "context_register_ms.setup")
 TINY = dict(
     model_type="glm4_moe_lite", hidden_size=64, num_hidden_layers=5,
     layers=3, first_k_dense_replace=1, vocab_size=512, vocab_rows=128,
@@ -117,12 +121,15 @@ def test_ctx_rehearsal_counters_feed_the_new_metrics(rehearsal):
     total, count = serve_lm_ctx.registry_total("context_register_seconds")
     assert count >= 4 and total > 0
     from benchmarks import readers
-    got = serve_lm_ctx.read_unlisted(readers.Measured(
+    got = readers.read_all(readers.Measured(
         cell, "TPU v5 lite", window, window_s=2.0,
         facts={"context_register_ms": 1e3 * total / count}))
     assert 0.0 < got["latent_cache_fill_pct.serve"] <= 100.0
     assert got["context_register_ms.setup"] > 0
-    assert "mla_attend_roofline.serve" not in got       # no trace: left out
+    # no trace: the device time and the rooflines are left out
+    assert not {"mla_attend_roofline.serve", "ctx_score_step_device_ms.serve",
+                "moe_gated_experts_roofline.serve"} & set(got)
+    assert set(got) <= {m["name"] for m in cell.per_layer()}
 
 
 def _served(cell, seed, n=3):
@@ -211,18 +218,19 @@ def test_the_cell_its_files_and_limits_load(cell):
     assert [m["name"] for m in cell.end_to_end()] == ["request_p50_ms",
                                                       "setup_s"]
     mine = {m["name"] for m in cell.per_layer()}
-    # the runner kind's own metrics have their files and no entry yet: one
-    # can only be appended, and the last place is another metric's
-    new = set(serve_lm_ctx.UNLISTED)
-    assert len(new) == 5 and not new & mine
-    assert cell.bench["per_layer"][-1]["name"] == "batches_cut_idle_pct.serve"
+    # every metric the runner kind brought is listed for its cell (PR 36
+    # appended the entries; each is found by its name)
+    new = set(BROUGHT)
+    assert len(new) == 5 and new <= mine
+    listed = {m["name"]: m for m in cell.bench["per_layer"]}
+    assert CELL not in listed["batches_cut_idle_pct.serve"]["workloads"]
     assert "batches_cut_idle_pct.serve" not in mine
     assert {"batch_wait_mean_ms.serve", "device_phase_mean_ms.serve",
             "compile_s.setup", "restore_s.setup",
             "expert_load_max_over_mean.serve",
             "batch_tokens_fill_pct.serve"} <= mine
     from benchmarks import readers
-    for name in mine | new:
+    for name in mine:
         spec = cell.layer_metric_spec(name)
         assert spec["reader"] in readers.KINDS and spec["name"] == name
     entry = next(c for c in cell.bench["configs"]

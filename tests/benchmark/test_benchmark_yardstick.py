@@ -1,16 +1,11 @@
 """The yardstick without a chip: the open-loop generator over a window
 of full length at the serve cell's own rate, BENCHMARK.json and every
-data file under benchmarks/ against the contract's limits (and that a
-configuration, a mix, a cell and a per-layer metric of an existing
-reader kind are added by files alone), the trace reduction on a
-synthetic event list and on a small trace recorded on the chip (TPU v5
-lite, java14m.train_hostfed, 100 ms), and the operations-and-bytes floor
-against hand counts with the peaks.
-
-This file is also ONE UNIT of the tier-1 run (`-n 6 --dist loadfile`
-hands out whole files, most tests first): it holds 19 or 20 tests and
-its long tests come first, so that the worker that takes it stays busy
-for three quarters of a minute. See bench_testlib.py, "Why three files".
+data file under benchmarks/ against the contract's limits (every
+per-layer entry found by its name, a case each; a configuration, a mix,
+a cell and a per-layer metric of an existing reader kind added by files
+alone), and the operations-and-bytes floor against hand counts with the
+peaks. The trace reduction has a file of its own,
+test_benchmark_trace.py.
 """
 
 import http.server
@@ -25,14 +20,12 @@ import pytest
 from bench_testlib import ROOT, make_toy_root
 
 from benchmarks import common, loadgen, readers, roofline
-from benchmarks import trace_reduce as tr
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
 WIDTH_WORDS = ("_dim", "_rank", "hidden", "intermediate", "latent", "state",
                "head", "expansion", "experts_per")
-MS = 1e6    # ns
 
 
 def bench():
@@ -195,16 +188,91 @@ def test_every_cell_reports_setup_another_metric_and_a_layer_metric():
         assert w["chips"] in cell.config["chips"]
 
 
-def test_every_per_layer_metric_has_a_reader_file_that_agrees():
+@pytest.mark.parametrize("name", [m["name"] for m in bench()["per_layer"]])
+def test_a_per_layer_entry_has_a_reader_file_that_agrees(name):
+    """Every entry, found by its NAME: its file exists, names a reader
+    kind there is, and states the entry's own fields; its cells are
+    cells. An entry holds no place: PR 36's nine are cases like the
+    rest, and a tenth appended behind them breaks no test."""
     b = bench()
+    entries = [m for m in b["per_layer"] if m["name"] == name]
+    assert len(entries) == 1
+    m = entries[0]
+    assert os.path.exists(os.path.join(
+        ROOT, "benchmarks", "layer_metrics", name + ".json"))
+    spec = common.Cell(ROOT, b["workloads"][0]["name"]
+                       ).layer_metric_spec(name)
+    assert spec["reader"] in readers.KINDS, name
+    for key in ("name", "layer", "unit", "moves", "source"):
+        assert spec[key] == m[key], (name, key)
+    assert m["better"] in ("lower", "higher") and spec["what"]
+    assert set(m["workloads"]) <= {w["name"] for w in b["workloads"]}
+    if "_roofline" in name:
+        assert m["unit"] == "%" and m["better"] == "higher"
+
+
+APPENDED_BY_PR_36 = {
+    "ctx_score_step_device_ms.serve": ("lower", "GK"),
+    "mla_attend_roofline.serve": ("higher", "G"),
+    "moe_gated_experts_roofline.serve": ("higher", "GK"),
+    "latent_cache_fill_pct.serve": ("higher", "GK"),
+    "context_register_ms.setup": ("lower", "GK"),
+    "index_select_roofline.serve": ("higher", "K"),
+    "sparse_attend_roofline.serve": ("higher", "K"),
+    "keys_selected_pct.serve": ("lower", "K"),
+    "dense_blocks_run_pct.train": ("lower", "T")}
+
+
+def test_the_nine_entries_of_pr_36_are_listed_for_their_cells():
+    """Each found by its name. Of places only what the driver's check
+    holds an appending PR to: the nine came after PR 27's entry, in
+    ISSUE 36's order; where in the list that is, no test says."""
+    b = bench()
+    names = [m["name"] for m in b["per_layer"]]
+    assert len(names) == len(set(names))
+    places = [names.index(n) for n in
+              ["batches_cut_idle_pct.serve", *APPENDED_BY_PR_36]]
+    assert places == sorted(places)
+    cells = {"G": ["glm47-flash-pp8.serve_score_ctx_open"],
+             "K": ["keye-vl2-pp8.serve_score_longctx_open"]}
+    cells["GK"] = cells["G"] + cells["K"]
+    cells["T"] = [w["name"] for w in b["workloads"] if ".train_" in w["name"]]
+    assert len(cells["T"]) == 4
     for m in b["per_layer"]:
-        spec = common.Cell(ROOT, b["workloads"][0]["name"]
-                           ).layer_metric_spec(m["name"])
-        assert spec["reader"] in readers.KINDS, m["name"]
-        for key in ("name", "layer", "unit", "moves", "source"):
-            assert spec[key] == m[key], (m["name"], key)
-        if m["name"].endswith("_roofline"):
-            assert m["unit"] == "%" and m["better"] == "higher"
+        if m["name"] in APPENDED_BY_PR_36:
+            better, which = APPENDED_BY_PR_36[m["name"]]
+            assert m["better"] == better and m["workloads"] == cells[which]
+    # the gauge stays an operator's (it would trace the step twice)
+    assert "train_step_async_collectives" not in json.dumps(b)
+    moves = {m["name"]: m["moves"] for m in b["per_layer"]}
+    assert moves["context_register_ms.setup"] == "setup_s"
+    assert moves["dense_blocks_run_pct.train"] == "examples_per_s"
+
+
+def test_a_tenth_entry_appended_in_a_copy_is_read_like_the_rest(tmp_path):
+    """What a later PR does: a file and an entry APPENDED. The copy's
+    cells read it, and every older entry reads as before."""
+    root = make_toy_root(str(tmp_path / "copy"))
+    cell = common.Cell(root, "toy.train")
+    real = {m["name"] for m in bench()["per_layer"]}
+    assert {m["name"] for m in cell.bench["per_layer"]} - real == {
+        "device_put_ms.train"}
+    mine = [m["name"] for m in cell.per_layer()]
+    assert {"dense_blocks_run_pct.train", "device_put_ms.train"} <= set(mine)
+
+    class Window:
+        def histogram(self, name, labels=None):
+            return {"train_dense_blocks_run_ratio": (1.214, 2),
+                    "train_context_blocks_live_ratio": (1.146, 2)}.get(name)
+
+        def gauge(self, name, labels=None):
+            return None
+    got = readers.read_all(readers.Measured(cell, "TPU v5 lite", Window(),
+                                            window_s=10.0))
+    assert got["dense_blocks_run_pct.train"] == pytest.approx(60.7)
+    assert got["gather_live_pct.train"] == pytest.approx(57.3)
+    # nothing to read (no trace, no such histogram): left out
+    assert not {"device_put_ms.train", "step_device_ms.train"} & set(got)
 
 
 def test_a_config_a_mix_a_cell_and_a_metric_are_added_by_files_alone(
@@ -228,122 +296,13 @@ def test_a_config_a_mix_a_cell_and_a_metric_are_added_by_files_alone(
                                             window_s=10.0))
     # the new metric reads through the existing kind; metrics with
     # nothing to read (no trace, no such histogram) are left out
-    assert got == {"device_put_ms.train": pytest.approx(5.0),
-                   "pack_ms.train": pytest.approx(2.0)}
+    assert got["device_put_ms.train"] == pytest.approx(5.0)
+    assert got["pack_ms.train"] == pytest.approx(2.0)
+    assert not {"read_ms.train", "step_device_ms.train"} & set(got)
     serve = common.Cell(root, "toy32.serve")
     assert serve.runner == "serve"
     assert {m["name"] for m in serve.end_to_end()} == {
         "request_p50_ms", "setup_s"}
-
-
-def synthetic():
-    """Two chips, two runs of jit_train_step each, 10 ms a run: compute
-    0-6 ms, an all-reduce 5-9 ms (1 ms of it under compute), idle 9-10."""
-    planes = []
-    for chip in range(2):
-        ops, mods, spans = [], [], []
-        for run in range(2):
-            t = run * 10 * MS
-            mods.append(["jit_train_step(123)", t, 10 * MS])
-            ops.append(["fusion.1 = f32[8] fusion(f32[8] p)", t, 6 * MS])
-            ops.append(["all-reduce-start.1 = f32[8] all-reduce-start(x)",
-                        t + 5 * MS, 0.1 * MS])
-            ops.append(["all-reduce-done.1 = f32[8] all-reduce-done(x)",
-                        t + 8.9 * MS, 0.1 * MS])
-            spans.append(["all-reduce-start.1 = f32[8] all-reduce-start(x)",
-                          t + 5 * MS, 4 * MS])
-        mods.append(["jit_unpack(9)", 20 * MS, 1 * MS])
-        ops.append(["copy.1 = s32[4] copy(s32[4] q)", 20 * MS, 1 * MS])
-        planes.append({"name": f"/device:TPU:{chip}", "lines": {
-            tr.OPS_LINE: ops, tr.MODULES_LINE: mods, tr.ASYNC_LINE: spans}})
-    planes.append({"name": "/host:CPU", "lines": {"python3": [
-        ["bench.next_batch", 9.2 * MS, 0.7 * MS],
-        ["bench.next_batch", 19.1 * MS, 0.8 * MS]]}})
-    return {"planes": planes}
-
-
-def test_interval_arithmetic():
-    assert tr.union([(0, 2), (1, 3), (5, 6)]) == [(0, 3), (5, 6)]
-    assert tr.total([(0, 3), (5, 6)]) == 4
-    assert tr.subtract([(0, 10)], [(2, 3), (5, 7)]) == [(0, 2), (3, 5),
-                                                        (7, 10)]
-    assert tr.subtract([(0, 4), (6, 9)], [(3, 7)]) == [(0, 3), (7, 9)]
-
-
-def test_busy_union_and_idle_share_synthetic():
-    got = tr.busy_and_window(synthetic())
-    # busy per chip: each run's 6 ms of compute (the all-reduce-start
-    # at 5.0-5.1 lies inside it) and its 0.1 ms all-reduce-done, then
-    # the 1 ms copy: 6.1 + 6.1 + 1
-    assert got["chips"] == 2
-    assert got["window_s"] == pytest.approx(21e-3)
-    assert got["busy_s"] == pytest.approx(13.2e-3)
-    idle = 1 - got["busy_s"] / got["window_s"]
-    assert idle == pytest.approx(1 - 13.2 / 21)
-
-
-def test_program_time_counts_only_the_named_program():
-    got = tr.program_time(synthetic(), "^jit_train_step")
-    assert got["runs"] == 4
-    assert got["seconds_per_run"] == pytest.approx(6.1e-3)
-    assert tr.program_time(synthetic(), "^jit_unpack")[
-        "seconds_per_run"] == pytest.approx(1e-3)
-    assert tr.program_time(synthetic(), "^jit_nothing") is None
-
-
-def test_exposed_collective_time():
-    whole = tr.op_time(synthetic(), "^all-reduce", "^jit_train_step")
-    bare = tr.op_time(synthetic(), "^all-reduce", "^jit_train_step",
-                      exposed_only=True)
-    assert whole["seconds_per_run"] == pytest.approx(4e-3)
-    # 5-9 ms span, compute covers 5-6: 3 ms with nothing else running
-    assert bare["seconds_per_run"] == pytest.approx(3e-3)
-    assert tr.op_time(synthetic(), "^all-gather", "^jit_train_step")[
-        "seconds_per_run"] == 0.0
-
-
-def test_breakdown_names_ops_and_attributes_gaps():
-    got = tr.breakdown(synthetic())
-    assert got["device_ops"][0][0].startswith("fusion.1")
-    assert got["device_ops"][0][1] == pytest.approx(4 * 6e-3)
-    gaps = dict(got["idle_gaps"])
-    # each chip idles 6.0-8.9 twice (host:other) and 9.0-10 / 19-20
-    # while the host fetched the next batch
-    assert gaps["bench.next_batch"] == pytest.approx(2 * 2 * 1e-3)
-    assert gaps["host:other"] == pytest.approx(2 * 2 * 2.9e-3)
-    assert len(got["device_ops"]) <= 10 and len(got["idle_gaps"]) <= 10
-
-
-def test_op_label_keeps_the_name_first_and_drops_layouts():
-    label = tr.op_label("%all-reduce.5 = f32[1301136,128]{1,0:T(8,128)} "
-                        "all-reduce(f32[1301136,128]{1,0} %fusion.5)")
-    assert label.startswith("all-reduce.5 = f32[1301136,128] all-reduce(")
-    assert "{" not in label and "%" not in label
-
-
-def recorded():
-    path = os.path.join(ROOT, "tests", "benchmark", "data",
-                        "trace_v5e_java14m_train_100ms.json")
-    with open(path) as f:
-        return json.load(f)
-
-
-def test_recorded_chip_trace_reduces():
-    trace = recorded()
-    assert [p["name"] for p in tr.device_planes(trace)] == ["/device:TPU:0"]
-    got = tr.busy_and_window(trace)
-    assert got["window_s"] == pytest.approx(0.1, rel=1e-6)
-    assert 0.99 < got["busy_s"] / got["window_s"] <= 1.0
-    step = tr.program_time(trace, "^jit_train_step")
-    assert step["runs"] == 3            # the third is cut by the 100 ms
-    assert 0.030 < step["seconds_per_run"] < 0.045
-    unpack = tr.program_time(trace, "^jit_unpack")
-    assert unpack["seconds_per_run"] < 1e-4
-    # one chip: no collective ran
-    assert tr.op_time(trace, "^(all-reduce|reduce-scatter|all-gather)",
-                      "^jit_train_step", True)["seconds_per_run"] == 0.0
-    top = tr.breakdown(trace)["device_ops"]
-    assert top[0][0].startswith("fusion.") and top[0][1] > 0
 
 
 def java14m():
