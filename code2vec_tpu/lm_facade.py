@@ -41,6 +41,7 @@ parameters are initialised from `--seed`, and `--save` writes them.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import json
 import os
@@ -52,7 +53,7 @@ import numpy as np
 
 from code2vec_tpu import obs
 from code2vec_tpu.config import Config
-from code2vec_tpu.model_facade import _H_FILL, _stage
+from code2vec_tpu.model_facade import _H_FILL, _device_part, _stage
 from code2vec_tpu.models import (
     hybrid_lm, latent_moe_lm, lm_common, sparse_gqa_moe_lm,
 )
@@ -345,24 +346,58 @@ class ScoringModel:
         the rows whose context is gone: they run as padding, `lengths`
         zeroed IN PLACE for them). With a cache the slots are looked up
         and the step dispatched under the cache's lock (module
-        docstring)."""
-        step = self._step(rows, length)
-        held = np.zeros((rows,), np.int32)
+        docstring). The first parts of the device stage
+        (`serving_predict_device_seconds`): the arguments are put on the
+        device here, not inside the call, so that each part is timed."""
         gone: Dict[int, str] = {}
-        if self.contexts is None:
-            return step(self.params, ids, lengths), held, gone
-        slot = np.zeros((rows,), np.int32)
-        with self._cache_lock:
-            for i, context in enumerate(contexts):
-                if context is None:
-                    continue
-                found = self.contexts.lookup(context)
-                if found is None:
-                    gone[i], lengths[i] = context, 0
-                else:
-                    slot[i], held[i] = found
-            return (step(self.params, ids, lengths, self.cache, slot, held),
-                    held, gone)
+        cached = self.contexts is not None
+        with contextlib.ExitStack() as locked:
+            if cached:
+                with _device_part("lookup"):
+                    locked.enter_context(self._cache_lock)
+                    slot = np.zeros((rows,), np.int32)
+                    held = np.zeros((rows,), np.int32)
+                    for i, context in enumerate(contexts):
+                        if context is None:
+                            continue
+                        found = self.contexts.lookup(context)
+                        if found is None:
+                            gone[i], lengths[i] = context, 0
+                        else:
+                            slot[i], held[i] = found
+            with _device_part("put"):
+                if not cached:
+                    held = np.zeros((rows,), np.int32)
+                on_device = jax.device_put(
+                    (ids, lengths, slot, held) if cached else (ids, lengths))
+            with _device_part("enqueue"):
+                out = self._step(rows, length)(
+                    self.params, *on_device[:2],
+                    *((self.cache,) if cached else ()), *on_device[2:])
+                del on_device   # the inputs go here, not between two parts
+        return out, held, gone
+        with contextlib.ExitStack() as locked:
+            with _device_part("lookup"):
+                locked.enter_context(self._cache_lock)
+                slot = np.zeros((rows,), np.int32)
+                held = np.zeros((rows,), np.int32)
+                for i, context in enumerate(contexts):
+                    if context is None:
+                        continue
+                    found = self.contexts.lookup(context)
+                    if found is None:
+                        gone[i], lengths[i] = context, 0
+                    else:
+                        slot[i], held[i] = found
+            with _device_part("put"):
+                d_ids, d_lengths, d_slot, d_held = jax.device_put(
+                    (ids, lengths, slot, held))
+            with _device_part("enqueue"):
+                out = self._step(rows, length)(
+                    self.params, d_ids, d_lengths, self.cache, d_slot,
+                    d_held)
+                del d_ids, d_lengths, d_slot, d_held    # not between parts
+        return out, held, gone
 
     def warmup(self, rows: Optional[int] = None) -> None:
         """Compile and run every (rows, length) shape once (and the one
@@ -494,11 +529,15 @@ class ScoringModel:
                 ids[i, :len(r.ids)] = r.ids
                 lengths[i] = len(r.ids)
             _H_FILL["rows"].observe(n / rows)
+            contexts = [r.context for r in requests]
         with _stage("device"):
-            got, held, gone = self._run_step(
-                rows, length, ids, lengths, [r.context for r in requests])
-            values, indices, lse, stats = jax.device_get(
-                (got.topk_values, got.topk_indices, got.lse, got.stats))
+            got, held, gone = self._run_step(rows, length, ids, lengths,
+                                             contexts)
+            with _device_part("wait"):
+                answer = jax.block_until_ready(
+                    (got.topk_values, got.topk_indices, got.lse, got.stats))
+            with _device_part("fetch"):
+                values, indices, lse, stats = jax.device_get(answer)
         _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
         if self.contexts is not None:
             q = lengths.astype(np.int64)

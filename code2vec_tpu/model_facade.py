@@ -63,6 +63,18 @@ _STAGE_HELP = (
 _H_STAGE = {stage: obs.histogram("serving_predict_stage_seconds",
                                  _STAGE_HELP, stage=stage)
             for stage in ("parse", "assemble", "device", "render")}
+_DEVICE_PART_HELP = (
+    "one part of a served call's device stage "
+    "(serving_predict_stage_seconds{stage=device}), which its parts "
+    "cover: put (host arrays to the device), lookup (a scoring model "
+    "with a context cache: its lock and the rows' slots), enqueue (the "
+    "jitted call until it returns), wait (block_until_ready on the "
+    "outputs that are fetched: the step's device time plus launch "
+    "latency, the one part the chip is busy in), fetch (the copies to "
+    "the host)")
+_H_DEVICE_PART = {part: obs.histogram("serving_predict_device_seconds",
+                                      _DEVICE_PART_HELP, part=part)
+                  for part in ("put", "lookup", "enqueue", "wait", "fetch")}
 _FILL_HELP = (
     "how much of a predict step's padded shape was real work: rows = "
     "live rows over the padded row shape, contexts = valid contexts "
@@ -76,6 +88,12 @@ _H_FILL = {dim: obs.histogram("serving_batch_fill_ratio", _FILL_HELP,
 
 def _stage(stage: str):
     return obs.span("predict." + stage, hist=_H_STAGE[stage])
+
+
+def _device_part(part: str):
+    """A child span of `predict.device`; a request's tree hangs it
+    under that stage by this name (serving/batcher.py)."""
+    return obs.span("predict.device." + part, hist=_H_DEVICE_PART[part])
 
 
 def _head_dispatch_counter(head: str):
@@ -402,16 +420,30 @@ class BucketedPredictMixin:
                 float(chunk.context_valid_mask[:live].sum())
                 / (padded_rows * m))
         with _stage("device"):
-            arrays = device_put_batch(padded, self.mesh)
-            out = self._call_predict_step(step, arrays)
-            topk_idx = np.asarray(out.topk_indices)[:n]
-            topk_val = np.asarray(out.topk_values)[:n]
-            code_vectors = np.asarray(out.code_vectors)[:n]
-            attention = np.asarray(out.attention)[:n]
+            out = self._run_predict_step(step, padded)
+            with _device_part("fetch"):
+                topk_idx = np.asarray(out.topk_indices)[:n]
+                topk_val = np.asarray(out.topk_values)[:n]
+                code_vectors = np.asarray(out.code_vectors)[:n]
+                attention = np.asarray(out.attention)[:n]
         with _stage("render"):
             return self._render_predictions(
                 chunk, n, m, topk_idx, topk_val, code_vectors, attention,
                 with_code_vectors)
+
+    def _run_predict_step(self, step, batch) -> EvalOutputs:
+        """Transfer in, dispatch, wait until the step's outputs are
+        ready: the device stage up to its fetch, part by part. The
+        warm-up runs every shape through here too."""
+        with _device_part("put"):
+            arrays = device_put_batch(batch, self.mesh)
+        with _device_part("enqueue"):
+            out = self._call_predict_step(step, arrays)
+            del arrays      # the inputs go here, not between two parts
+        with _device_part("wait"):
+            jax.block_until_ready((out.topk_indices, out.topk_values,
+                                   out.code_vectors, out.attention))
+        return out
 
     def _render_predictions(self, chunk, n: int, m: int, topk_idx,
                             topk_val, code_vectors, attention,
@@ -605,9 +637,7 @@ class Code2VecModel(BucketedPredictMixin):
         empty = self.alloc_predict_batch(rows)
         for m in self.context_buckets:
             step, _, _ = self._dispatch_predict_step(rows, rows, m)
-            out = self._call_predict_step(step, device_put_batch(
-                slice_contexts(empty, m), self.mesh))
-            jax.block_until_ready(out.topk_indices)
+            self._run_predict_step(step, slice_contexts(empty, m))
 
     # ------------------------------------------------------------ data
 

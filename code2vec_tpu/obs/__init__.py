@@ -17,6 +17,10 @@ One import surface for every instrumented layer:
   in a running `jax.profiler` trace, on the device trace's clock, and
   jax's compile events feed `jax_compile_seconds` (and, for a span
   that asks, `jax_compiles_during`).
+- Stalls and collections (`obs.stalls`): every collection of Python's
+  collector into `python_gc_pause_seconds`, and a thread that measures
+  how late it wakes into `host_stall_seconds` (the machine or the
+  program stood still), running while a trainer or a server does.
 - Exporters (`obs.exporters`): atomic Prometheus snapshot file
   (`--metrics_file`), localhost HTTP `/metrics` (`--metrics_port`),
   atomic JSON heartbeat (`--heartbeat_file`), and a dump of every
@@ -30,13 +34,16 @@ path, and the serving bridge all record into the same registry.
 
 from __future__ import annotations
 
-from code2vec_tpu.obs import exporters, flight, metrics, reqtrace, tracer
+from code2vec_tpu.obs import (
+    exporters, flight, metrics, reqtrace, stalls, tracer,
+)
 from code2vec_tpu.obs.flight import FlightRecorder, default_flight_recorder
 from code2vec_tpu.obs.metrics import (
     DEFAULT_BUCKETS, Counter, Gauge, Histogram, MetricsRegistry,
     default_registry,
 )
 from code2vec_tpu.obs.reqtrace import RequestTrace
+from code2vec_tpu.obs.stalls import default_host_watch
 from code2vec_tpu.obs.tracer import (
     SpanTracer, compiles_during, default_tracer, log_compiles_from_now, span,
     startup_phase,
@@ -48,7 +55,8 @@ __all__ = [
     "DEFAULT_BUCKETS", "counter", "gauge", "histogram", "span",
     "startup_phase", "log_compiles_from_now", "compiles_during",
     "default_registry", "default_flight_recorder", "default_tracer",
-    "exporters", "flight", "metrics", "reqtrace", "tracer",
+    "default_host_watch",
+    "exporters", "flight", "metrics", "reqtrace", "stalls", "tracer",
 ]
 
 

@@ -352,16 +352,27 @@ def log_compiles_from_now(log: Optional[Callable[[str], None]]) -> None:
     _steady_log = log
 
 
+def backend_compiles() -> int:
+    """Backend compiles (XLA compile or compile-cache load) this process
+    has finished since the listener was registered; 0 before."""
+    seen = _backend_compiles
+    return seen.count if seen is not None else 0
+
+
 def compiles_during(span_name: str) -> _metrics.Histogram:
     """The histogram for `span(span_name, compiles=...)`: backend
-    compiles that finished while one such span was open."""
+    compiles that finished while one such span was open. The host
+    watch (obs/stalls.py) observes `span="process"` once a tick, the
+    compiles finished since its tick before: ANYWHERE in the process,
+    also outside a step or a dispatched batch."""
     return _metrics.default_registry().histogram(
         "jax_compiles_during",
         "backend compiles (XLA compile or compile-cache load, on any "
         "thread) that finished while one span of this name was open, "
-        "one observation a span: the sum over a window is the compiles "
-        "inside the steps or batches there, and 0 is the sound reading "
-        "once every shape is warm",
+        "one observation a span (span=process: one a 20 ms tick of the "
+        "host watch, the whole process): the sum over a window is the "
+        "compiles inside the steps or batches there (span=process: "
+        "anywhere), and 0 is the sound reading once every shape is warm",
         buckets=(0, 1, 2, 4, 8, 16), span=span_name)
 
 
@@ -438,8 +449,7 @@ class span:
             annotation.__enter__()
         self._annotation = annotation
         if self.compiles is not None:
-            seen = _backend_compiles
-            self._compiles0 = seen.count if seen is not None else 0
+            self._compiles0 = backend_compiles()
         self._t0 = time.perf_counter()
         return self
 

@@ -31,8 +31,12 @@ per-line results; an optional `phases` dict receives the `batch_wait`
 duration of the coalesced model call the request rode in — that is the
 latency the request actually experienced (phases sum to ~total); the
 per-batch cost lives in `serving_device_seconds`, and amortized
-per-row cost is that divided by `serving_batch_rows`. `drain()` stops intake, flushes everything pending, and joins
-the dispatcher — the SIGTERM-grace path.
+per-row cost is that divided by `serving_batch_rows`. Beside `device`
+the dict gets `device_end`, the `perf_counter` instant at which that
+call ended: not a phase, the submitter subtracts it from the instant its
+own thread runs again (the server's `handoff` phase). `drain()` stops
+intake, flushes everything pending, and joins the dispatcher — the
+SIGTERM-grace path.
 
 Deadline propagation (serving/admission.py): `submit()` takes the
 request's Deadline. A request whose remaining budget cannot cover its
@@ -445,7 +449,8 @@ class DynamicBatcher:
                     continue
                 item.future.set_exception(e)
             return
-        dur = time.perf_counter() - t_dispatch
+        t_end = time.perf_counter()
+        dur = t_end - t_dispatch
         _H_DEVICE.observe(dur)
         # The deepest bucket in the batch is the shape the device call
         # compiled/ran at — that is the bucket this duration informs.
@@ -459,6 +464,7 @@ class DynamicBatcher:
             n = len(item.lines)
             if item.phases is not None:
                 item.phases["device"] = dur
+                item.phases["device_end"] = t_end
             if item.future.set_running_or_notify_cancel():
                 item.future.set_result(results[off:off + n])
             off += n
@@ -481,7 +487,9 @@ def _record_batch_spans(batch: List[_Pending], batch_id: int,
     `device` span hangs under it with the model call's stages
     (`stages`: what `tracer.collect` gathered around the call, the
     facade's predict.parse / .assemble / .device / .render) as its
-    children, and the process tracer records the batch exactly once —
+    children and the device stage's parts (predict.device.put /
+    .enqueue / .wait / .fetch) under the `predict.device` stage, and
+    the process tracer records the batch exactly once —
     tagged with every member trace id so the bulk Chrome trace links
     batch to requests."""
     traced = [item for item in batch if item.trace is not None]
@@ -489,6 +497,12 @@ def _record_batch_spans(batch: List[_Pending], batch_id: int,
         return
     batch_span_id = reqtrace.mint_span_id()
     device_span_id = reqtrace.mint_span_id() if stages else None
+    # a span closes behind its children, so the parts of a stage come
+    # BEFORE it in `stages`: the stages that have parts get their ids
+    # first (`predict.device.put` hangs under `predict.device`)
+    names = {name for name, _, _ in stages}
+    stage_ids = {name: reqtrace.mint_span_id()
+                 for name in names & {n.rpartition(".")[0] for n in names}}
     members = [item.trace.trace_id for item in traced]
     attrs = {"batch_id": batch_id, "rows": rows,
              "requests": len(batch)}
@@ -510,8 +524,11 @@ def _record_batch_spans(batch: List[_Pending], batch_id: int,
                             parent_id=batch_span_id)
         for name, start, seconds in stages:
             # the ring already has each stage once, from the span itself
-            item.trace.add_span(name, start, seconds,
-                                parent_id=device_span_id, forward=False)
+            item.trace.add_span(
+                name, start, seconds, span_id=stage_ids.get(name),
+                parent_id=stage_ids.get(name.rpartition(".")[0],
+                                        device_span_id),
+                forward=False)
     tracer.default_tracer().maybe_record(
         "serving_batch", t_dispatch, dur, span_id=batch_span_id,
         attrs=dict(attrs, member_trace_ids=members))
@@ -924,7 +941,8 @@ class ContinuousBatcher:
                 if item.future.set_running_or_notify_cancel():
                     item.future.set_exception(e)
             return
-        dur = time.perf_counter() - t_dispatch
+        t_end = time.perf_counter()
+        dur = t_end - t_dispatch
         _H_DEVICE.observe(dur)
         batch_bucket = max((i.bucket for i in live
                             if i.bucket is not None), default=None)
@@ -937,6 +955,7 @@ class ContinuousBatcher:
                     continue
                 if item.phases is not None:
                     item.phases["device"] = dur
+                    item.phases["device_end"] = t_end
                 if item.future.set_running_or_notify_cancel():
                     item.future.set_result(results[off:off + n])
         else:
@@ -945,6 +964,7 @@ class ContinuousBatcher:
                 n = len(item.lines)
                 if item.phases is not None:
                     item.phases["device"] = dur
+                    item.phases["device_end"] = t_end
                 if item.future.set_running_or_notify_cancel():
                     item.future.set_result(results[off:off + n])
                 off += n

@@ -119,7 +119,12 @@ from code2vec_tpu.serving.tenancy import (
 )
 from code2vec_tpu.utils.faults import FaultInjected
 
-_PIPELINE_PHASES = ("queue_wait", "extract", "batch_wait", "device")
+# A request's time inside `handle_request`, in order: admit + queue_wait
+# + extract + batch_wait + device + handoff + respond is its `total`
+# but for the few statements between them; `http` is the handler's own
+# time around `handle_request`.
+_PIPELINE_PHASES = ("admit", "queue_wait", "extract", "batch_wait",
+                    "device", "handoff", "respond", "http")
 
 # Env hook (set by the serving supervisor): bind the listen socket with
 # SO_REUSEPORT so N replica processes share one port and the kernel
@@ -127,10 +132,21 @@ _PIPELINE_PHASES = ("queue_wait", "extract", "batch_wait", "device")
 REUSEPORT_ENV = "C2V_SERVE_REUSEPORT"
 
 _PHASE_HELP = (
-    "per-request serving latency by phase: queue_wait (extractor "
-    "slot), extract (path extraction), batch_wait (coalescing), "
-    "device (model call), total (end to end; carries a `status` label "
-    "and is recorded for EVERY terminal status, shed/errored included)")
+    "per-request serving latency by phase: admit (entry to the end of "
+    "admission: normalise, key, cache probe, admission gate; a cache "
+    "hit ends here), queue_wait (extractor slot: the extractor call "
+    "less the extraction, so the wait for a worker and the pool's "
+    "bookkeeping around it), extract (path extraction), batch_wait "
+    "(coalescing), device (model call), handoff (end of the model "
+    "call to the handler thread running again: the dispatcher's "
+    "fan-out and the wake-up), respond (from there to the end of "
+    "handle_request: render, json.dumps, cache.put), total (entry to "
+    "exit of handle_request, what the phases before it add up to; "
+    "carries a "
+    "`status` label and is recorded for EVERY terminal status, "
+    "shed/errored included), http (the handler's own time around "
+    "that: read and decode of the body before, the write of the "
+    "answer after)")
 
 
 def _phase_hist(phase: str):
@@ -330,6 +346,7 @@ class PredictionServer:
         self.swap = SwapManager(self, build_model=swap_build_model,
                                 mount_index=swap_mount_index)
         self._httpd: Optional[socketserver.BaseServer] = None
+        self._host_watch = None     # set by start()
         self._inflight = 0
         self._inflight_cond = threading.Condition()
         self._draining = False
@@ -457,7 +474,7 @@ class PredictionServer:
         try:
             body = self._handle(endpoint, code, deadline, phases,
                                 params=params, trace=trace,
-                                tenant=tlabel)
+                                tenant=tlabel, t0=t0)
             status = 200
         except Shed as e:
             if tlabel is None:
@@ -501,13 +518,22 @@ class PredictionServer:
                                "trace_id": trace.trace_id}
                               ).encode() + b"\n"
         finally:
-            total = time.perf_counter() - t0
+            t_end = time.perf_counter()
+            total = t_end - t0
             root.attrs["status"] = status
             root.__exit__(None, None, None)
             # snapshot: the batcher dispatcher can still write phase
             # keys for a request that exited early via the result
             # backstop — iterating the live dict could raise mid-walk
             phases = dict(list(phases.items()))
+            # instants, not phases: `device_end` is left where a request
+            # went before its batch settled (the result backstop) and
+            # never subtracted it; `respond_from` is where the handler
+            # thread ran again, and `respond` ends with `total`
+            phases.pop("device_end", None)
+            t_back = phases.pop("respond_from", None)
+            if t_back is not None:
+                phases["respond"] = t_end - t_back
             for phase, dur in phases.items():
                 _H_PHASE[phase].observe(dur)
             if tlabel is None:
@@ -556,7 +582,10 @@ class PredictionServer:
                 phases: Dict[str, float],
                 params: Optional[Dict] = None,
                 trace: Optional[RequestTrace] = None,
-                tenant: Optional[str] = None) -> bytes:
+                tenant: Optional[str] = None,
+                t0: Optional[float] = None) -> bytes:
+        if t0 is None:
+            t0 = time.perf_counter()
         if trace is None:
             trace = RequestTrace()
         if endpoint not in self.endpoints:
@@ -600,10 +629,12 @@ class PredictionServer:
             # Cache hits serve BEFORE admission and breakers: graceful
             # degradation — a dead extractor pool cannot take the hit
             # path down with it (pinned in tests/test_serving_chaos.py).
+            phases["admit"] = time.perf_counter() - t0
             return cached  # type: ignore[return-value]
         with trace.span("admission"):
             self.admission.admit(deadline, tenant=tenant)
         t_admit = time.perf_counter()
+        phases["admit"] = t_admit - t0
         worked = True
         try:
             if endpoint == "score":
@@ -611,6 +642,12 @@ class PredictionServer:
             else:
                 lines, hash_to_string = self._extract(
                     code, deadline, phases, trace=trace)
+                # the whole extractor call less the extraction itself:
+                # the wait for a worker AND the pool's bookkeeping
+                # around it (liveness polls, the release), so that no
+                # time lies between two phases
+                phases["queue_wait"] = (time.perf_counter() - t_admit
+                                        - phases.get("extract", 0.0))
                 if self.traffic is not None:
                     self.traffic.record(lines)
             future = self.batcher.submit(lines, phases=phases,
@@ -633,6 +670,12 @@ class PredictionServer:
                 if "draining" in str(e):
                     raise Shed("draining", str(e))
                 raise
+            t_back = time.perf_counter()
+            # the dispatcher stamped where its model call ended
+            # (serving/batcher.py), this thread runs again here
+            device_end = phases.pop("device_end", None)
+            if device_end is not None:
+                phases["handoff"] = t_back - device_end
             results = [r for r, _ in raw]
             result_fp = raw[0][1] if raw else fp
             with trace.span("render"):
@@ -649,6 +692,9 @@ class PredictionServer:
                                            topk=self.topk,
                                            model=result_fp, **knobs)
             self.cache.put(key, body)
+            # `respond` ends where `total` does: handle_request takes
+            # one reading for both
+            phases["respond_from"] = t_back
             return body
         except Shed:
             # a post-admission shed (batcher DeadlineInfeasible, an
@@ -1009,6 +1055,7 @@ class PredictionServer:
                     self._error(500, f"{type(e).__name__}: {e}")
 
             def do_POST(self):  # noqa: N802 (stdlib API name)
+                t_in = time.perf_counter()
                 path, _, query = self.path.partition("?")
                 endpoint = path.lstrip("/")
                 if path == "/admin/reload":
@@ -1074,9 +1121,11 @@ class PredictionServer:
                         self._error(e.code, str(e),
                                     extra_headers=trace_headers())
                         return
+                    t_call = time.perf_counter()
                     status, body, headers = server.handle_request(
                         endpoint, code_text, deadline, params=params,
                         trace=trace, tenant=tenant)
+                    t_done = time.perf_counter()
                     if ("debug=trace" in query.split("&")
                             and server.config.serve_debug_trace):
                         # post-cache injection: hits and misses both
@@ -1084,6 +1133,8 @@ class PredictionServer:
                         # bytes stay trace-free/byte-stable
                         body = server._inject_trace(body, trace)
                     self._respond(status, body, extra_headers=headers)
+                    _H_PHASE["http"].observe(
+                        (t_call - t_in) + (time.perf_counter() - t_done))
                 finally:
                     server._exit_request()
 
@@ -1163,6 +1214,10 @@ class PredictionServer:
             Handler)
         httpd.daemon_threads = True
         self._httpd = httpd
+        # collections and host stalls while this server listens
+        # (obs/stalls.py); drain stops what this call started
+        self._host_watch = obs.default_host_watch()
+        self._host_watch.start(self.log)
         self.port = httpd.server_address[1]
         threading.Thread(target=httpd.serve_forever,
                          name="serving-http", daemon=True).start()
@@ -1282,6 +1337,8 @@ class PredictionServer:
                 self._httpd.server_close()
             except Exception:
                 pass  # teardown must never mask the drain result
+        if self._host_watch is not None:
+            self._host_watch.stop()
         self._drained.set()
         self.log(f"Drain complete ({'clean' if clean else 'timed out'})"
                  f"{self._device_suffix()}")

@@ -220,9 +220,6 @@ class Trainer:
             "individual batches whose loss came back NaN/Inf")
         g_loss = reg.gauge("train_last_avg_loss",
                            "window-average loss at the last drain")
-        g_throughput = reg.gauge(
-            "train_examples_per_sec",
-            "window throughput at the last log boundary")
         g_epoch = reg.gauge("train_epoch", "current epoch number")
         g_rss = reg.gauge("process_rss_bytes", "current resident set size")
         g_async = reg.gauge(
@@ -446,6 +443,11 @@ class Trainer:
                 f"--on_nonfinite_loss warn to push through.")
 
         write_heartbeat("starting")
+        # collections and host stalls, counted while the loop runs
+        # (obs/stalls.py): a window that lost seconds says in this log
+        # whether the machine or the process stood still
+        host_watch = obs.default_host_watch()
+        host_watch.start(log)
         try:
             batch_iter = iter(prefetcher)
             while True:
@@ -611,7 +613,6 @@ class Trainer:
                         f" [host: data-wait {win_data_wait:.2f}s, dispatch "
                         f"{win_dispatch:.2f}s, loss-sync {sync_s:.2f}s, "
                         f"device/other {other_s:.2f}s]")
-                    g_throughput.set(throughput)
                     g_epoch.set(epoch)
                     g_rss.set(current_rss_bytes())
                     # "Is the step loop input-bound at N hosts?" as ONE
@@ -664,6 +665,7 @@ class Trainer:
                 trace_active = False
             if watcher is not None:
                 watcher.uninstall()
+            host_watch.stop()
             obs.log_compiles_from_now(None)
             # Flush+close the TB event file HERE, not after the loop: a
             # crash (or the NaN-halt raise) must not lose the tail of the

@@ -36,10 +36,6 @@ _H_DEVICE_PUT = obs.histogram(
     "prefetch_device_put_seconds",
     "host-side cost of dispatching one batch's device transfer "
     "(consumer thread; the transfer itself is async)")
-_G_DEPTH = obs.gauge(
-    "prefetch_queue_depth",
-    "ready batches queued ahead of the consumer at its last take "
-    "(0 every step = the pipeline is feed-bound)")
 
 
 class DevicePrefetcher:
@@ -140,7 +136,6 @@ class DevicePrefetcher:
                 if isinstance(item, EpochEnd):
                     yield item
                     continue
-                _G_DEPTH.set(self._queue.qsize())
                 batch, packed = item
                 with obs.span("prefetch_device_put", hist=_H_DEVICE_PUT):
                     arrays = device_put_batch(batch, self.mesh,
