@@ -21,8 +21,20 @@ XLA gives a reduce over a matmul's OPERAND a pass of its own, so on a
 TPU pass B is a Pallas kernel (`_exp_sums_kernel`: a grid over tiles of
 target rows, the two accumulators resident in VMEM); everywhere else,
 and for shapes the kernel's blocks do not divide, it is the two plain
-ops. The kernel is no collective: where a mesh shards the batch's rows
-and nothing else it runs chip by chip under `shard_map`; a mesh that
+ops.
+
+Where a mesh shards the batch's rows and nothing else, its chips split
+the TARGET rows between them for the head (`target_shards`): under one
+`shard_map` over `data` each chip gathers every chip's code vectors
+(`(4096, 384)`: 6.3 MB), takes its `ceil(V / n)` rows of the replicated
+table and runs the three passes over them against all the rows. The
+row max, the row sums, `E @ W` and the label's logit cross the chips as
+`(B,)` and `(B, D)` reductions. Its `dW` is then the WHOLE sum for its
+rows: it is rounded to the compute dtype where one chip rounds it, and
+one all-gather of the shards in that dtype makes the table's cotangent
+on every chip. No table-shaped partial sum is left for GSPMD to
+all-reduce in float32 (`java14m.train_dp4`: 150 MB into a chip where the
+all-reduce moved 602 MB). The kernel is no collective: a mesh that
 shards the table keeps the plain ops, which GSPMD partitions.
 
 Same operand precision as the autodiff form (operands in the compute
@@ -41,6 +53,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
+from code2vec_tpu.ops.sharded import tp_label_logit
 from code2vec_tpu.parallel.mesh import AXIS_DATA
 
 # Pass B's kernel: target rows a grid step, and the most batch rows a
@@ -73,6 +86,19 @@ def head_cross_entropy(code_vectors: jax.Array, table: jax.Array,
                      compute_dtype, mesh)
 
 
+def target_shards(mesh: Optional[Mesh]) -> int:
+    """How many chips share the head's target rows: every chip of a mesh
+    that shards the batch's rows and nothing else, 1 anywhere else. Read
+    off the mesh alone."""
+    return 1 if _split_axis(mesh) is None else mesh.devices.size
+
+
+def _split_axis(mesh: Optional[Mesh]) -> Optional[str]:
+    if mesh is None or mesh.devices.size != mesh.shape[AXIS_DATA]:
+        return None
+    return AXIS_DATA
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
 def _head(code_vectors, table, labels, weights, real_rows, compute_dtype,
           mesh):
@@ -81,20 +107,65 @@ def _head(code_vectors, table, labels, weights, real_rows, compute_dtype,
     return loss
 
 
+# The residuals under `shard_map` over `data`: every row's code vector,
+# the table, every row's label and weight whole on every chip, the
+# logits by target columns, then the row max, the row sums and `E @ W`,
+# whole.
+_RESIDUAL_SPECS = (P(), P(), P(), P(), P(None, AXIS_DATA), P(), P(), P())
+
+
 def _head_fwd(code_vectors, table, labels, weights, real_rows,
               compute_dtype, mesh):
+    axis = _split_axis(mesh)
+    forward = functools.partial(
+        _forward, real_rows=real_rows, compute_dtype=compute_dtype,
+        axis=axis,
+        # a mesh that is not split here leaves the head to GSPMD
+        exp_sums=(_exp_sums_on_a_chip if mesh is None or axis
+                  else _exp_sums_plain))
+    if axis:
+        rows = P(axis)
+        forward = jax.shard_map(
+            forward, mesh=mesh, in_specs=(P(axis, None), P(), rows, rows),
+            out_specs=(P(), _RESIDUAL_SPECS), check_vma=False)
+    return forward(code_vectors, table, labels, weights)
+
+
+def _head_bwd(real_rows, compute_dtype, mesh, residuals, loss_ct):
+    axis = _split_axis(mesh)
+    backward = functools.partial(_backward, compute_dtype=compute_dtype,
+                                 axis=axis)
+    if axis:
+        backward = jax.shard_map(
+            backward, mesh=mesh, in_specs=_RESIDUAL_SPECS + (P(),),
+            out_specs=(P(axis, None), P()), check_vma=False)
+    return backward(*residuals, loss_ct) + (None, None)
+
+
+_head.defvjp(_head_fwd, _head_bwd)
+
+
+def _forward(code_vectors, table, labels, weights, *, real_rows,
+             compute_dtype, axis, exp_sums):
+    """Passes A and B and the loss over the target rows this chip holds:
+    all of them (`axis` None), or its shard of them against the rows of
+    every chip of `axis`."""
     with jax.named_scope("logits_ce"):
+        code_vectors, labels, weights = (
+            _across(_all_rows, x, axis)
+            for x in (code_vectors, labels, weights))
+        shard = _own_target_rows(table, compute_dtype, axis)
         logits = jnp.einsum("bd,vd->bv", code_vectors.astype(compute_dtype),
-                            table.astype(compute_dtype),
+                            shard.astype(compute_dtype),
                             preferred_element_type=jnp.float32)
-        if real_rows < table.shape[0]:
-            col = jnp.arange(table.shape[0])
+        if real_rows < shard.shape[0] * _chips(axis):
+            col = _own_columns(shard.shape[0], jnp.int32, axis)
             logits = jnp.where(col[None, :] < real_rows, logits, -jnp.inf)
-        row_max = jnp.max(logits, axis=-1)
-        sum_exp, exp_rows = _exp_sums(logits, row_max, table, compute_dtype,
-                                      mesh)
-        label_logit = jnp.take_along_axis(
-            logits, labels[:, None], axis=-1)[:, 0]
+        row_max = _across(jax.lax.pmax, jnp.max(logits, axis=-1), axis)
+        sum_exp, exp_rows = _across(
+            jax.lax.psum, exp_sums(logits, row_max, shard, compute_dtype),
+            axis)
+        label_logit = _label_logits(logits, labels, axis)
         # optax's order: log sum exp(l - m) - (l[label] - m)
         loss = jnp.sum(weights * (jnp.log(sum_exp)
                                   - (label_logit - row_max)))
@@ -102,48 +173,99 @@ def _head_fwd(code_vectors, table, labels, weights, real_rows,
                   sum_exp, exp_rows)
 
 
-def _head_bwd(real_rows, compute_dtype, mesh, residuals, loss_ct):
-    (code_vectors, table, labels, weights, logits, row_max, sum_exp,
-     exp_rows) = residuals
+def _backward(code_vectors, table, labels, weights, logits, row_max, sum_exp,
+              exp_rows, loss_ct, *, compute_dtype, axis):
+    """Pass C over this chip's target rows, and the code vectors'
+    cotangent for its own rows of the batch."""
     with jax.named_scope("logits_ce"):
         scale = loss_ct * weights                           # (B,)
-        label_rows = jnp.take(table, labels, axis=0).astype(
+        own_labels, own_scale, own_sums, own_rows = (
+            _own_rows(x, axis) for x in (labels, scale, sum_exp, exp_rows))
+        label_rows = jnp.take(table, own_labels, axis=0).astype(
             compute_dtype).astype(jnp.float32)
-        code_ct = scale[:, None] * (exp_rows / sum_exp[:, None] - label_rows)
+        code_ct = own_scale[:, None] * (own_rows / own_sums[:, None]
+                                        - label_rows)
         prob = jnp.exp(logits - row_max[:, None]) / sum_exp[:, None]
-        col = jnp.arange(table.shape[0], dtype=labels.dtype)
+        col = _own_columns(logits.shape[1], labels.dtype, axis)
         logits_ct = scale[:, None] * jnp.where(
             col[None, :] == labels[:, None], prob - 1.0, prob)
         table_ct = jnp.einsum("bv,bd->vd", logits_ct.astype(compute_dtype),
                               code_vectors.astype(compute_dtype),
                               preferred_element_type=jnp.float32)
-    # each rounded to the compute dtype first, as autodiff rounds the
-    # cotangent of an operand that was cast to it (on a data mesh the
-    # table's then leaves its all-reduce at half width)
-    return (code_ct.astype(compute_dtype).astype(code_vectors.dtype),
-            table_ct.astype(compute_dtype).astype(table.dtype), None, None)
+        # each rounded to the compute dtype first, as autodiff rounds the
+        # cotangent of an operand that was cast to it: the table's is
+        # whole by then, and crosses the chips at that width
+        table_ct = _all_target_rows(table_ct.astype(compute_dtype),
+                                    table.shape[0], axis)
+        code_ct = code_ct.astype(compute_dtype)
+        if axis is not None:
+            # the two leave together, so the shards are gathered before
+            # the encoder's backward starts: left to the scheduler, pass
+            # C and the all-gather stood between the halves of the token
+            # table's asynchronous all-reduce (training/step.py), and
+            # that step never ended on the chips (PR 38)
+            code_ct, table_ct = jax.lax.optimization_barrier(
+                (code_ct, table_ct))
+    return code_ct.astype(code_vectors.dtype), table_ct.astype(table.dtype)
 
 
-_head.defvjp(_head_fwd, _head_bwd)
+# ------------------------------------------ one chip's part of the whole
+# Each is the identity, or the whole, where no `axis` splits the head.
+
+def _across(collective, x, axis):
+    return x if axis is None else collective(x, axis)
+
+
+_all_rows = functools.partial(jax.lax.all_gather, tiled=True)
+
+
+def _chips(axis) -> int:
+    return 1 if axis is None else jax.lax.axis_size(axis)
+
+
+def _own_rows(x, axis):
+    """This chip's rows of an array that holds every chip's."""
+    if axis is None:
+        return x
+    rows = x.shape[0] // _chips(axis)
+    return jax.lax.dynamic_slice_in_dim(
+        x, jax.lax.axis_index(axis) * rows, rows)
+
+
+def _own_target_rows(table, compute_dtype, axis):
+    """This chip's `ceil(V / n)` rows of the whole table in the compute
+    dtype, the last chip's filled up with zero rows (one copy: the pad,
+    the slice and the cast)."""
+    if axis is None:
+        return table
+    rows = -(-table.shape[0] // _chips(axis))
+    padded = jnp.pad(table, ((0, rows * _chips(axis) - table.shape[0]),
+                             (0, 0)))
+    return jax.lax.dynamic_slice_in_dim(
+        padded, jax.lax.axis_index(axis) * rows, rows).astype(compute_dtype)
+
+
+def _all_target_rows(shard, rows: int, axis):
+    """The chips' shards put together, less the last one's filling."""
+    return shard if axis is None else _all_rows(shard, axis)[:rows]
+
+
+def _own_columns(width: int, dtype, axis):
+    """The target rows behind this chip's `width` columns of logits."""
+    col = jnp.arange(width, dtype=dtype)
+    return col if axis is None else col + jax.lax.axis_index(axis) * width
+
+
+def _label_logits(logits, labels, axis):
+    """Each row's logit at its label: a take where the chip holds every
+    column, else a take on the chip that holds the label's, summed over
+    the chips."""
+    if axis is None:
+        return jnp.take_along_axis(logits, labels[:, None], axis=-1)[:, 0]
+    return tp_label_logit(logits, labels, axis)
 
 
 # ---------------------------------------------------------------- pass B
-
-def _exp_sums(logits, row_max, table, compute_dtype, mesh):
-    """`(sum_v E, E @ table)` over `E = exp(logits - row_max)`: `(B,)`
-    and `(B, D)` float32."""
-    local = functools.partial(_exp_sums_on_a_chip,
-                              compute_dtype=compute_dtype)
-    if mesh is None:
-        return local(logits, row_max, table)
-    if mesh.devices.size != mesh.shape[AXIS_DATA]:
-        return _exp_sums_plain(logits, row_max, table, compute_dtype)
-    rows = P(AXIS_DATA)
-    return jax.shard_map(
-        local, mesh=mesh, in_specs=(P(AXIS_DATA, None), rows, P()),
-        out_specs=(rows, P(AXIS_DATA, None)), check_vma=False)(
-            logits, row_max, table)
-
 
 def _exp_sums_on_a_chip(logits, row_max, table, compute_dtype):
     batch, width = logits.shape[0], table.shape[1]

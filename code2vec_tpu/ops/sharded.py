@@ -64,8 +64,6 @@ def tp_softmax_ce(local_logits: jax.Array, labels: jax.Array,
     global sum-exp and the label's logit via psum (the label row lives on
     exactly one shard).
     """
-    v_local = local_logits.shape[-1]
-    offset = _shard_offset(v_local, axis_name)
     # Max shift is stabilization only — its gradient cancels exactly in
     # logsumexp (d/dm [log Σexp(x-m) + m] = 0), and pmax has no AD rule.
     local_max = jax.lax.stop_gradient(jnp.max(local_logits, axis=-1))  # (B,)
@@ -73,15 +71,23 @@ def tp_softmax_ce(local_logits: jax.Array, labels: jax.Array,
     sumexp = jnp.sum(jnp.exp(local_logits - global_max[:, None]), axis=-1)
     global_sumexp = jax.lax.psum(sumexp, axis_name)               # (B,)
 
-    local_labels = labels - offset
+    label_logit = tp_label_logit(local_logits, labels, axis_name)  # (B,)
+    return jnp.log(global_sumexp) + global_max - label_logit
+
+
+def tp_label_logit(local_logits: jax.Array, labels: jax.Array,
+                   axis_name: str) -> jax.Array:
+    """Each row's logit at its (global) label from row-sharded logits:
+    (B,) f32. The label's column lives on exactly one shard: a take
+    there, 0 elsewhere, summed over `axis_name`."""
+    v_local = local_logits.shape[-1]
+    local_labels = labels - _shard_offset(v_local, axis_name)
     in_range = (local_labels >= 0) & (local_labels < v_local)
     safe = jnp.clip(local_labels, 0, v_local - 1)
     label_logit_local = jnp.take_along_axis(
         local_logits, safe[:, None], axis=-1)[:, 0]
-    label_logit = jax.lax.psum(
-        jnp.where(in_range, label_logit_local, 0.0), axis_name)   # (B,)
-
-    return jnp.log(global_sumexp) + global_max - label_logit
+    return jax.lax.psum(
+        jnp.where(in_range, label_logit_local, 0.0), axis_name)
 
 
 def tp_log_softmax_at_topk(local_logits, axis_name: str):

@@ -32,6 +32,7 @@ from code2vec_tpu import obs
 from code2vec_tpu.obs import exporters as obs_exporters
 from code2vec_tpu.data.reader import EpochEnd
 from code2vec_tpu.ops import embed
+from code2vec_tpu.ops.head_ce import target_shards
 from code2vec_tpu.training.state import TrainState
 from code2vec_tpu.training.step import (
     async_collective_count, gathers_live_rows,
@@ -227,6 +228,13 @@ class Trainer:
             "collectives of the compiled train step that carry an "
             "asynchronous start (training/step.py "
             "async_collective_count), read once after the first step")
+        head_shards = target_shards(self.mesh)
+        reg.gauge(
+            "train_head_target_shards",
+            "chips that share the target rows of the train step's head "
+            "(ops/head_ce.py target_shards): every chip of a mesh that "
+            "shards the batch's rows and nothing else, else 1").set(
+                head_shards)
 
         batch_num = 0              # batches this run
         trace_active = False       # profiler trace in flight
@@ -536,6 +544,7 @@ class Trainer:
                         f"{disp.seconds:.2f}s (trace + compile, or a "
                         f"compile-cache load), ready after "
                         f"{first.seconds:.2f}s; {said_async}"
+                        f"head over {head_shards} target shard(s); "
                         f"batch {tuple(arrays[0].shape)}: "
                         f"{shard_layout(arrays[0])}")
                     obs.log_compiles_from_now(log)

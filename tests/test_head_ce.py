@@ -213,23 +213,41 @@ def test_a_donated_state_gives_the_same_update():
                                    atol=1e-8)
 
 
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
 @pytest.mark.parametrize("form", ["plain", "kernel"])
+@pytest.mark.parametrize("columns", [300, 301, 1003])
+@pytest.mark.parametrize("chips", [2, 4, 8])
 def test_four_chips_over_the_rows_give_one_chips_loss_and_gradients(
-        monkeypatch, toy_tiles, form):
-    """GSPMD over a data mesh: rows sharded, the table whole on every
-    chip; pass B runs chip by chip under `shard_map` (the kernel is no
-    collective), and the table's gradient is summed over the chips."""
+        monkeypatch, toy_tiles, chips, columns, form, dtype):
+    """A data mesh of `chips`: the batch's rows arrive sharded and the
+    table whole on every chip, and the chips split the TARGET rows for
+    the head. What crosses them: every chip's code vectors, labels and
+    weights (an all-gather), the row max (`pmax`), the row sums, `E @
+    W` and the label's logit (`psum`s of `(B,)` and `(B, D)`), and in
+    the backward one all-gather of the finished shards of the table's
+    gradient; no table-shaped sum. The last chip's shard is ragged (301
+    and 1,003 rows leave 1 and 3 modulo 2, 4 and 8; 300 leaves 4 modulo
+    8) and holds the table's own padded rows (`real_rows < V`) and two
+    of the labels; every fifth row has weight 0. The code vectors'
+    cotangent comes back sharded over `data` as they went in."""
     if form == "kernel":
         monkeypatch.setattr(head_ce, "_exp_sums_on_a_chip", _interpreted)
     rng = np.random.default_rng(3)
+    real_rows = columns - 5
+    labels = rng.integers(0, real_rows, 32).astype(np.int32)
+    labels[[1, 30]] = real_rows - 1, real_rows - 2
     case = dict(
         x=rng.normal(size=(32, 128)).astype(np.float32),
-        table=(rng.normal(size=(300, 128)) * 0.2).astype(np.float32),
-        labels=rng.integers(0, 300, 32).astype(np.int32),
+        table=(rng.normal(size=(columns, 128)) * 0.2).astype(np.float32),
+        labels=labels,
         weights=np.where(np.arange(32) % 5 == 0, 0, 1 / 32).astype(
             np.float32))
-    want = _value_and_grads(_optax_form, case, 300, jnp.float32)
-    mesh = make_mesh(MeshPlan(dp=4, tp=1, cp=1))
+    shard = -(-columns // chips)
+    assert (real_rows - 2) // shard == chips - 1    # in the last shard
+    dtype = jnp.dtype(dtype)
+    want = _value_and_grads(_optax_form, case, real_rows, dtype)
+    mesh = make_mesh(MeshPlan(dp=chips, tp=1, cp=1))
+    assert head_ce.target_shards(mesh) == chips
     rows = NamedSharding(mesh, P(AXIS_DATA))
     placed = dict(
         x=jax.device_put(case["x"], NamedSharding(mesh, P(AXIS_DATA, None))),
@@ -237,9 +255,12 @@ def test_four_chips_over_the_rows_give_one_chips_loss_and_gradients(
         labels=jax.device_put(case["labels"], rows),
         weights=jax.device_put(case["weights"], rows))
     got = jax.jit(lambda c: _value_and_grads(
-        head_cross_entropy, c, 300, jnp.float32, mesh=mesh))(placed)
+        head_cross_entropy, c, real_rows, dtype, mesh=mesh))(placed)
     assert got[1][0].sharding.spec[0] == AXIS_DATA
-    _assert_close(got, want, jnp.float32)
+    assert got[1][1].sharding.is_fully_replicated
+    assert got[1][1].shape == case["table"].shape
+    assert not np.asarray(got[1][1])[real_rows:].any()
+    _assert_close(got, want, dtype)
 
 
 def test_a_mesh_that_shards_the_table_keeps_the_plain_ops(monkeypatch):

@@ -93,6 +93,75 @@ def test_no_full_logits_collective_in_tp_steps():
             f"collective:\n" + "\n".join(offending[:4]))
 
 
+# The data mesh's dense step (`--dp 4`): 16 rows over 4 chips, and a
+# target table whose 30 rows leave 2 modulo 4 (java14m's 261,245 leave
+# 1), so the chips' shards are 8 rows and the last holds 2 of filling.
+DP, DP_ROWS = 4, 16
+DP_DIMS = ModelDims(token_vocab_size=64, path_vocab_size=40,
+                    target_vocab_size=30, token_dim=16, path_dim=16)
+_RESULT_TYPE = re.compile(
+    r"= (.*?) (?:all-gather|all-reduce|reduce-scatter|all-to-all)"
+    r"(?:-start)?\(")
+
+
+def _dp_train_step_lowered(dp: int):
+    config = Config(train_data_path_prefix="unused",
+                    compute_dtype="bfloat16", dp=dp,
+                    train_batch_size=DP_ROWS, max_contexts=M)
+    mesh = make_mesh(MeshPlan(dp=dp, tp=1, cp=1)) if dp > 1 else None
+    module = Code2VecModule(dims=DP_DIMS, compute_dtype=jnp.bfloat16)
+    opt = make_optimizer(config)
+    state = create_train_state(module, opt, jax.random.PRNGKey(0),
+                               mesh=mesh, config=config)
+    rng = np.random.default_rng(0)
+    ids = lambda: rng.integers(0, 16, (DP_ROWS, M)).astype(np.int32)
+    arrays = device_put_batch(RowBatch(
+        source_token_indices=ids(), path_indices=ids(),
+        target_token_indices=ids(),
+        context_valid_mask=np.ones((DP_ROWS, M), np.float32),
+        target_index=rng.integers(1, 16, (DP_ROWS,)).astype(np.int32),
+        example_valid=np.ones((DP_ROWS,), bool)), mesh)
+    step = TrainStepBuilder(module, opt, config,
+                            mesh=mesh).make_train_step(state)
+    return step.lower(state, *arrays, jax.random.PRNGKey(1))
+
+
+def test_the_dp_steps_target_gradient_is_gathered_not_reduced():
+    """On a data mesh the chips split the head's target rows
+    (ops/head_ce.py): the target table's gradient is whole on its chip,
+    so no all-reduce carries an array of the table's shape, padded or
+    not, exactly one all-gather puts the shards together, in the compute
+    dtype (read in the lowered text: the CPU's compiler widens a
+    bfloat16 collective, the TPU's does not), and no collective moves a
+    `(B, V)` array of logits. The detector detects: the token table's
+    gradient IS all-reduced."""
+    step = _dp_train_step_lowered(DP)
+    text = step.compile().as_text()
+    rows, dim = DP_DIMS.target_vocab_size, DP_DIMS.code_dim
+    filled = -(-rows // DP) * DP
+    table = re.compile(rf"\[(?:{rows}|{filled}),{dim}\]")
+    results = [(m.group(1), ln) for ln in _collective_lines(text)
+               if (m := _RESULT_TYPE.search(ln))]
+    reduced = [r for r, ln in results if "all-reduce" in ln]
+    assert any(f"f32[{DP_DIMS.token_vocab_size},{DP_DIMS.token_dim}]" in r
+               for r in reduced), "the shape pattern is stale"
+    assert not [r for r in reduced if table.search(r)]
+    gathered = [r for r, ln in results
+                if "all-gather" in ln and table.search(r)]
+    assert len(gathered) == 1 and f"[{filled},{dim}]" in gathered[0], gathered
+    lowered = [ln for ln in step.as_text().splitlines()
+               if "all_gather" in ln and f"{filled}x{dim}x" in ln]
+    assert len(lowered) == 1 and lowered[0].rstrip().endswith(
+        f"-> tensor<{filled}x{dim}xbf16>"), lowered
+    logits = re.compile(rf"\[{DP_ROWS},(?:{rows}|{filled})\]")
+    assert not [r for r, _ in results if logits.search(r)]
+
+
+def test_the_one_device_step_holds_no_collective():
+    assert not _collective_lines(
+        _dp_train_step_lowered(1).compile().as_text())
+
+
 def test_sparse_step_exchanges_rows_not_tables():
     """(ii) Differential: the dense step's table-shaped gradient
     all-reduce disappears under use_sparse_embedding_update, replaced by
