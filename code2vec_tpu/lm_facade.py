@@ -23,7 +23,13 @@ device, where the file's `serve.context_cache` gives the slots. The
 cache is the module's own: a tuple with one entry a layer, each entry an
 array or a tuple of arrays (two kinds of state a token); the facade
 holds it, hands it to the steps, donates it to registration and counts
-its bytes, and reads nothing inside it. A
+its bytes, and reads nothing inside it. A module says what a
+slot IS: rows of the cache a token (the default), or with `CACHE_KIND =
+"state"` one state of fixed size whatever the context's length
+(models/retention_lm.py): registration then CARRIES the slot's state from
+chunk to chunk (a chunk that starts at 0 starts from zeros, so a reused
+slot never leaks what it held), `tokens_per_slot` is only the longest
+context admitted, and the gauges read slots held over slots. A
 context is registered once (`register_context`: chunk by chunk under
 one compiled shape, into a free or the least recently used slot,
 serving/context_cache.py), and a request may then name it: its tokens
@@ -55,7 +61,7 @@ from code2vec_tpu import obs
 from code2vec_tpu.config import Config
 from code2vec_tpu.model_facade import _H_FILL, _device_part, _stage
 from code2vec_tpu.models import (
-    hybrid_lm, latent_moe_lm, lm_common, sparse_gqa_moe_lm,
+    hybrid_lm, latent_moe_lm, lm_common, retention_lm, sparse_gqa_moe_lm,
 )
 from code2vec_tpu.ops.sparse_attn import unpack_bits
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
@@ -128,9 +134,22 @@ _C_KEYS_SELECTED = obs.counter(
     "keys the selection kept (the step's own count, fetched with the "
     "answer), summed over a scoring step's rows, real queries and layers")
 
+_C_STATES = obs.counter(
+    "retention_states_read_total",
+    "(row, layer) retention states a scoring step's rows read from "
+    "their contexts' cache slots")
+_C_STATE_BYTES = obs.counter(
+    "retention_state_bytes_read_total",
+    "bytes of the retention states a scoring step's rows read from the "
+    "cache, every layer (the states as held: float32)")
+_G_SLOT_BYTES = obs.gauge(
+    "state_cache_slot_bytes",
+    "bytes one context holds in a cache of fixed-size states, every "
+    "layer; 0 for a cache of token rows")
+
 # the configuration file's `model_type` -> the module that runs it
 MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm,
-                 "KeyeVL2": sparse_gqa_moe_lm}
+                 "KeyeVL2": sparse_gqa_moe_lm, "brumby": retention_lm}
 
 
 def selected_positions(words: np.ndarray, capacity: int, held: int
@@ -159,7 +178,8 @@ class ScoreResult(NamedTuple):
     probabilities: np.ndarray   # (top_k,) softmax over the rows held
     tokens: int
     routing_last: np.ndarray    # (expert layers, k): the router's choice
-    #                             at the last position
+    #                             at the last position; (0, 0) for a
+    #                             model with no expert layer, never absent
     context_tokens: int = 0     # tokens of the context read before them
     unknown_context: Optional[str] = None   # set INSTEAD of an answer:
     #                             the context went between the request's
@@ -204,11 +224,13 @@ class ScoringModel:
         self.top_k = int(config.top_k_words_considered_during_prediction)
         self._buckets = parse_buckets(
             serve.get("length_buckets", ()), self.token_budget)
+        experts = "no expert layer"
+        if getattr(self.lm, "n_routed_experts", 0):
+            experts = (f"experts [{self.lm.expert_first}, "
+                       f"{self.lm.expert_first + self.lm.experts_held}) of "
+                       f"{self.lm.n_routed_experts}")
         self.log(f"Creating scoring model from {config.model_config}: "
-                 f"pattern {self.lm.pattern}, experts "
-                 f"[{self.lm.expert_first}, "
-                 f"{self.lm.expert_first + self.lm.experts_held}) of "
-                 f"{self.lm.n_routed_experts}, vocabulary rows "
+                 f"pattern {self.lm.pattern}, {experts}, vocabulary rows "
                  f"{self.lm.vocab_rows} of {self.lm.vocab_size}")
         if config.is_loading:
             config.model_load_path = ckpt_mod.resolve_load_path(
@@ -231,12 +253,23 @@ class ScoringModel:
         self._predict_steps: Dict[Tuple[int, int], object] = {}
         self._fingerprint: Optional[str] = None
         self.contexts: Optional[ContextSlots] = None
+        self.state_cache = False    # a slot is one state, not token rows
         held = serve.get("context_cache")
         if held and hasattr(self.module, "init_cache"):
+            self.state_cache = getattr(self.module, "CACHE_KIND",
+                                       "tokens") == "state"
             self.contexts = ContextSlots(held["slots"],
-                                         held["tokens_per_slot"])
+                                         held["tokens_per_slot"],
+                                         fixed_size=self.state_cache)
             self.register_chunk = int(held["register_chunk"])
-            if self.contexts.capacity % self.register_chunk:
+            if self.state_cache:
+                most = self.lm.max_position_embeddings - self._buckets[-1]
+                if self.contexts.capacity > most:
+                    raise ValueError(
+                        f"{config.model_config}: tokens_per_slot admits "
+                        f"contexts past the model's positions less the "
+                        f"longest question ({most})")
+            elif self.contexts.capacity % self.register_chunk:
                 raise ValueError(
                     f"{config.model_config}: tokens_per_slot must be a "
                     f"multiple of register_chunk")
@@ -246,12 +279,25 @@ class ScoringModel:
             self._register_lock = threading.Lock()
             self._register_step = None
             self.served_endpoints = ("score", "contexts")
-            self.log(f"Context cache: {self.contexts.slots} slots x "
-                     f"{self.contexts.capacity} tokens x {self.lm.layers} "
-                     f"layers x {self.lm.cache_width} values = "
-                     f"{sum(a.nbytes for a in jax.tree.leaves(self.cache)):,}"
-                     f" bytes; "
-                     f"registration in chunks of {self.register_chunk}")
+            arrays = jax.tree.leaves(self.cache)
+            total = sum(a.nbytes for a in arrays)
+            # a slot's share of every array (its leading dimension counts
+            # the slots, and whatever spare ones the module keeps)
+            self.slot_bytes = sum(a.nbytes // a.shape[0] for a in arrays)
+            _G_SLOT_BYTES.set(self.slot_bytes if self.state_cache else 0)
+            if self.state_cache:
+                self.log(f"Context cache: {self.contexts.slots} slots, one "
+                         f"state of {self.slot_bytes:,} bytes a context "
+                         f"({self.lm.layers} layers; contexts of up to "
+                         f"{self.contexts.capacity} tokens) = {total:,} "
+                         f"bytes; registration in chunks of "
+                         f"{self.register_chunk}")
+            else:
+                self.log(f"Context cache: {self.contexts.slots} slots x "
+                         f"{self.contexts.capacity} tokens x "
+                         f"{self.lm.layers} layers x {self.lm.cache_width} "
+                         f"values = {total:,} bytes; registration in "
+                         f"chunks of {self.register_chunk}")
         self.log(f"Model created: {lm_common.count_leaves(specs):,} "
                  f"parameters; {self.describe_devices()}")
 
@@ -297,7 +343,8 @@ class ScoringModel:
             # goes, and has to be registered again
             with self._register_lock, self._cache_lock:
                 self.contexts = ContextSlots(self.contexts.slots,
-                                             self.contexts.capacity)
+                                             self.contexts.capacity,
+                                             self.contexts.fixed_size)
 
     def save(self, model_save_path: Optional[str] = None) -> str:
         path = ckpt_mod.save_params(
@@ -376,28 +423,6 @@ class ScoringModel:
                     *((self.cache,) if cached else ()), *on_device[2:])
                 del on_device   # the inputs go here, not between two parts
         return out, held, gone
-        with contextlib.ExitStack() as locked:
-            with _device_part("lookup"):
-                locked.enter_context(self._cache_lock)
-                slot = np.zeros((rows,), np.int32)
-                held = np.zeros((rows,), np.int32)
-                for i, context in enumerate(contexts):
-                    if context is None:
-                        continue
-                    found = self.contexts.lookup(context)
-                    if found is None:
-                        gone[i], lengths[i] = context, 0
-                    else:
-                        slot[i], held[i] = found
-            with _device_part("put"):
-                d_ids, d_lengths, d_slot, d_held = jax.device_put(
-                    (ids, lengths, slot, held))
-            with _device_part("enqueue"):
-                out = self._step(rows, length)(
-                    self.params, d_ids, d_lengths, self.cache, d_slot,
-                    d_held)
-                del d_ids, d_lengths, d_slot, d_held    # not between parts
-        return out, held, gone
 
     def warmup(self, rows: Optional[int] = None) -> None:
         """Compile and run every (rows, length) shape once (and the one
@@ -410,7 +435,8 @@ class ScoringModel:
             jax.block_until_ready(out.topk_values)
         if self.contexts is not None:
             # a chunk of no real token into slot 0: what it writes there
-            # lies behind the length of whatever the slot holds
+            # lies behind the length of whatever the slot holds (a state
+            # takes nothing from it and comes back as it was)
             self._register_chunk(np.zeros((self.register_chunk,), np.int32),
                                  0, 0, self.contexts.capacity
                                  - self.register_chunk)
@@ -468,7 +494,8 @@ class ScoringModel:
 
     def register_context(self, ids: Sequence[int]) -> Dict:
         """Run a context's tokens through the model once and keep their
-        latents in a cache slot. -> {"context": its id, "tokens",
+        latents (or the state behind them) in a cache slot. -> {"context":
+        its id, "tokens",
         "evicted": the id that lost the slot, or None, "held": whether
         it was there already}. Scoring steps run between the chunks."""
         if self.contexts is None:
@@ -539,12 +566,18 @@ class ScoringModel:
             with _device_part("fetch"):
                 values, indices, lse, stats = jax.device_get(answer)
         _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
-        if self.contexts is not None:
+        _C_UNKNOWN.inc(len(gone))
+        if self.state_cache:
+            # a row reads its context's state, the same bytes whatever
+            # the context's length: nothing here counts tokens
+            reading = int(((held > 0) & (lengths > 0)).sum())
+            _C_STATES.inc(reading * self.lm.layers)
+            _C_STATE_BYTES.inc(reading * self.slot_bytes)
+        elif self.contexts is not None:
             q = lengths.astype(np.int64)
             pairs = int((q * held + q * (q + 1) // 2).sum())
             _C_KEYS.inc(int((held + q).sum()))
             _C_PAIRS.inc(pairs)
-            _C_UNKNOWN.inc(len(gone))
             if stats.selected_keys is not None:
                 # every visible key of a query is index-scored, so the
                 # two counts are one number while the indexer prunes none
@@ -568,6 +601,9 @@ class ScoringModel:
 
     @staticmethod
     def _observe_router(stats) -> None:
+        """A model with no expert layer hands over arrays of zero
+        layers: nothing is observed and the `moe_*` series stay where
+        they were."""
         real = int(stats.real_tokens)
         for load, unserved in zip(stats.load, stats.unserved_tokens):
             total = int(load.sum())

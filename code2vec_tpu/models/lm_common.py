@@ -17,7 +17,8 @@ class Leaf(NamedTuple):
     name: str
     shape: Tuple[int, ...]
     dtype: str          # "bfloat16" for matrices, "float32" for the rest
-    init: str           # normal | ones | zeros | a_log | dt_bias | bias
+    init: str           # normal | ones | zeros | a_log | dt_bias | bias |
+    #                     gate_bias
 
 
 def layer_prefix(index: int) -> str:
@@ -46,7 +47,7 @@ def init_leaf(cfg, leaf: Leaf, key) -> jax.Array:
     projections and embeddings, `A` in [1, 16], `dt` log-uniform in
     [time_step_min, time_step_max] (floor time_step_floor) through the
     inverse softplus, ones for norms and `D`, a small non-zero
-    correction bias."""
+    correction bias, a retention gate's bias by the memory it gives."""
     dtype = jnp.dtype(leaf.dtype)
     if leaf.init == "normal":
         return (0.02 * jax.random.normal(key, leaf.shape, jnp.float32)
@@ -70,7 +71,19 @@ def init_leaf(cfg, leaf: Leaf, key) -> jax.Array:
         return dt + jnp.log(-jnp.expm1(-dt))        # inverse softplus
     if leaf.init == "bias":
         return 0.01 * jax.random.normal(key, leaf.shape, jnp.float32)
+    if leaf.init == "gate_bias":
+        return gate_bias(leaf.shape[0], *cfg.gate_memory_tokens)
     raise ValueError(f"unknown initializer {leaf.init!r}")
+
+
+def gate_bias(heads: int, low: float, high: float) -> jax.Array:
+    """The bias of a retention gate a key/value head: `sigmoid(b) = 1 -
+    1 / m` for memories `m` of `low` to `high` tokens, log-spaced over
+    the heads. At zero a gate would be one half and the state forget in
+    twenty tokens, every context answering alike; trained retention
+    heads remember thousands."""
+    memory = jnp.exp(jnp.linspace(jnp.log(low), jnp.log(high), heads))
+    return jnp.log(memory - 1.0).astype(jnp.float32)
 
 
 def init_leaves(cfg, specs: List[Leaf], seed: int) -> Dict[str, jax.Array]:

@@ -260,6 +260,17 @@ def _layer_norm(x: jax.Array, weight: jax.Array, bias: jax.Array,
     return (x - mean) * jax.lax.rsqrt(var + eps) * weight + bias
 
 
+def project_heads(u: jax.Array, w: jax.Array, norm: jax.Array, heads: int,
+                  head_dim: int, positions: jax.Array, theta: float,
+                  sections, eps: float) -> jax.Array:
+    """u (rows, l, hidden) -> (rows, l, heads, head_dim) float32: the
+    projection, an RMSNorm with a weight over each head, the rotary."""
+    rows, length, _ = u.shape
+    x = _matmul(u, w, F32).reshape(rows, length, heads, head_dim)
+    return sparse_attn.rotate(rms_norm(x, norm, eps), positions, theta,
+                              sections)
+
+
 def attention_block(cfg: LMConfig, p: Dict[str, jax.Array], u: jax.Array,
                     positions: jax.Array, cached: LayerCache,
                     slot: jax.Array, cached_len: jax.Array,
@@ -274,10 +285,9 @@ def attention_block(cfg: LMConfig, p: Dict[str, jax.Array], u: jax.Array,
     bf16 = jnp.bfloat16
     with jax.named_scope("gqa_proj"):
         def heads(w, norm, n):
-            x = _matmul(u, w, F32).reshape(rows, length, n, d)
-            return sparse_attn.rotate(
-                rms_norm(x, norm, cfg.norm_eps), positions, cfg.rope_theta,
-                cfg.mrope_section).astype(bf16)
+            return project_heads(u, w, norm, n, d, positions,
+                                 cfg.rope_theta, cfg.mrope_section,
+                                 cfg.norm_eps).astype(bf16)
         q = heads(p["wq"], p["q_norm"], hq)
         k = heads(p["wk"], p["k_norm"], hkv)
         v = _matmul(u, p["wv"]).reshape(rows, length, hkv, d)

@@ -1,10 +1,15 @@
 """Who holds which slot of a device-resident context cache.
 
-A cache of `slots` slots, each `capacity` tokens long, lives on the
-device (the model owns the arrays); this is the host's book of it: which
-context id sits in which slot with how many tokens, which slot a new
-context gets (a free one, else the least recently used), and what a
-request that names an id may read. It touches no array.
+A cache of `slots` slots lives on the device (the model owns the
+arrays); this is the host's book of it: which context id sits in which
+slot with how many tokens, which slot a new context gets (a free one,
+else the least recently used), and what a request that names an id may
+read. It touches no array. A slot is either `capacity` TOKENS long (a
+row of the cache a token) or, with `fixed_size`, one STATE whatever the
+tokens behind it: `capacity` is then only the longest context admitted,
+and the fill gauge reads slots held over slots (a share of token rows
+means nothing where a context of 8 and one of 32 thousand tokens take
+the same bytes).
 
 An id is the content's own hash, so registering the same tokens twice
 finds the slot already filled. A slot being filled belongs to no id: the
@@ -33,7 +38,8 @@ _G_TOKENS = obs.gauge(
     "latent_cache_tokens_held", "real tokens in the slots held")
 _G_FILL = obs.gauge(
     "latent_cache_fill_ratio",
-    "real tokens held over the capacity of all slots")
+    "real tokens held over the capacity of all slots; for a cache of "
+    "fixed-size states, slots held over slots")
 _C_EVICTED = obs.counter(
     "latent_cache_evictions_total",
     "contexts that lost their slot to a newer one (least recently used)")
@@ -55,11 +61,12 @@ def context_id(ids: np.ndarray) -> str:
 
 
 class ContextSlots:
-    def __init__(self, slots: int, capacity: int):
+    def __init__(self, slots: int, capacity: int, fixed_size: bool = False):
         if slots < 1 or capacity < 1:
             raise ValueError("a context cache needs at least one slot of at "
                              "least one token")
         self.slots, self.capacity = int(slots), int(capacity)
+        self.fixed_size = bool(fixed_size)
         self._lock = threading.Lock()
         self._held: "OrderedDict[str, Held]" = OrderedDict()  # oldest first
         self._free = list(range(self.slots - 1, -1, -1))
@@ -69,7 +76,8 @@ class ContextSlots:
         tokens = sum(h.tokens for h in self._held.values())
         _G_SLOTS.set(len(self._held))
         _G_TOKENS.set(tokens)
-        _G_FILL.set(tokens / (self.slots * self.capacity))
+        _G_FILL.set(len(self._held) / self.slots if self.fixed_size
+                    else tokens / (self.slots * self.capacity))
 
     def lookup(self, context: str) -> Optional[Held]:
         """The slot and length of a registered context, now the most

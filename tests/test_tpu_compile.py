@@ -76,3 +76,55 @@ def test_tiles_divide_the_published_widths():
     from code2vec_tpu.ops.moe import _tile
     assert (_tile(1024), _tile(2688), _tile(4096), _tile(100)) == (
         1024, 896, 1024, 0)
+
+
+@pytest.mark.parametrize("shape", ["16x16", "1x16", "register_2048"])
+def test_the_retention_models_steps_compile_at_published_widths(
+        one_chip, shape):
+    """The scoring step of a rerank burst (sixteen rows of 16 on sixteen
+    states), the one-row step and the 2,048-token registration chunk of
+    `brumby-14b-pp8` at its published widths, against the whole state
+    cache. Each program's temporaries stay under 2 GB: a `(16, 16, 40,
+    8256)` float32 `phi(Q)` is 0.34 GB, the sixteen rows' states copied
+    out of the cache 0.55 GB a LAYER (and hoisted, 2.7 GB), `phi` of
+    every query of a registration chunk at once 2.7 GB a layer."""
+    import json
+    import os
+    from code2vec_tpu.models import retention_lm as lm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "brumby-14b-pp8.json")) as f:
+        raw = json.load(f)
+    cfg = lm.LMConfig.from_dict(raw)
+    held = raw["serve"]["context_cache"]
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    params = {leaf.name: spec(leaf.shape, jnp.dtype(leaf.dtype))
+              for leaf in lm.leaf_specs(cfg)}
+    cache = tuple(spec(layer.shape, layer.dtype) for layer in jax.eval_shape(
+        lambda: lm.init_cache(cfg, held["slots"], held["tokens_per_slot"])))
+    assert cache[0].shape == (33, 8, 129, 8256)
+    scalar = spec((), jnp.int32)
+    if shape == "register_2048":
+        compiled = jax.jit(
+            lambda p, c, ids, n, slot, start: lm.ctx_register_step(
+                cfg, p, c, ids, n, slot, start), donate_argnums=(1,)).lower(
+            params, cache, spec((held["register_chunk"],), jnp.int32),
+            scalar, scalar, scalar).compile()
+    else:
+        rows, length = (int(n) for n in shape.split("x"))
+        compiled = jax.jit(
+            lambda p, ids, n, c, slot, at: lm.lm_score_step(
+                cfg, 10, 4096, p, ids, n, c, slot, at)).lower(
+            params, spec((rows, length), jnp.int32), spec((rows,), jnp.int32),
+            cache, spec((rows,), jnp.int32), spec((rows,), jnp.int32)
+        ).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 2e9, memory.temp_size_in_bytes
+    if shape == "register_2048":
+        # the donated cache is updated in place, not copied
+        assert memory.alias_size_in_bytes > 5.6e9
+    else:
+        # weights and cache are the program's arguments: 6.4 + 5.6 GB
+        assert 11.9e9 < memory.argument_size_in_bytes < 12.3e9
