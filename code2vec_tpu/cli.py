@@ -577,7 +577,7 @@ def arguments_parser() -> ArgumentParser:
                         default=None, metavar="ROWS",
                         help="target-table rows per block of the "
                              "blockwise top-k prediction head (default "
-                             "4096; 0 forces the classic full-logits "
+                             "16384; 0 forces the classic full-logits "
                              "materialization)")
     parser.add_argument("-fw", "--framework", dest="dl_framework",
                         choices=["jax", "tensorflow", "keras"], default="jax",

@@ -472,8 +472,12 @@ class Config:
     # twice). Indices/values are exactly the full path's (pinned in
     # tests/test_quant.py). Engages only when the target vocab exceeds
     # one block and the table is unsharded over `model` (tp == 1);
-    # 0 forces the classic full-logits path.
-    topk_block_size: int = 4096
+    # 0 forces the classic full-logits path. 16,384 since PR 40 (4,096
+    # until then): a trip's merge costs several times its matmul, so
+    # fewer, wider trips win (the head alone on a v5e, 64 rows: 2.13 ms
+    # at 4,096, 0.87 at 16,384; 1,024 rows: 20.2 -> 9.6 ms; PERF.md
+    # section 6), and 1,024 rows of live logits are 67 MB.
+    topk_block_size: int = 16384
 
     # -- release artifacts (code2vec_tpu/release; no reference
     # equivalent — the reference's --release only strips optimizer

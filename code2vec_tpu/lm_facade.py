@@ -59,11 +59,14 @@ import numpy as np
 
 from code2vec_tpu import obs
 from code2vec_tpu.config import Config
-from code2vec_tpu.model_facade import _H_FILL, _device_part, _stage
+from code2vec_tpu.model_facade import (
+    _H_FILL, _device_part, _stage, head_sorted_columns_gauge,
+)
 from code2vec_tpu.models import (
     hybrid_lm, latent_moe_lm, lm_common, retention_lm, sparse_gqa_moe_lm,
 )
 from code2vec_tpu.ops.sparse_attn import unpack_bits
+from code2vec_tpu.ops.topk import sorted_columns
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
 from code2vec_tpu.serving.context_cache import (
     ContextSlots, chunks, context_id,
@@ -368,6 +371,8 @@ class ScoringModel:
         if step is None:
             cfg, k, module = self.lm, self.top_k, self.module
             block = min(4096, cfg.vocab_rows)
+            head_sorted_columns_gauge("score").set(
+                sorted_columns(rows, block, min(k, cfg.vocab_rows)))
 
             if self.contexts is None:
                 def lm_score_step(params, ids, lengths):

@@ -106,6 +106,20 @@ def _head_dispatch_counter(head: str):
         "(head=exact|mips; batch-shape-aware dispatch)", head=head)
 
 
+def head_sorted_columns_gauge(step: str):
+    """Columns that enter a sort in one trip of a built step's top-k
+    head (ops/topk.py `sorted_columns`): which merge the static shapes
+    chose, set where the step is built. Label value dynamic, NAME a
+    literal, as above."""
+    return obs.gauge(
+        "head_topk_sorted_columns",
+        "columns that enter a sort in one trip of a built step's "
+        "blockwise top-k head (step=predict|eval|score): block + k with "
+        "the plain merge, block/g + k + k*g behind the exact group "
+        "prefilter, the whole row where the blockwise head is off",
+        step=step)
+
+
 class BucketedPredictMixin:
     """The bucketed predict path shared by the training facade and the
     release-artifact runtime (release/runtime.py): line parsing, context
@@ -612,6 +626,8 @@ class Code2VecModel(BucketedPredictMixin):
         # request shapes. len == compilations (each entry only ever sees
         # its one shape).
         self._predict_steps: Dict[Tuple[int, int], object] = {}
+        # (params, float32 target table, served params): _served_params
+        self._served: Optional[tuple] = None
         # Async checkpoint commit pipeline; created by _make_save_fn when
         # config.async_checkpointing, closed when training ends.
         self._committer: Optional[ckpt_mod.AsyncCommitter] = None
@@ -981,6 +997,9 @@ class Code2VecModel(BucketedPredictMixin):
     def _get_eval_step(self):
         if self._eval_step is None:
             self._eval_step = self.builder.make_eval_step(self.state)
+            head_sorted_columns_gauge("eval").set(
+                self.builder.eval_head_sorted_columns(
+                    self.config.test_batch_size))
         return self._eval_step
 
     def evaluate(self) -> Optional[ModelEvaluationResults]:
@@ -1067,7 +1086,19 @@ class Code2VecModel(BucketedPredictMixin):
             return jax.jit(step)
         # a FRESH jitted eval step per shape (BucketedPredictMixin): each
         # entry compiles exactly once for its one padded shape
+        head_sorted_columns_gauge("predict").set(
+            self.builder.eval_head_sorted_columns(batch_rows))
         return self.builder.make_eval_step(self.state)
+
+    def describe_head(self) -> str:
+        """The served head in a few words, for the server's start-up
+        line: which head answers and what its merge sorts a trip."""
+        if self._get_mips_topk() is not None:
+            return "head mips"
+        rows = int(self.config.serve_batch_size)
+        return (f"head exact, "
+                f"{self.builder.eval_head_sorted_columns(rows)} columns "
+                f"sorted a trip at {rows} rows")
 
     def _get_mips_topk(self):
         """The facade's lazily-built MIPS head closure, or None when the
@@ -1102,7 +1133,32 @@ class Code2VecModel(BucketedPredictMixin):
         return cached
 
     def _call_predict_step(self, step, arrays):
-        return step(self.state.params, *arrays)
+        return step(self._served_params(), *arrays)
+
+    def _served_params(self):
+        """The params a SERVED step reads: the live leaves, the target
+        table in the module's compute dtype. The head streams that table
+        block by block and casts each block; XLA hoists the cast onto
+        the whole table and, handed the float32 master, runs it in every
+        batch (0.91 of a 4.69 ms step at java14m's sizes, ops/topk.py).
+        Serving's weights change only when `self.state` does, so the
+        cast is made once per state: the copy is keyed on the identity
+        of the params and of the float32 leaf, and a load, a restore, a
+        training run or a swapped model is followed by the next call.
+        The same `astype` of the same values: every logit is bitwise
+        what the step fed the float32 table computes. The training-time
+        eval step (`_get_eval_step`) keeps the masters: they change
+        every step."""
+        live = self.state.params
+        table = live["target_embedding"]
+        dtype = self.module.compute_dtype
+        if table.dtype == dtype:
+            return live
+        held = self._served
+        if held is None or held[0] is not live or held[1] is not table:
+            held = self._served = (live, table, dict(
+                live, target_embedding=table.astype(dtype)))
+        return held[2]
 
     def eval_callable(self):
         """(eval_step, params) pair for callers that drive the eval step

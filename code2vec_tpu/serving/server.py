@@ -1479,7 +1479,9 @@ def serve_main(config, model=None, *, stop: Optional[threading.Event]
         # compile outlasts the default request deadline
         with obs.startup_phase("serve_warm") as warm:
             warmup()
-        config.log(f"Predict buckets warmed in {warm.seconds:.2f}s")
+        describe_head = getattr(model, "describe_head", None)
+        config.log(f"Predict buckets warmed in {warm.seconds:.2f}s"
+                   + (f" ({describe_head()})" if describe_head else ""))
     server.start()
     obs.log_compiles_from_now(config.log)
 

@@ -753,6 +753,20 @@ class TrainStepBuilder:
             return 0
         return block
 
+    def eval_head_sorted_columns(self, rows: int) -> int:
+        """Columns that enter a sort in one trip of the head of an
+        eval/served step of `rows` rows, a fact of the built step (gauge
+        `head_topk_sorted_columns`): the blockwise merge's count
+        (ops/topk.py `sorted_columns`), or the whole logit row where the
+        blockwise head is off."""
+        from code2vec_tpu.ops.topk import sorted_columns
+        dims = self.module.dims
+        k = min(self.config.top_k_words_considered_during_prediction,
+                dims.real_target_vocab_size)
+        block = self._eval_topk_block()
+        return (sorted_columns(rows, block, k) if block
+                else dims.target_vocab_size)
+
     def _make_gspmd_eval_step(self, example_state: TrainState, k: int) -> Callable:
         module = self.module
 

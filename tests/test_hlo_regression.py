@@ -22,6 +22,7 @@ import re
 
 import numpy as np
 import jax
+import pytest
 import jax.numpy as jnp
 
 from code2vec_tpu.config import Config
@@ -197,3 +198,67 @@ def test_sparse_step_exchanges_rows_not_tables():
     # dense moves no ids at all
     assert not [ln for ln in _collective_lines(dense_text)
                 if "all-gather" in ln and re.search(r"s32\[\d+\]", ln)]
+
+
+# ------------------------------------------------- the served head (PR 40)
+
+SERVE_ROWS, SERVE_K = 32, 10
+SERVE_DIMS = ModelDims(token_vocab_size=64, path_vocab_size=40,
+                       target_vocab_size=9000, token_dim=16, path_dim=16)
+
+
+def _eval_step_text(served: bool, group=None) -> str:
+    """The lowered eval step at a small vocabulary (three 4,096-row
+    blocks, the last one clamped), fed the float32 masters as training-
+    time eval feeds it, or the table in the compute dtype as the facade
+    serves it (`Code2VecModel._served_params`). Read in the LOWERED
+    text: the CPU's compiler widens every bfloat16 operand to float32
+    and narrows it again, the TPU's does not (tests/test_tpu_compile.py
+    reads the text compiled for the chip)."""
+    from code2vec_tpu.ops import topk
+    config = Config(train_data_path_prefix="unused",
+                    compute_dtype="bfloat16", topk_block_size=4096,
+                    test_batch_size=SERVE_ROWS,
+                    train_batch_size=SERVE_ROWS, max_contexts=M)
+    module = Code2VecModule(dims=SERVE_DIMS, compute_dtype=jnp.bfloat16)
+    opt = make_optimizer(config)
+    state = create_train_state(module, opt, jax.random.PRNGKey(0),
+                               mesh=None, config=config)
+    builder = TrainStepBuilder(module, opt, config, mesh=None)
+    assert builder._eval_topk_block() == 4096
+    params = state.params
+    if served:
+        params = dict(params, target_embedding=params[
+            "target_embedding"].astype(module.compute_dtype))
+    batch = (jnp.zeros((SERVE_ROWS, M), jnp.int32),) * 3 + (
+        jnp.ones((SERVE_ROWS, M), jnp.float32),
+        jnp.ones((SERVE_ROWS,), jnp.int32), jnp.ones((SERVE_ROWS,), bool))
+    with pytest.MonkeyPatch.context() as patch:
+        if group is not None:
+            patch.setattr(topk, "_GROUP", group)
+        return builder.make_eval_step(state).lower(params, *batch).as_text()
+
+
+def test_the_served_step_converts_no_table_and_sorts_no_whole_block():
+    """The served step is handed the target table in its compute dtype
+    (cast once per state, model_facade.py), so it holds no `convert`
+    that makes a bfloat16 array of the table's shape or of a block's;
+    the training-time eval step, fed the float32 masters that change
+    every step, still converts. Behind the exact group prefilter
+    (ops/topk.py) no array of `block + k` columns is left to sort; with
+    the prefilter off (a group of 0) the same step holds one: the
+    detector detects."""
+    from code2vec_tpu.ops.topk import _prefilter_group, sorted_columns
+    rows, dim = SERVE_DIMS.target_vocab_size, SERVE_DIMS.code_dim
+    table_cast = re.compile(
+        rf"convert .*tensor<(?:{rows}|4096)x{dim}xf32>\) -> "
+        rf"tensor<(?:{rows}|4096)x{dim}xbf16>")
+    whole_block = f"tensor<{SERVE_ROWS}x{4096 + SERVE_K}xf32>"
+    served = _eval_step_text(served=True)
+    assert not table_cast.search(served)
+    assert whole_block not in served
+    merged = (sorted_columns(SERVE_ROWS, 4096, SERVE_K)
+              - 4096 // _prefilter_group(SERVE_ROWS, 4096, SERVE_K))
+    assert f"tensor<{SERVE_ROWS}x{merged}xf32>" in served  # [running|chosen]
+    assert table_cast.search(_eval_step_text(served=False))
+    assert whole_block in _eval_step_text(served=True, group=0)

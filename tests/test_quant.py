@@ -27,45 +27,129 @@ pytestmark = pytest.mark.quant
 # ----------------------------------------------------- blockwise top-k
 
 
-@pytest.mark.parametrize("block", [1, 3, 7, 16, 64, 100, 1000])
-def test_blockwise_from_logits_matches_lax_top_k(block):
+def _assert_filters(rows, width, k, filters=True):
+    """The case runs the merge it is named for (ops/topk.py picks by the
+    static shapes): the exact group prefilter, or the plain merge."""
+    from code2vec_tpu.ops.topk import _prefilter_group
+    assert bool(_prefilter_group(rows, width, k)) == filters, (
+        rows, width, k)
+
+
+# (rows, vocab) beside each block: the small shapes run the plain merge
+# (a block under k x g columns), as do 1 and 16 rows at any width (too
+# few rows for a sort to outweigh the prefilter's small ops); from 32
+# rows up the 4,096-column blocks run the group prefilter, the last
+# (ragged) block of 8,200 the plain one behind it.
+@pytest.mark.parametrize("b,v,block,filters", [
+    (9, 97, 1, False), (9, 97, 3, False), (9, 97, 7, False),
+    (9, 97, 16, False), (9, 97, 64, False), (9, 97, 100, False),
+    (9, 97, 1000, False),
+    (1, 8192, 4096, False), (16, 8200, 4096, False),
+    (32, 8200, 4096, True), (64, 12288, 4096, True),
+    (64, 32768 + 5, 16384, True),
+])
+def test_blockwise_from_logits_matches_lax_top_k(b, v, block, filters):
     from code2vec_tpu.ops.topk import blockwise_top_k_from_logits
     rng = np.random.default_rng(0)
-    logits = jnp.asarray(rng.standard_normal((9, 97)), jnp.float32)
+    logits = jnp.asarray(rng.standard_normal((b, v)), jnp.float32)
     k = 10
+    _assert_filters(b, min(block, v), k, filters)
     fv, fi = jax.lax.top_k(logits, k)
     bv, bi = blockwise_top_k_from_logits(logits, k, block)
     np.testing.assert_array_equal(np.asarray(fi), np.asarray(bi))
     np.testing.assert_array_equal(np.asarray(fv), np.asarray(bv))
 
 
-@pytest.mark.parametrize("block", [2, 5, 16, 41])
-def test_blockwise_tie_breaking_matches(block):
-    """Ties everywhere: logits drawn from 4 distinct values, so every
-    top-k selection is decided by lax.top_k's lower-index-first rule —
-    the merge must reproduce it exactly."""
+def _tied_logits(kind, rng, shape):
+    if kind == "grid":
+        # ties everywhere: four distinct values
+        return rng.choice([-1.0, 0.0, 0.5, 2.0], size=shape)
+    if kind == "grid_inf":
+        # ... and whole stretches of -inf, so that groups tie at -inf
+        return rng.choice([-np.inf, -np.inf, -np.inf, 0.0, 0.5, 2.0],
+                          size=shape)
+    # "sparse": mostly one value, 0.4 % of the entries 1-3, so that
+    # groups with DIFFERENT maxima hold equal elements: a merge that
+    # visits the chosen groups in lax.top_k's value order (not in
+    # ascending id order) puts a later group's 1 ahead of an earlier
+    # group's 1 and answers with the higher index
+    x = np.zeros(shape)
+    hit = rng.random(shape) < 0.004
+    x[hit] = rng.integers(1, 4, int(hit.sum()))
+    return x
+
+
+@pytest.mark.parametrize("kind,b,v,block", [
+    ("grid", 6, 83, 2), ("grid", 6, 83, 5), ("grid", 6, 83, 16),
+    ("grid", 6, 83, 41),
+    ("grid", 32, 8192, 4096), ("grid_inf", 32, 8192, 4096),
+    ("sparse", 32, 8192, 4096), ("sparse", 40, 12288, 4096),
+    ("sparse", 6, 8192, 4096),      # few rows: the plain merge
+])
+def test_blockwise_tie_breaking_matches(kind, b, v, block):
+    """Ties everywhere: every top-k selection is decided by
+    lax.top_k's lower-index-first rule — the merge must reproduce it
+    exactly, through the group prefilter too (the 4,096 blocks at 32
+    rows and more)."""
     from code2vec_tpu.ops.topk import blockwise_top_k_from_logits
     rng = np.random.default_rng(1)
-    logits = jnp.asarray(
-        rng.choice([-1.0, 0.0, 0.5, 2.0], size=(6, 83)), jnp.float32)
-    for k in (1, 5, 64):
-        fv, fi = jax.lax.top_k(logits, k)
-        bv, bi = blockwise_top_k_from_logits(logits, k, block)
-        np.testing.assert_array_equal(np.asarray(fi), np.asarray(bi),
-                                      err_msg=f"k={k} block={block}")
-        np.testing.assert_array_equal(np.asarray(fv), np.asarray(bv))
+    for trial in range(1 if v < 100 else 3):
+        logits = jnp.asarray(_tied_logits(kind, rng, (b, v)), jnp.float32)
+        for k in (1, 5, 10, 64):
+            fv, fi = jax.lax.top_k(logits, k)
+            bv, bi = blockwise_top_k_from_logits(logits, k, block)
+            np.testing.assert_array_equal(
+                np.asarray(fi), np.asarray(bi),
+                err_msg=f"k={k} block={block} trial={trial}")
+            np.testing.assert_array_equal(np.asarray(fv), np.asarray(bv))
+    if block == 4096:
+        _assert_filters(b, block, 10, filters=b >= 32)
+        _assert_filters(b, block, 64, filters=False)
 
 
-@pytest.mark.parametrize("v,block,k", [
-    (1000, 96, 10),     # clamped last block (1000 % 96 != 0)
-    (1000, 1024, 10),   # block > vocab: degenerates to one full block
-    (50, 8, 20),        # k larger than a block
-    (7, 3, 7),          # k == vocab
+def test_unsorted_group_ids_would_fail_the_sparsely_tied_case(monkeypatch):
+    """The detector detects: with the chosen groups left in lax.top_k's
+    VALUE order a later group's 1 stands before an earlier group's 1
+    among the candidates and the answer names the higher index first,
+    so the sort of the group ids in `_merge_top_k` is not removable."""
+    from code2vec_tpu.ops import topk
+    g = topk._prefilter_group(32, 4096, 10)
+    x = np.zeros((32, 8192), np.float32)
+    x[:, 7 * g + 5] = 3.0       # group 7 leads by its maximum ...
+    x[:, 7 * g + 9] = 1.0       # ... and holds a 1
+    x[:, 2 * g + 1] = 1.0       # group 2's maximum is an equal 1
+    logits = jnp.asarray(x)
+    fv, fi = jax.lax.top_k(logits, 10)
+    assert list(np.asarray(fi)[0, :3]) == [7 * g + 5, 2 * g + 1, 7 * g + 9]
+    bv, bi = topk.blockwise_top_k_from_logits(logits, 10, 4096)
+    np.testing.assert_array_equal(np.asarray(fi), np.asarray(bi))
+    monkeypatch.setattr(topk.jnp, "sort", lambda x, axis=-1: x)
+    bv, bi = topk.blockwise_top_k_from_logits(logits, 10, 4096)
+    np.testing.assert_array_equal(np.asarray(fv), np.asarray(bv))
+    assert list(np.asarray(bi)[0, :3]) == [7 * g + 5, 7 * g + 9, 2 * g + 1]
+
+
+@pytest.mark.parametrize("b,v,block,k,filters", [
+    (5, 1000, 96, 10, False),     # clamped last block (1000 % 96 != 0)
+    (5, 1000, 1024, 10, False),   # block > vocab: one full block
+    (5, 50, 8, 20, False),        # k larger than a block
+    (5, 7, 3, 7, False),          # k == vocab
+    # the served shapes: 64 rows behind the group prefilter, the last
+    # block clamped (9,000 % 4,096 != 0, as 261,245 % 4,096 and % 16,384)
+    (64, 9000, 4096, 10, True),
+    (64, 40000, 16384, 10, True),
+    # where the plain merge must stay: the token models' rows ...
+    (1, 9000, 4096, 10, False),
+    (16, 9000, 4096, 10, False),
+    # ... and where k x g is no small share of the block
+    (32, 9000, 4096, 100, False),  # a retrieval k
+    (32, 9000, 256, 10, False),    # a block under k x g columns
 ])
-def test_blockwise_matmul_matches_full(v, block, k):
+def test_blockwise_matmul_matches_full(b, v, block, k, filters):
     from code2vec_tpu.ops.topk import blockwise_matmul_top_k
+    _assert_filters(b, min(block, v), min(k, v), filters)
     rng = np.random.default_rng(2)
-    cv = jnp.asarray(rng.standard_normal((5, 24)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((b, 24)), jnp.float32)
     tbl = jnp.asarray(rng.standard_normal((v, 24)), jnp.float32)
     full = jnp.einsum("bd,vd->bv", cv, tbl,
                       preferred_element_type=jnp.float32)
@@ -85,44 +169,83 @@ def test_blockwise_matmul_matches_full(v, block, k):
                                rtol=1e-5)
 
 
-def test_blockwise_matmul_bf16_and_valid_rows():
+@pytest.mark.parametrize("b,v,real,block,k,filters", [
+    (4, 128, 119, 48, 8, False),
+    # the second block holds 4 live columns: every group of it but one
+    # is all -inf, fewer than k finite entries reach its merge
+    (32, 8192, 4100, 4096, 10, True),
+    # the clamped last block's already-visited prefix AND the padded
+    # classifier rows masked in one block
+    (32, 9000, 8990, 4096, 10, True),
+])
+def test_blockwise_matmul_bf16_and_valid_rows(b, v, real, block, k, filters):
     """bf16 compute parity with the full bf16 einsum, and padded
     classifier rows (valid_rows) never selected."""
     from code2vec_tpu.ops.topk import blockwise_matmul_top_k
+    _assert_filters(b, block, k, filters)
     rng = np.random.default_rng(3)
-    v, real = 128, 119
-    cv = jnp.asarray(rng.standard_normal((4, 16)), jnp.float32)
-    tbl = jnp.asarray(rng.standard_normal((v, 16)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((b, 16)), jnp.float32)
+    # a coarse grid: exact in bf16 and full of ties across the blocks
+    tbl = jnp.asarray(rng.integers(-2, 3, (v, 16)) if filters
+                      else rng.standard_normal((v, 16)), jnp.float32)
     full = jnp.einsum("bd,vd->bv", cv.astype(jnp.bfloat16),
                       tbl.astype(jnp.bfloat16),
                       preferred_element_type=jnp.float32)
     full = jnp.where(jnp.arange(v)[None, :] < real, full, -jnp.inf)
-    fv, fi = jax.lax.top_k(full, 8)
+    fv, fi = jax.lax.top_k(full, k)
     out = jax.jit(lambda c, t: blockwise_matmul_top_k(
-        c, t, 8, 48, valid_rows=real, compute_dtype=jnp.bfloat16))(cv, tbl)
+        c, t, k, block, valid_rows=real, compute_dtype=jnp.bfloat16))(cv, tbl)
     np.testing.assert_array_equal(np.asarray(fi), np.asarray(out.indices))
     np.testing.assert_array_equal(np.asarray(fv), np.asarray(out.values))
     assert int(np.asarray(out.indices).max()) < real
+    # the served copy of the table (cast once a state, model_facade.py)
+    # answers bitwise as the float32 master cast block by block
+    served = jax.jit(lambda c, t: blockwise_matmul_top_k(
+        c, t, k, block, valid_rows=real, compute_dtype=jnp.bfloat16))(
+            cv, tbl.astype(jnp.bfloat16))
+    for got, want in zip(served, out):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
 
-def test_blockwise_int8_scales_match_dequantized_full():
+@pytest.mark.parametrize("b,v,block,k,filters", [
+    (6, 300, 64, 7, False), (32, 9000, 4096, 10, True)])
+def test_blockwise_int8_scales_match_dequantized_full(b, v, block, k,
+                                                      filters):
     """The fused-dequant block matmul selects the same top-k as a full
     matmul against the explicitly dequantized table."""
     from code2vec_tpu.ops.quant import quantize_rows
     from code2vec_tpu.ops.topk import blockwise_matmul_top_k
+    _assert_filters(b, block, k, filters)
     rng = np.random.default_rng(4)
-    tbl = rng.standard_normal((300, 24)).astype(np.float32)
+    tbl = rng.standard_normal((v, 24)).astype(np.float32)
     q, s = quantize_rows(tbl)
     deq = q.astype(np.float32) * s
-    cv = jnp.asarray(rng.standard_normal((6, 24)), jnp.float32)
+    cv = jnp.asarray(rng.standard_normal((b, 24)), jnp.float32)
     full = jnp.einsum("bd,vd->bv", cv, jnp.asarray(deq),
                       preferred_element_type=jnp.float32)
-    fv, fi = jax.lax.top_k(full, 7)
+    fv, fi = jax.lax.top_k(full, k)
     out = jax.jit(lambda c, t, sc: blockwise_matmul_top_k(
-        c, t, 7, 64, scales=sc))(cv, jnp.asarray(q), jnp.asarray(s))
+        c, t, k, block, scales=sc))(cv, jnp.asarray(q), jnp.asarray(s))
     np.testing.assert_array_equal(np.asarray(fi), np.asarray(out.indices))
     np.testing.assert_allclose(np.asarray(fv), np.asarray(out.values),
                                rtol=1e-6)
+
+
+@pytest.mark.parametrize("rows,width,k,columns", [
+    (64, 16384, 10, 128 + 10 + 1280),   # the served step at the defaults
+    (1024, 16384, 10, 1418),            # the training-time eval step
+    (64, 4096, 10, 32 + 10 + 1280),     # `--topk_block 4096`
+    (1, 4096, 10, 4106),        # a token model's one-row step ...
+    (16, 4096, 10, 4106),       # ... and a rerank burst's sixteen rows
+    (64, 4096, 100, 4196),      # retrieval's k: k x g is most of the block
+    (64, 256, 10, 266),         # a block under k x g columns
+    (64, 4100, 10, 4110),       # no whole number of groups
+    (64, 97, 10, 107),          # a tiny vocabulary
+])
+def test_merge_form_follows_the_static_shapes(rows, width, k, columns):
+    """What `head_topk_sorted_columns` says of a built step."""
+    from code2vec_tpu.ops.topk import sorted_columns
+    assert sorted_columns(rows, width, k) == columns
 
 
 def test_gathered_label_logits_match_full_column():

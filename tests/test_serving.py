@@ -834,6 +834,98 @@ def test_predict_accepts_lazy_iterable(served_model):
             b.attention_per_context.keys()
 
 
+def _served_against_masters(model, lines):
+    """One predict batch as SERVED (the params `_call_predict_step`
+    hands the step) beside a fresh step fed the float32 masters on the
+    same device arrays -> ((indices, values), (indices, values))."""
+    seen = []
+    real = model._call_predict_step
+
+    def spy(step, arrays):
+        seen.append(arrays)
+        out = real(step, arrays)
+        seen.append(out)
+        return out
+    model._call_predict_step = spy
+    try:
+        model.predict(lines, batch_size=4)
+    finally:
+        del model._call_predict_step
+    arrays, out = seen
+    fed = model.builder.make_eval_step(model.state)(
+        model.state.params, *arrays)
+    return tuple((np.asarray(o.topk_indices), np.asarray(o.topk_values))
+                 for o in (out, fed))
+
+
+def test_the_served_tables_cast_copy_follows_the_weights(tmp_path):
+    """The served step reads the target table in the compute dtype, cast
+    ONCE per state (model_facade.py `_served_params`), and the copy
+    follows the weights: after `--load`, after a restore into a live
+    model and after a hot-swap the served top-k is bitwise that of a
+    step fed the float32 table, and no stale copy answers."""
+    import jax.numpy as jnp
+    from code2vec_tpu.model_facade import Code2VecModel
+    from code2vec_tpu.serving.server import PredictionServer
+    from code2vec_tpu.serving.swap import SwapManager
+    from code2vec_tpu.training import checkpoint as ckpt_mod
+    _write_synthetic_dataset(tmp_path)
+
+    def build(**kw):
+        return Code2VecModel(_serving_config(
+            tmp_path, compute_dtype="bfloat16", topk_block_size=2, **kw))
+    lines = ["name|alpha " + " ".join(["tok0,p0,tok0", "tok1,p2,tok1"]),
+             "name|beta " + " ".join(["tok3,p1,tok3"] * 3)]
+
+    def same(got, want):
+        return all(np.array_equal(g, w) for g, w in zip(got, want))
+
+    a, c = build(seed=1), build(seed=2)
+    assert a.builder._eval_topk_block() == 2          # the blockwise head
+    served_a, masters_a = _served_against_masters(a, lines)
+    assert same(served_a, masters_a)
+    held = a._served_params()
+    assert held is a._served_params()                 # cast once
+    assert held["target_embedding"].dtype == jnp.bfloat16
+    assert a.state.params["target_embedding"].dtype == jnp.float32
+    assert held["transform"] is a.state.params["transform"]
+    served_c, masters_c = _served_against_masters(c, lines)
+    assert same(served_c, masters_c) and not same(served_c, served_a)
+
+    # --load: a fresh model restored from a's checkpoint answers as a
+    path_a, path_c = a.save(str(tmp_path / "a")), c.save(str(tmp_path / "c"))
+    b = build(seed=3, model_load_path=path_a)
+    served_b, masters_b = _served_against_masters(b, lines)
+    assert same(served_b, masters_b) and same(served_b, served_a)
+
+    # a restore into the LIVE model: the next call casts the new table
+    stale = b._served_params()
+    b.state = ckpt_mod.load_model(path_c, b.state, config=b.config,
+                                  params_only=True)
+    served_b, masters_b = _served_against_masters(b, lines)
+    assert same(served_b, masters_b) and same(served_b, served_c)
+    assert b._served_params() is not stale
+
+    # hot-swap: the server's next batch reads the NEW model's copy, the
+    # old model keeps its own
+    srv = PredictionServer(a, a.config, log=lambda m: None)
+    srv.swap = SwapManager(srv, build_model=lambda target: c)
+    srv.swap.request_reload("weights-c")
+    deadline = time.time() + 30
+    while srv.swap.status()["state"] not in ("ready", "failed"):
+        assert time.time() < deadline
+        time.sleep(0.02)
+    assert srv.swap.status()["state"] == "ready" and srv.model is c
+    served_now, masters_now = _served_against_masters(srv.model, lines)
+    assert same(served_now, masters_now) and same(served_now, served_c)
+    assert same(_served_against_masters(a, lines)[0], served_a)
+    # which merge the built step took, as the operator reads it
+    assert obs.gauge("head_topk_sorted_columns", step="predict").value \
+        == a.builder.eval_head_sorted_columns(4) == 2 + min(
+            10, a.dims.real_target_vocab_size)
+    assert "columns sorted a trip" in a.describe_head()
+
+
 # ------------------------------------------------------------- http
 
 

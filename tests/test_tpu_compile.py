@@ -72,6 +72,43 @@ def test_the_heads_pass_b_kernel_compiles_at_published_widths(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("rows,vocab,dim,dtype,block", [
+    (64, 261245, 384, "bfloat16", 16384),   # java14m.serve_open's step
+    (64, 261245, 384, "bfloat16", 4096),    # ... under `--topk_block 4096`
+    (1, 151936, 2048, "float32", 4096),     # a token model's one-row step
+    (16, 151936, 5120, "float32", 4096),    # a rerank burst's sixteen rows
+])
+def test_the_blockwise_head_compiles_at_the_cells_shapes(
+        one_chip, rows, vocab, dim, dtype, block):
+    """The served head (ops/topk.py) at the cells' shapes, k 10, its
+    table already in the compute dtype as the facade and the token
+    models hand it over. At 64 rows the chip's compiler takes the group
+    prefilter's reshape, gathers and two short sorts, and the compiled
+    text holds no array of `block + k` columns (the whole block is
+    never sorted); at the token models' one and sixteen rows the plain
+    merge stays and the array is there. In neither is a `convert` that
+    makes a table-shaped array: nothing that does not depend on the
+    batch is left in the batch's program."""
+    import re
+    from code2vec_tpu.ops.topk import blockwise_matmul_top_k, sorted_columns
+    dtype = jnp.dtype(dtype)
+
+    def head(vectors, table):
+        with jax.default_matmul_precision(
+                "highest" if dtype == jnp.float32 else "default"):
+            return blockwise_matmul_top_k(
+                vectors, table, 10, block, valid_rows=vocab - 3,
+                compute_dtype=dtype)
+    text = jax.jit(head).lower(
+        jax.ShapeDtypeStruct((rows, dim), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((vocab, dim), dtype, sharding=one_chip)
+    ).compile().as_text()
+    filtered = sorted_columns(rows, block, 10) < (block + 10) // 2
+    assert filtered == (rows >= 32)
+    assert (f"[{rows},{block + 10}]" in text) != filtered
+    assert not re.search(rf"\[{vocab},{dim}\]\S* convert\(", text)
+
+
 def test_tiles_divide_the_published_widths():
     from code2vec_tpu.ops.moe import _tile
     assert (_tile(1024), _tile(2688), _tile(4096), _tile(100)) == (
