@@ -64,12 +64,13 @@ from code2vec_tpu.model_facade import (
 )
 from code2vec_tpu.models import (
     hybrid_lm, latent_moe_lm, lm_common, retention_lm, sparse_gqa_moe_lm,
+    window_moe_lm,
 )
 from code2vec_tpu.ops.sparse_attn import unpack_bits
 from code2vec_tpu.ops.topk import sorted_columns
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
 from code2vec_tpu.serving.context_cache import (
-    ContextSlots, chunks, context_id,
+    ContextSlots, PoolTooSmall, chunks, context_id,
 )
 from code2vec_tpu.training import checkpoint as ckpt_mod
 from code2vec_tpu.utils.device import describe_devices
@@ -150,9 +151,39 @@ _G_SLOT_BYTES = obs.gauge(
     "bytes one context holds in a cache of fixed-size states, every "
     "layer; 0 for a cache of token rows")
 
+_C_WINDOW_KEYS = obs.counter(
+    "score_window_keys_read_total",
+    "cached keys of the rings that a scoring step's rows may see (a "
+    "row's context tokens, at most the window less one), times the "
+    "window layers")
+_C_FULL_KEYS = obs.counter(
+    "score_full_keys_read_total",
+    "cached keys of the pages that a scoring step's rows may see (a "
+    "row's context tokens), times the full layers")
+_C_PAGES_NEEDED = obs.counter(
+    "score_pages_needed_total",
+    "pages a scoring step's rows hold, each row its own, times the full "
+    "layers")
+_C_PAGES_VISITED = obs.counter(
+    "score_pages_visited_total",
+    "pages a scoring step's loop walked: its real rows times the longest "
+    "page list among them, times the full layers (a row rides the "
+    "longest row's trips)")
+
 # the configuration file's `model_type` -> the module that runs it
 MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm,
-                 "KeyeVL2": sparse_gqa_moe_lm, "brumby": retention_lm}
+                 "KeyeVL2": sparse_gqa_moe_lm, "brumby": retention_lm,
+                 "afmoe": window_moe_lm}
+# what a module's `CACHE_KIND` may say a context's slot is
+CACHE_KINDS = ("tokens", "state", "paged")
+
+
+def cache_kind(module) -> str:
+    kind = getattr(module, "CACHE_KIND", "tokens")
+    if kind not in CACHE_KINDS:
+        raise ValueError(f"{module.__name__}: CACHE_KIND {kind!r} is none "
+                         f"of {', '.join(CACHE_KINDS)}")
+    return kind
 
 
 def selected_positions(words: np.ndarray, capacity: int, held: int
@@ -257,27 +288,40 @@ class ScoringModel:
         self._fingerprint: Optional[str] = None
         self.contexts: Optional[ContextSlots] = None
         self.state_cache = False    # a slot is one state, not token rows
+        self.paged_cache = False    # a ring slot and a list of pages
         held = serve.get("context_cache")
         if held and hasattr(self.module, "init_cache"):
-            self.state_cache = getattr(self.module, "CACHE_KIND",
-                                       "tokens") == "state"
-            self.contexts = ContextSlots(held["slots"],
-                                         held["tokens_per_slot"],
-                                         fixed_size=self.state_cache)
+            kind = cache_kind(self.module)
+            self.state_cache = kind == "state"
+            self.paged_cache = kind == "paged"
             self.register_chunk = int(held["register_chunk"])
-            if self.state_cache:
+            self.contexts = ContextSlots(
+                held["slots"], held["tokens_per_slot"],
+                fixed_size=self.state_cache,
+                pages=held["pages"] if self.paged_cache else 0,
+                page_tokens=self.register_chunk)
+            if self.state_cache or self.paged_cache:
                 most = self.lm.max_position_embeddings - self._buckets[-1]
                 if self.contexts.capacity > most:
                     raise ValueError(
                         f"{config.model_config}: tokens_per_slot admits "
                         f"contexts past the model's positions less the "
                         f"longest question ({most})")
-            elif self.contexts.capacity % self.register_chunk:
+            if (not self.state_cache
+                    and self.contexts.capacity % self.register_chunk):
                 raise ValueError(
                     f"{config.model_config}: tokens_per_slot must be a "
                     f"multiple of register_chunk")
-            self.cache = self.module.init_cache(
-                self.lm, self.contexts.slots, self.contexts.capacity)
+            if self.paged_cache:
+                # a row's page list is as long as the longest context
+                self.list_pages = self.contexts.pages_for(
+                    self.contexts.capacity)
+                self.cache = self.module.init_cache(
+                    self.lm, self.contexts.slots, self.contexts.pages,
+                    self.register_chunk)
+            else:
+                self.cache = self.module.init_cache(
+                    self.lm, self.contexts.slots, self.contexts.capacity)
             self._cache_lock = threading.Lock()
             self._register_lock = threading.Lock()
             self._register_step = None
@@ -288,7 +332,20 @@ class ScoringModel:
             # the slots, and whatever spare ones the module keeps)
             self.slot_bytes = sum(a.nbytes // a.shape[0] for a in arrays)
             _G_SLOT_BYTES.set(self.slot_bytes if self.state_cache else 0)
-            if self.state_cache:
+            if self.paged_cache:
+                rings = sum(a.nbytes for a in arrays
+                            if a.shape[0] == self.contexts.slots)
+                self.log(f"Context cache: {self.contexts.slots} ring slots "
+                         f"of {self.register_chunk} tokens "
+                         f"({rings:,} bytes) and a pool of "
+                         f"{self.contexts.pages} pages of "
+                         f"{self.register_chunk} tokens "
+                         f"({total - rings:,} bytes), pattern "
+                         f"{self.lm.pattern}; contexts of up to "
+                         f"{self.contexts.capacity} tokens "
+                         f"({self.list_pages} pages); registration in "
+                         f"chunks of {self.register_chunk}")
+            elif self.state_cache:
                 self.log(f"Context cache: {self.contexts.slots} slots, one "
                          f"state of {self.slot_bytes:,} bytes a context "
                          f"({self.lm.layers} layers; contexts of up to "
@@ -345,9 +402,7 @@ class ScoringModel:
             # latents of the old weights answer nothing: every context
             # goes, and has to be registered again
             with self._register_lock, self._cache_lock:
-                self.contexts = ContextSlots(self.contexts.slots,
-                                             self.contexts.capacity,
-                                             self.contexts.fixed_size)
+                self.contexts = self.contexts.fresh()
 
     def save(self, model_save_path: Optional[str] = None) -> str:
         path = ckpt_mod.save_params(
@@ -380,9 +435,12 @@ class ScoringModel:
                                                 lengths)
                 step = jax.jit(lm_score_step)
             else:
-                def ctx_score_step(params, ids, lengths, cache, slot, held):
+                # `more`: a paged cache's page lists, a row
+                def ctx_score_step(params, ids, lengths, cache, slot, held,
+                                   *more):
                     return module.lm_score_step(cfg, k, block, params, ids,
-                                                lengths, cache, slot, held)
+                                                lengths, cache, slot, held,
+                                                *more)
                 step = jax.jit(ctx_score_step)
             self._predict_steps[key] = step
             self.log(f"Compiling scoring step for shape (rows={rows}, "
@@ -409,19 +467,25 @@ class ScoringModel:
                     locked.enter_context(self._cache_lock)
                     slot = np.zeros((rows,), np.int32)
                     held = np.zeros((rows,), np.int32)
+                    more = ()
+                    if self.paged_cache:
+                        more = (np.zeros((rows, self.list_pages), np.int32),)
                     for i, context in enumerate(contexts):
                         if context is None:
                             continue
                         found = self.contexts.lookup(context)
                         if found is None:
                             gone[i], lengths[i] = context, 0
-                        else:
-                            slot[i], held[i] = found
+                            continue
+                        slot[i], held[i] = found.slot, found.tokens
+                        if self.paged_cache:
+                            more[0][i, :len(found.pages)] = found.pages
             with _device_part("put"):
                 if not cached:
                     held = np.zeros((rows,), np.int32)
                 on_device = jax.device_put(
-                    (ids, lengths, slot, held) if cached else (ids, lengths))
+                    (ids, lengths, slot, held) + more if cached
+                    else (ids, lengths))
             with _device_part("enqueue"):
                 out = self._step(rows, length)(
                     self.params, *on_device[:2],
@@ -442,9 +506,13 @@ class ScoringModel:
             # a chunk of no real token into slot 0: what it writes there
             # lies behind the length of whatever the slot holds (a state
             # takes nothing from it and comes back as it was)
-            self._register_chunk(np.zeros((self.register_chunk,), np.int32),
-                                 0, 0, self.contexts.capacity
-                                 - self.register_chunk)
+            # (a paged cache keeps what lies past a chunk's real tokens:
+            # a chunk of none writes nothing)
+            self._register_chunk(
+                np.zeros((self.register_chunk,), np.int32), 0, 0,
+                0 if self.paged_cache
+                else self.contexts.capacity - self.register_chunk,
+                () if self.paged_cache else None)
 
     def _token_ids(self, ids: Sequence[int], most: int) -> np.ndarray:
         try:
@@ -481,20 +549,27 @@ class ScoringModel:
     # ----------------------------------------------------------- contexts
 
     def _register_chunk(self, ids: np.ndarray, real: int, slot: int,
-                        start: int) -> None:
+                        start: int, pages: Optional[Sequence[int]] = None
+                        ) -> None:
+        """`pages`: with a paged cache, the context's page list."""
         if self._register_step is None:
             cfg, module = self.lm, self.module
 
-            def ctx_register_step(params, cache, ids, length, slot, start):
+            def ctx_register_step(params, cache, ids, length, slot, start,
+                                  *more):
                 return module.ctx_register_step(cfg, params, cache, ids,
-                                                length, slot, start)
+                                                length, slot, start, *more)
             self._register_step = jax.jit(ctx_register_step,
                                           donate_argnums=(1,))
+        more = ()
+        if pages is not None:
+            more = (np.zeros((self.list_pages,), np.int32),)
+            more[0][:len(pages)] = pages
         with obs.span("context.register.chunk", hist=_H_REGISTER_CHUNK):
             with self._cache_lock:
                 self.cache = self._register_step(
                     self.params, self.cache, ids, np.int32(real),
-                    np.int32(slot), np.int32(start))
+                    np.int32(slot), np.int32(start), *more)
             jax.block_until_ready(self.cache)
 
     def register_context(self, ids: Sequence[int]) -> Dict:
@@ -505,25 +580,42 @@ class ScoringModel:
         it was there already}. Scoring steps run between the chunks."""
         if self.contexts is None:
             raise ValueError("this model keeps no contexts")
-        arr = self._token_ids(ids, self.contexts.capacity)
+        try:
+            arr = self._token_ids(ids, self.contexts.capacity)
+        except ValueError as e:
+            if "ids must hold" not in str(e):
+                raise
+            limit = ("the model's positions less the longest question"
+                     if self.state_cache or self.paged_cache
+                     else "a cache slot's tokens")
+            raise ValueError(f"{e} (the longest context admitted: {limit})")
         context = context_id(arr)
         with self._register_lock, obs.span("context.register",
                                            hist=_H_REGISTER):
             if self.contexts.lookup(context) is not None:
                 return {"context": context, "tokens": int(arr.size),
                         "evicted": None, "held": True}
-            slot, evicted = self.contexts.acquire()
+            pages, also = None, {}
+            if self.paged_cache:
+                try:
+                    slot, pages, gone = self.contexts.acquire_pages(arr.size)
+                except PoolTooSmall as e:
+                    raise ValueError(f"{e} (the page pool)")
+                evicted = gone[0] if gone else None
+                also = {"evicted_contexts": gone}
+            else:
+                slot, evicted = self.contexts.acquire()
             try:
                 for start, real in chunks(arr.size, self.register_chunk):
                     part = np.zeros((self.register_chunk,), np.int32)
                     part[:real] = arr[start:start + real]
-                    self._register_chunk(part, real, slot, start)
+                    self._register_chunk(part, real, slot, start, pages)
             except BaseException:
-                self.contexts.release(slot)
+                self.contexts.release(slot, pages or ())
                 raise
-            self.contexts.commit(slot, context, arr.size)
+            self.contexts.commit(slot, context, arr.size, pages or ())
         return {"context": context, "tokens": int(arr.size),
-                "evicted": evicted, "held": False}
+                "evicted": evicted, "held": False, **also}
 
     def selected_positions(self, result: ScoreResult) -> List[np.ndarray]:
         """By layer, the positions of context ++ question that the
@@ -578,6 +670,8 @@ class ScoringModel:
             reading = int(((held > 0) & (lengths > 0)).sum())
             _C_STATES.inc(reading * self.lm.layers)
             _C_STATE_BYTES.inc(reading * self.slot_bytes)
+        elif self.paged_cache:
+            self._count_paged(held, lengths)
         elif self.contexts is not None:
             q = lengths.astype(np.int64)
             pairs = int((q * held + q * (q + 1) // 2).sum())
@@ -603,6 +697,22 @@ class ScoringModel:
                     None if stats.selected_last is None
                     else stats.selected_last[i]))
             return results
+
+    def _count_paged(self, held: np.ndarray, lengths: np.ndarray) -> None:
+        """What a step on a paged cache reads, from its rows' lengths:
+        the ring rows and the page tokens its real rows may see, the
+        pages they hold and the pages the full layers' loop walks (every
+        real row rides the longest list's trips)."""
+        window, full = self.lm.window_layers, self.lm.full_layers
+        seen = held[lengths > 0].astype(np.int64)
+        if not seen.size:
+            return
+        needed = -(-seen // self.register_chunk)
+        _C_WINDOW_KEYS.inc(int(np.minimum(
+            seen, self.register_chunk - 1).sum()) * window)
+        _C_FULL_KEYS.inc(int(seen.sum()) * full)
+        _C_PAGES_NEEDED.inc(int(needed.sum()) * full)
+        _C_PAGES_VISITED.inc(int(needed.max()) * seen.size * full)
 
     @staticmethod
     def _observe_router(stats) -> None:

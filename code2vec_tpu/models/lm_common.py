@@ -88,10 +88,13 @@ def gate_bias(heads: int, low: float, high: float) -> jax.Array:
 
 def init_leaves(cfg, specs: List[Leaf], seed: int) -> Dict[str, jax.Array]:
     """Leaf by leaf on the device, so that nothing larger than the
-    largest leaf exists beside the parameters."""
+    largest leaf exists beside the parameters. A leaf's name is no part
+    of its initializer, so leaves of one shape share one compiled
+    program."""
     root = jax.random.PRNGKey(seed)
     make = jax.jit(init_leaf, static_argnums=(0, 1))
-    return {leaf.name: make(cfg, leaf, jax.random.fold_in(root, i))
+    return {leaf.name: make(cfg, leaf._replace(name=""),
+                            jax.random.fold_in(root, i))
             for i, leaf in enumerate(specs)}
 
 

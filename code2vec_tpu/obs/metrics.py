@@ -78,6 +78,10 @@ class Counter:
         with self._lock:
             self._value += amount
 
+    def reset(self) -> None:
+        with self._lock:
+            self._value = 0.0
+
     @property
     def value(self) -> float:
         return self._value
@@ -104,6 +108,9 @@ class Gauge:
 
     def set_to_current_time(self) -> None:
         self.set(time.time())
+
+    def reset(self) -> None:
+        self.set(0.0)
 
     @property
     def value(self) -> float:
@@ -132,6 +139,12 @@ class Histogram:
             self._counts[i] += 1
             self._sum += value
             self._count += 1
+
+    def reset(self) -> None:
+        with self._lock:
+            self._counts = [0] * len(self._counts)
+            self._sum = 0.0
+            self._count = 0
 
     @property
     def count(self) -> int:
@@ -267,6 +280,18 @@ class MetricsRegistry:
                 else:
                     out.append((tag, float(child.value)))
         return out
+
+    def reset(self, prefix: str = "") -> None:
+        """Every series whose name starts with `prefix` back to what a
+        process starts with. The objects stay, so a handle taken at
+        import goes on recording; a scrape sees what a restart shows.
+        For a test that reads a series WHOLE and shares its process."""
+        with self._lock:
+            children = [child for name, f in self._families.items()
+                        if name.startswith(prefix)
+                        for child in f.children.values()]
+        for child in children:
+            child.reset()
 
     def collect(self) -> Dict[str, Dict[LabelsKey, object]]:
         """Raw {name: {labels_key: metric}} view (tests, debugging)."""

@@ -11,6 +11,15 @@ and the fill gauge reads slots held over slots (a share of token rows
 means nothing where a context of 8 and one of 32 thousand tokens take
 the same bytes).
 
+A book may also keep a POOL OF PAGES beside the slots (`pages` of
+`page_tokens` tokens each), for a model whose layers hold a context in
+two geometries (models/window_moe_lm.py): a slot is then the context's
+RING (the last tokens, in its window layers) and its
+`ceil(tokens / page_tokens)` pages hold every token (in its full
+layers). `acquire_pages` takes both at once and evicts least recently
+used contexts WHOLE, ring slot and pages, until the new one fits; a
+context that no eviction could make room for is refused, never cut.
+
 An id is the content's own hash, so registering the same tokens twice
 finds the slot already filled. A slot being filled belongs to no id: the
 id it held is gone the moment the slot is taken, and the new id appears
@@ -26,7 +35,7 @@ from __future__ import annotations
 import hashlib
 import threading
 from collections import OrderedDict
-from typing import Dict, NamedTuple, Optional, Sequence, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -47,11 +56,30 @@ _C_REGISTERED = obs.counter(
     "contexts_registered_total",
     "contexts whose registration filled a slot (a repeat of a held "
     "context fills none)")
+_G_PAGES = obs.gauge(
+    "page_pool_pages_held",
+    "pages of the full layers' pool that registered contexts hold")
+_G_PAGE_FILL = obs.gauge(
+    "page_pool_fill_ratio", "pages held over the pages of the pool")
+_G_RINGS = obs.gauge(
+    "window_ring_slots_held",
+    "ring slots of the window layers that hold a registered context")
 
 
 class Held(NamedTuple):
     slot: int
     tokens: int
+
+
+class HeldPages(NamedTuple):
+    """What a book with a page pool keeps of a context."""
+    slot: int                   # its ring slot
+    tokens: int
+    pages: Tuple[int, ...]      # page g holds tokens [g P, (g + 1) P)
+
+
+class PoolTooSmall(ValueError):
+    """A context needs more pages than the whole pool has."""
 
 
 def context_id(ids: np.ndarray) -> str:
@@ -61,21 +89,38 @@ def context_id(ids: np.ndarray) -> str:
 
 
 class ContextSlots:
-    def __init__(self, slots: int, capacity: int, fixed_size: bool = False):
+    def __init__(self, slots: int, capacity: int, fixed_size: bool = False,
+                 pages: int = 0, page_tokens: int = 0):
         if slots < 1 or capacity < 1:
             raise ValueError("a context cache needs at least one slot of at "
                              "least one token")
+        if pages and page_tokens < 1:
+            raise ValueError("a page pool needs pages of at least one token")
         self.slots, self.capacity = int(slots), int(capacity)
         self.fixed_size = bool(fixed_size)
+        self.pages, self.page_tokens = int(pages), int(page_tokens)
         self._lock = threading.Lock()
         self._held: "OrderedDict[str, Held]" = OrderedDict()  # oldest first
         self._free = list(range(self.slots - 1, -1, -1))
+        self._free_pages = list(range(self.pages - 1, -1, -1))
         self._publish()
+
+    def fresh(self) -> "ContextSlots":
+        """An empty book of the same geometry."""
+        return ContextSlots(self.slots, self.capacity, self.fixed_size,
+                            self.pages, self.page_tokens)
 
     def _publish(self) -> None:
         tokens = sum(h.tokens for h in self._held.values())
         _G_SLOTS.set(len(self._held))
         _G_TOKENS.set(tokens)
+        if self.pages:
+            held = self.pages - len(self._free_pages)
+            _G_PAGES.set(held)
+            _G_PAGE_FILL.set(held / self.pages)
+            _G_RINGS.set(len(self._held))
+            _G_FILL.set(held / self.pages)
+            return
         _G_FILL.set(len(self._held) / self.slots if self.fixed_size
                     else tokens / (self.slots * self.capacity))
 
@@ -102,17 +147,55 @@ class ContextSlots:
             self._publish()
             return held.slot, context
 
-    def commit(self, slot: int, context: str, tokens: int) -> None:
-        """`slot`, taken by `acquire`, now holds all of `context`."""
+    def pages_for(self, tokens: int) -> int:
+        return -(-int(tokens) // self.page_tokens)
+
+    def acquire_pages(self, tokens: int
+                      ) -> Tuple[int, Tuple[int, ...], List[str]]:
+        """A ring slot and the pages a context of `tokens` tokens needs,
+        taken at once: least recently used contexts are evicted whole,
+        here and now, until both are free. -> (slot, pages, evicted
+        ids). Raises PoolTooSmall for a context that an EMPTY pool could
+        not hold, LookupError when what is missing is being filled."""
+        need = self.pages_for(tokens)
+        if need > self.pages:
+            raise PoolTooSmall(
+                f"a context of {int(tokens)} tokens needs {need} pages of "
+                f"{self.page_tokens} tokens; the pool has {self.pages}")
         with self._lock:
-            self._held[context] = Held(slot, int(tokens))
+            evicted = []
+            while not self._free or len(self._free_pages) < need:
+                if not self._held:
+                    self._publish()
+                    raise LookupError("the ring slots or pages that are "
+                                      "missing are being filled")
+                context, held = self._held.popitem(last=False)
+                self._free.append(held.slot)
+                self._free_pages.extend(reversed(held.pages))
+                evicted.append(context)
+                _C_EVICTED.inc()
+            slot = self._free.pop()
+            pages = tuple(self._free_pages.pop() for _ in range(need))
+            self._publish()
+            return slot, pages, evicted
+
+    def commit(self, slot: int, context: str, tokens: int,
+               pages: Sequence[int] = ()) -> None:
+        """`slot` (and `pages`), taken by `acquire` (`acquire_pages`),
+        now hold all of `context`."""
+        with self._lock:
+            self._held[context] = (
+                HeldPages(slot, int(tokens), tuple(pages)) if self.pages
+                else Held(slot, int(tokens)))
             _C_REGISTERED.inc()
             self._publish()
 
-    def release(self, slot: int) -> None:
-        """A slot taken by `acquire` whose filling failed."""
+    def release(self, slot: int, pages: Sequence[int] = ()) -> None:
+        """A slot (and pages) taken by `acquire` whose filling failed."""
         with self._lock:
             self._free.append(slot)
+            self._free_pages.extend(reversed(tuple(pages)))
+            self._publish()
 
     def held(self) -> Dict[str, Held]:
         with self._lock:

@@ -55,3 +55,21 @@ def tiny_config(tmp_path):
         shuffle_buffer_size=8,
         seed=0,
     )
+
+
+@pytest.fixture(autouse=True, scope="module")
+def _router_series_from_zero_for_the_file_that_reads_them_whole(request):
+    """tests/benchmark/test_benchmark_lm.py holds the expert router's
+    series (`moe_*`) SINCE THE PROCESS STARTED to its own toy's four
+    experts a (step, layer), and reads exactly 4.0 alone. Under `--dist
+    loadfile` a worker runs many files in one process, files with more
+    tests first: tests/test_latent_moe_lm.py and test_sparse_gqa_moe_lm.py
+    serve toys of sixteen experts (13.9 and 13.0 hit a step and layer)
+    and are queued before it, so it fails (5.0 <= 4) whenever the
+    scheduler hands it to a worker that ran either; every new test file
+    moves that draw. That file may be edited only by a PR of kind
+    `benchmark`: until one makes it read the series over its own
+    rehearsal, it alone starts from zero. No other file's series move."""
+    if request.module.__name__.rpartition(".")[2] == "test_benchmark_lm":
+        from code2vec_tpu import obs
+        obs.default_registry().reset("moe_")

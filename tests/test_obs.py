@@ -59,6 +59,26 @@ def test_registration_is_idempotent_and_type_checked():
         reg.gauge("x_total")
 
 
+def test_reset_zeroes_the_series_of_a_prefix_and_keeps_the_handles():
+    reg = MetricsRegistry()
+    c, other = reg.counter("moe_x_total"), reg.counter("serving_x_total")
+    g = reg.gauge("moe_g", stage="a")
+    h = reg.histogram("moe_h", buckets=(1.0,))
+    for m in (c, other, g):
+        m.inc(3)
+    h.observe(0.5)
+    h.observe(2.0)
+    reg.reset("moe_")
+    assert (c.value, g.value, h.count, h.sum) == (0.0, 0.0, 0, 0.0)
+    assert h.cumulative_counts() == [0] and other.value == 3
+    c.inc()                 # the handle taken before still records
+    h.observe(0.5)
+    assert reg.counter("moe_x_total").value == 1
+    assert h.cumulative_counts() == [1] and h.count == 1
+    reg.reset()
+    assert other.value == 0 and c.value == 0
+
+
 def test_prometheus_render_format():
     reg = MetricsRegistry()
     reg.counter("req_total", "requests", method="get").inc(3)

@@ -426,11 +426,15 @@ def test_contexts_registered_in_chunks_are_scored_evicted_and_reused(served):
     for layer, was in zip(model.cache, before):
         np.testing.assert_array_equal(np.asarray(layer), was)
     # a third context takes the slot of the one not used last, from zeros
-    used_last = bodies[5]["context"]
+    # (eight threads posted the bodies at once, and which step ran last
+    # is the scheduler's: one more lookup, alone, says which was used last)
+    used_last = ids[1]
+    status, _ = _post(server, "score", {
+        "context": used_last, "ids": [1, 2, 3], "top_k": 4})
+    assert status == 200
     third_tokens = _tokens(33, 70)
     status, third = _post(server, "contexts", {"ids": third_tokens.tolist()})
-    assert status == 200 and third["evicted"] in ids
-    assert third["evicted"] != used_last
+    assert status == 200 and third["evicted"] == ids[0]
     status, answer = _post(server, "score", {
         "context": third["evicted"], "ids": [1, 2, 3], "top_k": 4})
     assert status == 404 and "evicted" in answer["error"]

@@ -165,3 +165,66 @@ def test_the_retention_models_steps_compile_at_published_widths(
     else:
         # weights and cache are the program's arguments: 6.4 + 5.6 GB
         assert 11.9e9 < memory.argument_size_in_bytes < 12.3e9
+
+
+@pytest.mark.parametrize("shape", ["1x128", "4x64", "register_2048"])
+def test_the_window_and_page_models_steps_compile_at_published_widths(
+        one_chip, shape):
+    """The one-row and the four-row scoring step, each row with a
+    32-page list (a 65,536-token context), and the 2,048-token
+    registration chunk of `trinity-mini-pp4` at its published widths,
+    against the rings and the whole page pool. Each program's
+    temporaries stay under 1.5 GB: a chunk's `(2048 queries, 32 heads,
+    65536 keys)` float32 scores at once would be 17 GB, one page's 0.54
+    GB, which is why the ops fold keys in blocks of 512 there."""
+    import json
+    import os
+    from code2vec_tpu.models import window_moe_lm as lm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "trinity-mini-pp4.json")) as f:
+        raw = json.load(f)
+    cfg = lm.LMConfig.from_dict(raw)
+    held = raw["serve"]["context_cache"]
+    chunk = held["register_chunk"]
+    listed = held["tokens_per_slot"] // chunk
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    params = {leaf.name: spec(leaf.shape, jnp.dtype(leaf.dtype))
+              for leaf in lm.leaf_specs(cfg)}
+    cache = tuple(spec(layer.shape, layer.dtype) for layer in jax.eval_shape(
+        lambda: lm.init_cache(cfg, held["slots"], held["pages"], chunk)))
+    assert listed == 32 and [c.shape[0] for c in cache] == [
+        20, 20, 20, 160, 20, 20, 20, 160]
+    scalar = spec((), jnp.int32)
+    if shape == "register_2048":
+        compiled = jax.jit(
+            lambda p, c, ids, n, slot, start, pages: lm.ctx_register_step(
+                cfg, p, c, ids, n, slot, start, pages),
+            donate_argnums=(1,)).lower(
+            params, cache, spec((chunk,), jnp.int32), scalar, scalar,
+            scalar, spec((listed,), jnp.int32)).compile()
+    else:
+        rows, length = (int(n) for n in shape.split("x"))
+        compiled = jax.jit(
+            lambda p, ids, n, c, slot, at, pages: lm.lm_score_step(
+                cfg, 10, 4096, p, ids, n, c, slot, at, pages)).lower(
+            params, spec((rows, length), jnp.int32), spec((rows,), jnp.int32),
+            cache, spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+            spec((rows, listed), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5e9, memory.temp_size_in_bytes
+    if shape == "register_2048":
+        # the donated rings and pool are updated in place, not copied
+        assert memory.alias_size_in_bytes > 1.8e9
+    else:
+        # weights, rings and pool are the program's arguments
+        assert 13.7e9 < memory.argument_size_in_bytes < 13.9e9
+        # and no array of the cache is re-laid: with a token a ROW of
+        # the pool the four-row step copied each full layer's 671 MB
+        # (0.70 GB of temporaries; ops/window_attn.py)
+        assert memory.temp_size_in_bytes < 0.2e9, memory.temp_size_in_bytes
+        assert "copy(" not in "".join(
+            line for line in compiled.as_text().splitlines()
+            if "bf16[160,1024,2048]" in line.split("=")[0])
