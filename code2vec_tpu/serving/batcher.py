@@ -9,16 +9,33 @@ would recompile per distinct (rows, contexts) pair. So:
 
 - Requests (groups of extracted method lines) enqueue; a single
   dispatcher thread runs one model call over the coalesced rows.
-  THE DISPATCH RULE, which both batchers of this module follow: a
-  dispatcher that is free and has a live request pending cuts a batch
-  at once. The model call in flight is the only batching window: what
+  THE DISPATCH RULE: a dispatcher that is free and has a live request
+  pending cuts a batch at once UNLESS the server reports requests EN
+  ROUTE (seen by the server, not yet submitted here: the `en_route`
+  callable `DynamicBatcher` is given) and the batch still has room.
+  Then it waits on the batcher's condition until no request is en
+  route, or the batch is full (the row cap, `_fits` under
+  `max_batch_tokens`), or a ceiling has passed since the oldest pending
+  request was submitted, whichever comes first, and cuts. The ceiling
+  is read from what the batcher observes: half the pending bucket's
+  tracked device time (a wait longer than the step it saves can never
+  pay, and a bucket's cheapest step, one row, is a third to a half of
+  its fullest; a tracker still cold means no wait at all), and never
+  more than `GATHER_CAP_S` (below, with the chip measurement that chose
+  it). So a burst whose requests reach the server together rides ONE
+  step, and a lone request on an idle server still finds nothing at the
+  door and waits for nothing. The model call
+  in flight stays the batching window behind a busy dispatcher: what
   arrives behind it piles up and is cut together (inside the row cap
   and the token budget) the moment it returns, so batches grow with the
-  backlog by themselves and a lone request on an idle server waits for
-  nothing. `serving_batch_cut_idle_ratio` says, once a dispatched
-  batch, which of the two it was: 1 when the batch's oldest request
-  was submitted with no call in flight and none ran before it was cut,
-  0 when it was cut behind a call.
+  backlog by themselves. Without `en_route` (standalone construction)
+  and in `ContinuousBatcher`, which runs in no cell of the benchmark,
+  a free dispatcher always cuts at once. Two histograms say, once a
+  dispatched batch, what happened: `serving_batch_cut_idle_ratio` 1
+  when the batch's oldest request was submitted with no call in flight
+  and none ran before it was cut, 0 when it was cut behind a call;
+  `serving_batch_gathered_ratio` 1 when the dispatcher waited for
+  requests en route before this cut (the span `serve.delay`), else 0.
 - The model call itself buckets the context axis (model_facade.predict
   `context_buckets`): rows are padded to the smallest configured bucket
   that fits their deepest valid context, so the number of compiled
@@ -73,8 +90,10 @@ _H_DEVICE = obs.histogram(
     "one coalesced model call: parse + pad + device step + unpack")
 _DISPATCHER_HELP = (
     "time a dispatcher thread spent in one state, observed on leaving "
-    "it: idle (nothing pending), delay (continuous batcher only: the "
-    "head slot is pending and a parse is still writing into it), "
+    "it: idle (nothing pending), delay (a request is pending and the "
+    "free dispatcher holds the cut: the dynamic batcher while the "
+    "server reports requests en route, the continuous batcher while a "
+    "parse is still writing into the head slot), "
     "dispatch (inside the coalesced model call and its fan-out). "
     "dispatch over wall time is the busy share of the thread every "
     "request passes through")
@@ -107,6 +126,33 @@ _H_CUT_IDLE = obs.histogram(
     "was cut behind a model call. The mean is the share of batches "
     "that a fixed coalescing delay would have held back",
     buckets=(0.0, 1.0))
+_H_GATHERED = obs.histogram(
+    "serving_batch_gathered_ratio",
+    "one observation a dispatched batch: 1 when the free dispatcher "
+    "waited for requests the server reported en route before it cut "
+    "this batch, else 0. The mean is the share of batches the gather "
+    "engaged for",
+    buckets=(0.0, 1.0))
+
+# The most a free dispatcher waits for requests en route, counted from
+# the submit of the oldest pending request: this share of the pending
+# bucket's tracked device time, and never more than the cap. What chose
+# them (my chip runs, PR 42, `brumby-14b-pp8.serve_score_rerank_burst`,
+# one warm server, 10 s windows of 60 bursts of sixteen, two machines):
+# the server takes a burst in at 0.7-0.8 ms a request (accept, thread,
+# headers, JSON, admission: serial under the interpreter lock), so the
+# sixteenth is submitted 11.3-12.7 ms after the first in the median,
+# 13.2-14.5 ms at the 90th percentile. Under a ceiling of 4 ms a step
+# was cut with 7-8 rows and the median request moved by nothing (60.9-
+# 61.7 ms against 60.0-61.3 with no gather), at 10-12 ms with 12-15 rows
+# and a second step of 1-4 (61.2-63.4 against 65.7-67.8 on that
+# machine), at 16 ms 41-43 of 49 bursts of the 16 bucket rode ONE step
+# (58.2-60.3, the slowest request of a burst 69.4-69.6 against 73.6-
+# 86.9); 24 ms read no better (62.8). A quarter of the tracked step
+# (40 ms: ~10 ms) cuts the burst short; half of it is above the cap in
+# every token cell and 3-4 ms in `java14m.serve_open`.
+GATHER_STEP_SHARE = 0.5
+GATHER_CAP_S = 0.016
 
 
 def _cut_idle(items, t_free: float, in_flight: int = 0) -> float:
@@ -219,6 +265,12 @@ class DynamicBatcher:
     order across per-tenant sub-queues (tenancy.dwrr_take) instead of
     global FIFO, so one tenant's backlog cannot monopolize a device
     batch; a single tenant (or no policy) keeps the exact FIFO path.
+
+    `en_route(within_s) -> int` is the server's count of requests it has
+    seen and not yet submitted, those seen in the last `within_s`
+    seconds: what a free dispatcher gathers before it cuts (the dispatch
+    rule, module docstring). The server calls `en_route_changed()` when
+    the count falls. None: a free dispatcher always cuts at once.
     """
 
     def __init__(self, predict_fn: Callable[[List[str]], List],
@@ -226,8 +278,12 @@ class DynamicBatcher:
                  buckets: Optional[Sequence[int]] = None,
                  tenancy=None,
                  bucket_of: Optional[Callable[[object], int]] = None,
-                 max_batch_tokens: Optional[int] = None):
+                 max_batch_tokens: Optional[int] = None,
+                 en_route: Optional[Callable[[float], int]] = None):
         self.predict_fn = predict_fn
+        self._en_route = en_route
+        self._gathering = False     # the dispatcher is inside a gather
+        self._gathered = False      # ... and was, before the last cut
         self.max_batch_rows = max(1, int(max_batch_rows))
         self.tenancy = tenancy
         self._dwrr_state: dict = {}
@@ -308,7 +364,13 @@ class DynamicBatcher:
                 return item.future
             self._pending.append(item)
             self._pending_rows += len(item.lines)
-            self._cond.notify_all()
+            # A gathering dispatcher wakes for a full batch, for the
+            # server's word that nobody is en route any more
+            # (`en_route_changed`) and at its ceiling: a wake-up a
+            # submit costs every request of a burst a hand-over of the
+            # interpreter lock on the path the gather waits for.
+            if not self._gathering or self._full_locked():
+                self._cond.notify_all()
         return item.future
 
     def rebucket(self, buckets: Optional[Sequence[int]]) -> None:
@@ -327,6 +389,15 @@ class DynamicBatcher:
             self._cond.notify_all()
         self._thread.join(timeout)
 
+    def en_route_changed(self) -> None:
+        """The server's count of requests en route fell to nothing: a
+        dispatcher that is gathering looks again at once. (`_gathering`
+        is set under the lock BEFORE the dispatcher reads the count, so
+        a fall it did not see finds the flag up.)"""
+        if self._gathering:
+            with self._cond:
+                self._cond.notify_all()
+
     # -------------------------------------------------------- dispatcher
 
     def _run(self) -> None:
@@ -339,15 +410,17 @@ class DynamicBatcher:
             self._t_free = time.perf_counter()
 
     def _collect(self) -> Optional[List[_Pending]]:
-        """Block until a live request is pending and cut a batch at
-        once (the dispatch rule, module docstring): this thread being
-        here means no model call is in flight. Expired items are
-        settled as 504 here, before they can occupy a device slot;
-        draining flushes what is pending and then ends the thread."""
+        """Block until a live request is pending, gather what the
+        server reports en route, and cut a batch (the dispatch rule,
+        module docstring): this thread being here means no model call
+        is in flight. Expired items are settled as 504 here, before
+        they can occupy a device slot; draining flushes what is pending
+        and then ends the thread."""
         with self._cond:
             while True:
                 if self._pending:
                     self._expire_locked()
+                    self._gathered = self._gather_locked()
                     if self._pending:
                         return self._take_locked()
                 elif self._draining:
@@ -356,6 +429,54 @@ class DynamicBatcher:
                 else:
                     with _state("idle"):
                         self._cond.wait()
+
+    def _gather_locked(self) -> bool:
+        """Hold the cut while `_gather_left_locked` says so, in the
+        dispatcher state `delay`; True when it waited. What expires
+        meanwhile is settled before the cut, as behind a call."""
+        if self._en_route is None:
+            return False
+        self._gathering = True
+        try:
+            left = self._gather_left_locked()
+            if left <= 0.0:
+                return False
+            with _state("delay"):
+                while left > 0.0:
+                    self._cond.wait(left)
+                    self._expire_locked()
+                    left = self._gather_left_locked()
+            return True
+        finally:
+            self._gathering = False
+
+    def _gather_left_locked(self) -> float:
+        """Seconds a free dispatcher may still wait before it cuts; 0
+        when nothing is pending or en route, the batch is full, the
+        batcher drains, the pending bucket's device time is not tracked
+        yet, or the ceiling has passed."""
+        if not self._pending or self._draining or self._full_locked():
+            return 0.0
+        step = self.device_times.p95(self._deepest_locked())
+        if step is None:
+            return 0.0
+        ceiling = min(GATHER_CAP_S, step * GATHER_STEP_SHARE)
+        left = self._pending[0].t_submit + ceiling - time.perf_counter()
+        if left <= 0.0 or self._en_route(ceiling) <= 0:
+            return 0.0
+        return left
+
+    def _deepest_locked(self) -> Optional[int]:
+        """The deepest bucket pending: the shape a cut now would run."""
+        return max((i.bucket for i in self._pending
+                    if i.bucket is not None), default=None)
+
+    def _full_locked(self) -> bool:
+        """No room for one more row: by the row cap, or by the token
+        budget at the deepest bucket pending."""
+        return (self._pending_rows >= self.max_batch_rows
+                or not self._fits(self._pending_rows + 1,
+                                  self._deepest_locked() or 0))
 
     def _expire_locked(self) -> None:
         alive: List[_Pending] = []
@@ -433,6 +554,7 @@ class DynamicBatcher:
             all_lines.extend(item.lines)
         _C_BATCHES.inc()
         _H_CUT_IDLE.observe(_cut_idle(batch, self._t_free))
+        _H_GATHERED.observe(float(self._gathered))
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
         _H_BATCH_ROWS.observe(len(all_lines))
@@ -906,6 +1028,7 @@ class ContinuousBatcher:
         rows_live = sum(len(i.lines) for i in live)
         _C_BATCHES.inc()
         _H_CUT_IDLE.observe(slot.cut_idle)
+        _H_GATHERED.observe(0.0)    # this batcher never gathers
         self.batches_dispatched += 1
         batch_id = self.batches_dispatched
         _H_BATCH_ROWS.observe(rows_live)

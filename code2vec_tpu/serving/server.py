@@ -84,13 +84,14 @@ from __future__ import annotations
 import http.server
 import json
 import os
+import select
 import signal
 import socket
 import socketserver
 import threading
 import time
 from concurrent.futures import TimeoutError as _FutureTimeout
-from typing import Dict, Optional, Tuple
+from typing import Callable, Dict, Optional, Tuple
 
 import numpy as np
 
@@ -168,6 +169,10 @@ _REQUESTS_HELP = "HTTP requests by endpoint and outcome"
 def _requests_counter(endpoint: str, status: str):
     return obs.counter("serving_requests_total", _REQUESTS_HELP,
                        endpoint=endpoint, status=status)
+
+
+def _nothing() -> None:
+    """`handle_request`'s `arrived` for callers that keep no count."""
 
 
 class _HTTPError(Exception):
@@ -259,6 +264,10 @@ class PredictionServer:
         # ids and starts no extractor.
         self.endpoints = tuple(getattr(
             model, "served_endpoints", ("predict", "embed", "neighbors")))
+        # ... and those whose requests end in the batcher (/contexts is
+        # the model's own: it interleaves its chunks itself)
+        self.batched_endpoints = tuple(
+            e for e in self.endpoints if e != "contexts")
         self.pool = None
         if getattr(model, "uses_extractor", True):
             self.pool = ExtractorPool(
@@ -281,6 +290,16 @@ class PredictionServer:
         # rows are not extractor lines (batcher.DynamicBatcher)
         own = getattr(model, "batcher_options", dict)()
         batcher_kw.update(own)
+        # Requests EN ROUTE to the batcher (its dispatch rule gathers
+        # them before a free dispatcher cuts): a connection from the
+        # instant the listener hands it to a handler thread, or from its
+        # next request line where it is kept alive, until the request is
+        # submitted, enters the extractor pool (extraction takes
+        # milliseconds: not "about to arrive") or ends any other way.
+        # Keyed by the connection, valued by when the request was seen.
+        self._en_route: Dict[object, float] = {}
+        self._en_route_lock = threading.Lock()
+        self._en_route_within_s = 0.0   # the horizon the batcher asks with
         if getattr(self.config, "serve_continuous", False) and not own:
             # --serve_continuous: slot-reservation dispatcher + the
             # zero-copy parse-into-slot path (batcher.ContinuousBatcher)
@@ -290,8 +309,13 @@ class PredictionServer:
                                        "serve_inflight_steps", 2),
                 backend=_ContinuousBackend(self), **batcher_kw)
         else:
-            self.batcher = DynamicBatcher(self._batched_predict,
-                                          **batcher_kw)
+            self.batcher = DynamicBatcher(
+                self._batched_predict,
+                en_route=self.requests_en_route, **batcher_kw)
+        # whom the last request to leave the count tells (the continuous
+        # batcher never gathers)
+        self._nobody_en_route = getattr(self.batcher, "en_route_changed",
+                                        _nothing)
         self.cache = PredictionCache(self.config.serve_cache_entries)
         self.topk = self.config.top_k_words_considered_during_prediction
         # Live-traffic sample for the continuous-training pipeline's
@@ -436,16 +460,63 @@ class PredictionServer:
         self.device_breaker.record(ok=True)
         return [(r, fp) for r in results]
 
+    # --------------------------------------------------------- en route
+
+    def _en_route_enter(self, conn) -> None:
+        with self._en_route_lock:
+            self._en_route[conn] = time.perf_counter()
+
+    def _en_route_leave(self, conn) -> None:
+        """Idempotent. When the last one leaves, a gathering dispatcher
+        is told so and looks again at once (by the horizon it last
+        asked with)."""
+        with self._en_route_lock:
+            if self._en_route.pop(conn, None) is None:
+                return
+            left = self._en_route_fresh_locked(self._en_route_within_s)
+        if not left:
+            self._nobody_en_route()
+
+    def _en_route_fresh_locked(self, within_s: float) -> int:
+        horizon = time.perf_counter() - within_s
+        return sum(seen >= horizon for seen in self._en_route.values())
+
+    def requests_en_route(self, within_s: float) -> int:
+        """Requests seen in the last `within_s` seconds and not yet
+        submitted to the batcher: its `en_route` signal. An older entry
+        counts for nothing (a connection that sends nothing, a probing
+        or pooled client, holds no dispatcher)."""
+        with self._en_route_lock:
+            self._en_route_within_s = within_s
+            n = self._en_route_fresh_locked(within_s)
+        if n or self._httpd is None:
+            return n
+        # A connection the kernel holds for the listener is en route
+        # too: a handler thread runs its request through to the submit
+        # before `serve_forever` gets the interpreter back to accept the
+        # next one, so a burst's later requests are not in the count yet
+        # when its first is pending (PERF.md section 6, PR 42).
+        try:
+            return len(select.select([self._httpd.socket], [], [], 0)[0])
+        except (OSError, ValueError):   # the listener is closed
+            return 0
+
     # ---------------------------------------------------------- predict
 
     def handle_request(self, endpoint: str, code: str,
                        deadline: Optional[Deadline] = None,
                        params: Optional[Dict] = None,
                        trace: Optional[RequestTrace] = None,
-                       tenant: Optional[str] = None
+                       tenant: Optional[str] = None,
+                       arrived: Callable[[], None] = _nothing
                        ) -> Tuple[int, bytes, Dict[str, str]]:
         """Full serve path for one request -> (http_status, body,
-        extra_headers). EVERY terminal status lands in
+        extra_headers). `arrived()` (the HTTP handler's: the request
+        leaves the count of requests en route) is called once the
+        request is submitted to the batcher or enters the extractor
+        pool (a request that ends before either leaves with its
+        handler); in-process callers pass none. EVERY terminal status
+        lands in
         serving_request_seconds{phase=total,status=...} and
         serving_requests_total — overload and errors are measured, not
         invisible. Every request carries a trace (inbound `traceparent`
@@ -474,7 +545,7 @@ class PredictionServer:
         try:
             body = self._handle(endpoint, code, deadline, phases,
                                 params=params, trace=trace,
-                                tenant=tlabel, t0=t0)
+                                tenant=tlabel, t0=t0, arrived=arrived)
             status = 200
         except Shed as e:
             if tlabel is None:
@@ -583,7 +654,8 @@ class PredictionServer:
                 params: Optional[Dict] = None,
                 trace: Optional[RequestTrace] = None,
                 tenant: Optional[str] = None,
-                t0: Optional[float] = None) -> bytes:
+                t0: Optional[float] = None,
+                arrived: Callable[[], None] = _nothing) -> bytes:
         if t0 is None:
             t0 = time.perf_counter()
         if trace is None:
@@ -640,6 +712,7 @@ class PredictionServer:
             if endpoint == "score":
                 lines, hash_to_string = [score_request], {}
             else:
+                arrived()           # in the extractor pool: not en route
                 lines, hash_to_string = self._extract(
                     code, deadline, phases, trace=trace)
                 # the whole extractor call less the extraction itself:
@@ -653,6 +726,7 @@ class PredictionServer:
             future = self.batcher.submit(lines, phases=phases,
                                          deadline=deadline, trace=trace,
                                          tenant=tenant)
+            arrived()               # AFTER the submit: pending, then gone
             try:
                 if deadline is not None and deadline.bounded:
                     # Backstop: the batcher settles expired futures
@@ -1010,6 +1084,28 @@ class PredictionServer:
             def log_message(self, *args):  # per-request stderr silenced
                 pass
 
+            def parse_request(self):
+                """The request line and headers are read: a POST to an
+                endpoint that ends in the batcher is en route from here
+                (a kept-alive connection's next request: the earliest
+                the server sees of it); anything else never reaches the
+                batcher and leaves the count its connection entered."""
+                ok = super().parse_request()
+                if (ok and self.command == "POST"
+                        and self.path.partition("?")[0].lstrip("/")
+                        in server.batched_endpoints):
+                    server._en_route_enter(self.connection)
+                else:
+                    server._en_route_leave(self.connection)
+                return ok
+
+            def handle_one_request(self):
+                try:
+                    super().handle_one_request()
+                finally:
+                    # however the request ended: the count cannot leak
+                    server._en_route_leave(self.connection)
+
             def _respond(self, code: int, body: bytes,
                          ctype: str = "application/json",
                          extra_headers: Optional[Dict[str, str]] = None
@@ -1124,7 +1220,9 @@ class PredictionServer:
                     t_call = time.perf_counter()
                     status, body, headers = server.handle_request(
                         endpoint, code_text, deadline, params=params,
-                        trace=trace, tenant=tenant)
+                        trace=trace, tenant=tenant,
+                        arrived=lambda: server._en_route_leave(
+                            self.connection))
                     t_done = time.perf_counter()
                     if ("debug=trace" in query.split("&")
                             and server.config.serve_debug_trace):
@@ -1207,6 +1305,23 @@ class PredictionServer:
                         server.log(f"SO_REUSEPORT unavailable ({e}); "
                                    f"plain bind")
                 http.server.ThreadingHTTPServer.server_bind(self)
+
+            def process_request(self, request, client_address):
+                # the earliest the server sees of a request: most
+                # clients open one connection a request
+                server._en_route_enter(request)
+                try:
+                    super().process_request(request, client_address)
+                except BaseException:
+                    server._en_route_leave(request)
+                    raise
+
+            def process_request_thread(self, request, client_address):
+                try:
+                    super().process_request_thread(request,
+                                                   client_address)
+                finally:
+                    server._en_route_leave(request)
 
         httpd = _Listener(
             (host if host is not None else self.config.serve_host,

@@ -770,6 +770,206 @@ def test_cut_idle_ratio_tells_straight_through_from_behind_a_call(
         batcher.drain(timeout=10)
 
 
+# ------------- the rule's one exception: gathering what is en route
+
+
+class _Door:
+    """The server's count of requests en route, kept by hand: what a
+    `DynamicBatcher` is given as `en_route`. `arrive()` is a request
+    that was en route being submitted: the submit first, then the count
+    falls and the batcher is told, in the server's order."""
+
+    def __init__(self, n=0):
+        self.n = n
+        self.asked = []         # the horizons the dispatcher asked with
+
+    def __call__(self, within_s):
+        self.asked.append(within_s)
+        return self.n
+
+    def arrive(self, batcher, lines, **kwargs):
+        future = batcher.submit(lines, **kwargs)
+        self.n -= 1
+        batcher.en_route_changed()
+        return future
+
+
+def _gathering_batcher(monkeypatch, predict_fn, door, cap_s, step_s=None,
+                       **kwargs):
+    """A DynamicBatcher that is given the signal, under a cap of
+    `cap_s`, its tracker having seen `step_s` steps in every bucket (a
+    half of which is the ceiling where that is less than the cap);
+    `step_s=None` leaves the tracker cold."""
+    from code2vec_tpu.serving import batcher as batcher_mod
+    monkeypatch.setattr(batcher_mod, "GATHER_CAP_S", cap_s)
+    batcher = batcher_mod.DynamicBatcher(predict_fn, en_route=door,
+                                         **kwargs)
+    if step_s is not None:
+        for bucket in batcher.buckets or (None,):
+            for _ in range(batcher.device_times.MIN_SAMPLES):
+                batcher.device_times.record(bucket, step_s)
+    return batcher
+
+
+def test_requests_en_route_join_the_pending_one_in_one_call(monkeypatch):
+    """The server says three more are on their way: the free dispatcher
+    holds the first, the three join it as they are submitted, and ONE
+    model call takes the four rows in submit order."""
+    predict_fn = _HeldCall(lambda lines: list(lines))
+    predict_fn.release()
+    door = _Door(3)
+    batcher = _gathering_batcher(monkeypatch, predict_fn, door,
+                                 cap_s=10.0, step_s=80.0,
+                                 max_batch_rows=64)
+    try:
+        phases = {}
+        futures = [batcher.submit(["r0"], phases=phases)]
+        time.sleep(0.1)
+        assert predict_fn.calls == []           # held, not dispatched
+        for i in (1, 2, 3):
+            futures.append(door.arrive(batcher, [f"r{i}"]))
+        assert [f.result(timeout=10) for f in futures] \
+            == [["r0"], ["r1"], ["r2"], ["r3"]]
+        assert predict_fn.calls == [["r0", "r1", "r2", "r3"]]
+        # cut when the last was submitted, far under the 10 s ceiling
+        assert 0.1 <= phases["batch_wait"] < 5.0
+        assert set(door.asked) == {10.0}
+    finally:
+        batcher.drain(timeout=10)
+    assert batcher.batches_dispatched == 1
+
+
+@pytest.mark.parametrize("case", [
+    "nothing_en_route", "cold_tracker", "signal_stuck", "step_is_short",
+    "full_by_rows", "full_by_tokens", "draining"])
+def test_when_a_free_dispatcher_cuts_without_the_gather_running_out(
+        monkeypatch, case):
+    """The ends of a gather other than the last arrival: nothing at the
+    door or a tracker still cold (no wait at all), a signal that never
+    falls (the ceiling: the cap, or half the tracked step where that
+    is less), a batch that is full by the row cap or by the token
+    budget, and a drain (both at once, whatever the ceiling)."""
+    from code2vec_tpu.serving.batcher import bucket_for
+    predict_fn = _HeldCall(lambda lines: list(lines))
+    predict_fn.release()
+    kwargs, cap_s, step_s, n = dict(max_batch_rows=64), 10.0, 80.0, 1
+    sent, low, high = [["solo"]], 0.0, 0.05
+    if case == "nothing_en_route":
+        n = 0
+    elif case == "cold_tracker":
+        step_s = None
+    elif case == "signal_stuck":
+        cap_s, low, high = 0.2, 0.15, 2.0
+    elif case == "step_is_short":
+        step_s, low, high = 0.4, 0.15, 2.0      # half of it: 0.2 s
+    elif case == "full_by_rows":
+        kwargs, sent, high = dict(max_batch_rows=2), [["a"], ["b"]], 2.0
+    elif case == "full_by_tokens":
+        buckets = (128, 256)
+        kwargs = dict(max_batch_rows=64, buckets=buckets,
+                      max_batch_tokens=512,
+                      bucket_of=lambda r: bucket_for(len(r), buckets))
+        # 2 rows x the 256 bucket: a third would pass 512 tokens
+        sent, high = [["x" * 100], ["y" * 200]], 2.0
+    else:
+        high = 2.0
+    door = _Door(n)
+    batcher = _gathering_batcher(monkeypatch, predict_fn, door, cap_s,
+                                 step_s, **kwargs)
+    try:
+        phases = {}
+        t = time.perf_counter()
+        futures = [batcher.submit(lines, phases=phases) for lines in sent]
+        if case == "draining":
+            time.sleep(0.05)
+            assert predict_fn.calls == []       # gathering
+            batcher.drain(timeout=10)
+        assert [f.result(timeout=10) for f in futures] == sent
+        assert time.perf_counter() - t < high + 1.0
+        assert low <= phases["batch_wait"] < high
+    finally:
+        batcher.drain(timeout=10)
+    assert predict_fn.calls == [[line for lines in sent for line in lines]]
+    if case == "cold_tracker":
+        assert door.asked == []                 # not even asked
+    elif case in ("signal_stuck", "step_is_short"):
+        assert set(door.asked) == {0.2}         # the horizon IS the ceiling
+
+
+def test_request_expiring_during_a_gather_settles_504(monkeypatch):
+    """Deadlines are not weakened by a gather either: a request whose
+    budget runs out while the dispatcher waits for what is en route
+    settles as DeadlineExceeded before the cut and never reaches
+    `predict_fn`. (Its own bucket's steps are short, so its budget
+    passes admission; the ceiling is half the DEEPEST pending bucket's
+    step, here above the cap of 0.3 s.)"""
+    from code2vec_tpu.serving.admission import Deadline, DeadlineExceeded
+    from code2vec_tpu.serving.batcher import bucket_for
+    predict_fn = _HeldCall(lambda lines: list(lines))
+    predict_fn.release()
+    buckets = (128, 256)
+    batcher = _gathering_batcher(
+        monkeypatch, predict_fn, _Door(1), cap_s=0.3, max_batch_rows=8,
+        buckets=buckets, bucket_of=lambda r: bucket_for(len(r), buckets))
+    for bucket, step_s in ((128, 0.01), (256, 80.0)):
+        for _ in range(batcher.device_times.MIN_SAMPLES):
+            batcher.device_times.record(bucket, step_s)
+    try:
+        alive = batcher.submit(["y" * 200])
+        doomed = batcher.submit(["x" * 100], deadline=Deadline(0.05))
+        with pytest.raises(DeadlineExceeded):
+            doomed.result(timeout=10)
+        assert alive.result(timeout=10) == ["y" * 200]
+    finally:
+        batcher.drain(timeout=10)
+    assert predict_fn.calls == [["y" * 200]]
+
+
+def test_gathered_and_cut_idle_ratios_tell_the_three_cuts_apart(
+        monkeypatch):
+    """`serving_batch_gathered_ratio` beside
+    `serving_batch_cut_idle_ratio`, one observation each a dispatched
+    batch: (1, 1) gathered, (0, 1) straight through, (0, 0) behind a
+    call."""
+    from code2vec_tpu.obs.metrics import Histogram
+    from code2vec_tpu.serving import batcher as batcher_mod
+    cut_idle = Histogram(buckets=(0.0, 1.0))
+    gathered = Histogram(buckets=(0.0, 1.0))
+    monkeypatch.setattr(batcher_mod, "_H_CUT_IDLE", cut_idle)
+    monkeypatch.setattr(batcher_mod, "_H_GATHERED", gathered)
+
+    def seen():
+        return (gathered.sum, cut_idle.sum, gathered.count)
+
+    predict_fn = _HeldCall()
+    door = _Door(1)
+    batcher = _gathering_batcher(monkeypatch, predict_fn, door,
+                                 cap_s=10.0, step_s=80.0,
+                                 max_batch_rows=8)
+    try:
+        # gathered: held for the one en route, cut when it arrived; the
+        # call it rides is the held one
+        first = batcher.submit(["g0"])
+        time.sleep(0.05)
+        second = door.arrive(batcher, ["g1"])
+        assert predict_fn.entered.wait(10)
+        assert seen() == (1.0, 1.0, 1)
+        # behind that call: not gathered, not cut idle
+        behind = batcher.submit(["b0"])
+        predict_fn.release()
+        for f in (first, second, behind):
+            f.result(timeout=10)
+        assert predict_fn.calls == [["g0", "g1"], ["b0"]]
+        assert seen() == (1.0, 1.0, 2)
+        # straight through: free again, nothing at the door
+        time.sleep(0.05)
+        assert batcher.submit(["s0"]).result(timeout=10) == ["S0"]
+        assert seen() == (1.0, 2.0, 3)
+    finally:
+        predict_fn.release()
+        batcher.drain(timeout=10)
+
+
 def test_parse_buckets_and_bucket_for():
     from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
     assert parse_buckets("32,64,128", 200) == (32, 64, 128, 200)
@@ -1140,6 +1340,257 @@ def test_sigterm_drain_finishes_inflight(served_model, fake_extractor,
 
 
 # ---------------------------------------------------- request tracing
+
+
+# ---------------------------- the server's count of requests en route
+
+
+class _FakeScorer:
+    """A `--model_config` model in the manner of lm_facade.ScoringModel,
+    without arrays: `POST /score` rows reach the batcher straight from
+    the handler thread (no extractor), a step sleeps `step_s`."""
+
+    served_endpoints = ("score", "contexts")
+    uses_extractor = False
+    model_name = "fake-scorer"
+    top_k = 10
+    context_buckets = (16, 32)
+    _predict_steps = {}
+
+    def __init__(self, config, step_s=0.01):
+        self.config, self.step_s, self.calls = config, step_s, []
+
+    def model_fingerprint(self):
+        return "fake-fingerprint"
+
+    def predict_compile_count(self):
+        return 0
+
+    def batcher_options(self):
+        from code2vec_tpu.serving.batcher import bucket_for
+        return {"bucket_of": lambda r: bucket_for(len(r.ids),
+                                                  self.context_buckets),
+                "max_batch_tokens": 16 * 16}
+
+    def validate(self, ids, top_k, context=None):
+        from types import SimpleNamespace
+        if not ids:
+            raise ValueError("ids must hold a token")
+        if context == "gone":
+            raise LookupError("context 'gone' is unknown")
+        return SimpleNamespace(ids=list(ids), top_k=int(top_k))
+
+    def register_context(self, ids):
+        time.sleep(0.3)
+        return {"context": "c0", "tokens": len(ids)}
+
+    def score_batch(self, rows):
+        from types import SimpleNamespace
+        self.calls.append([r.ids[0] for r in rows])
+        time.sleep(self.step_s)
+        return [SimpleNamespace(
+            unknown_context=None, tokens=len(r.ids), context_tokens=0,
+            token_ids=[r.ids[0]], logits=[1.0], probabilities=[1.0])
+            for r in rows]
+
+
+@pytest.fixture()
+def scoring_server(tmp_path, monkeypatch):
+    """A server over the fake scorer whose batcher gathers under a
+    ceiling of 2 s (the cap lifted to it, the 16 bucket's tracker
+    holding steps of 8 s): wide enough for sixteen client threads of
+    this machine."""
+    from code2vec_tpu.serving import batcher as batcher_mod
+    from code2vec_tpu.serving.server import PredictionServer
+    monkeypatch.setattr(batcher_mod, "GATHER_CAP_S", 2.0)
+    config = _serving_config(tmp_path, serve_batch_size=16,
+                             serve_deadline_ms=30000.0)
+    srv = PredictionServer(_FakeScorer(config), config,
+                           log=lambda m: None)
+    for _ in range(srv.batcher.device_times.MIN_SAMPLES):
+        srv.batcher.device_times.record(16, 8.0)
+    srv.start(port=0)
+    yield srv
+    srv.drain(timeout=10)
+
+
+def _score_body(first_id, **more):
+    return json.dumps(dict({"ids": [first_id, 7, 7], "top_k": 1}, **more))
+
+
+def _nobody_en_route(srv, within_s=5.0):
+    """The count comes back to nothing (a handler thread leaves it a
+    moment after its client has the answer)."""
+    t_end = time.monotonic() + within_s
+    while srv._en_route and time.monotonic() < t_end:
+        time.sleep(0.005)
+    return not srv._en_route and srv.requests_en_route(60.0) == 0
+
+
+def test_sixteen_posts_at_one_instant_ride_one_batch(scoring_server):
+    """A burst as the rerank cell sends it, one connection a request:
+    all sixteen are connected before any sends, so from the first
+    submit to the last the server sees the others en route (accepted,
+    or held by the kernel for the listener) and the free dispatcher
+    cuts ONE batch of sixteen."""
+    import http.client
+    srv, model = scoring_server, scoring_server.model
+    connected = threading.Barrier(16)
+    statuses = [None] * 16
+
+    def client(i):
+        conn = http.client.HTTPConnection("127.0.0.1", srv.port,
+                                          timeout=30)
+        try:
+            conn.connect()
+            connected.wait(10)
+            conn.request("POST", "/score", body=_score_body(100 + i),
+                         headers={"Content-Type": "application/json"})
+            resp = conn.getresponse()
+            statuses[i] = resp.status
+            resp.read()
+        finally:
+            conn.close()
+
+    threads = [threading.Thread(target=client, args=(i,))
+               for i in range(16)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(30)
+    assert statuses == [200] * 16
+    assert len(model.calls) == 1
+    assert sorted(model.calls[0]) == list(range(100, 116))
+    assert _nobody_en_route(srv)
+    # ... and a lone request afterwards finds nothing at the door
+    t = time.perf_counter()
+    assert _post(srv.port, "score", _score_body(200))[0] == 200
+    assert time.perf_counter() - t < 1.0
+    assert model.calls[1:] == [[200]]
+
+
+def test_a_silent_connection_holds_a_request_to_the_ceiling_at_most(
+        tmp_path, monkeypatch):
+    """A connection that sends nothing (a pooled or probing client)
+    counts as en route only while it is younger than the ceiling: a
+    request right behind it is cut at the ceiling, a later one at
+    once."""
+    import socket
+    from code2vec_tpu.obs.metrics import Histogram
+    from code2vec_tpu.serving import batcher as batcher_mod
+    from code2vec_tpu.serving.server import PredictionServer
+    gathered = Histogram(buckets=(0.0, 1.0))
+    monkeypatch.setattr(batcher_mod, "_H_GATHERED", gathered)
+    monkeypatch.setattr(batcher_mod, "GATHER_CAP_S", 0.3)
+    config = _serving_config(tmp_path, serve_batch_size=16,
+                             serve_deadline_ms=30000.0)
+    srv = PredictionServer(_FakeScorer(config), config,
+                           log=lambda m: None)
+    for _ in range(srv.batcher.device_times.MIN_SAMPLES):
+        srv.batcher.device_times.record(16, 8.0)
+    srv.start(port=0)
+    silent = socket.create_connection(("127.0.0.1", srv.port))
+    try:
+        t = time.perf_counter()
+        assert _post(srv.port, "score", _score_body(1))[0] == 200
+        held = time.perf_counter() - t
+        assert (gathered.sum, gathered.count) == (1.0, 1)
+        assert 0.2 < held < 2.0         # the ceiling, 0.3 s, and no more
+        time.sleep(0.35)                # the silent one is stale now
+        t = time.perf_counter()
+        assert _post(srv.port, "score", _score_body(2))[0] == 200
+        assert time.perf_counter() - t < 0.25
+        assert (gathered.sum, gathered.count) == (1.0, 2)
+    finally:
+        silent.close()
+        assert _nobody_en_route(srv)    # a closed socket leaves the count
+        srv.drain(timeout=10)
+
+
+def test_en_route_count_is_nothing_after_every_way_a_request_ends(
+        scoring_server):
+    """The count cannot leak: answered, cache hit, shed, 400, 404, an
+    endpoint that never reaches the batcher, a client that went away."""
+    import socket
+    srv = scoring_server
+    port = srv.port
+    body = _score_body(5)
+    assert _post(port, "score", body)[0] == 200
+    assert _post(port, "score", body)[0] == 200             # cache hit
+    assert srv.model.calls == [[5]]
+    assert _post(port, "score", "{not json")[0] == 400
+    assert _post(port, "score", json.dumps({"top_k": 1}))[0] == 400
+    assert _post(port, "score", _score_body(6, context="gone"))[0] == 404
+    assert _post(port, "predict", "class A {}")[0] == 404   # not served
+    assert _post(port, "nowhere", "x")[0] == 404
+    # shed: a budget under the bucket's tracked step (DeadlineInfeasible)
+    req = urllib.request.Request(
+        f"http://127.0.0.1:{port}/score", data=_score_body(8).encode(),
+        method="POST", headers={"X-Deadline-Ms": "4000"})
+    with pytest.raises(urllib.error.HTTPError) as shed:
+        urllib.request.urlopen(req, timeout=30)
+    assert shed.value.code == 503
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}/healthz",
+                                timeout=30) as r:
+        assert r.status == 200
+    assert _nobody_en_route(srv)
+    # a registration never reaches the batcher: while the model works
+    # on it (0.3 s) it holds no dispatcher
+    done = []
+    t = threading.Thread(target=lambda: done.append(
+        _post(port, "contexts", json.dumps({"ids": [1, 2, 3]}))))
+    t.start()
+    time.sleep(0.15)
+    assert t.is_alive() and srv.requests_en_route(60.0) == 0
+    t.join(30)
+    assert done[0][0] == 200
+    # a client that sends half a request and goes away
+    gone = socket.create_connection(("127.0.0.1", port))
+    gone.sendall(b"POST /score HTTP/1.1\r\nContent-Length: 400\r\n\r\n{")
+    time.sleep(0.1)
+    assert srv.requests_en_route(60.0) == 1     # seen, not submitted
+    gone.close()
+    assert _nobody_en_route(srv)
+    # refused at the door of a draining server
+    with srv._inflight_cond:
+        srv._draining = True
+    try:
+        assert _post(port, "score", _score_body(9))[0] == 503
+    finally:
+        with srv._inflight_cond:
+            srv._draining = False
+    assert _nobody_en_route(srv)
+    assert srv.model.calls == [[5]]     # none of them reached the model
+
+
+def test_a_predict_request_in_extraction_is_not_en_route(
+        served_model, fake_extractor, monkeypatch):
+    """2 ms of extraction is not "about to arrive": a `/predict`
+    request leaves the count where it enters the extractor pool, so
+    `/predict` traffic all but bypasses the gather."""
+    from code2vec_tpu.serving.server import PredictionServer
+    monkeypatch.setenv("C2V_FAKE_SLEEP", "0.6")
+    srv = PredictionServer(served_model, served_model.config,
+                           log=lambda m: None)
+    srv.start(port=0)
+    try:
+        done = []
+        t = threading.Thread(target=lambda: done.append(_post(
+            srv.port, "predict",
+            "class A { int slow() { return 1; } } // SLOW_MARKER")))
+        t.start()
+        seen_inside = False
+        t_end = time.monotonic() + 10
+        while t.is_alive() and time.monotonic() < t_end:
+            if srv.admission.depth >= 1:        # past the gate: extracting
+                seen_inside = True
+                assert srv.requests_en_route(60.0) == 0
+            time.sleep(0.02)
+        t.join(30)
+        assert seen_inside and done[0][0] == 200
+        assert _nobody_en_route(srv)
+    finally:
+        srv.drain(timeout=10)
 
 
 def _post_full(port, endpoint, body, ctype="text/plain", headers=None,
