@@ -17,9 +17,15 @@ to each row's `depth` (its deepest valid context), under static shapes:
   other slots stay zero. A flat slot is whole tiles where a `(64, 50)`
   corner of the grid is not (200 = 8 x 25), and what consumes the rows
   (ops/encode_live.py) runs over the filled slots and no others.
-- backward: the ids are sorted once, the dead ones last, and one sorted
-  scatter-add takes the shortest of `SCATTER_SIZES` static prefixes of
-  the list that holds every live entry (`_embed_bwd` says why not a loop).
+- backward: the ids are sorted once, the dead ones last
+  (`_sorted_entries`). One chip alone stops there: its train step hands
+  the sorted `(key, row)` list to the table's Adam (`sorted_row_list`,
+  ops/adam_rows.py) and no table-shaped gradient exists. A mesh of
+  chips needs the table for its all-reduce: `_embed_bwd`, the lookup's
+  VJP, is the mesh's path, and ONE sorted scatter-add takes the list in.
+  Either moves the rows of the shortest of `SCATTER_SIZES` static
+  prefixes of the list that holds every live entry (`_embed_bwd` says
+  why not a loop).
 """
 
 from __future__ import annotations
@@ -150,9 +156,40 @@ def _embed_fwd(table, ids, depth, dtype):
     return outs, (table, slot_ids, below)
 
 
+def _sorted_entries(residuals, cotangents):
+    """The backward's list before any row moves: the entries' keys
+    SORTED, the dead ones (past their row's depth) behind a key past the
+    table's end; each sorted key's `source`, a row of `updates`; the
+    cotangent rows by slot as the outputs went; and how many entries
+    are live."""
+    table, slot_ids, below = residuals
+    width = table.shape[1]
+    past_end = table.shape[0]
+    keys = jnp.concatenate(
+        [jnp.where(below, i, past_end).reshape(-1) for i in slot_ids])
+    keys, source = jax.lax.sort_key_val(
+        keys, jnp.arange(keys.shape[0], dtype=jnp.int32))
+    updates = jnp.concatenate([ct.reshape(-1, width) for ct in cotangents])
+    entries = len(slot_ids) * jnp.sum(below, dtype=jnp.int32)
+    return keys, source, updates, entries
+
+
+def _over_live_prefix(entries, total: int, of_length):
+    """`of_length(n)()` for the shortest of `SCATTER_SIZES` static
+    prefix lengths `n` of a list of `total` that holds its `entries`
+    live ones (they come first)."""
+    step = -(-total // SCATTER_SIZES)
+    return jax.lax.switch(
+        jnp.maximum(entries - 1, 0) // step,
+        [of_length(min((n + 1) * step, total))
+         for n in range(SCATTER_SIZES)])
+
+
 def _embed_bwd(dtype, residuals, cotangents):
     """Scatter-add of the live entries' cotangent rows into one
-    table-shaped gradient. On the chip a scatter is either unsorted and
+    table-shaped gradient: what a mesh of chips needs, whose all-reduce
+    sums tables (one chip alone hands the list itself to its Adam,
+    `sorted_row_list`). On the chip a scatter is either unsorted and
     pays the memory's latency for every row (75 ns on a v5e), or sorted:
     one pass over the table (2.1 ms for java14m's token table) plus 11 ns
     a row, which is what XLA makes of `jnp.take`'s transpose. A loop of
@@ -162,15 +199,8 @@ def _embed_bwd(dtype, residuals, cotangents):
     adds a prefix of the list: the shortest of `SCATTER_SIZES` static
     lengths that holds every live entry. The cotangents come by slot, as
     the outputs went, so a sorted key's `source` is a compact row."""
-    table, slot_ids, below = residuals
-    width = table.shape[1]
-    past_end = table.shape[0]
-    keys = jnp.concatenate(
-        [jnp.where(below, i, past_end).reshape(-1) for i in slot_ids])
-    keys, source = jax.lax.sort_key_val(
-        keys, jnp.arange(keys.shape[0], dtype=jnp.int32))
-    updates = jnp.concatenate([ct.reshape(-1, width) for ct in cotangents])
-    step = -(-keys.shape[0] // SCATTER_SIZES)
+    table = residuals[0]
+    keys, source, updates, entries = _sorted_entries(residuals, cotangents)
 
     def scatter_prefix(length):
         def scatter():
@@ -180,15 +210,41 @@ def _embed_bwd(dtype, residuals, cotangents):
                 mode="drop")
         return scatter
 
-    entries = len(slot_ids) * jnp.sum(below, dtype=jnp.int32)
-    grad = jax.lax.switch(
-        jnp.maximum(entries - 1, 0) // step,
-        [scatter_prefix(min((n + 1) * step, keys.shape[0]))
-         for n in range(SCATTER_SIZES)])
+    grad = _over_live_prefix(entries, keys.shape[0], scatter_prefix)
     return grad, None, None
 
 
 embed_live_rows.defvjp(_embed_fwd, _embed_bwd)
+
+
+def live_rows_and_entries(table: jax.Array, ids: Tuple[jax.Array, ...],
+                          depth: jax.Array, dtype):
+    """`embed_live_rows`'s outputs, and the `entries` that
+    `sorted_row_list` needs beside their cotangents: the lookup taken
+    OUT of the differentiated function, for a step whose optimizer
+    takes the table's gradient as a list (training/step.py, one chip)."""
+    return _embed_fwd(table, ids, depth, dtype)
+
+
+def sorted_row_list(entries, cotangents) -> Tuple[jax.Array, jax.Array]:
+    """The table's gradient as `(keys, rows)`, the first half of
+    `_embed_bwd`: the keys of ALL entries sorted, a dead entry's a key
+    past the table's end, and each key's cotangent row (the compute
+    dtype, as the outputs were), moved by one gather over the shortest
+    static prefix that holds every live entry; zero rows behind it."""
+    keys, source, updates, live = _sorted_entries(entries, cotangents)
+    total = keys.shape[0]
+
+    def gather_prefix(length):
+        def gather():
+            # `source` is a permutation: no row to fill in, and no pass
+            # over the gathered rows to look for one
+            rows = updates.at[source[:length]].get(
+                mode="promise_in_bounds")
+            return jnp.pad(rows, ((0, total - length), (0, 0)))
+        return gather
+
+    return keys, _over_live_prefix(live, total, gather_prefix)
 
 
 def live_block_ratio(context_valid_mask: np.ndarray, chips: int = 1,

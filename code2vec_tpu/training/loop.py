@@ -35,7 +35,7 @@ from code2vec_tpu.ops import embed
 from code2vec_tpu.ops.head_ce import target_shards
 from code2vec_tpu.training.state import TrainState
 from code2vec_tpu.training.step import (
-    async_collective_count, gathers_live_rows,
+    adam_row_list_tables, async_collective_count, gathers_live_rows,
 )
 from code2vec_tpu.utils.device import describe_devices, shard_layout
 from code2vec_tpu.utils.prefetch import DevicePrefetcher
@@ -235,6 +235,14 @@ class Trainer:
             "(ops/head_ce.py target_shards): every chip of a mesh that "
             "shards the batch's rows and nothing else, else 1").set(
                 head_shards)
+        row_list_tables = adam_row_list_tables(config, self.mesh)
+        reg.gauge(
+            "train_adam_row_list_tables",
+            "tables whose gradient the train step hands to Adam as the "
+            "backward's sorted (key, row) list and never builds as a "
+            "table (training/step.py adam_row_list_tables): the token "
+            "and path tables where one chip holds them whole, else 0"
+            ).set(row_list_tables)
 
         batch_num = 0              # batches this run
         trace_active = False       # profiler trace in flight
@@ -545,6 +553,8 @@ class Trainer:
                         f"compile-cache load), ready after "
                         f"{first.seconds:.2f}s; {said_async}"
                         f"head over {head_shards} target shard(s); "
+                        f"Adam takes {row_list_tables} table(s)' gradient "
+                        f"as a row list; "
                         f"batch {tuple(arrays[0].shape)}: "
                         f"{shard_layout(arrays[0])}")
                     obs.log_compiles_from_now(log)

@@ -16,18 +16,39 @@ opt-state's type). The cell of `BENCHMARK.json`, or the queued cell
    jit with NamedSharding-annotated inputs/outputs — the scaling-book
    recipe: annotate, let XLA insert the collectives. Where every chip
    holds whole tables it runs the live-rows lookup (ops/embed.py) and
-   the dense chain over the slots it fills (ops/encode_live.py), chip
-   by chip under shard_map. All four train cells (`java14m.train_dp4`
-   and the three `*.train_hostfed*`). On a data-only mesh of more than
-   one TPU chip (`--dp N`) steps 1 and 2 are compiled so that a
-   gradient's all-reduce may be an asynchronous collective carried by
-   the fusions that do not read it: in step 1 the token table's runs
-   beside the other two tables' Adam (`train_step_compiler_options`,
-   `_cotangents_leave_together`, PR 32); every other mesh keeps the
-   default compile. On a data-only mesh the chips also split the head's
-   TARGET rows between them (ops/head_ce.py `target_shards`, PR 38): the
-   target table's gradient is whole on its chip and arrives by one
-   all-gather in the compute dtype, not by a float32 all-reduce.
+   the dense chain over the slots it fills (ops/encode_live.py). It has
+   two forms; `adam_row_list_tables` says which, from the mesh and, on
+   one chip, from whether the tables are what the row-list kernel takes
+   (128 wide under bfloat16 rows, ops/adam_rows.py `kernel_takes`) and
+   the optimizer the one it follows (not stock `optax.adam` over a
+   bfloat16 first moment); where it says none, one chip runs the second
+   form too:
+   - **one chip** (no mesh, or a mesh of one device; the three
+     `*.train_hostfed*` cells): the token and path tables' gradients
+     never exist as tables. The lookups stand outside the differentiated
+     function, the first half of the lookup's backward makes the sorted
+     `(key, cotangent row)` list, and each table's Adam
+     (ops/adam_rows.py, kernels `adam_token_rows` / `adam_path_rows`)
+     walks its table once and takes its gradient rows from that list:
+     the same dense update of every row, 16 bytes a parameter where the
+     zeroed float32 table, its scatter and its read back made 28 (PR 43).
+   - **a data mesh of more chips** (`--dp N`; `java14m.train_dp4`): the
+     chips' gradients meet in an all-reduce, which sums TABLES, so the
+     lookup keeps its VJP (one sorted scatter into a table a chip) and
+     `scoped_adam_update` takes every leaf. Lookup and chain run chip by
+     chip under shard_map. Steps 1 and 2 are compiled here so that a
+     gradient's all-reduce may be an asynchronous collective carried by
+     the fusions that do not read it: in step 1 the token table's runs
+     beside the other two tables' Adam (`train_step_compiler_options`,
+     `_cotangents_leave_together`, PR 32); every other mesh keeps the
+     default compile. The chips also split the head's TARGET rows
+     between them (ops/head_ce.py `target_shards`, PR 38): the target
+     table's gradient is whole on its chip and arrives by one
+     all-gather in the compute dtype, not by a float32 all-reduce.
+   The two needs conflict (a gradient that must cross chips as a table
+   against one that need not exist), so the forms are separate paths,
+   not one that adapts. tp / cp meshes under `--gspmd` keep `jnp.take`
+   and the chain over the whole grid.
 2. **GSPMD, touched-rows Adam**: gathers outside the differentiated
    function, (ids, grad rows) in place of table-shaped gradients.
    Queued: `java14m.train_dp4_sparse` (B1, B2).
@@ -62,8 +83,11 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from code2vec_tpu.models.code2vec import Code2VecModule
+from code2vec_tpu.ops.adam_rows import adam_rows_into_table, kernel_takes
 from code2vec_tpu.ops.attention import masked_single_query_attention
-from code2vec_tpu.ops.embed import context_depth, embed_live_rows
+from code2vec_tpu.ops.embed import (
+    context_depth, embed_live_rows, live_rows_and_entries, sorted_row_list,
+)
 from code2vec_tpu.ops.encode_live import encode_live_blocks
 from code2vec_tpu.ops.head_ce import head_cross_entropy
 from code2vec_tpu.ops import sharded as tp_ops
@@ -73,7 +97,8 @@ from code2vec_tpu.training.sparse_adam import (
     HybridOptState, sparse_adam_rows,
 )
 from code2vec_tpu.training.state import (
-    TrainState, split_sparse_dense, state_spec_tree, uses_sparse_update,
+    SPARSE_PARAM_NAMES, TrainState, split_sparse_dense, state_spec_tree,
+    uses_sparse_update,
 )
 
 class EvalOutputs(NamedTuple):
@@ -149,6 +174,23 @@ def scoped_adam_update(optimizer: optax.GradientTransformation, grads,
         is_leaf=lambda n: isinstance(n, dict) and set(n) <= keys)
 
 
+def _is_adam_moments(node) -> bool:
+    return isinstance(node, optax.ScaleByAdamState)
+
+
+def _adam_moments(opt_state) -> optax.ScaleByAdamState:
+    """The one `ScaleByAdamState` of `make_optimizer`'s state."""
+    found, = (n for n in jax.tree.leaves(opt_state, is_leaf=_is_adam_moments)
+              if _is_adam_moments(n))
+    return found
+
+
+def _with_adam_moments(opt_state, moments: optax.ScaleByAdamState):
+    """`opt_state` with `moments` where its `ScaleByAdamState` stands."""
+    return jax.tree.map(lambda n: moments if _is_adam_moments(n) else n,
+                        opt_state, is_leaf=_is_adam_moments)
+
+
 # What `_encode_live_rows` reads of the parameters (the head's table is
 # the logits' alone).
 _ENCODER_PARAMS = ("token_embedding", "path_embedding", "transform",
@@ -167,6 +209,33 @@ def gathers_live_rows(config, mesh: Optional[Mesh]) -> bool:
     if uses_sparse_update(config):
         return False
     return mesh is None or _data_only(mesh)
+
+
+def adam_row_list_tables(config, mesh: Optional[Mesh]) -> int:
+    """How many tables' gradients the dense step hands to Adam as the
+    backward's sorted `(key, row)` list (ops/adam_rows.py) and never
+    builds as tables: the token and the path table, or none. Three
+    things say which, and a TPU then runs the kernel for both tables:
+    - the mesh: the step gathers live rows and ONE chip holds the tables
+      whole (a data mesh of more chips sums table-shaped gradients
+      across them; tp / cp meshes keep `jnp.take`);
+    - the tables: what the kernel takes (`kernel_takes`: 128 wide,
+      cotangent rows in bfloat16, the compute dtype);
+    - the optimizer: not the one whose arithmetic the list's Adam cannot
+      follow. With a bfloat16 first moment beside a float32 second one
+      `make_optimizer` is stock `optax.adam`, which multiplies `b1 * mu`
+      in bfloat16."""
+    if not gathers_live_rows(config, mesh):
+        return 0
+    if mesh is not None and mesh.devices.size > 1:
+        return 0
+    if not all(kernel_takes(width, config.compute_dtype) for width in
+               (config.token_embeddings_size, config.path_embeddings_size)):
+        return 0
+    if (getattr(config, "adam_nu_dtype", "float32") == "float32"
+            and config.adam_mu_dtype != "float32"):
+        return 0
+    return len(SPARSE_PARAM_NAMES)
 
 
 def _data_only(mesh: Mesh) -> bool:
@@ -390,6 +459,8 @@ class TrainStepBuilder:
             self.module.dropout_keep_rate)
 
     def _make_gspmd_train_step(self, example_state: TrainState) -> Callable:
+        if adam_row_list_tables(self.config, self.mesh):
+            return self._make_one_chip_train_step(example_state)
         module, optimizer = self.module, self.optimizer
         live_rows = gathers_live_rows(self.config, self.mesh)
         order_rows, encode = _order_rows_by_depth, self._encode_live_rows
@@ -428,6 +499,102 @@ class TrainStepBuilder:
             params, opt_state = scoped_adam_update(
                 optimizer, grads, state.opt_state, state.params)
             return TrainState(step=state.step + 1, params=params,
+                              opt_state=opt_state), loss
+
+        return self._jit_train_step(train_step, example_state)
+
+    def _make_one_chip_train_step(self, example_state: TrainState) -> Callable:
+        """Step 1 where ONE chip holds the tables whole: the same dense
+        Adam on every parameter, and the token and path tables'
+        gradients never exist as tables. The two lookups stand outside
+        the differentiated function (as in step 2), its VJP gives their
+        cotangents by slot, the first half of the lookup's backward
+        makes of them the sorted `(key, row)` list (ops/embed.py
+        `sorted_row_list`), and each table's Adam takes its gradient
+        rows from that list while it walks the table once
+        (ops/adam_rows.py): 16 bytes a parameter where a zeroed float32
+        table, its scatter and its read back made 28. The target table
+        and the dense leaves keep `scoped_adam_update`; the optimizer
+        state keeps its tree and its one count. The backward is taken in
+        two halves, the head's and then the encoder's, with the target
+        table's Adam held between them: it is what reads the float32
+        logits last, and left to the scheduler it ran after the
+        encoder's backward, the logits lying beside the rows' cotangents
+        (1.76 GB of temporaries at java14m's size against 1.66 so; the
+        step with table-shaped gradients 1.71; compiles for a described
+        v5e, PR 43)."""
+        optimizer, adam = self.optimizer, self._adam_kwargs()
+        dtype = self.module.compute_dtype
+        keep = self.module.dropout_keep_rate
+
+        def train_step(state: TrainState, src, pth, tgt, mask, labels, valid, rng):
+            dropout_rng = jax.random.fold_in(rng, state.step)
+            if self.mesh is not None:
+                # chip 0 of a mesh of one: the mask that mesh's step draws
+                dropout_rng = jax.random.fold_in(dropout_rng, 0)
+            src, pth, tgt, mask, labels, valid, depth = _order_rows_by_depth(
+                src, pth, tgt, mask, labels, valid)
+            tables, rest = split_sparse_dense(state.params)
+            with jax.named_scope("embed_gather"):
+                (src_rows, tgt_rows), token_entries = live_rows_and_entries(
+                    tables["token_embedding"], (src, tgt), depth, dtype)
+                (path_rows,), path_entries = live_rows_and_entries(
+                    tables["path_embedding"], (pth,), depth, dtype)
+
+            moments = _adam_moments(state.opt_state)
+
+            def adam_of(grads, params):
+                # `scoped_adam_update` of some leaves: the state cut to them
+                return scoped_adam_update(
+                    optimizer, grads,
+                    _with_adam_moments(state.opt_state, moments._replace(
+                        mu={k: moments.mu[k] for k in params},
+                        nu={k: moments.nu[k] for k in params})), params)
+
+            def encode(dense, src_rows, path_rows, tgt_rows):
+                return encode_live_blocks(
+                    (src_rows, path_rows, tgt_rows), dense["transform"],
+                    dense["attention"][:, 0], mask, depth, dropout_rng, keep)
+
+            head = {"target_embedding": rest.pop("target_embedding")}
+            code_vectors, encoder_vjp = jax.vjp(
+                encode, rest, src_rows, path_rows, tgt_rows)
+            loss, (head_grads, code_ct) = jax.value_and_grad(
+                lambda head, code_vectors: self._head_loss(
+                    head, code_vectors, labels, valid), argnums=(0, 1))(
+                        head, code_vectors)
+            # the target table's Adam (its gradient is a product over
+            # the logits, fused into it) runs, and the logits are gone,
+            # before the encoder's backward builds the rows' cotangents
+            new_params, head_state = adam_of(head_grads, head)
+            code_ct, new_params, head_state = jax.lax.optimization_barrier(
+                (code_ct, new_params, head_state))
+            grads, src_ct, path_ct, tgt_ct = encoder_vjp(code_ct)
+            dense_params, rest_state = adam_of(grads, rest)
+            new_params.update(dense_params)
+            new, at_head = _adam_moments(rest_state), _adam_moments(head_state)
+            mu, nu = {**new.mu, **at_head.mu}, {**new.nu, **at_head.nu}
+            # as `_scale_by_adam_nu_dtype` has them, of the shared count
+            # that `scoped_adam_update` has just incremented, once
+            count = new.count.astype(jnp.float32)
+            bias1, bias2 = 1.0 - adam["b1"] ** count, 1.0 - adam["b2"] ** count
+            with jax.named_scope("embed_row_list"):
+                lists = {
+                    "token_embedding": sorted_row_list(
+                        token_entries, (src_ct, tgt_ct)),
+                    "path_embedding": sorted_row_list(
+                        path_entries, (path_ct,))}
+            for name, (keys, rows) in lists.items():
+                scope = _ADAM_SCOPES[name]
+                with jax.named_scope(scope):
+                    new_params[name], mu[name], nu[name] = (
+                        adam_rows_into_table(
+                            tables[name], moments.mu[name], moments.nu[name],
+                            keys, rows, bias1, bias2, name=scope + "_rows",
+                            **adam))
+            opt_state = _with_adam_moments(
+                rest_state, new._replace(mu=mu, nu=nu))
+            return TrainState(step=state.step + 1, params=new_params,
                               opt_state=opt_state), loss
 
         return self._jit_train_step(train_step, example_state)

@@ -133,7 +133,10 @@ _SCOPES = {
     False: ("embed_gather", "/transform/", "/attention/",
             "/transpose(jvp(transform))/", "/transpose(jvp(attention))/",
             "/jvp(head_ce)/logits_ce/", "/transpose(jvp(head_ce))/logits_ce/",
-            "adam_token", "adam_path", "adam_target", "adam_dense"),
+            "adam_token", "adam_path", "adam_target", "adam_dense",
+            # one chip: the backward's sorted (key, row) list, which the
+            # two tables' Adam takes in place of a table-shaped gradient
+            "embed_row_list"),
     # the touched-rows step gathers outside the differentiated function
     # and updates the two tables row-wise under the same two names
     True: ("embed_gather", "transform", "attention",
@@ -176,10 +179,10 @@ def test_scope_names_are_in_the_step_and_change_nothing_else(monkeypatch,
     named = scoped.as_text(debug_info=True)
     for scope in _SCOPES[sparse]:
         assert scope in named, scope
-    # the table-shaped gradient scatter (the backward of
-    # ops/embed.py embed_live_rows) carries the forward's scope
-    if not sparse:
-        assert "transpose(jvp(embed_gather))" in named
+    # one chip's lookups stand outside the differentiated function: no
+    # transposed lookup (a mesh's table-shaped gradient scatter, the
+    # backward of ops/embed.py embed_live_rows) is in its step
+    assert "transpose(jvp(embed_gather))" not in named
     manager = source_info_util.ExtendNameStackContextManager
     monkeypatch.setattr(manager, "__enter__", lambda self: None)
     monkeypatch.setattr(manager, "__exit__", lambda self, *exc: None)

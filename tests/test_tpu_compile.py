@@ -72,6 +72,94 @@ def test_the_heads_pass_b_kernel_compiles_at_published_widths(
     assert "tpu_custom_call" in compiled.as_text()
 
 
+@pytest.mark.parametrize("table_rows,entries", [
+    (1301136, 409600),      # java14m's token table, source + target ids
+    (911417, 204800),       # ... its path table
+    (1301136, 1024000),     # java14m-ctx500's 500 contexts
+    (300, 2048),            # a toy's table, under one tile
+])
+def test_the_row_list_adam_compiles_at_published_widths(
+        one_chip, table_rows, entries):
+    """Adam of a table from the backward's sorted row list
+    (ops/adam_rows.py) at the tables java14m runs: rows no tile divides,
+    bfloat16 moments, a batch's entries. Lowered for the TPU the op
+    picks the kernel, the state is updated in place, and nothing
+    table-shaped is left among the program's temporaries."""
+    from code2vec_tpu.ops.adam_rows import adam_rows_into_table
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    compiled = jax.jit(
+        lambda *args: adam_rows_into_table(
+            *args, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
+            name="adam_token_rows"),
+        donate_argnums=(0, 1, 2)).lower(
+            shape((table_rows, 128), jnp.float32),
+            shape((table_rows, 128), jnp.bfloat16),
+            shape((table_rows, 128), jnp.bfloat16),
+            shape((entries,), jnp.int32),
+            shape((entries, 128), jnp.bfloat16),
+            shape((), jnp.float32), shape((), jnp.float32)).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "adam_token_rows" in text
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= table_rows * 128 * 8
+    assert memory.temp_size_in_bytes < 16e6 + entries * 128 * 2
+
+
+def test_the_one_chip_step_compiles_without_a_table_shaped_gradient(
+        one_chip):
+    """`java14m.train_hostfed`'s step for one described chip: both
+    tables' Adam is the row-list kernel, and no op of the program makes
+    a float32 array of either table's shape (the zeroed table, its
+    scatter, a gradient operand: each was one; the kernels' own results
+    are the donated state)."""
+    import re
+    from code2vec_tpu.config import Config
+    from code2vec_tpu.models.code2vec import Code2VecModule, ModelDims
+    from code2vec_tpu.training.state import (
+        TrainState, init_params, make_optimizer)
+    from code2vec_tpu.training.step import TrainStepBuilder
+    rows, contexts = 1024, 200
+    config = Config(train_data_path_prefix="unused", train_batch_size=rows,
+                    max_contexts=contexts)
+    dims = ModelDims(token_vocab_size=1301136, path_vocab_size=911417,
+                     target_vocab_size=261245, token_dim=128, path_dim=128)
+    module = Code2VecModule(dims=dims,
+                            dropout_keep_rate=config.dropout_keep_rate,
+                            compute_dtype=jnp.dtype(config.compute_dtype))
+    optimizer = make_optimizer(config)
+
+    def init(rng):
+        params = init_params(module, rng)
+        return TrainState(step=jnp.zeros((), jnp.int32), params=params,
+                          opt_state=optimizer.init(params))
+
+    def placed(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    abstract = jax.eval_shape(init, jax.random.PRNGKey(0))
+    batch = [jax.ShapeDtypeStruct(s, d) for s, d in
+             [((rows, contexts), jnp.int32)] * 3
+             + [((rows, contexts), jnp.float32), ((rows,), jnp.int32),
+                ((rows,), jnp.bool_)]]
+    key = jax.eval_shape(lambda: jax.random.key(
+        0, impl=config.dropout_prng_impl))
+    step = TrainStepBuilder(module, optimizer, config).make_train_step(
+        abstract)
+    compiled = step.lower(jax.tree.map(placed, abstract),
+                          *map(placed, batch), placed(key)).compile()
+    text = compiled.as_text()
+    for kernel in ("adam_token_rows", "adam_path_rows"):
+        assert re.search(rf"%{kernel}\S* = .*tpu_custom_call", text)
+    made = re.findall(
+        r"= f32\[(?:1301136|911417),128\]\S* ([a-z-]+)\(", text)
+    assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+    # the target table's Adam, which reads the float32 logits, is held
+    # before the encoder's backward: the step with table-shaped gradients
+    # compiled to 1,711,592,960 B of temporaries (PR 42's tree)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.70e9
+
+
 @pytest.mark.parametrize("rows,vocab,dim,dtype,block", [
     (64, 261245, 384, "bfloat16", 16384),   # java14m.serve_open's step
     (64, 261245, 384, "bfloat16", 4096),    # ... under `--topk_block 4096`
