@@ -57,18 +57,6 @@ def arguments_parser() -> ArgumentParser:
                         help="rows per coalesced serving device batch "
                              "(also the padded row count of every "
                              "compiled predict shape; default 64)")
-    parser.add_argument("--serve_continuous", action="store_true",
-                        default=None,
-                        help="continuous batching: admit arriving rows "
-                             "into the next device step of an already-"
-                             "forming slot (zero-copy parse into the "
-                             "slot buffer; a row arriving while a step "
-                             "is on device rides the NEXT step)")
-    parser.add_argument("--serve_inflight_steps", type=int, default=None,
-                        metavar="N",
-                        help="device steps the continuous batcher may "
-                             "keep in flight at once (default 2; only "
-                             "read with --serve_continuous)")
     parser.add_argument("--serve_buckets", default=None, metavar="LIST",
                         help="comma-separated padded-context-count "
                              "buckets for the predict path (default "
@@ -387,30 +375,6 @@ def arguments_parser() -> ArgumentParser:
                              "profile, int4 packs two weights per byte "
                              "for another ~2x — per-scheme accuracy "
                              "deltas in BENCH_QUANT.md)")
-    parser.add_argument("--serve_mips_nprobe", type=int, default=None,
-                        metavar="N",
-                        help="approximate-MIPS prediction head: search "
-                             "only the N nearest coarse-quantizer "
-                             "lists of the target-name table at "
-                             "serve/predict time instead of streaming "
-                             "all ~246K rows (default 0 = exact "
-                             "blockwise top-k; BENCH_QUANT.md records "
-                             "the agreement-vs-speedup sweep and the "
-                             "tuned value)")
-    parser.add_argument("--serve_mips_nlist", type=int, default=None,
-                        metavar="N",
-                        help="coarse-quantizer size of the MIPS head "
-                             "(default 0 = sqrt(vocab) auto)")
-    parser.add_argument("--serve_mips_crossover", type=int, default=None,
-                        metavar="ROWS",
-                        help="batch-shape-aware head dispatch: device "
-                             "batches with at most ROWS live rows "
-                             "route to the MIPS head, bulk shapes to "
-                             "the exact blockwise head (default -1 = "
-                             "adopt the crossover calibrated at "
-                             "export, or all-MIPS for artifacts "
-                             "without one; 0 = exact-only bit-for-bit; "
-                             "requires --serve_mips_nprobe > 0)")
     parser.add_argument("--no_aot", action="store_true",
                         help="skip the jax.export AOT lowerings in the "
                              "exported artifact (consumers then always "
@@ -785,8 +749,6 @@ def config_from_args(argv=None) -> Config:
                                       "save_barrier_timeout_s",
                                       "serve_port", "serve_host",
                                       "serve_batch_size",
-                                      "serve_continuous",
-                                      "serve_inflight_steps",
                                       "serve_buckets",
                                       "model_config",
                                       "serve_token_budget",
@@ -839,9 +801,6 @@ def config_from_args(argv=None) -> Config:
                                       "serve_artifact",
                                       "export_artifact_path",
                                       "release_scheme",
-                                      "serve_mips_nprobe",
-                                      "serve_mips_nlist",
-                                      "serve_mips_crossover",
                                       "train_corpus_manifest",
                                       "topk_block_size",
                                       "embed_out", "embed_dtype",

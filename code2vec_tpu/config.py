@@ -230,18 +230,6 @@ class Config:
     # test_batch_size because serving favors latency over peak
     # throughput.
     serve_batch_size: int = 64
-    # Continuous batching (serving/batcher.py ContinuousBatcher): admit
-    # newly-arrived rows into the next device step of an already-forming
-    # slot instead of collect-then-dispatch — a row arriving while a
-    # step is on device rides the NEXT step — and parse extractor
-    # output straight into the slot's padded (rows, contexts) buffer
-    # (zero-copy request path). Both batchers follow one dispatch rule
-    # (serving/batcher.py): a free dispatcher dispatches at once.
-    serve_continuous: bool = False
-    # Device steps the continuous batcher may keep in flight at once
-    # (worker threads; step N+1 launches as soon as step N's dispatch
-    # returns). Only read with --serve_continuous.
-    serve_inflight_steps: int = 2
     # Padded-context-count buckets for the predict path (comma list;
     # max_contexts is always appended, entries >= max_contexts or not
     # divisible by cp are dropped): every predict batch pads its context
@@ -499,28 +487,6 @@ class Config:
     # byte — another ~2x below int8), or float32 (= --no_quantize).
     # Per-scheme accuracy deltas vs same-run fp32 in BENCH_QUANT.md.
     release_scheme: str = "int8"
-    # Approximate-MIPS prediction head (retrieval/mips.py): when > 0,
-    # serve/predict top-k over the ~246K-name classifier searches only
-    # the rows of the `serve_mips_nprobe` nearest coarse-quantizer
-    # lists instead of streaming the whole table (blockwise exact path
-    # stays the default at 0, and remains the accuracy-eval path
-    # regardless). Top-1 agreement vs exact is measured per nprobe in
-    # BENCH_QUANT.md; the tuned value documented there keeps agreement
-    # >= 0.99.
-    serve_mips_nprobe: int = 0
-    # Coarse-quantizer size of the MIPS head; 0 = sqrt(real vocab) auto.
-    serve_mips_nlist: int = 0
-    # Batch-shape-aware exact/MIPS head dispatch (release/runtime.py):
-    # device batches with at most this many LIVE rows route to the MIPS
-    # head, bulk shapes to the exact blockwise head — the PR-14 residue
-    # (MIPS wins 10-56x single-row, loses at bulk) resolved per batch
-    # instead of per server. -1 = adopt the crossover the export
-    # calibration recorded in the artifact meta (mips_crossover), or
-    # legacy all-MIPS when the artifact carries none; 0 = exact-only,
-    # bit-for-bit identical to serving with nprobe 0; > 0 = explicit
-    # crossover row count. Requires serve_mips_nprobe > 0 to take
-    # effect (there is no MIPS head to dispatch to otherwise).
-    serve_mips_crossover: int = -1
     # Also AOT-export (jax.export) the bucketed serve functions into
     # the artifact, one per (serve_batch_size, context bucket) shape,
     # so a serving replica cold-starts from deserialized lowerings
@@ -1093,43 +1059,6 @@ class Config:
             raise ValueError(
                 "release_scheme must be one of int8, fp8_e4m3, "
                 "fp8_e5m2, int4, float32.")
-        if self.serve_mips_nprobe < 0:
-            raise ValueError(
-                "serve_mips_nprobe must be >= 0 (0 = exact blockwise "
-                "top-k, the default).")
-        if self.serve_mips_nlist < 0:
-            raise ValueError(
-                "serve_mips_nlist must be >= 0 (0 = sqrt(vocab) auto).")
-        if self.serve_mips_nprobe > 0:
-            if not (self.serve or self.predict
-                    or self.export_artifact_path):
-                raise ValueError(
-                    "serve_mips_nprobe applies to serve/--predict (the "
-                    "prediction head) and export (which calibrates and "
-                    "records the exact/MIPS crossover in the artifact "
-                    "meta); eval/embed always use the exact blockwise "
-                    "path, so the knob would be a silent no-op here.")
-            if self.is_testing:
-                raise ValueError(
-                    "--serve_mips_nprobe cannot be combined with "
-                    "--test: accuracy evaluation always scores the "
-                    "exact blockwise head. Measure MIPS agreement and "
-                    "speedup with experiments/quant_bench.py "
-                    "(BENCH_QUANT.md) instead.")
-        if self.serve_mips_crossover < -1:
-            raise ValueError(
-                "serve_mips_crossover must be >= -1 (-1 = adopt the "
-                "artifact's calibrated crossover, 0 = exact-only, "
-                "> 0 = explicit crossover row count).")
-        if self.serve_mips_crossover > 0 and self.serve_mips_nprobe == 0:
-            raise ValueError(
-                "serve_mips_crossover > 0 requires serve_mips_nprobe "
-                "> 0: there is no MIPS head to dispatch small batches "
-                "to without an IVF probe budget.")
-        if self.serve_inflight_steps < 1:
-            raise ValueError(
-                "serve_inflight_steps must be >= 1 (device steps the "
-                "continuous batcher may keep in flight).")
         if self.train_corpus_manifest and not self.use_packed_data:
             raise ValueError(
                 "--train_corpus_manifest requires packed data: the "

@@ -117,18 +117,8 @@ def parse_context_lines(
     max_contexts: int,
     estimator_action: EstimatorAction,
     keep_strings: bool = False,
-    out: Optional[RowBatch] = None,
-    row_offset: int = 0,
 ) -> RowBatch:
     """Parse raw `.c2v` lines into a RowBatch (unfiltered).
-
-    With `out`, parse straight into rows [row_offset, row_offset+len)
-    of an existing keep-strings RowBatch (the serving slot buffer from
-    `empty_predict_batch`) instead of allocating a fresh batch — the
-    zero-copy request path. Rows are reset to PAD first (buffers are
-    pooled/reused), `example_valid` flips True for the filled rows, and
-    `out` itself is returned. The write is row-local, so concurrent
-    callers may fill DISJOINT row ranges of one buffer without a lock.
 
     Reference row parse: path_context_reader.py:184-228.
     """
@@ -163,36 +153,14 @@ def parse_context_lines(
     token_pad = vocabs.token_vocab.pad_index
     path_pad = vocabs.path_vocab.pad_index
 
-    if out is None:
-        src = np.full((n, m), token_pad, dtype=np.int32)
-        pth = np.full((n, m), path_pad, dtype=np.int32)
-        tgt = np.full((n, m), token_pad, dtype=np.int32)
-        target_index = np.empty((n,), dtype=np.int32)
-        if keep:
-            src_s = np.full((n, m), "", dtype=object)
-            pth_s = np.full((n, m), "", dtype=object)
-            tgt_s = np.full((n, m), "", dtype=object)
-    else:
-        if not keep:
-            raise ValueError("out= requires the keep-strings parse path")
-        if out.source_token_indices.shape[1] != m:
-            raise ValueError(
-                f"out buffer context width "
-                f"{out.source_token_indices.shape[1]} != {m}")
-        sl = slice(row_offset, row_offset + n)
-        src = out.source_token_indices[sl]
-        pth = out.path_indices[sl]
-        tgt = out.target_token_indices[sl]
-        target_index = out.target_index[sl]
-        src_s = out.source_strings[sl]
-        pth_s = out.path_strings[sl]
-        tgt_s = out.target_token_strings[sl]
-        src[:] = token_pad
-        pth[:] = path_pad
-        tgt[:] = token_pad
-        src_s[:] = ""
-        pth_s[:] = ""
-        tgt_s[:] = ""
+    src = np.full((n, m), token_pad, dtype=np.int32)
+    pth = np.full((n, m), path_pad, dtype=np.int32)
+    tgt = np.full((n, m), token_pad, dtype=np.int32)
+    target_index = np.empty((n,), dtype=np.int32)
+    if keep:
+        src_s = np.full((n, m), "", dtype=object)
+        pth_s = np.full((n, m), "", dtype=object)
+        tgt_s = np.full((n, m), "", dtype=object)
     target_strings: List[str] = []
 
     target_lookup = vocabs.target_vocab.lookup_index
@@ -224,14 +192,6 @@ def parse_context_lines(
     # identical to the reference.
     mask = ((src != token_pad) | (tgt != token_pad) | (pth != path_pad))
     context_valid_mask = mask.astype(np.float32)
-
-    if out is not None:
-        sl = slice(row_offset, row_offset + n)
-        out.context_valid_mask[sl] = context_valid_mask
-        out.example_valid[sl] = True
-        for i, t in enumerate(target_strings):
-            out.target_strings[row_offset + i] = t
-        return out
 
     return RowBatch(
         source_token_indices=src,
@@ -273,8 +233,9 @@ def invalid_batch(batch_size: int, max_contexts: int) -> RowBatch:
 
     Multi-host eval pads short hosts' streams with these so all hosts
     run the same number of collective eval steps
-    (parallel/distributed.py lockstep_eval_stream); index 0 is the pad
-    row in every vocab, matching `_pad_rows`' fill."""
+    (parallel/distributed.py lockstep_eval_stream), and the predict
+    warm-up runs every compiled shape on one; index 0 is the pad row in
+    every vocab, matching `_pad_rows`' fill."""
     return RowBatch(
         source_token_indices=np.zeros((batch_size, max_contexts), np.int32),
         path_indices=np.zeros((batch_size, max_contexts), np.int32),
@@ -283,35 +244,6 @@ def invalid_batch(batch_size: int, max_contexts: int) -> RowBatch:
         target_index=np.zeros((batch_size,), np.int32),
         example_valid=np.zeros((batch_size,), bool),
         target_strings=[""] * batch_size,
-    )
-
-
-def empty_predict_batch(batch_size: int, max_contexts: int,
-                        vocabs: Code2VecVocabs) -> RowBatch:
-    """Pad-filled keep-strings RowBatch — the serving slot buffer.
-
-    Every row starts invalid (PAD indices, zero mask); requests reserve
-    disjoint row ranges and `parse_context_lines(out=...)` fills them in
-    place, so a coalesced device batch ships without any per-request
-    array intermediate. PAD fill (not zeros) matters: an unclaimed row
-    must look exactly like `_pad_rows`' padding so the device step's
-    row-local math is identical to the collect-then-dispatch path."""
-    m = max_contexts
-    token_pad = vocabs.token_vocab.pad_index
-    path_pad = vocabs.path_vocab.pad_index
-    return RowBatch(
-        source_token_indices=np.full((batch_size, m), token_pad,
-                                     dtype=np.int32),
-        path_indices=np.full((batch_size, m), path_pad, dtype=np.int32),
-        target_token_indices=np.full((batch_size, m), token_pad,
-                                     dtype=np.int32),
-        context_valid_mask=np.zeros((batch_size, m), np.float32),
-        target_index=np.zeros((batch_size,), np.int32),
-        example_valid=np.zeros((batch_size,), bool),
-        target_strings=[""] * batch_size,
-        source_strings=np.full((batch_size, m), "", dtype=object),
-        path_strings=np.full((batch_size, m), "", dtype=object),
-        target_token_strings=np.full((batch_size, m), "", dtype=object),
     )
 
 
@@ -338,21 +270,6 @@ def slice_contexts(batch: RowBatch, m: int) -> RowBatch:
         path_strings=cut(batch.path_strings),
         target_token_strings=cut(batch.target_token_strings),
     )
-
-
-def truncate_rows(batch: RowBatch, rows: int) -> RowBatch:
-    """Drop trailing rows (basic slices -> views, no copies). Callers
-    guarantee the dropped rows are padding/invalid — the serving head
-    dispatch trims a full-width slot buffer down to the smaller row
-    shape the MIPS step compiled at."""
-    if batch.target_index.shape[0] <= rows:
-        return batch
-
-    def cut(x):
-        return None if x is None else x[:rows]
-
-    return RowBatch(**{f.name: cut(getattr(batch, f.name))
-                       for f in dataclasses.fields(RowBatch)})
 
 
 def _pad_rows(batch: RowBatch, batch_size: int) -> RowBatch:

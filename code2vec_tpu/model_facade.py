@@ -96,16 +96,6 @@ def _device_part(part: str):
     return obs.span("predict.device." + part, hist=_H_DEVICE_PART[part])
 
 
-def _head_dispatch_counter(head: str):
-    """Per-head device-batch routing counter. A helper (not a module
-    global) because the label value is dynamic; the metric NAME stays a
-    literal for scripts/check_metrics_doc.py."""
-    return obs.counter(
-        "serving_head_dispatch_total",
-        "device predict batches routed per retrieval head "
-        "(head=exact|mips; batch-shape-aware dispatch)", head=head)
-
-
 def head_sorted_columns_gauge(step: str):
     """Columns that enter a sort in one trip of a built step's top-k
     head (ops/topk.py `sorted_columns`): which merge the static shapes
@@ -351,88 +341,33 @@ class BucketedPredictMixin:
             results.extend(self._predict_chunk(lines, bs,
                                                with_code_vectors))
 
-    def alloc_predict_batch(self, batch_size: int):
-        """A reusable pad-filled slot buffer for the zero-copy serving
-        path (serving/batcher.py ContinuousBatcher): requests parse
-        straight into disjoint row ranges via `parse_lines_into` and
-        the whole buffer ships through `predict_parsed`."""
-        from code2vec_tpu.data.reader import empty_predict_batch
-        return empty_predict_batch(batch_size, self.config.max_contexts,
-                                   self.vocabs)
-
-    def parse_lines_into(self, lines: List[str], out, row_offset: int
-                         ) -> None:
-        """Parse extractor lines into `out`'s rows starting at
-        row_offset (zero-copy: no per-request RowBatch intermediate)."""
-        parse_context_lines(lines, self.vocabs, self.config.max_contexts,
-                            EstimatorAction.Predict, keep_strings=True,
-                            out=out, row_offset=row_offset)
-
-    def _dispatch_predict_step(self, n: int, bs: int, m: int):
-        """Pick the compiled step for a batch with n live rows ->
-        (step, padded_rows, head). The facade always pads to the full
-        serve batch and runs one head for every shape (MIPS when the
-        nprobe knob is on and the table is unsharded, exact otherwise);
-        ReleaseModel overrides this with batch-shape-aware exact/MIPS
-        dispatch. Every device batch increments
-        serving_head_dispatch_total{head} via the shared predict
-        path."""
-        head = "exact" if self._get_mips_topk() is None else "mips"
-        return self._get_bucketed_predict_step(bs, m), bs, head
-
     def _predict_chunk(self, lines: List[str], bs: int,
                        with_code_vectors: bool
                        ) -> List[ModelPredictionResults]:
+        from code2vec_tpu.data.reader import _pad_rows, slice_contexts
+        from code2vec_tpu.serving.batcher import bucket_for
         with _stage("parse"):
             chunk = parse_context_lines(lines, self.vocabs,
                                         self.config.max_contexts,
                                         EstimatorAction.Predict,
                                         keep_strings=True)
-        return self._predict_parsed(chunk, len(lines), bs,
-                                    with_code_vectors)
-
-    def predict_parsed(self, chunk, n: int,
-                       batch_size: Optional[int] = None,
-                       with_code_vectors: Optional[bool] = None
-                       ) -> List[ModelPredictionResults]:
-        """Predict over an ALREADY-PARSED RowBatch (first `n` rows are
-        live) — the zero-copy serving entry: the continuous batcher
-        hands the slot buffer straight here, skipping the line-parse
-        the classic path pays per coalesced batch."""
-        bs = int(batch_size or self._default_predict_batch_size())
-        if with_code_vectors is None:
-            with_code_vectors = self.config.export_code_vectors
-        return self._predict_parsed(chunk, n, bs, with_code_vectors)
-
-    def _predict_parsed(self, chunk, n: int, bs: int,
-                        with_code_vectors: bool
-                        ) -> List[ModelPredictionResults]:
-        from code2vec_tpu.data.reader import _pad_rows, slice_contexts
-        from code2vec_tpu.serving.batcher import bucket_for
+        n = len(lines)
         with _stage("assemble"):
             # Deepest VALID context column decides the bucket: the slice
-            # below only ever removes all-padding columns. (Slot buffers
-            # keep unclaimed rows' masks zeroed, so pooled reuse cannot
-            # inflate the bucket.)
+            # below only ever removes all-padding columns.
             any_valid_col = chunk.context_valid_mask.any(axis=0)
             deepest = (int(np.nonzero(any_valid_col)[0][-1]) + 1
                        if any_valid_col.any() else 1)
             m = bucket_for(deepest, self.context_buckets)
             chunk = slice_contexts(chunk, m)
-            step, padded_rows, head = self._dispatch_predict_step(n, bs, m)
-            _head_dispatch_counter(head).inc()
-            if chunk.target_index.shape[0] > padded_rows:
-                from code2vec_tpu.data.reader import truncate_rows
-                chunk = truncate_rows(chunk, padded_rows)
+            step = self._get_bucketed_predict_step(bs, m)
             # Pad the row count to the step's fixed row shape: row count
             # and context bucket together fully determine the compiled
             # shape.
-            padded = _pad_rows(chunk, padded_rows)
-            live = min(n, padded_rows)
-            _H_FILL["rows"].observe(live / padded_rows)
+            padded = _pad_rows(chunk, bs)
+            _H_FILL["rows"].observe(n / bs)
             _H_FILL["contexts"].observe(
-                float(chunk.context_valid_mask[:live].sum())
-                / (padded_rows * m))
+                float(chunk.context_valid_mask.sum()) / (bs * m))
         with _stage("device"):
             out = self._run_predict_step(step, padded)
             with _device_part("fetch"):
@@ -648,12 +583,13 @@ class Code2VecModel(BucketedPredictMixin):
         all-padding batch (the contract of `ReleaseModel.warmup`):
         `serve` calls it before it listens, so no request pays a
         bucket's compile out of its deadline."""
-        from code2vec_tpu.data.reader import slice_contexts
+        from code2vec_tpu.data.reader import invalid_batch, slice_contexts
         rows = int(rows or self.config.serve_batch_size)
-        empty = self.alloc_predict_batch(rows)
+        empty = invalid_batch(rows, self.config.max_contexts)
         for m in self.context_buckets:
-            step, _, _ = self._dispatch_predict_step(rows, rows, m)
-            self._run_predict_step(step, slice_contexts(empty, m))
+            self._run_predict_step(
+                self._get_bucketed_predict_step(rows, m),
+                slice_contexts(empty, m))
 
     # ------------------------------------------------------------ data
 
@@ -1066,24 +1002,6 @@ class Code2VecModel(BucketedPredictMixin):
     # ---------------------------------------------------------- predict
 
     def _make_predict_step(self, batch_rows: int, m: int):
-        mips = self._get_mips_topk()
-        if mips is not None:
-            # Approximate-MIPS prediction head (--serve_mips_nprobe,
-            # retrieval/mips.py): encode exactly, then search nprobe
-            # coarse lists of the target table instead of streaming all
-            # of it. Predict/serve only — the accuracy-eval path
-            # (_get_eval_step) always keeps the exact head.
-            module = self.module
-
-            def step(params, src, pth, tgt, mask, labels, valid):
-                code_vectors, attention = module.apply(
-                    {"params": params}, src, pth, tgt, mask,
-                    deterministic=True, method=Code2VecModule.encode)
-                values, indices = mips(code_vectors.astype(jnp.float32))
-                return EvalOutputs(values, indices, code_vectors,
-                                   attention, jnp.zeros((), jnp.float32))
-
-            return jax.jit(step)
         # a FRESH jitted eval step per shape (BucketedPredictMixin): each
         # entry compiles exactly once for its one padded shape
         head_sorted_columns_gauge("predict").set(
@@ -1092,45 +1010,11 @@ class Code2VecModel(BucketedPredictMixin):
 
     def describe_head(self) -> str:
         """The served head in a few words, for the server's start-up
-        line: which head answers and what its merge sorts a trip."""
-        if self._get_mips_topk() is not None:
-            return "head mips"
+        line: what the head's merge sorts a trip."""
         rows = int(self.config.serve_batch_size)
         return (f"head exact, "
                 f"{self.builder.eval_head_sorted_columns(rows)} columns "
                 f"sorted a trip at {rows} rows")
-
-    def _get_mips_topk(self):
-        """The facade's lazily-built MIPS head closure, or None when the
-        knob is off or the mesh shards the table (the head gathers from
-        an unsharded device copy; sharded serving keeps the exact
-        head, logged once)."""
-        nprobe = int(getattr(self.config, "serve_mips_nprobe", 0) or 0)
-        if nprobe <= 0:
-            return None
-        if self.mesh is not None:
-            if not getattr(self, "_mips_mesh_warned", False):
-                self._mips_mesh_warned = True
-                self.log("serve_mips_nprobe ignored: the MIPS head "
-                         "needs an unsharded target table (mesh is "
-                         "active); serving with the exact blockwise "
-                         "head")
-            return None
-        cached = getattr(self, "_mips_topk", None)
-        if cached is None:
-            from code2vec_tpu.retrieval.mips import MipsHead
-            head = MipsHead.build(
-                np.asarray(jax.device_get(
-                    self.state.params["target_embedding"])), None,
-                real_vocab=self.dims.real_target_vocab_size,
-                nlist=int(getattr(self.config, "serve_mips_nlist", 0)
-                          or 0),
-                nprobe=nprobe, seed=self.config.seed, log=self.log)
-            self.mips_head = head
-            k = min(self.config.top_k_words_considered_during_prediction,
-                    self.dims.real_target_vocab_size)
-            cached = self._mips_topk = head.topk_fn(k, nprobe)
-        return cached
 
     def _call_predict_step(self, step, arrays):
         return step(self._served_params(), *arrays)

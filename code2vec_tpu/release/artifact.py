@@ -252,26 +252,6 @@ def export_artifact(model, out_dir: str, *, quantize: Optional[bool] = None,
         json.dump(meta, f, indent=2, sort_keys=True)
         f.write("\n")
 
-    # Head-crossover calibration (hybrid exact/MIPS dispatch): when the
-    # exporter is configured for a MIPS head, time both heads across a
-    # small rows grid and record the largest MIPS-winning row count so
-    # serving replicas with --serve_mips_crossover -1 can adopt it.
-    # Runs after the meta is on disk (the calibrator loads the bundle
-    # like a replica would) and after the fingerprint is fixed — the
-    # fingerprint core never covers mips_crossover, so calibrated and
-    # uncalibrated exports of the same tables stay byte-identical in
-    # identity.
-    if (int(getattr(config, "serve_mips_nprobe", 0) or 0) > 0
-            and int(getattr(config, "serve_mips_crossover", -1)) != 0):
-        from code2vec_tpu.release.runtime import calibrate_mips_crossover
-        crossover, cal_table = calibrate_mips_crossover(
-            out_dir, config, log=log)
-        meta["mips_crossover"] = crossover
-        meta["mips_calibration"] = cal_table
-        with open(os.path.join(out_dir, META_NAME), "w") as f:
-            json.dump(meta, f, indent=2, sort_keys=True)
-            f.write("\n")
-
     log(f"Exported release artifact to {out_dir}: scheme={scheme}, "
         f"tables {fp32_bytes / 1e6:.1f} MB fp32 -> {written / 1e6:.1f} MB "
         f"({fp32_bytes / max(written, 1):.2f}x smaller), "

@@ -71,17 +71,10 @@ def _aot_counter(outcome: str):
         outcome=outcome)
 
 
-def make_release_step(meta: dict, mips_topk=None):
+def make_release_step(meta: dict):
     """Pure serve/eval function over artifact params:
     (params, src, pth, tgt, mask, labels, valid) ->
     (topk_values, topk_indices, code_vectors, attention, loss_sum).
-
-    `mips_topk` (a retrieval/mips.py `MipsHead.topk_fn` closure)
-    replaces the exact blockwise classifier head with the
-    approximate-MIPS candidate search — serve/predict only, never the
-    accuracy-eval path (config.verify rejects the combination); its
-    steps report loss_sum = 0 (no logsumexp exists over a candidate
-    subset, and no serving consumer reads it).
 
     Returns a plain tuple (not EvalOutputs) so jax.export can serialize
     the output pytree without namedtuple registration; callers wrap.
@@ -134,10 +127,6 @@ def make_release_step(meta: dict, mips_topk=None):
         code_vectors, attention = masked_single_query_attention(
             transformed, params["attention"][:, 0], mask)
         code_vectors = code_vectors.astype(jnp.float32)
-        if mips_topk is not None:
-            values, indices = mips_topk(code_vectors)
-            return (values, indices, code_vectors, attention,
-                    jnp.zeros((), jnp.float32))
         target_s = scale(params, "target_embedding")
         out = blockwise_matmul_top_k(
             code_vectors, params["target_embedding"], k, block,
@@ -313,95 +302,14 @@ class ReleaseModel(BucketedPredictMixin):
         fp8_np = {SCHEME_FP8_E4M3: ml_dtypes.float8_e4m3fn,
                   SCHEME_FP8_E5M2: ml_dtypes.float8_e5m2}.get(
             self.artifact.scheme)
-        mips_nprobe = int(getattr(config, "serve_mips_nprobe", 0) or 0)
-        # Batch-shape-aware head dispatch (--serve_mips_crossover, the
-        # PR-14 residue: MIPS wins 10-56x single-row but loses at bulk):
-        # batches with <= mips_rows live rows route to the MIPS head,
-        # bulk shapes to the exact blockwise head. -1 adopts the
-        # crossover the export calibration recorded in the artifact
-        # meta (mips_crossover) and falls back to legacy all-MIPS for
-        # artifacts without one; 0 disables MIPS entirely (exact-only,
-        # bit-for-bit the nprobe=0 path); a crossover at or above the
-        # serve batch size IS all-MIPS (every batch is below it).
-        crossover = int(getattr(config, "serve_mips_crossover", -1))
-        self.mips_rows = 0          # hybrid threshold; 0 = no split
-        self._mips_all = False
-        if mips_nprobe > 0:
-            if crossover == 0:
-                mips_nprobe = 0
-            elif crossover < 0:
-                calibrated = int(meta.get("mips_crossover", 0) or 0)
-                if calibrated > 0:
-                    self.mips_rows = calibrated
-                else:
-                    self._mips_all = True
-            else:
-                self.mips_rows = crossover
-            if self.mips_rows >= int(config.serve_batch_size):
-                self._mips_all, self.mips_rows = True, 0
         self.params = {}
         for name, arr in self.artifact.tables.items():
-            if self._mips_all and name.startswith("target_embedding"):
-                # all-MIPS: the head (below) holds the list-reordered
-                # copy and the exact head never runs, so transferring
-                # the original-order table would double the dominant
-                # table's device footprint. Hybrid dispatch keeps it —
-                # the exact head serves every bulk batch.
-                continue
             if fp8_np is not None and not name.endswith(".scale") \
                     and arr.dtype == np.uint8:
                 arr = np.asarray(arr).view(fp8_np)
             self.params[name.replace(".scale", "_scale")] = jnp.asarray(arr)
         self._step_fn = make_release_step(meta)
-        # Approximate-MIPS prediction head (--serve_mips_nprobe > 0):
-        # built once from the artifact's (quantized) target table; the
-        # predict steps then search nprobe coarse lists instead of
-        # streaming the whole classifier. AOT lowerings bake the exact
-        # head, so MIPS steps always jit (logged below); the exact
-        # `_step_fn` remains the fallback/eval program.
-        self.mips_head = None
-        self._mips_step = None
-        if mips_nprobe > 0:
-            from code2vec_tpu.retrieval.mips import MipsHead
-            dims = meta["dims"]
-            int4_dim = (int(dims["path_dim"]) + 2 * int(dims["token_dim"])
-                        if self.artifact.scheme == SCHEME_INT4 else None)
-            # Build from the HOST-side artifact tables (fp8 viewed to
-            # its ml_dtypes type, like the device-param load above) —
-            # the head holds the list-reordered quantized rows on
-            # device. All-MIPS skipped the original-order table in the
-            # device-param loop above (the MIPS step never reads it)
-            # so the dominant table is device-resident exactly once;
-            # hybrid dispatch pays for both copies because the exact
-            # head still serves every bulk batch.
-            tgt = np.asarray(self.artifact.tables["target_embedding"])
-            if fp8_np is not None and tgt.dtype == np.uint8:
-                tgt = tgt.view(fp8_np)
-            tgt_scale = self.artifact.tables.get("target_embedding.scale")
-            self.mips_head = MipsHead.build(
-                tgt,
-                None if tgt_scale is None else np.asarray(tgt_scale),
-                real_vocab=int(dims["real_target_vocab_size"]),
-                nlist=int(getattr(config, "serve_mips_nlist", 0) or 0),
-                nprobe=mips_nprobe, int4_dim=int4_dim,
-                seed=int(getattr(config, "seed", 0)), log=self.log)
-            k = min(int(meta["topk"]),
-                    int(dims["real_target_vocab_size"]))
-            self._mips_step = make_release_step(
-                meta, mips_topk=self.mips_head.topk_fn(k, mips_nprobe))
-            mode = ("all batches" if self._mips_all
-                    else f"batches with <= {self.mips_rows} live rows "
-                         f"(exact blockwise head above)")
-            self.log(f"Approximate-MIPS head active for {mode}: nprobe "
-                     f"{self.mips_head.nprobe}/{self.mips_head.nlist} "
-                     f"lists per prediction (MIPS steps always jit — "
-                     f"the AOT lowerings bake the exact head)")
         self._predict_steps: Dict[Tuple[int, int], object] = {}
-        # MIPS steps cached apart from the exact `_predict_steps` so
-        # compile-count surfaces (healthz, quant_bench) keep counting
-        # exact serve shapes, and each head's compile budget stays
-        # <= len(buckets) per rows shape.
-        self._mips_predict_steps: Dict[Tuple[int, int], object] = {}
         self.aot_loads = {"aot": 0, "jit_fallback": 0, "jit_error": 0}
         self.log(
             f"Release model loaded from {self.artifact.path}: scheme="
@@ -433,8 +341,6 @@ class ReleaseModel(BucketedPredictMixin):
     # ------------------------------------------------- predict plumbing
 
     def _make_predict_step(self, batch_rows: int, m: int):
-        if self._mips_all:
-            return jax.jit(self._mips_step)
         aot = self.meta.get("aot") or {}
         path = self.artifact.aot_path(batch_rows, m)
         if path is not None and _backend_matches(
@@ -468,32 +374,6 @@ class ReleaseModel(BucketedPredictMixin):
             self.aot_loads["jit_fallback"] += 1
             _aot_counter("jit_fallback").inc()
         return jax.jit(self._step_fn)
-
-    def _get_mips_predict_step(self, rows: int, m: int):
-        key = (rows, m)
-        step = self._mips_predict_steps.get(key)
-        if step is None:
-            step = self._mips_predict_steps[key] = jax.jit(self._mips_step)
-            self.log(f"Compiled MIPS predict step for shape "
-                     f"(rows={rows}, contexts={m})")
-        return step
-
-    def _dispatch_predict_step(self, n: int, batch_rows: int, m: int):
-        """Per-batch-shape head dispatch: batches whose LIVE row count
-        is at or below the resolved crossover route to the MIPS head
-        compiled at the crossover shape (small batches repad down, so
-        a lone interactive row never pays the bulk shape's exact
-        scan); everything else takes the exact blockwise head at the
-        serve shape. All-MIPS and exact-only modes degenerate to the
-        single-head behaviour."""
-        if self._mips_all:
-            return (self._get_bucketed_predict_step(batch_rows, m),
-                    batch_rows, "mips")
-        if 0 < n <= self.mips_rows:
-            return (self._get_mips_predict_step(self.mips_rows, m),
-                    self.mips_rows, "mips")
-        return (self._get_bucketed_predict_step(batch_rows, m),
-                batch_rows, "exact")
 
     @staticmethod
     def _dummy_batch(rows: int, m: int):
@@ -546,62 +426,4 @@ class ReleaseModel(BucketedPredictMixin):
             step = self._get_bucketed_predict_step(rows, m)
             out = self._call_predict_step(step, self._dummy_batch(rows, m))
             jax.block_until_ready(out.topk_indices)
-            if self.mips_rows > 0:
-                # hybrid dispatch: small batches take the MIPS head at
-                # the crossover shape — warm it too or the first
-                # interactive request pays the jit it was routed to
-                # avoid
-                step = self._get_mips_predict_step(self.mips_rows, m)
-                out = self._call_predict_step(
-                    step, self._dummy_batch(self.mips_rows, m))
-                jax.block_until_ready(out.topk_indices)
         return time.perf_counter() - t0
-
-
-def calibrate_mips_crossover(artifact_dir: str, config, log=print):
-    """Export-time head-crossover calibration: load the just-written
-    artifact, time the exact blockwise head against the MIPS head on
-    dummy batches over a small rows grid (one context bucket — the
-    crossover is a rows property; per-context cost scales both heads
-    alike), and return `(crossover, table)` where crossover is the
-    largest row count at which MIPS still wins (0 if it never does,
-    scanning stops at the first exact-head win so a noisy outlier deep
-    in bulk territory cannot stretch the threshold). The exporter
-    records the value as meta["mips_crossover"]; serving adopts it via
-    --serve_mips_crossover -1. Timings are median-of-3 after a warmup
-    execution, so jit/compile cost never pollutes the comparison."""
-    import dataclasses
-
-    nprobe = int(getattr(config, "serve_mips_nprobe", 0) or 0) or 8
-    cfg = dataclasses.replace(
-        config, serve_artifact=artifact_dir, serve_mips_nprobe=nprobe,
-        serve_mips_crossover=1)  # hybrid: both heads live + both tables
-    model = ReleaseModel(cfg, log=log)
-    bs = int(cfg.serve_batch_size)
-    grid = sorted({r for r in (1, 2, 4, 8, 16, bs) if 1 <= r <= bs})
-    m = model.context_buckets[0]
-    table, crossover = {}, 0
-    for rows in grid:
-        batch = model._dummy_batch(rows, m)
-        timing = {}
-        for head, step in (
-                ("exact", model._get_bucketed_predict_step(rows, m)),
-                ("mips", model._get_mips_predict_step(rows, m))):
-            jax.block_until_ready(
-                model._call_predict_step(step, batch).topk_indices)
-            samples = []
-            for _ in range(3):
-                t0 = time.perf_counter()
-                jax.block_until_ready(
-                    model._call_predict_step(step, batch).topk_indices)
-                samples.append(time.perf_counter() - t0)
-            timing[head] = sorted(samples)[1]
-        table[str(rows)] = {k: round(v * 1e6, 1) for k, v in timing.items()}
-        if timing["mips"] < timing["exact"]:
-            crossover = rows
-        else:
-            break
-    log(f"MIPS crossover calibration (nprobe {nprobe}, bucket {m}): "
-        f"crossover={crossover} over rows grid {grid} "
-        f"(us medians: {table})")
-    return crossover, table

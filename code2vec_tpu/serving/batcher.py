@@ -29,7 +29,6 @@ would recompile per distinct (rows, contexts) pair. So:
   arrives behind it piles up and is cut together (inside the row cap
   and the token budget) the moment it returns, so batches grow with the
   backlog by themselves. Without `en_route` (standalone construction)
-  and in `ContinuousBatcher`, which runs in no cell of the benchmark,
   a free dispatcher always cuts at once. Two histograms say, once a
   dispatched batch, what happened: `serving_batch_cut_idle_ratio` 1
   when the batch's oldest request was submitted with no call in flight
@@ -89,11 +88,10 @@ _H_DEVICE = obs.histogram(
     "serving_device_seconds",
     "one coalesced model call: parse + pad + device step + unpack")
 _DISPATCHER_HELP = (
-    "time a dispatcher thread spent in one state, observed on leaving "
+    "time the dispatcher thread spent in one state, observed on leaving "
     "it: idle (nothing pending), delay (a request is pending and the "
-    "free dispatcher holds the cut: the dynamic batcher while the "
-    "server reports requests en route, the continuous batcher while a "
-    "parse is still writing into the head slot), "
+    "free dispatcher holds the cut while the server reports requests "
+    "en route), "
     "dispatch (inside the coalesced model call and its fan-out). "
     "dispatch over wall time is the busy share of the thread every "
     "request passes through")
@@ -112,13 +110,6 @@ def _state(state: str):
 
 _C_BATCHES = obs.counter("serving_batches_total",
                          "device batches dispatched by the batcher")
-_G_INFLIGHT = obs.gauge(
-    "serving_batch_inflight_steps",
-    "device steps currently in flight (continuous batching)")
-_C_RIDES = obs.counter(
-    "serving_batch_inflight_rides_total",
-    "admissions that arrived while a step was in flight and ride the "
-    "next one (continuous batching)")
 _H_CUT_IDLE = obs.histogram(
     "serving_batch_cut_idle_ratio",
     "one observation a dispatched batch: 1 when its oldest request "
@@ -155,13 +146,11 @@ GATHER_STEP_SHARE = 0.5
 GATHER_CAP_S = 0.016
 
 
-def _cut_idle(items, t_free: float, in_flight: int = 0) -> float:
+def _cut_idle(items, t_free: float) -> float:
     """What `serving_batch_cut_idle_ratio` observes for a batch that is
-    being cut: 1.0 when no model call is in flight (`in_flight`: the
-    continuous batcher's other workers) and the oldest of `items` was
-    submitted after the last one returned (`t_free`), else 0.0."""
-    return float(in_flight == 0
-                 and min(i.t_submit for i in items) >= t_free)
+    being cut: 1.0 when the oldest of `items` was submitted after the
+    last model call returned (`t_free`), else 0.0."""
+    return float(min(i.t_submit for i in items) >= t_free)
 
 
 def parse_buckets(spec, max_contexts: int, cp: int = 1) -> Tuple[int, ...]:
@@ -190,7 +179,7 @@ def bucket_for(n_contexts: int, buckets: Sequence[int]) -> int:
 
 class _Pending:
     __slots__ = ("lines", "future", "t_submit", "phases", "deadline",
-                 "bucket", "trace", "settled", "tenant")
+                 "bucket", "trace", "tenant")
 
     def __init__(self, lines: List[str], phases: Optional[dict],
                  deadline: Optional[Deadline] = None,
@@ -203,14 +192,9 @@ class _Pending:
         self.deadline = deadline
         self.bucket = bucket
         self.trace = trace
-        # collapsed tenant label (serving/tenancy.py) — the batchers'
-        # DWRR fill and per-slot share caps key on it; None when the
-        # tenancy layer is off
+        # collapsed tenant label (serving/tenancy.py) — the batcher's
+        # DWRR fill keys on it; None when the tenancy layer is off
         self.tenant = tenant
-        # continuous batcher: an item settled early (504 / parse error)
-        # stays in its slot (its rows are reserved in the fixed-shape
-        # buffer, mask-zeroed) but is skipped at result fan-out
-        self.settled = False
 
 
 class _DeviceTimeTracker:
@@ -579,8 +563,8 @@ class DynamicBatcher:
         batch_bucket = max((i.bucket for i in batch
                             if i.bucket is not None), default=None)
         self.device_times.record(batch_bucket, dur)
-        self._record_batch_spans(batch, batch_id, batch_bucket,
-                                 len(all_lines), t_dispatch, dur, stages)
+        _record_batch_spans(batch, batch_id, batch_bucket,
+                            len(all_lines), t_dispatch, dur, stages)
         off = 0
         for item in batch:
             n = len(item.lines)
@@ -590,13 +574,6 @@ class DynamicBatcher:
             if item.future.set_running_or_notify_cancel():
                 item.future.set_result(results[off:off + n])
             off += n
-
-    def _record_batch_spans(self, batch: List[_Pending], batch_id: int,
-                            bucket: Optional[int], rows: int,
-                            t_dispatch: float, dur: float,
-                            stages=()) -> None:
-        _record_batch_spans(batch, batch_id, bucket, rows, t_dispatch,
-                            dur, stages)
 
 
 def _record_batch_spans(batch: List[_Pending], batch_id: int,
@@ -654,440 +631,3 @@ def _record_batch_spans(batch: List[_Pending], batch_id: int,
     tracer.default_tracer().maybe_record(
         "serving_batch", t_dispatch, dur, span_id=batch_span_id,
         attrs=dict(attrs, member_trace_ids=members))
-
-
-class StaleParse(RuntimeError):
-    """Raised by a backend's `predict_rows` when the live model's
-    fingerprint no longer matches the slot's parse-time fingerprint (a
-    hot-swap landed between parse and dispatch): the slot's int rows
-    were built against the OLD vocab tables and must not run under the
-    new weights. The worker falls back to the lines path, re-parsing
-    under the current model — so the batch still answers with exactly
-    one fingerprint."""
-
-
-class _Slot:
-    """One forming/in-flight device batch of the continuous batcher.
-
-    `rows` rows of the fixed-shape buffer are reserved (parse writes
-    land in disjoint row ranges, so only the RESERVATION is locked —
-    the parse itself runs on the submitter thread outside the lock,
-    tracked by `pending_writes`)."""
-
-    __slots__ = ("kind", "items", "offsets", "rows", "buffer",
-                 "pending_writes", "sealed", "cut_idle", "fps")
-
-    def __init__(self, kind: str, buffer=None):
-        self.kind = kind              # "rows" (zero-copy) | "lines"
-        self.items: List[_Pending] = []
-        self.offsets: List[Tuple[int, int]] = []   # (row_offset, n)
-        self.rows = 0
-        self.buffer = buffer
-        self.pending_writes = 0
-        self.sealed = False
-        self.cut_idle = 0.0           # _cut_idle(), set when a worker takes it
-        self.fps: set = set()         # model fingerprints seen at parse
-
-
-class ContinuousBatcher:
-    """Slot-reservation dispatcher: continuous batching for the serve
-    path (--serve_continuous).
-
-    The collect-then-dispatch DynamicBatcher parses a batch's lines
-    inside its one model call. Here the next batch is always forming:
-    `submit()` reserves rows in the tail slot under the lock, parses
-    the extractor lines straight into the slot's padded (rows,
-    contexts) buffer OUTSIDE the lock (zero-copy:
-    reader.parse_context_lines(out=...) — no per-request RowBatch
-    between extractor_pool and the device step), and up to
-    `inflight_steps` worker threads follow the module's dispatch rule:
-    the head slot is due as soon as a worker is free and no parse is
-    still writing into it (`serve.delay` is the wait for that parse). A
-    row that arrives while every worker is inside a step rides the
-    next one with whatever else arrived behind it. A serial client
-    gets byte-identical responses from both batchers.
-
-    Admission control is re-expressed against the in-flight step's ETA:
-    a bounded-deadline request is refused (`DeadlineInfeasible`) when
-    `remaining < eta + p95(bucket)` where eta is 0 if a worker is free,
-    else the soonest in-flight step's expected completion. Cold
-    tracker => no refusal, as in the classic batcher.
-
-    `backend` is the model adapter (serving/server.py) with:
-    alloc(rows), parse_into(lines, buffer, row_offset) -> fingerprint,
-    predict_rows(buffer, n_rows, fingerprint) -> results (raising
-    StaleParse when `fingerprint` is no longer the live model's), and
-    predict_lines(lines) -> results. Without a backend (unit tests)
-    every slot is a "lines" slot dispatched through `predict_fn`,
-    exercising the continuous machinery alone. Oversized requests
-    (> max_batch_rows) and slots whose parse-time fingerprint no longer
-    matches the live model (mid-batch hot-swap) fall back to the lines
-    path — predict_lines re-parses under the CURRENT model, so every
-    response batch still carries exactly one fingerprint.
-    """
-
-    def __init__(self, predict_fn: Optional[Callable[[List[str]], List]]
-                 = None,
-                 max_batch_rows: int = 64,
-                 buckets: Optional[Sequence[int]] = None,
-                 inflight_steps: int = 2, backend=None, tenancy=None):
-        if predict_fn is None and backend is None:
-            raise ValueError("ContinuousBatcher needs a predict_fn or "
-                             "a backend")
-        self.predict_fn = predict_fn
-        self.backend = backend
-        self.tenancy = tenancy
-        self.max_batch_rows = max(1, int(max_batch_rows))
-        self.buckets = tuple(buckets) if buckets else None
-        self.inflight_steps = max(1, int(inflight_steps))
-        self.device_times = _DeviceTimeTracker()
-        self._cond = threading.Condition()
-        self._slots: deque = deque()
-        self._pool: List = []
-        self._pool_cap = self.inflight_steps + 2
-        self._inflight = 0
-        self._inflight_meta: List[List] = []   # [t_launch, bucket]
-        self._draining = False
-        self.batches_dispatched = 0
-        self.rides = 0
-        self._t_free = 0.0      # when the last model call returned
-        self._workers = [
-            threading.Thread(target=self._worker,
-                             name=f"serving-batcher-{i}", daemon=True)
-            for i in range(self.inflight_steps)]
-        for t in self._workers:
-            t.start()
-
-    # -------------------------------------------------------------- API
-
-    _bucket_of = DynamicBatcher._bucket_of
-    _bucket_fn = None       # rows are extractor lines (no token budget)
-
-    def _tenant_cap_hit_locked(self, slot: "_Slot",
-                               tenant: Optional[str], n: int) -> bool:
-        """Per-slot share cap: in a slot already SHARED by other
-        tenants, one tenant may reserve at most its weighted share of
-        the slot's rows — overflow opens the next slot instead of
-        squeezing batch-mates out. A slot holding a single tenant (the
-        common case, and every tenancy-off run) is never capped, so
-        the classic fill behavior is untouched."""
-        if self.tenancy is None or not slot.items:
-            return False
-        tenants = {i.tenant for i in slot.items}
-        if tenants == {tenant}:
-            return False
-        held = sum(len(i.lines) for i in slot.items
-                   if i.tenant == tenant)
-        total_w = sum(self.tenancy.weight(t)
-                      for t in tenants | {tenant})
-        cap = max(1, int(self.max_batch_rows
-                         * self.tenancy.weight(tenant)
-                         / (total_w or 1.0)))
-        return held + n > cap
-
-    def submit(self, lines: Sequence[str],
-               phases: Optional[dict] = None,
-               deadline: Optional[Deadline] = None,
-               trace=None, tenant: Optional[str] = None) -> Future:
-        item = _Pending(list(lines), phases, deadline, trace=trace,
-                        tenant=tenant)
-        if not item.lines:
-            item.future.set_result([])
-            return item.future
-        item.bucket = self._bucket_of(item.lines)
-        if deadline is not None and deadline.bounded:
-            if deadline.expired():
-                expired_counter("batch_wait").inc()
-                item.future.set_exception(DeadlineExceeded(
-                    "request deadline expired before batching"))
-                return item.future
-            p95 = self.device_times.p95(item.bucket)
-            if p95 is not None:
-                eta = self._inflight_eta()
-                if deadline.remaining() < eta + p95:
-                    # The request cannot finish inside its budget even
-                    # riding the very next step: the soonest in-flight
-                    # step completes in `eta`, then its own bucket's
-                    # p95 device time runs.
-                    item.future.set_exception(DeadlineInfeasible(
-                        f"remaining deadline budget "
-                        f"{deadline.remaining() * 1e3:.0f}ms is below "
-                        f"the in-flight step ETA {eta * 1e3:.0f}ms + "
-                        f"bucket p95 device time {p95 * 1e3:.0f}ms",
-                        retry_after_s=eta + p95))
-                    return item.future
-        n = len(item.lines)
-        kind = ("rows" if self.backend is not None
-                and n <= self.max_batch_rows
-                and getattr(self.backend, "supports_rows",
-                            lambda: True)() else "lines")
-        with self._cond:
-            if self._draining:
-                item.future.set_exception(
-                    RuntimeError("batcher is draining; not accepting "
-                                 "new requests"))
-                return item.future
-            slot = self._slots[-1] if self._slots else None
-            if (slot is None or slot.sealed or slot.kind != kind
-                    or slot.rows + n > self.max_batch_rows
-                    or self._tenant_cap_hit_locked(slot, tenant, n)):
-                if slot is not None and not slot.sealed:
-                    slot.sealed = True
-                buffer = self._get_buffer_locked() if kind == "rows" \
-                    else None
-                slot = _Slot(kind, buffer)
-                self._slots.append(slot)
-            off = slot.rows
-            slot.items.append(item)
-            slot.offsets.append((off, n))
-            slot.rows += n
-            if slot.rows >= self.max_batch_rows:
-                slot.sealed = True
-            if self._inflight > 0:
-                # this row arrived while a step was on device
-                self.rides += 1
-                _C_RIDES.inc()
-            if kind == "rows":
-                slot.pending_writes += 1
-            self._cond.notify_all()
-        if kind != "rows":
-            return item.future
-        # Zero-copy parse, outside the lock: this submitter thread
-        # writes its own disjoint row range of the slot buffer.
-        try:
-            fp = self.backend.parse_into(item.lines, slot.buffer, off)
-        except BaseException as e:  # noqa: BLE001 — future must settle
-            with self._cond:
-                slot.pending_writes -= 1
-                slot.buffer.context_valid_mask[off:off + n] = 0.0
-                slot.buffer.example_valid[off:off + n] = False
-                item.settled = True
-                if item.future.set_running_or_notify_cancel():
-                    item.future.set_exception(e)
-                self._cond.notify_all()
-            return item.future
-        with self._cond:
-            slot.pending_writes -= 1
-            slot.fps.add(fp)
-            self._cond.notify_all()
-        return item.future
-
-    def rebucket(self, buckets: Optional[Sequence[int]]) -> None:
-        """Hot-swap support: adopt the new model's bucket grid, drop
-        device-time samples keyed to the old one, and drop pooled
-        buffers (they were allocated by the old model's backend). Slots
-        already forming keep their parse-time fingerprints — the worker
-        notices the mismatch and re-parses via the lines path, so a
-        batch never mixes weights generations."""
-        with self._cond:
-            self.buckets = tuple(buckets) if buckets else None
-            self.device_times = _DeviceTimeTracker()
-            self._pool = []
-
-    def drain(self, timeout: Optional[float] = None) -> None:
-        """Stop intake, flush every forming slot (partially filled
-        included), join the workers. Idempotent."""
-        with self._cond:
-            self._draining = True
-            self._cond.notify_all()
-        deadline = None if timeout is None \
-            else time.monotonic() + timeout
-        for t in self._workers:
-            t.join(None if deadline is None
-                   else max(deadline - time.monotonic(), 0.0))
-
-    # -------------------------------------------------------- dispatch
-
-    def _inflight_eta(self) -> float:
-        """Seconds until the soonest in-flight step is expected to
-        free a worker; 0 when a worker is idle or the tracker is cold
-        for any in-flight bucket (never refuse on a guess)."""
-        with self._cond:
-            if self._inflight < self.inflight_steps:
-                return 0.0
-            meta = [tuple(m) for m in self._inflight_meta]
-        now = time.perf_counter()
-        eta = None
-        for t_launch, bucket, _slot in meta:
-            p95 = self.device_times.p95(bucket)
-            if p95 is None:
-                return 0.0
-            done_in = max(t_launch + p95 - now, 0.0)
-            eta = done_in if eta is None else min(eta, done_in)
-        return eta or 0.0
-
-    def _get_buffer_locked(self):
-        if self._pool:
-            return self._pool.pop()
-        return self.backend.alloc(self.max_batch_rows)
-
-    def _release_buffer(self, buffer, rows: int) -> None:
-        if buffer is None:
-            return
-        # wipe the used rows' validity so a pooled buffer can never
-        # inflate the next batch's bucket (indices are re-PADded per
-        # claim by parse_into)
-        buffer.context_valid_mask[:rows] = 0.0
-        buffer.example_valid[:rows] = False
-        with self._cond:
-            if len(self._pool) < self._pool_cap:
-                self._pool.append(buffer)
-
-    def _expire_head_locked(self, slot: _Slot) -> None:
-        if slot.pending_writes:
-            return   # a parse is writing; next pass catches expiries
-        for (off, n), item in zip(slot.offsets, slot.items):
-            if item.settled or item.deadline is None \
-                    or not item.deadline.expired():
-                continue
-            expired_counter("batch_wait").inc()
-            item.settled = True
-            if slot.buffer is not None:
-                slot.buffer.context_valid_mask[off:off + n] = 0.0
-                slot.buffer.example_valid[off:off + n] = False
-            if item.future.set_running_or_notify_cancel():
-                item.future.set_exception(DeadlineExceeded(
-                    "request deadline expired behind the model "
-                    "call in flight"))
-
-    def _worker(self) -> None:
-        while True:
-            slot = self._next_slot()
-            if slot is None:
-                return
-            try:
-                with _state("dispatch"):
-                    self._run_slot(slot)
-            finally:
-                self._release_buffer(slot.buffer, slot.rows)
-                with self._cond:
-                    self._inflight -= 1
-                    self._t_free = time.perf_counter()
-                    self._inflight_meta = [
-                        m for m in self._inflight_meta
-                        if m[2] is not slot]
-                    _G_INFLIGHT.set(self._inflight)
-                    self._cond.notify_all()
-
-    def _next_slot(self) -> Optional[_Slot]:
-        with self._cond:
-            while True:
-                slot = self._slots[0] if self._slots else None
-                if slot is None:
-                    if self._draining:
-                        return None
-                    with _state("idle"):
-                        self._cond.wait()
-                    continue
-                self._expire_head_locked(slot)
-                if all(i.settled for i in slot.items) \
-                        and not slot.pending_writes:
-                    self._slots.popleft()
-                    self._release_buffer_nolock_queue(slot)
-                    continue
-                if slot.pending_writes == 0:
-                    # the dispatch rule: this worker is free, the head
-                    # slot holds a live request and nothing is writing
-                    self._slots.popleft()
-                    slot.sealed = True
-                    slot.cut_idle = _cut_idle(
-                        [i for i in slot.items if not i.settled],
-                        self._t_free, self._inflight)
-                    self._inflight += 1
-                    bucket = max((i.bucket for i in slot.items
-                                  if i.bucket is not None
-                                  and not i.settled), default=None)
-                    self._inflight_meta.append(
-                        [time.perf_counter(), bucket, slot])
-                    _G_INFLIGHT.set(self._inflight)
-                    return slot
-                with _state("delay"):
-                    self._cond.wait()
-
-    def _release_buffer_nolock_queue(self, slot: _Slot) -> None:
-        # called with the lock held for a fully-expired slot: return
-        # the (already mask-wiped) buffer straight to the pool
-        if slot.buffer is not None \
-                and len(self._pool) < self._pool_cap:
-            self._pool.append(slot.buffer)
-            slot.buffer = None
-
-    def _run_slot(self, slot: _Slot) -> None:
-        t_dispatch = time.perf_counter()
-        with self._cond:
-            self._expire_head_locked(slot)
-        live = [i for i in slot.items if not i.settled]
-        if not live:
-            return
-        for item in live:
-            wait = t_dispatch - item.t_submit
-            if item.phases is not None:
-                item.phases["batch_wait"] = wait
-            if item.trace is not None:
-                item.trace.add_span("batch_wait", item.t_submit, wait)
-        rows_live = sum(len(i.lines) for i in live)
-        _C_BATCHES.inc()
-        _H_CUT_IDLE.observe(slot.cut_idle)
-        _H_GATHERED.observe(0.0)    # this batcher never gathers
-        self.batches_dispatched += 1
-        batch_id = self.batches_dispatched
-        _H_BATCH_ROWS.observe(rows_live)
-        use_rows = slot.kind == "rows" and len(slot.fps) == 1
-        try:
-            with tracer.collect() as stages:
-                if use_rows:
-                    try:
-                        results = self.backend.predict_rows(
-                            slot.buffer, slot.rows, next(iter(slot.fps)))
-                    except StaleParse:
-                        use_rows = False
-                    else:
-                        if len(results) < slot.rows:
-                            raise RuntimeError(
-                                f"predict_rows returned {len(results)} "
-                                f"results for {slot.rows} rows")
-                if not use_rows:
-                    # lines fallback: plain lines slot, a rows slot that
-                    # straddled a hot-swap (mixed parse fingerprints or
-                    # StaleParse), — re-parse under the CURRENT model so
-                    # the batch answers with one fingerprint
-                    all_lines = [l for i in live for l in i.lines]
-                    fn = (self.backend.predict_lines
-                          if self.backend is not None else self.predict_fn)
-                    results = fn(all_lines)
-                    if len(results) != len(all_lines):
-                        raise RuntimeError(
-                            f"predict_fn returned {len(results)} results "
-                            f"for {len(all_lines)} lines")
-        except BaseException as e:  # noqa: BLE001 — futures must settle
-            for item in live:
-                if item.future.set_running_or_notify_cancel():
-                    item.future.set_exception(e)
-            return
-        t_end = time.perf_counter()
-        dur = t_end - t_dispatch
-        _H_DEVICE.observe(dur)
-        batch_bucket = max((i.bucket for i in live
-                            if i.bucket is not None), default=None)
-        self.device_times.record(batch_bucket, dur)
-        _record_batch_spans(live, batch_id, batch_bucket, rows_live,
-                            t_dispatch, dur, stages)
-        if use_rows:
-            for (off, n), item in zip(slot.offsets, slot.items):
-                if item.settled:
-                    continue
-                if item.phases is not None:
-                    item.phases["device"] = dur
-                    item.phases["device_end"] = t_end
-                if item.future.set_running_or_notify_cancel():
-                    item.future.set_result(results[off:off + n])
-        else:
-            off = 0
-            for item in live:
-                n = len(item.lines)
-                if item.phases is not None:
-                    item.phases["device"] = dur
-                    item.phases["device_end"] = t_end
-                if item.future.set_running_or_notify_cancel():
-                    item.future.set_result(results[off:off + n])
-                off += n

@@ -102,9 +102,7 @@ from code2vec_tpu.serving.admission import (
     _SHED_HELP, AdmissionController, Deadline, DeadlineExceeded, Shed,
     deadline_from_request, expired_counter, retry_after_seconds,
 )
-from code2vec_tpu.serving.batcher import (
-    ContinuousBatcher, DynamicBatcher, StaleParse,
-)
+from code2vec_tpu.serving.batcher import DynamicBatcher
 from code2vec_tpu.serving.breaker import CircuitBreaker
 from code2vec_tpu.serving.cache import (
     PredictionCache, cache_key_normalized, normalize_source,
@@ -181,62 +179,6 @@ class _HTTPError(Exception):
         self.code = code
 
 
-class _ContinuousBackend:
-    """ContinuousBatcher's model adapter: the zero-copy slot path.
-
-    Every method reads the server's (model, fingerprint) reference
-    exactly once, so parse and predict each bind to one weights
-    generation; `predict_rows` refuses (StaleParse) when the slot's
-    parse-time fingerprint is no longer the live one — the batcher then
-    re-parses via `predict_lines` under the current model, preserving
-    one-fingerprint-per-batch across hot-swaps."""
-
-    def __init__(self, server: "PredictionServer"):
-        self._server = server
-
-    def supports_rows(self) -> bool:
-        """The CURRENT model exposes the zero-copy slot surface (the
-        facade and ReleaseModel both do via BucketedPredictMixin; a
-        swapped-in minimal model may not). Checked per submit, so
-        slots formed after a swap to a lines-only model degrade to the
-        predict_lines path instead of failing on a missing method."""
-        model, _ = self._server._model_ref
-        return (hasattr(model, "parse_lines_into")
-                and hasattr(model, "alloc_predict_batch")
-                and hasattr(model, "predict_parsed"))
-
-    def alloc(self, rows: int):
-        model, _ = self._server._model_ref
-        return model.alloc_predict_batch(rows)
-
-    def parse_into(self, lines, buffer, row_offset: int) -> str:
-        model, fp = self._server._model_ref
-        model.parse_lines_into(lines, buffer, row_offset)
-        return fp
-
-    def predict_rows(self, buffer, n_rows: int, fingerprint: str):
-        server = self._server
-        model, fp = server._model_ref
-        if fp != fingerprint:
-            raise StaleParse(
-                f"slot rows were parsed under fingerprint "
-                f"{fingerprint}; live model is {fp}")
-        server.device_breaker.check()
-        try:
-            results = model.predict_parsed(
-                buffer, n_rows,
-                batch_size=server.config.serve_batch_size,
-                with_code_vectors=True)
-        except BaseException:
-            server.device_breaker.record(ok=False)
-            raise
-        server.device_breaker.record(ok=True)
-        return [(r, fp) for r in results]
-
-    def predict_lines(self, lines):
-        return self._server._batched_predict(lines)
-
-
 class PredictionServer:
     """Owns the pool + batcher + cache + admission gate + breakers +
     swap manager around one (swappable) model.
@@ -288,8 +230,7 @@ class PredictionServer:
             tenancy=self.tenancy)
         # how a row buckets and what a batch may hold, where the model's
         # rows are not extractor lines (batcher.DynamicBatcher)
-        own = getattr(model, "batcher_options", dict)()
-        batcher_kw.update(own)
+        batcher_kw.update(getattr(model, "batcher_options", dict)())
         # Requests EN ROUTE to the batcher (its dispatch rule gathers
         # them before a free dispatcher cuts): a connection from the
         # instant the listener hands it to a handler thread, or from its
@@ -300,22 +241,11 @@ class PredictionServer:
         self._en_route: Dict[object, float] = {}
         self._en_route_lock = threading.Lock()
         self._en_route_within_s = 0.0   # the horizon the batcher asks with
-        if getattr(self.config, "serve_continuous", False) and not own:
-            # --serve_continuous: slot-reservation dispatcher + the
-            # zero-copy parse-into-slot path (batcher.ContinuousBatcher)
-            self.batcher = ContinuousBatcher(
-                self._batched_predict,
-                inflight_steps=getattr(self.config,
-                                       "serve_inflight_steps", 2),
-                backend=_ContinuousBackend(self), **batcher_kw)
-        else:
-            self.batcher = DynamicBatcher(
-                self._batched_predict,
-                en_route=self.requests_en_route, **batcher_kw)
-        # whom the last request to leave the count tells (the continuous
-        # batcher never gathers)
-        self._nobody_en_route = getattr(self.batcher, "en_route_changed",
-                                        _nothing)
+        self.batcher = DynamicBatcher(
+            self._batched_predict,
+            en_route=self.requests_en_route, **batcher_kw)
+        # whom the last request to leave the count tells
+        self._nobody_en_route = self.batcher.en_route_changed
         self.cache = PredictionCache(self.config.serve_cache_entries)
         self.topk = self.config.top_k_words_considered_during_prediction
         # Live-traffic sample for the continuous-training pipeline's
@@ -1013,11 +943,7 @@ class PredictionServer:
                                if self.pool is not None else None),
             "batcher": {"max_batch_rows": self.batcher.max_batch_rows,
                         "batches_dispatched":
-                            self.batcher.batches_dispatched,
-                        "continuous":
-                            isinstance(self.batcher, ContinuousBatcher),
-                        "inflight_rides":
-                            getattr(self.batcher, "rides", 0)},
+                            self.batcher.batches_dispatched},
             "cache": {"capacity": self.cache.capacity,
                       "entries": len(self.cache)},
             "admission": {

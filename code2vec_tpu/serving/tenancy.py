@@ -23,8 +23,8 @@ deployments that don't opt in:
   `shed_reason=tenant_quota` with `Retry-After` derived from THAT
   tenant's bucket refill time — never the fleet-wide queue estimate.
 - **Batch fairness**: `dwrr_take` is the deficit-weighted-round-robin
-  order the classic batcher uses to fill a device batch when multiple
-  tenants are pending, so a filled slot cannot be monopolized by one
+  order the batcher uses to fill a device batch when multiple
+  tenants are pending, so a full batch cannot be monopolized by one
   tenant's backlog.
 - **Bounded metric cardinality**: every tenant-labeled metric
   registration funnels through `tenant_metric`, which refuses any

@@ -1,8 +1,8 @@
 """Quantized release-artifact bench: quality delta per scheme,
-footprint, cold start, the approximate-MIPS head sweep, serving
-throughput, and the blockwise eval-step A/B.
+footprint, cold start, serving throughput, and the blockwise eval-step
+A/B.
 
-Six phases, one artifact (`experiments/results/quant.json`), summarized
+Five phases, one artifact (`experiments/results/quant.json`), summarized
 in BENCH_QUANT.md; the blockwise eval-step A/B additionally lands in
 BENCH_EVAL.json (the eval-throughput satellite of PR 8):
 
@@ -15,9 +15,7 @@ BENCH_EVAL.json (the eval-throughput satellite of PR 8):
    runtime's forward re-implementation), and the int8 / fp8-e4m3 /
    int4 release artifacts (per-scheme quality deltas with the fp32 row
    reproduced in the SAME run — the roofline PR's sub-int8 acceptance
-   discipline). A `mips` phase measures the approximate-MIPS head's
-   agreement (real table/queries) and latency regime (flagship shape,
-   serve batch sizes).
+   discipline).
 2. **footprint** — fp32 vs int8 table bytes (meta["table_bytes"]) and
    on-disk artifact size.
 3. **cold start** — ReleaseModel.warmup() over every serve bucket from
@@ -440,173 +438,6 @@ def flagship_phase(log) -> dict:
     return out
 
 
-def mips_phase(st: dict, log) -> dict:
-    """Approximate-MIPS prediction head (retrieval/mips.py), two
-    measurements with separate jobs:
-
-    1. **Agreement** (quality) on the REAL trained target table with
-       the REAL test-set code vectors: top-1 agreement vs the exact
-       blockwise head per nprobe; the tuned value is the smallest
-       nprobe keeping agreement >= 0.99.
-    2. **Speedup** (latency) at the FLAGSHIP classifier shape
-       (261245 x 384) at SERVE batch sizes. The regime matters: the
-       exact head streams the table ONCE per batch (cost ~V, shared
-       across rows) while the MIPS head gathers nprobe lists PER ROW
-       (cost ~B x nprobe x maxlen) — so MIPS wins exactly where
-       serving lives, small coalesced batches over a big vocab, and
-       LOSES at bulk-eval batch sizes. Both regimes are recorded; the
-       knob's default stays 0 (exact)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from code2vec_tpu.config import Config
-    from code2vec_tpu.model_facade import Code2VecModel
-    from code2vec_tpu.ops.topk import blockwise_matmul_top_k
-    from code2vec_tpu.retrieval.mips import MipsHead
-    from code2vec_tpu.training.step import device_put_batch
-
-    prefix = st["prefix"]
-    config = Config(model_load_path=st["ckpt"],
-                    test_data_path=prefix + ".test.c2v",
-                    test_batch_size=1024, max_contexts=200,
-                    verbose_mode=0)
-    model = Code2VecModel(config)
-    config.num_test_examples = model._count_examples(
-        config.test_data_path)
-    eval_step, params = model.eval_callable()
-    cvs = []
-    for batch in model._eval_batches():
-        arrays = device_put_batch(batch, model.mesh)
-        out = eval_step(params, *arrays)
-        valid = np.asarray(arrays[5])
-        cvs.append(np.asarray(out.code_vectors)[valid])
-    queries = np.concatenate(cvs).astype(np.float32)
-    table = np.asarray(
-        jax.device_get(model.state.params["target_embedding"]))
-    real_v = model.dims.real_target_vocab_size
-    k = 10
-    head = MipsHead.build(table, None, real_vocab=real_v, seed=0,
-                          log=log)
-    tbl_dev = jnp.asarray(table)
-    exact_fn = jax.jit(lambda q: blockwise_matmul_top_k(
-        q, tbl_dev, k, 4096, valid_rows=real_v)[:2])
-
-    bsz = 1024
-    exact_top1 = np.concatenate([
-        np.asarray(exact_fn(jnp.asarray(queries[i:i + bsz]))[1])[:, 0]
-        for i in range(0, len(queries), bsz)])
-
-    nprobes = sorted({p for p in (1, 2, 4, 8, 16, 32, 64)
-                      if p < head.nlist} | {head.nlist})
-    sweep = []
-    tuned = None
-    for nprobe in nprobes:
-        fn = jax.jit(head.topk_fn(k, nprobe))
-        approx_top1 = np.concatenate([
-            np.asarray(fn(jnp.asarray(queries[i:i + bsz]))[1])[:, 0]
-            for i in range(0, len(queries), bsz)])
-        agreement = float((approx_top1 == exact_top1).mean())
-        sweep.append({"nprobe": nprobe,
-                      "top1_agreement": round(agreement, 4)})
-        log(f"  MIPS nprobe {nprobe}/{head.nlist}: top-1 agreement "
-            f"{agreement:.4f}")
-        if tuned is None and agreement >= 0.99:
-            tuned = nprobe
-    del model
-
-    out = {
-        "agreement": {
-            "target_vocab": real_v,
-            "nlist": head.nlist,
-            "queries": int(len(queries)),
-            "k": k,
-            "head_build_s": head.build_seconds,
-            "sweep": sweep,
-            "tuned_nprobe": tuned,
-            "tuned_rule": "smallest nprobe with top-1 agreement "
-                          ">= 0.99 vs exact blockwise top-k",
-            "tuned_list_fraction": (None if tuned is None else
-                                    round(tuned / head.nlist, 3)),
-        },
-        "flagship_timing": _mips_flagship_timing(
-            tuned, head.nlist, k, log),
-    }
-    return out
-
-
-def _mips_flagship_timing(corpus_tuned, corpus_nlist, k, log) -> dict:
-    """Exact-vs-MIPS head latency at the flagship classifier shape
-    (timing is shape-, not value-, dependent, so a random table stands
-    in; AGREEMENT comes from the real-corpus sweep above). Swept over
-    serve-relevant batch sizes; combinations whose per-batch candidate
-    gather would exceed a memory budget are recorded as skipped — that
-    IS the result (the gather growing past the whole-table stream is
-    exactly why the exact head stays the bulk-eval path)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from code2vec_tpu.ops.topk import blockwise_matmul_top_k
-    from code2vec_tpu.retrieval.mips import MipsHead
-
-    v, d = FLAGSHIP_TARGET_VOCAB, 384
-    rng = np.random.default_rng(23)
-    table = rng.standard_normal((v, d)).astype(np.float32)
-    log(f"Building flagship-shape MIPS head ({v} x {d}) ...")
-    head = MipsHead.build(table, None, real_vocab=v, kmeans_iters=2,
-                          seed=0, log=log)
-    maxlen = int(head._list_pad.shape[1])
-    tbl_dev = jnp.asarray(table)
-    # sqrt-scaled tuned equivalent: on the corpus, tuned/sqrt(nlist)
-    # ~ 1.5; IVF probe counts scale ~sqrt(nlist), not linearly
-    candidates = {4, 8, 16, 32}
-    if corpus_tuned:
-        candidates.add(int(np.ceil(
-            corpus_tuned / np.sqrt(corpus_nlist)
-            * np.sqrt(head.nlist))))
-    gather_budget = 1 << 30  # 1 GiB of gathered candidate rows
-
-    rows = []
-    for b in (1, 8, 64):
-        q = jnp.asarray(rng.standard_normal((b, d)), jnp.float32)
-        fn = jax.jit(lambda x: blockwise_matmul_top_k(
-            x, tbl_dev, k, 4096)[:2])
-
-        def timed(f, reps=5):
-            jax.block_until_ready(f(q))
-            t0 = time.perf_counter()
-            for _ in range(reps):
-                out = f(q)
-            jax.block_until_ready(out)
-            return (time.perf_counter() - t0) / reps * 1e3
-
-        exact_ms = timed(fn)
-        for nprobe in sorted(candidates):
-            gather_bytes = b * nprobe * maxlen * d * 4
-            if gather_bytes > gather_budget:
-                rows.append({"batch": b, "nprobe": nprobe,
-                             "skipped": f"candidate gather "
-                                        f"{gather_bytes / 1e9:.1f} GB "
-                                        f"> budget"})
-                continue
-            ms = timed(jax.jit(head.topk_fn(k, nprobe)))
-            rows.append({"batch": b, "nprobe": nprobe,
-                         "exact_ms": round(exact_ms, 2),
-                         "mips_ms": round(ms, 2),
-                         "speedup": round(exact_ms / ms, 2)})
-            log(f"  flagship B={b} nprobe={nprobe}: exact "
-                f"{exact_ms:.1f} ms vs MIPS {ms:.1f} ms "
-                f"({exact_ms / ms:.2f}x)")
-    return {
-        "target_vocab": v, "dim": d, "nlist": head.nlist,
-        "max_list_len": maxlen, "head_build_s": head.build_seconds,
-        "note": "random table (timing is shape-dependent only); "
-                "agreement from the real-corpus sweep",
-        "rows": rows,
-    }
-
-
 def update_bench_eval(flagship: dict, env: dict) -> None:
     data = {}
     if os.path.exists(BENCH_EVAL):
@@ -642,12 +473,11 @@ def write_report(result: dict) -> None:
     cs = result.get("cold_start") or {}
     sv = result.get("serving") or {}
     fl = result.get("flagship_eval_step") or {}
-    mp = result.get("mips") or {}
     tb = q["int8_meta_table_bytes"]
     tb8, tb4 = q["fp8_meta_table_bytes"], q["int4_meta_table_bytes"]
     lines = [
         "# BENCH_QUANT: quantized release artifacts "
-        "(int8/fp8/int4), blockwise top-k, MIPS head, AOT serve",
+        "(int8/fp8/int4), blockwise top-k, AOT serve",
         "",
         "Produced by `scripts/run_quant_bench.sh` → "
         "`experiments/quant_bench.py` → `experiments/results/quant.json`.",
@@ -753,60 +583,6 @@ def write_report(result: dict) -> None:
             f"{fl['peak_live_logits_bytes']['full'] / 1e6:.0f} MB → "
             f"{fl['peak_live_logits_bytes']['blockwise'] / 1e6:.0f} MB.",
         ]
-    if mp:
-        ag, ft = mp["agreement"], mp["flagship_timing"]
-        tuned = ag.get("tuned_nprobe")
-        lines += [
-            "",
-            "## Approximate-MIPS head "
-            "(`--serve_mips_nprobe`, retrieval/mips.py)",
-            "",
-            "**Agreement** (real trained table, "
-            f"{ag['target_vocab']} names, nlist {ag['nlist']}; "
-            f"queries = the {ag['queries']} real test-set code "
-            "vectors):",
-            "",
-            "| nprobe | top-1 agreement vs exact |",
-            "|---|---|",
-        ] + [
-            f"| {row['nprobe']}"
-            + (" ← tuned" if row["nprobe"] == tuned else "")
-            + f" | {row['top1_agreement']:.4f} |"
-            for row in ag["sweep"]
-        ] + [
-            "",
-            (f"Tuned value: **nprobe {tuned}** "
-             f"({ag['tuned_list_fraction'] * 100:.0f}% of lists) — "
-             f"{ag['tuned_rule']}. "
-             if tuned is not None else
-             "No swept nprobe below nlist reached 0.99 agreement on "
-             "this corpus — ship the exact head. "),
-            "",
-            "**Latency regime** (flagship classifier shape "
-            f"{ft['target_vocab']} x {ft['dim']}, nlist "
-            f"{ft['nlist']}, max list {ft['max_list_len']}; exact "
-            "streams the table once per batch, MIPS gathers nprobe "
-            "lists per ROW — so the crossover is batch size):",
-            "",
-            "| batch | nprobe | exact ms | MIPS ms | speedup |",
-            "|---|---|---|---|---|",
-        ] + [
-            (f"| {r['batch']} | {r['nprobe']} | {r['exact_ms']} "
-             f"| {r['mips_ms']} | {r['speedup']}x |"
-             if "skipped" not in r else
-             f"| {r['batch']} | {r['nprobe']} | — | — "
-             f"| skipped: {r['skipped']} |")
-            for r in ft["rows"]
-        ] + [
-            "",
-            "The head pays off at SERVE batch sizes over the big "
-            "vocab and loses to the shared streaming matmul at "
-            "bulk-eval batches — which is why the knob DEFAULTS to 0 "
-            "(exact blockwise top-k), accuracy evaluation always "
-            "scores the exact head (config.verify enforces), and "
-            "enabling it is recommended only for latency-sensitive "
-            "serving with small `--serve_batch_size`.",
-        ]
     lines += [
         "",
         "## Reproduce",
@@ -829,11 +605,6 @@ def main(argv=None) -> None:
     p.add_argument("--patience", type=int, default=3)
     p.add_argument("--skip-serving", action="store_true")
     p.add_argument("--skip-flagship", action="store_true")
-    p.add_argument("--skip-mips", action="store_true")
-    p.add_argument("--only-mips", action="store_true",
-                   help="recompute just the MIPS phase against the "
-                        "cached model, merge into the existing "
-                        "quant.json, rewrite the report")
     p.add_argument("--fresh", action="store_true",
                    help="discard the cached corpus/model/artifacts")
     args = p.parse_args(argv)
@@ -854,21 +625,9 @@ def main(argv=None) -> None:
 
     t_all = time.time()
     st = ensure_trained(args.root, args.epochs, args.patience, log)
-    if args.only_mips:
-        with open(OUT_PATH) as f:
-            result = json.load(f)
-        result["mips"] = mips_phase(st, log)
-        with open(OUT_PATH, "w") as f:
-            json.dump(result, f, indent=2)
-            f.write("\n")
-        write_report(result)
-        log(f"Rewrote {OUT_PATH} and {BENCH_MD} (MIPS phase only)")
-        return
     result = {"bench": "quant", "environment": env,
               "quality": quality_phase(st, workdir, log),
               "cold_start": cold_start_phase(st, workdir, log)}
-    if not args.skip_mips:
-        result["mips"] = mips_phase(st, log)
     if not args.skip_serving:
         result["serving"] = serving_phase(workdir, log)
     if not args.skip_flagship:

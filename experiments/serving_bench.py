@@ -59,8 +59,6 @@ EDGE_OUT_PATH = os.path.join(
     REPO, "experiments", "results", "serving_edge.json")
 SLO_OUT_PATH = os.path.join(
     REPO, "experiments", "results", "serving_slo.json")
-MIXED_OUT_PATH = os.path.join(
-    REPO, "experiments", "results", "serving_mixed.json")
 TENANTS_OUT_PATH = os.path.join(
     REPO, "experiments", "results", "serving_tenants.json")
 
@@ -854,262 +852,6 @@ def tracing_main() -> None:
         f"({regression_pct:+.2f}%, bar <2%) -> "
         f"{'ACCEPTED' if out['accepted'] else 'REGRESSION'}")
     log(f"Wrote {TRACING_OUT_PATH}")
-
-
-def mixed_main() -> None:
-    """`python experiments/serving_bench.py mixed`: the PR-18 A/B —
-    continuous batching + zero-copy request path and per-batch-shape
-    head dispatch. Three PAIRED-arm scenarios (PR-12 discipline: arms
-    sampled inside one load stream against one warmed model, so machine
-    drift and abrupt noise cancel), one output file
-    (experiments/results/serving_mixed.json):
-
-    - mixed_load: interleaved single-method + bulk-class traffic at 4
-      concurrent clients, cache off, classic collect-then-dispatch vs
-      --serve_continuous — two servers over the SAME model, and every
-      body is sent to BOTH servers back-to-back in per-slot shuffled
-      order (exact per-body pairing). Bar: continuous p50 < classic
-      p50 — a late single row rides the in-flight step's successor.
-    - uncontended: one serial client, single-method bodies, cache off —
-      the no-contention tax of the slot-reservation machinery. Bar:
-      continuous p50 regresses < 2% vs classic.
-    - single_row_head_dispatch: ReleaseModel.predict on model-ready
-      single-row lines (no HTTP/extraction, so the head difference is
-      not drowned in extractor latency), per-batch MIPS dispatch with
-      the crossover ADOPTED from the export calibration vs exact-only,
-      arms alternated per call in shuffled pair order. Bar: hybrid
-      p50 < exact p50.
-
-    Also re-asserts the compile-count bound: serving traffic through
-    both HTTP arms triggers <= len(buckets) pjit compilations at the
-    serve row shape."""
-    import dataclasses
-
-    from code2vec_tpu.serving.server import PredictionServer
-    from experiments.javagen import NOUNS, generate_class
-
-    def log(msg: str) -> None:
-        print(msg, flush=True)
-
-    log("Building model + corpus (mixed-load / head-dispatch A/B) ...")
-    model = build_model()
-    grng = random.Random(18)
-    singles = [generate_class(grng, NOUNS, f"Single{i}", "com.bench", 1)
-               for i in range(12)]
-    bulks = [generate_class(grng, NOUNS, f"Bulk{i}", "com.bench", 8)
-             for i in range(4)]
-
-    base = dataclasses.replace(model.config, serve_cache_entries=0)
-    classic = PredictionServer(model, base, log=lambda m: None)
-    continuous = PredictionServer(
-        model, dataclasses.replace(base, serve_continuous=True,
-                                   serve_inflight_steps=2),
-        log=lambda m: None)
-    ports = {"classic": classic.start(port=0),
-             "continuous": continuous.start(port=0)}
-
-    def paired_stream(bodies_for, n_clients: int, slots: int, seed: int):
-        lat = {"classic": [], "continuous": []}
-        errors = [0]
-        lock = threading.Lock()
-
-        def client(ci: int) -> None:
-            crng = random.Random(seed + ci)
-            for k in range(slots):
-                body = bodies_for(crng, k)
-                order = ["classic", "continuous"]
-                crng.shuffle(order)
-                for arm in order:
-                    t0 = time.perf_counter()
-                    try:
-                        _post(ports[arm], body)
-                    except Exception:
-                        with lock:
-                            errors[0] += 1
-                        continue
-                    dt = time.perf_counter() - t0
-                    with lock:
-                        lat[arm].append(dt)
-
-        threads = [threading.Thread(target=client, args=(ci,))
-                   for ci in range(n_clients)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        return lat, errors[0]
-
-    def arm_stats(lat: dict) -> dict:
-        out = {}
-        for arm, samples in lat.items():
-            ordered = sorted(samples)
-            out[arm] = {
-                "samples": len(ordered),
-                "p50_ms": round(_pct(ordered, 0.50) * 1e3, 2),
-                "p90_ms": round(_pct(ordered, 0.90) * 1e3, 2),
-                "p99_ms": round(_pct(ordered, 0.99) * 1e3, 2),
-                "mean_ms": round(statistics.mean(ordered) * 1e3, 2),
-            }
-        return out
-
-    try:
-        log("Warming both servers (compiles + pool spin-up) ...")
-        for body in singles + bulks:
-            for port in ports.values():
-                _post(port, body)
-
-        log("Scenario mixed_load: 4 clients, 1-in-4 slots bulk, "
-            "paired per body ...")
-
-        def mixed_body(crng, k):
-            if k % 4 == 3:
-                return bulks[crng.randrange(len(bulks))]
-            return singles[crng.randrange(len(singles))]
-
-        rides0 = continuous.batcher.rides
-        cont_b0 = continuous.batcher.batches_dispatched
-        classic_b0 = classic.batcher.batches_dispatched
-        mixed_lat, mixed_errors = paired_stream(mixed_body, 4, 32, 1800)
-        mixed_stats = arm_stats(mixed_lat)
-        mixed = {
-            "clients": 4,
-            "slots_per_client": 32,
-            "bulk_every_slots": 4,
-            "errors": mixed_errors,
-            "arms": mixed_stats,
-            "continuous_inflight_rides":
-                continuous.batcher.rides - rides0,
-            "continuous_batches":
-                continuous.batcher.batches_dispatched - cont_b0,
-            "classic_batches":
-                classic.batcher.batches_dispatched - classic_b0,
-        }
-        log(f"  mixed_load: classic p50={mixed_stats['classic']['p50_ms']}"
-            f"ms continuous p50={mixed_stats['continuous']['p50_ms']}ms "
-            f"rides={mixed['continuous_inflight_rides']}")
-
-        log("Scenario uncontended: 1 serial client, singles only ...")
-        uncont_lat, uncont_errors = paired_stream(
-            lambda crng, k: singles[crng.randrange(len(singles))],
-            1, 80, 2600)
-        uncont_stats = arm_stats(uncont_lat)
-        u_classic = uncont_stats["classic"]["p50_ms"]
-        u_cont = uncont_stats["continuous"]["p50_ms"]
-        uncont_reg = round((u_cont - u_classic) / u_classic * 100.0, 2)
-        uncontended = {
-            "requests_per_arm": uncont_stats["classic"]["samples"],
-            "errors": uncont_errors,
-            "arms": uncont_stats,
-            "p50_regression_pct": uncont_reg,
-            "acceptance_bar_pct": 2.0,
-        }
-        log(f"  uncontended: classic p50={u_classic}ms continuous "
-            f"p50={u_cont}ms ({uncont_reg:+.2f}%, bar <2%)")
-
-        compiled = sum(1 for rows, _ in model._predict_steps
-                       if rows == SERVE_BATCH)
-        assert compiled <= len(model.context_buckets), (
-            f"serving triggered {compiled} compilations for "
-            f"{len(model.context_buckets)} buckets")
-    finally:
-        classic.drain(timeout=30)
-        continuous.drain(timeout=30)
-
-    log("Exporting calibrated artifact (head-dispatch arms) ...")
-    from code2vec_tpu.release.artifact import export_artifact
-    from code2vec_tpu.release.runtime import ReleaseModel
-    art_dir = os.path.join(WORKDIR, "mixed_artifact")
-    old_cfg = model.config
-    model.config = dataclasses.replace(old_cfg, serve_mips_nprobe=8)
-    try:
-        meta = export_artifact(model, art_dir, aot=False,
-                               log=lambda m: None)
-    finally:
-        model.config = old_cfg
-    crossover = int(meta.get("mips_crossover", 0) or 0)
-    rel_base = dataclasses.replace(
-        old_cfg, train_data_path_prefix=None, serve_artifact=art_dir,
-        serve_cache_entries=0)
-    exact_rm = ReleaseModel(rel_base, log=lambda m: None)
-    # serve_mips_crossover stays at the -1 default: the hybrid arm
-    # ADOPTS the crossover the export calibration just recorded
-    hybrid_rm = ReleaseModel(
-        dataclasses.replace(rel_base, serve_mips_nprobe=8),
-        log=lambda m: None)
-
-    max_ctx = int(old_cfg.max_contexts)
-    lrng = random.Random(99)
-
-    def mk_line(i: int) -> str:
-        ctxs = [f"tok{lrng.randrange(VOCAB)},p{lrng.randrange(VOCAB)},"
-                f"tok{lrng.randrange(VOCAB)}" for _ in range(10)]
-        return (f"get|n{i % (VOCAB // 2)} " + " ".join(ctxs)
-                + " " * (max_ctx - len(ctxs)))
-
-    lines = [mk_line(i) for i in range(24)]
-    log("Scenario single_row_head_dispatch: paired ReleaseModel "
-        f"predicts, calibrated crossover={crossover} ...")
-    exact_rm.predict(lines[:1])       # warmup: compiles both arms'
-    hybrid_rm.predict(lines[:1])      # steps outside the measurement
-    mips0 = _counter("serving_head_dispatch_total", head="mips")
-    head_lat = {"exact": [], "hybrid": []}
-    prng = random.Random(7)
-    for it in range(150):
-        line = lines[it % len(lines)]
-        order = [("exact", exact_rm), ("hybrid", hybrid_rm)]
-        prng.shuffle(order)
-        for arm, rm in order:
-            t0 = time.perf_counter()
-            rm.predict([line])
-            head_lat[arm].append(time.perf_counter() - t0)
-    mips_dispatches = int(_counter("serving_head_dispatch_total",
-                                   head="mips") - mips0)
-    head_stats = arm_stats(head_lat)
-    head = {
-        "calls_per_arm": 150,
-        "calibrated_crossover": crossover,
-        "calibration_us": meta.get("mips_calibration"),
-        "mips_dispatches": mips_dispatches,
-        "arms": head_stats,
-    }
-    log(f"  head dispatch: exact p50={head_stats['exact']['p50_ms']}ms "
-        f"hybrid p50={head_stats['hybrid']['p50_ms']}ms "
-        f"(mips dispatches {mips_dispatches})")
-
-    accepted = {
-        "mixed_p50_improves":
-            mixed_stats["continuous"]["p50_ms"]
-            < mixed_stats["classic"]["p50_ms"],
-        "uncontended_p50_regression_under_2pct": uncont_reg < 2.0,
-        "single_row_mips_beats_exact":
-            head_stats["hybrid"]["p50_ms"]
-            < head_stats["exact"]["p50_ms"],
-        "compile_count_bound": compiled <= len(model.context_buckets),
-    }
-    out = {
-        "bench": "serving_mixed",
-        "serve_batch_size": SERVE_BATCH,
-        "buckets": list(model.context_buckets),
-        "pjit_compilations_serving": compiled,
-        "pjit_compilations_bound": len(model.context_buckets),
-        "mixed_load": mixed,
-        "uncontended": uncontended,
-        "single_row_head_dispatch": head,
-        "accepted": accepted,
-        "all_accepted": all(accepted.values()),
-    }
-    os.makedirs(os.path.dirname(MIXED_OUT_PATH), exist_ok=True)
-    with open(MIXED_OUT_PATH, "w") as f:
-        json.dump(out, f, indent=2)
-        f.write("\n")
-    missed = ", ".join(k for k, v in accepted.items() if not v)
-    log(f"Wrote {MIXED_OUT_PATH} "
-        f"({'ALL ACCEPTED' if out['all_accepted'] else 'BARS MISSED: ' + missed})")
-    diag = os.environ.get("C2V_CHAOS_DIAG_DIR")
-    if diag:
-        from code2vec_tpu import obs
-        obs.exporters.write_prometheus(
-            os.path.join(diag, "serving_mixed_metrics.prom"))
 
 
 def fleet_main() -> None:
@@ -2572,8 +2314,6 @@ if __name__ == "__main__":
         edge_main()
     elif len(sys.argv) > 1 and sys.argv[1] == "slo":
         slo_main()
-    elif len(sys.argv) > 1 and sys.argv[1] == "mixed":
-        mixed_main()
     elif len(sys.argv) > 1 and sys.argv[1] == "tenants":
         tenants_main()
     elif len(sys.argv) > 1 and sys.argv[1] == "p95":

@@ -12,8 +12,6 @@
 # Usage: scripts/run_serving_bench.sh [extra args passed to the bench]
 #        scripts/run_serving_bench.sh resilience   # PR-9 overload +
 #        kill-replica scenarios -> results/serving_resilience.json
-#        scripts/run_serving_bench.sh mixed        # PR-18 continuous-
-#        batching + head-dispatch paired A/B -> results/serving_mixed.json
 #        scripts/run_serving_bench.sh tenants      # PR-20 tenancy
 #        overhead + hot-tenant fairness drill -> results/serving_tenants.json
 set -u -o pipefail

@@ -1146,236 +1146,161 @@ def test_config_release_scheme_validation():
                release_scheme="int2").verify()
 
 
-# -------------------------------------- approximate-MIPS head pins
+# ------------------------------------- the one served head (exact)
+
+_PROBE = "name|x1 tok1,p1,tok1 tok2,p2,tok2"
+# two sets of batch-mates, every row inside the 4-context bucket: the
+# probe rides the same compiled shape whoever comes with it
+_MATES = (["name|x2 tok3,p3,tok3",
+           "name|x3 tok1,p2,tok2 tok4,p0,tok4 tok5,p1,tok5",
+           "name|x4 tok0,p0,tok0"],
+          ["name|x5 tok5,p3,tok5 tok0,p1,tok1",
+           "name|x6 tok2,p2,tok2",
+           "name|x7 tok4,p1,tok3 tok3,p0,tok4 tok1,p3,tok0 tok2,p0,tok2"])
 
 
-@roofline
-@pytest.mark.parametrize("scheme", ["f32", "int8", "int4"])
-def test_mips_full_probe_matches_blockwise_exact(scheme):
-    """nprobe = nlist searches every row: the MIPS head must return the
-    exact blockwise head's top-k (indices and values) for every table
-    flavor."""
-    from code2vec_tpu.ops import quant
-    from code2vec_tpu.ops.topk import blockwise_matmul_top_k
-    from code2vec_tpu.retrieval.mips import MipsHead
-    rng = np.random.default_rng(11)
-    v, d, b, k, real = 500, 24, 6, 7, 470
-    t = rng.standard_normal((v, d)).astype(np.float32)
-    cv = rng.standard_normal((b, d)).astype(np.float32)
-    if scheme == "f32":
-        head = MipsHead.build(t, None, real_vocab=real, nlist=16, seed=0)
-        ref = blockwise_matmul_top_k(jnp.asarray(cv), jnp.asarray(t), k,
-                                     128, valid_rows=real)
-    elif scheme == "int8":
-        q, s = quant.quantize_rows(t)
-        head = MipsHead.build(q, s, real_vocab=real, nlist=16, seed=0)
-        ref = blockwise_matmul_top_k(jnp.asarray(cv), jnp.asarray(q), k,
-                                     128, scales=jnp.asarray(s),
-                                     valid_rows=real)
-    else:
-        q, s = quant.quantize_rows_int4(t)
-        head = MipsHead.build(q, s, real_vocab=real, int4_dim=d,
-                              nlist=16, seed=0)
-        ref = blockwise_matmul_top_k(jnp.asarray(cv), jnp.asarray(q), k,
-                                     128, scales=jnp.asarray(s),
-                                     valid_rows=real, int4_dim=d)
-    vals, idx = head.search(cv, k, nprobe=head.nlist)
-    np.testing.assert_array_equal(idx, np.asarray(ref.indices))
-    np.testing.assert_allclose(vals, np.asarray(ref.values), rtol=1e-5)
+def _release_config(model, art_dir):
+    return dataclasses.replace(model.config, train_data_path_prefix=None,
+                               model_load_path=None,
+                               serve_artifact=art_dir)
 
 
-@roofline
-def test_mips_agreement_on_clustered_table():
-    """On clustered data (what trained name embeddings look like,
-    BENCH_RETRIEVAL.md) a small nprobe already recovers the exact
-    top-1: agreement >= 0.95 at nprobe 4 of 20."""
-    from code2vec_tpu.ops.topk import blockwise_matmul_top_k
-    from code2vec_tpu.retrieval.mips import MipsHead
-    rng = np.random.default_rng(12)
-    centers = rng.standard_normal((20, 16)).astype(np.float32) * 4
-    t = np.repeat(centers, 40, axis=0) + \
-        rng.standard_normal((800, 16)).astype(np.float32) * 0.3
-    queries = centers[rng.integers(0, 20, 50)] + \
-        rng.standard_normal((50, 16)).astype(np.float32) * 0.3
-    head = MipsHead.build(t, None, real_vocab=800, nlist=20, seed=0)
-    _, approx = head.search(queries, 1, nprobe=4)
-    exact = blockwise_matmul_top_k(jnp.asarray(queries), jnp.asarray(t),
-                                   1, 256)
-    agreement = float((approx[:, 0]
-                       == np.asarray(exact.indices)[:, 0]).mean())
-    assert agreement >= 0.95, agreement
+def _assert_same_answer(mine, ref):
+    assert mine.topk_predicted_words == ref.topk_predicted_words
+    np.testing.assert_array_equal(
+        np.asarray(mine.topk_predicted_words_scores),
+        np.asarray(ref.topk_predicted_words_scores))
+    np.testing.assert_array_equal(mine.code_vector, ref.code_vector)
 
 
-@roofline
-def test_release_model_mips_matches_exact_at_full_probe(exported,
-                                                        tmp_path):
-    """serve_mips_nprobe = nlist through the real ReleaseModel predict
-    surface returns the exact model's predictions."""
+@pytest.mark.parametrize("n", [1, 2, 4])
+@pytest.mark.parametrize("surface", ["release", "facade"])
+def test_a_rows_answer_does_not_depend_on_its_batch_mates(exported,
+                                                          surface, n):
+    """One head, one padded shape a bucket: a row's served names,
+    scores and code vector are the same bits alone, beside one other
+    row and in a full batch of `serve_batch_size` live rows, whoever
+    the others are; at another place in the batch, the same names."""
     from code2vec_tpu.release.runtime import ReleaseModel
     model, art_dir, _ = exported
-    lines = ["name|x1 tok1,p1,tok1 tok2,p2,tok2" + " " * 14,
-             "name|x2 tok3,p3,tok3" + " " * 15]
-    cfg = dataclasses.replace(model.config, train_data_path_prefix=None,
-                              model_load_path=None,
-                              serve_artifact=art_dir)
-    exact = ReleaseModel(cfg, log=lambda m: None).predict(lines)
-    cfg_mips = dataclasses.replace(cfg, serve_mips_nprobe=10_000,
-                                   serve_mips_nlist=8)
-    rm = ReleaseModel(cfg_mips, log=lambda m: None)
-    assert rm.mips_head is not None
-    # the dominant table is device-resident exactly once: the head
-    # holds the reordered copy, the original-order table is never
-    # transferred
-    assert "target_embedding" not in rm.params
-    assert "target_embedding_scale" not in rm.params
-    approx = rm.predict(lines)
-    for e, a in zip(exact, approx):
-        assert e.topk_predicted_words == a.topk_predicted_words
-        np.testing.assert_allclose(a.topk_predicted_words_scores,
-                                   e.topk_predicted_words_scores,
-                                   rtol=1e-4)
+    served = model if surface == "facade" else ReleaseModel(
+        _release_config(model, art_dir), log=lambda m: None)
+    bs = int(model.config.serve_batch_size)
+    assert bs == 4
+
+    def predict(lines):
+        return served.predict(lines, batch_size=bs,
+                              with_code_vectors=True)
+
+    before = set(served._predict_steps)
+    ref = predict([_PROBE] + _MATES[1])[0]
+    _assert_same_answer(predict([_PROBE] + _MATES[0][:n - 1])[0], ref)
+    last = predict(_MATES[0][:n - 1] + [_PROBE])[-1]
+    assert last.topk_predicted_words == ref.topk_predicted_words
+    np.testing.assert_allclose(last.code_vector, ref.code_vector,
+                               rtol=1e-5, atol=1e-6)
+    # every call rode the one shape
+    assert (bs, 4) in served._predict_steps
+    assert set(served._predict_steps) - before <= {(bs, 4)}
 
 
-@roofline
-def test_facade_mips_predict_matches_exact_at_full_probe(tmp_path):
-    """The facade predict path honors serve_mips_nprobe too (serve
-    --load without an artifact): full probe == exact facade predict."""
-    (tmp_path / "exact").mkdir()
-    (tmp_path / "mips").mkdir()
-    model = _tiny_model(tmp_path / "exact")
-    lines = ["name|x1 tok1,p1,tok1 tok2,p2,tok2" + " " * 14]
-    exact = model.predict(lines)
-    mips_model = _tiny_model(tmp_path / "mips", predict=True,
-                             serve_mips_nprobe=10_000,
-                             serve_mips_nlist=8)
-    approx = mips_model.predict(lines)
-    assert mips_model.mips_head is not None
-    assert exact[0].topk_predicted_words == approx[0].topk_predicted_words
+@pytest.mark.parametrize("surface", ["release", "facade"])
+def test_warmup_compiles_one_step_a_bucket_and_a_lone_row_none(
+        exported, tmp_path, surface):
+    """After `warmup()` the served model holds exactly one compiled step
+    a context bucket, and a predict of ONE row finds its step there: no
+    second shape exists for few rows."""
+    from code2vec_tpu.release.runtime import ReleaseModel
+    model, art_dir, _ = exported
+    if surface == "facade":
+        served = _tiny_model(tmp_path)
+    else:
+        served = ReleaseModel(_release_config(model, art_dir),
+                              log=lambda m: None)
+    bs = int(served.config.serve_batch_size)
+    served.warmup()
+    buckets = served.context_buckets
+    assert served.predict_compile_count() == len(buckets) == 3
+    steps = dict(served._predict_steps)
+    assert set(steps) == {(bs, m) for m in buckets}
+    [answer] = served.predict([_PROBE], batch_size=bs)
+    assert answer.topk_predicted_words
+    assert served._predict_steps == steps           # the same objects
+    assert all(step._cache_size() == 1 for step in steps.values())
 
 
-@roofline
-def test_config_rejects_mips_misuse():
-    with pytest.raises(ValueError, match="serve_mips_nprobe"):
-        Config(train_data_path_prefix="<t>",
-               serve_mips_nprobe=4).verify()     # neither serve nor predict
-    with pytest.raises(ValueError, match="exact blockwise head"):
-        Config(train_data_path_prefix="<t>", serve=True,
-               test_data_path="x.c2v", serve_mips_nprobe=4).verify()
-    Config(train_data_path_prefix="<t>", serve=True,
-           serve_mips_nprobe=4).verify()
+@pytest.mark.parametrize("knob", ["float32", "int8", "int4"])
+def test_served_names_are_lax_top_k_over_the_dequantised_logits(
+        exported, tmp_path, knob):
+    """Through `ReleaseModel.predict`, whatever the table's bytes: the
+    served top-k names and their order are `lax.top_k` over the full
+    logits of the row's code vector against the dequantised table (the
+    real rows of it)."""
+    from code2vec_tpu.ops import quant
+    from code2vec_tpu.release.artifact import (
+        SCHEME_BY_KNOB, export_artifact, load_artifact,
+    )
+    from code2vec_tpu.release.runtime import ReleaseModel
+    model, _, _ = exported
+    art_dir = str(tmp_path / f"art_{knob}")
+    meta = export_artifact(model, art_dir, scheme=SCHEME_BY_KNOB[knob],
+                           aot=False, log=lambda m: None)
+    rm = ReleaseModel(_release_config(model, art_dir), log=lambda m: None)
+    lines = [_PROBE] + _MATES[0]
+    served = rm.predict(lines, with_code_vectors=True)
+    tables = load_artifact(art_dir).tables
+    q = np.asarray(tables["target_embedding"])
+    if knob == "float32":
+        table = q
+    elif knob == "int8":
+        table = quant.dequantize_rows(
+            q, np.asarray(tables["target_embedding.scale"]))
+    else:
+        d = model.dims.path_dim + 2 * model.dims.token_dim
+        table = quant.dequantize_rows_int4(
+            q, np.asarray(tables["target_embedding.scale"]), d)
+    real = int(meta["dims"]["real_target_vocab_size"])
+    k = min(int(meta["topk"]), real)
+    for row in served:
+        logits = jnp.asarray(row.code_vector, jnp.float32) \
+            @ jnp.asarray(table[:real], jnp.float32).T
+        _, want = jax.lax.top_k(logits, k)
+        assert row.topk_predicted_words == [
+            rm.vocabs.target_vocab.lookup_word(int(j)) for j in want]
 
 
-@roofline
-def test_config_rejects_crossover_misuse():
-    with pytest.raises(ValueError, match="serve_mips_crossover"):
-        Config(train_data_path_prefix="<t>", serve=True,
-               serve_mips_nprobe=4, serve_mips_crossover=-2).verify()
-    with pytest.raises(ValueError, match="no MIPS head"):
-        Config(train_data_path_prefix="<t>", serve=True,
-               serve_mips_crossover=2).verify()  # nprobe unset
-    Config(train_data_path_prefix="<t>", serve=True,
-           serve_mips_nprobe=4, serve_mips_crossover=2).verify()
-    # 0 (exact-only) is legal with or without a probe budget
-    Config(train_data_path_prefix="<t>", serve=True,
-           serve_mips_nprobe=4, serve_mips_crossover=0).verify()
+def test_an_older_exporters_mips_keys_are_ignored(exported, tmp_path):
+    """An artifact written under the removed `--serve_mips_nprobe`
+    carries `mips_crossover` and `mips_calibration` in its meta: it
+    loads, has the fingerprint of the same artifact without them and
+    answers bit for bit like it (the exact head serves every batch)."""
+    import shutil
 
-
-@roofline
-def test_release_hybrid_dispatch_parity_at_crossover(exported):
-    """Per-batch-shape head dispatch at the crossover boundary: with
-    --serve_mips_crossover 1 a single-row predict routes to the MIPS
-    head compiled at the crossover shape while a bulk predict takes the
-    exact blockwise head at the serve shape — and at full probe both
-    sides of the boundary must agree with the exact-only model (the
-    PR-14 agreement bar is exact equality at nprobe = nlist)."""
+    from code2vec_tpu.release.artifact import META_NAME, load_artifact
     from code2vec_tpu.release.runtime import ReleaseModel
     model, art_dir, meta = exported
-    single = ["name|x1 tok1,p1,tok1 tok2,p2,tok2" + " " * 14]
-    bulk = ["name|x1 tok1,p1,tok1" + " " * 15,
-            "name|x2 tok3,p3,tok3" + " " * 15,
-            "name|x3 tok1,p2,tok2" + " " * 15]
-    cfg = dataclasses.replace(model.config, train_data_path_prefix=None,
-                              model_load_path=None,
-                              serve_artifact=art_dir)
-    exact = ReleaseModel(cfg, log=lambda m: None)
-    hybrid_cfg = dataclasses.replace(cfg, serve_mips_nprobe=10_000,
-                                     serve_mips_nlist=8,
-                                     serve_mips_crossover=1)
-    rm = ReleaseModel(hybrid_cfg, log=lambda m: None)
-    assert rm.mips_rows == 1 and not rm._mips_all
-    # hybrid keeps the original-order table device-resident: the exact
-    # head serves every bulk batch (all-MIPS skips it)
-    assert "target_embedding" in rm.params
-    for mine, ref in zip(rm.predict(single), exact.predict(single)):
-        assert mine.topk_predicted_words == ref.topk_predicted_words
-        np.testing.assert_allclose(mine.topk_predicted_words_scores,
-                                   ref.topk_predicted_words_scores,
-                                   rtol=1e-4)
-    # the single row compiled/ran the MIPS step at the crossover shape,
-    # cached apart from the exact serve-shape steps
-    assert rm._mips_predict_steps and \
-        all(rows == 1 for rows, _ in rm._mips_predict_steps)
-    for mine, ref in zip(rm.predict(bulk), exact.predict(bulk)):
-        assert mine.topk_predicted_words == ref.topk_predicted_words
-        np.testing.assert_allclose(mine.topk_predicted_words_scores,
-                                   ref.topk_predicted_words_scores,
-                                   rtol=1e-4)
-    assert all(rows == int(meta["serve_batch_size"])
-               for rows, _ in rm._predict_steps)
-
-
-@roofline
-def test_release_crossover_zero_restores_exact_bitforbit(exported):
-    """--serve_mips_crossover 0 with a probe budget set must be
-    bit-for-bit the nprobe=0 path: no head built, no reordered device
-    copy, byte-identical scores."""
-    from code2vec_tpu.release.runtime import ReleaseModel
-    model, art_dir, _ = exported
-    lines = ["name|x1 tok1,p1,tok1 tok2,p2,tok2" + " " * 14,
-             "name|x2 tok3,p3,tok3" + " " * 15]
-    cfg = dataclasses.replace(model.config, train_data_path_prefix=None,
-                              model_load_path=None,
-                              serve_artifact=art_dir)
-    exact = ReleaseModel(cfg, log=lambda m: None)
-    off = dataclasses.replace(cfg, serve_mips_nprobe=4,
-                              serve_mips_nlist=8, serve_mips_crossover=0)
-    rm = ReleaseModel(off, log=lambda m: None)
-    assert rm.mips_head is None and rm._mips_step is None
-    assert rm.mips_rows == 0 and not rm._mips_all
-    assert "target_embedding" in rm.params
-    for mine, ref in zip(rm.predict(lines), exact.predict(lines)):
-        assert mine.topk_predicted_words == ref.topk_predicted_words
-        np.testing.assert_array_equal(
-            np.asarray(mine.topk_predicted_words_scores),
-            np.asarray(ref.topk_predicted_words_scores))
-
-
-@roofline
-def test_export_calibration_records_crossover(tmp_path):
-    """An exporter configured with a MIPS head runs the head-crossover
-    calibration pass: meta gains mips_crossover (largest MIPS-winning
-    row count) + the timing table, on disk and in the returned dict —
-    and the content fingerprint is unchanged vs an uncalibrated export
-    of the same tables (the fingerprint core excludes calibration)."""
-    from code2vec_tpu.release.artifact import export_artifact
-    model = _tiny_model(tmp_path)
-    plain = export_artifact(model, str(tmp_path / "plain"), aot=False,
-                            log=lambda m: None)
-    assert "mips_crossover" not in plain
-    old_cfg = model.config
-    model.config = dataclasses.replace(old_cfg, serve_mips_nprobe=4,
-                                       serve_mips_nlist=4)
-    try:
-        cal = export_artifact(model, str(tmp_path / "cal"), aot=False,
-                              log=lambda m: None)
-    finally:
-        model.config = old_cfg
-    assert isinstance(cal["mips_crossover"], int)
-    assert 0 <= cal["mips_crossover"] <= int(cal["serve_batch_size"])
-    assert cal["mips_calibration"]
-    for timing in cal["mips_calibration"].values():
-        assert set(timing) == {"exact", "mips"}
-    assert cal["fingerprint"] == plain["fingerprint"]
-    with open(os.path.join(tmp_path, "cal", "release_meta.json")) as f:
-        on_disk = json.load(f)
-    assert on_disk["mips_crossover"] == cal["mips_crossover"]
+    old_dir = str(tmp_path / "written_by_an_older_exporter")
+    shutil.copytree(art_dir, old_dir)
+    with open(os.path.join(old_dir, META_NAME)) as f:
+        old_meta = json.load(f)
+    assert "mips_crossover" not in old_meta
+    old_meta["mips_crossover"] = 2
+    old_meta["mips_calibration"] = {
+        "1": {"exact": 911.4, "mips": 402.7},
+        "2": {"exact": 930.2, "mips": 671.9},
+        "4": {"exact": 951.0, "mips": 1290.3}}
+    with open(os.path.join(old_dir, META_NAME), "w") as f:
+        json.dump(old_meta, f, indent=2, sort_keys=True)
+        f.write("\n")
+    assert load_artifact(old_dir).fingerprint == meta["fingerprint"]
+    plain = ReleaseModel(_release_config(model, art_dir),
+                         log=lambda m: None)
+    old = ReleaseModel(_release_config(model, old_dir),
+                       log=lambda m: None)
+    assert old.model_fingerprint() == plain.model_fingerprint()
+    for lines in ([_PROBE], [_PROBE] + _MATES[0]):      # 1 row, a full batch
+        for mine, ref in zip(
+                old.predict(lines, with_code_vectors=True),
+                plain.predict(lines, with_code_vectors=True)):
+            _assert_same_answer(mine, ref)
+    assert set(old._predict_steps) == set(plain._predict_steps)

@@ -296,10 +296,10 @@ class _HeldCall:
             assert self._go.wait(10), "the held call was never released"
         return self.fn(lines)
 
-    def hold(self, batcher, **kwargs):
+    def hold(self, batcher, rows=("hold",), **kwargs):
         """Submit the request whose call is held; returns its future
         once the dispatcher is inside `predict_fn`."""
-        future = batcher.submit(["hold"], **kwargs)
+        future = batcher.submit(list(rows), **kwargs)
         assert self.entered.wait(10)
         return future
 
@@ -307,18 +307,36 @@ class _HeldCall:
         self._go.set()
 
 
+class _TokenRow(str):
+    """A `/score` row as the batcher sees one: something with `.ids`
+    (lm_facade.ScoreRequest). A string besides, so that one `predict_fn`
+    answers both kinds of row."""
+
+    @property
+    def ids(self):
+        return self.encode()
+
+
 def _make_batcher(kind, predict_fn, **kwargs):
-    """Either batcher over a plain `predict_fn`; the continuous one with
-    a single worker, so that one held call holds the whole dispatcher
-    as it does in the classic batcher."""
-    from code2vec_tpu.serving import batcher as batcher_mod
-    if kind == "classic":
-        return batcher_mod.DynamicBatcher(predict_fn, **kwargs)
-    return batcher_mod.ContinuousBatcher(predict_fn, inflight_steps=1,
-                                         **kwargs)
+    """The batcher as the benchmark's cells build it (server.py): over
+    extractor LINES (code2vec's facades: rows cap a batch), or over
+    TOKEN rows with the `bucket_of` and `max_batch_tokens` that
+    `ScoringModel.batcher_options()` gives and the model's buckets."""
+    from code2vec_tpu.serving.batcher import DynamicBatcher
+    if kind == "tokens":
+        from types import SimpleNamespace
+        from code2vec_tpu.lm_facade import ScoringModel
+        model = SimpleNamespace(_buckets=(16, 32), token_budget=16 * 16)
+        kwargs = dict(ScoringModel.batcher_options(model),
+                      buckets=model._buckets, **kwargs)
+    return DynamicBatcher(predict_fn, **kwargs)
 
 
-BATCHERS = ("classic", "continuous")
+def _rows(kind, *names):
+    return [(_TokenRow if kind == "tokens" else str)(n) for n in names]
+
+
+BATCHERS = ("lines", "tokens")
 
 
 def test_batcher_coalesces_concurrent_requests():
@@ -372,15 +390,14 @@ def test_batcher_flushes_on_delay_and_preserves_order():
     batcher.drain()
 
 
-@pytest.mark.parametrize("kind,workers", [("classic", 1), ("continuous", 2)])
-def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
-                                                       workers):
+@pytest.mark.parametrize("kind", BATCHERS)
+def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind):
     """`serving_dispatcher_seconds{state}`: idle, delay and dispatch are
-    observed on leaving each state and together account for every
+    observed on leaving each state and together account for the
     dispatcher thread's life; dispatch over the wall time is the busy
-    share of the thread(s) every request passes through. A free
-    dispatcher dispatches: with no parse to wait for, nothing is ever
-    spent in `delay`."""
+    share of the thread every request passes through. A free
+    dispatcher dispatches: with nobody en route, nothing is ever spent
+    in `delay`."""
     from code2vec_tpu.obs.metrics import Histogram
     from code2vec_tpu.serving import batcher as batcher_mod
     states = {s: Histogram() for s in ("idle", "delay", "dispatch")}
@@ -390,15 +407,11 @@ def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
         time.sleep(0.02)
         return [l.upper() for l in lines]
 
-    t0 = time.perf_counter()
-    if kind == "classic":
-        batcher = batcher_mod.DynamicBatcher(predict_fn, max_batch_rows=8)
-    else:
-        batcher = batcher_mod.ContinuousBatcher(
-            predict_fn, max_batch_rows=8, inflight_steps=workers)
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=8)
+    t0 = time.perf_counter()        # behind the model's import
     for i in range(6):
-        assert batcher.submit([f"a{i}", f"b{i}"]).result(timeout=10) \
-            == [f"A{i}", f"B{i}"]
+        assert batcher.submit(_rows(kind, f"a{i}", f"b{i}")).result(
+            timeout=10) == [f"A{i}", f"B{i}"]
         time.sleep(0.01 * i)            # some idle time between requests
     batcher.drain(timeout=10)
     wall = time.perf_counter() - t0
@@ -408,7 +421,7 @@ def test_dispatcher_states_cover_the_threads_wall_time(monkeypatch, kind,
     assert states["delay"].count == 0     # nobody waits out a window
     assert states["idle"].count >= 1
     covered = sum(h.sum for h in states.values())
-    assert covered == pytest.approx(wall * workers, rel=0.1, abs=0.05)
+    assert covered == pytest.approx(wall, rel=0.1, abs=0.05)
 
 
 def test_batcher_error_propagates_and_drain_refuses():
@@ -448,8 +461,8 @@ def test_device_time_tracker_caches_sorted_view():
 def test_batch_span_attrs_shared_and_thread_count_stable():
     """The dispatch thread builds ONE batch-span attrs dict per batch —
     every member trace holds the same object by reference, not a
-    per-member dict construction; and the classic batcher runs exactly
-    one dispatcher thread."""
+    per-member dict construction; and the batcher runs exactly one
+    dispatcher thread."""
     from code2vec_tpu.obs.reqtrace import RequestTrace
     from code2vec_tpu.serving.batcher import DynamicBatcher
     before = threading.active_count()
@@ -483,181 +496,32 @@ def test_batch_span_attrs_shared_and_thread_count_stable():
     batcher.drain()
 
 
-# ------------------------------------------------ continuous batcher
-
-
-def test_continuous_row_rides_step_n_plus_1():
-    """A row admitted while step N is on device rides step N+1 the
-    moment the worker frees — never step N+2 when a slot is free."""
-    from code2vec_tpu.serving.batcher import ContinuousBatcher
-    calls = []
-
-    def predict(lines):
-        calls.append(list(lines))
-        time.sleep(0.25)
-        return [l.upper() for l in lines]
-
-    batcher = ContinuousBatcher(predict, max_batch_rows=4,
-                                inflight_steps=1)
-    # the worker is free -> step N dispatches immediately
-    f1 = batcher.submit(["a1", "a2", "a3", "a4"])
-    time.sleep(0.1)                      # step N is on device now
-    t0 = time.perf_counter()
-    f2 = batcher.submit(["b"])           # admitted mid-step-N
-    assert f1.result(timeout=10) == ["A1", "A2", "A3", "A4"]
-    assert f2.result(timeout=10) == ["B"]
-    waited = time.perf_counter() - t0
-    # rode step N+1 (~0.15s left of N + 0.25s of N+1) instead of
-    # waiting for step N+2
-    assert waited < 1.0, waited
-    assert batcher.batches_dispatched == 2
-    assert calls == [["a1", "a2", "a3", "a4"], ["b"]]
-    assert batcher.rides == 1
-    batcher.drain()
-
-
-def test_continuous_refusal_against_inflight_eta():
-    """Deadline-infeasible refusal is re-expressed against the
-    in-flight step's ETA: a budget that covers the bucket p95 alone but
-    NOT eta + p95 is refused while a step occupies the only worker, and
-    admitted once the worker is free."""
-    from code2vec_tpu.serving.admission import (
-        Deadline, DeadlineInfeasible,
-    )
-    from code2vec_tpu.serving.batcher import ContinuousBatcher
-    release = threading.Event()
-
-    def predict(lines):
-        release.wait(10)
-        return list(lines)
-
-    batcher = ContinuousBatcher(predict, max_batch_rows=1,
-                                inflight_steps=1)
-    for _ in range(4):
-        batcher.device_times.record(None, 0.5)   # p95 = 0.5s
-    f1 = batcher.submit(["x"])                   # occupies the worker
-    deadline_waited = time.perf_counter() + 2.0
-    while batcher._inflight == 0:
-        assert time.perf_counter() < deadline_waited
-        time.sleep(0.005)
-    # 0.8s budget > p95 0.5s (the classic check would admit), but the
-    # in-flight step needs ~0.5s more before a worker frees: refused.
-    f2 = batcher.submit(["y"], deadline=Deadline(0.8))
-    with pytest.raises(DeadlineInfeasible):
-        f2.result(timeout=5)
-    release.set()
-    f1.result(timeout=10)
-    while batcher._inflight:
-        time.sleep(0.005)
-    # worker free -> eta 0 -> the same budget is feasible again
-    f3 = batcher.submit(["z"], deadline=Deadline(0.8))
-    assert f3.result(timeout=10) == ["z"]
-    batcher.drain()
-
-
-def test_continuous_drain_flushes_partial_slot():
-    from code2vec_tpu.serving.batcher import ContinuousBatcher
+@pytest.mark.parametrize("kind", BATCHERS)
+def test_drain_dispatches_what_is_pending_before_it_joins(kind):
+    """`drain()` stops intake, cuts what piled up behind the call in
+    flight, settles those futures and only then ends the thread."""
     predict_fn = _HeldCall(lambda lines: [l * 2 for l in lines])
-    batcher = ContinuousBatcher(predict_fn, max_batch_rows=100,
-                                inflight_steps=1)
-    held = predict_fn.hold(batcher)
-    f = batcher.submit(["q"])           # a partial slot behind the call
+    batcher = _make_batcher(kind, predict_fn, max_batch_rows=100)
+    held = predict_fn.hold(batcher, _rows(kind, "hold"))
+    f = batcher.submit(_rows(kind, "q"))    # pending behind the call
     drainer = threading.Thread(target=batcher.drain, args=(10,))
-    drainer.start()                     # intake stops while it forms
+    drainer.start()                         # intake stops meanwhile
+    deadline = time.monotonic() + 5
+    while not batcher._draining and time.monotonic() < deadline:
+        time.sleep(0.005)
+    refused = batcher.submit(_rows(kind, "z"))
     predict_fn.release()
     drainer.join(timeout=15)
-    assert not drainer.is_alive()
-    assert held.result(timeout=1) == ["holdhold"]
-    assert f.result(timeout=1) == ["qq"]
-    f2 = batcher.submit(["z"])
+    assert not drainer.is_alive() and not batcher._thread.is_alive()
+    # both were settled by the time the join returned
+    assert held.done() and held.result() == ["holdhold"]
+    assert f.done() and f.result() == ["qq"]
+    assert predict_fn.calls == [["hold"], ["q"]]
     with pytest.raises(RuntimeError, match="draining"):
-        f2.result(timeout=5)
+        refused.result(timeout=5)
 
 
-def test_continuous_serial_client_byte_identical(served_model,
-                                                 fake_extractor,
-                                                 tmp_path):
-    """For a serial client (no concurrency, so continuous batching has
-    nothing to chain) the zero-copy slot path must answer byte-for-byte
-    what collect-then-dispatch answers."""
-    import dataclasses
-    from code2vec_tpu.serving.server import PredictionServer
-    codes = [
-        "class A { int f(int n) { return n; } } NCTX2",
-        "class B { int g() { return 2; } int h() { return 3; } NCTX5 }",
-        "class C { void noop() { } } NCTX1",
-    ]
-    classic = PredictionServer(served_model, served_model.config,
-                               log=lambda m: None)
-    continuous = PredictionServer(
-        served_model,
-        dataclasses.replace(served_model.config, serve_continuous=True,
-                            serve_inflight_steps=2),
-        log=lambda m: None)
-    try:
-        from code2vec_tpu.serving.batcher import ContinuousBatcher
-        assert isinstance(continuous.batcher, ContinuousBatcher)
-        assert not isinstance(classic.batcher, ContinuousBatcher)
-        for endpoint in ("predict", "embed"):
-            for code in codes:
-                s1, b1, _ = classic.handle_request(endpoint, code)
-                s2, b2, _ = continuous.handle_request(endpoint, code)
-                assert (s1, s2) == (200, 200)
-                assert b1 == b2, (endpoint, code)
-        # the continuous arm really took the zero-copy rows path: its
-        # batches dispatched without a single lines-mode fallback
-        assert continuous.batcher.batches_dispatched >= len(codes)
-    finally:
-        classic.drain(timeout=10)
-        continuous.drain(timeout=10)
-
-
-def test_continuous_stale_parse_falls_back_to_lines_path():
-    """A slot whose rows were parsed under a fingerprint that is no
-    longer live (the model hot-swapped between parse and dispatch) must
-    be re-dispatched through predict_lines under the CURRENT model —
-    results settle normally, every response from one batch carries one
-    fingerprint, no error surfaces to the caller."""
-    from code2vec_tpu.serving.batcher import ContinuousBatcher, StaleParse
-
-    calls = {"rows": 0, "lines": 0}
-
-    class _Buf:
-        def __init__(self, rows):
-            self.context_valid_mask = np.zeros((rows, 4), np.float32)
-            self.example_valid = np.zeros((rows,), bool)
-
-    class _Backend:
-        def supports_rows(self):
-            return True
-
-        def alloc(self, rows):
-            return _Buf(rows)
-
-        def parse_into(self, lines, buffer, row_offset):
-            return "fpOLD"
-
-        def predict_rows(self, buffer, n_rows, fingerprint):
-            calls["rows"] += 1
-            raise StaleParse("model swapped after parse")
-
-        def predict_lines(self, lines):
-            calls["lines"] += 1
-            return [f"fpNEW:{ln}" for ln in lines]
-
-    b = ContinuousBatcher(max_batch_rows=4, backend=_Backend(),
-                          inflight_steps=1)
-    try:
-        futs = [b.submit([f"l{i}"]) for i in range(2)]
-        out = [f.result(timeout=5) for f in futs]
-    finally:
-        b.drain(timeout=5)
-    assert calls["rows"] >= 1, "rows path never attempted"
-    assert calls["lines"] >= 1, "StaleParse did not fall back to lines"
-    assert out == [["fpNEW:l0"], ["fpNEW:l1"]]
-
-
-# ------------------------- the dispatch rule, shared by both batchers
+# ------------- the dispatch rule, for a batcher of lines and of tokens
 
 
 @pytest.mark.parametrize("kind", BATCHERS)
@@ -669,7 +533,7 @@ def test_lone_request_on_idle_batcher_dispatches_at_once(kind):
     batcher = _make_batcher(kind, predict_fn, max_batch_rows=64)
     try:
         phases = {}
-        first = batcher.submit(["solo"], phases=phases)
+        first = batcher.submit(_rows(kind, "solo"), phases=phases)
         assert predict_fn.entered.wait(10)      # inside the model call
         assert predict_fn.calls == [["solo"]]   # ... with nothing else
         predict_fn.release()
@@ -680,14 +544,12 @@ def test_lone_request_on_idle_batcher_dispatches_at_once(kind):
         batcher.drain(timeout=10)
 
 
-@pytest.mark.parametrize("kind,budget", [("classic", None),
-                                         ("continuous", None),
-                                         ("classic", 512)])
-def test_requests_behind_a_held_call_are_cut_as_one_batch(kind, budget):
+@pytest.mark.parametrize("budget", [None, 512])
+def test_requests_behind_a_held_call_are_cut_as_one_batch(budget):
     """The call in flight is the only batching window: what was
     submitted behind it is cut the moment it returns, in submit order,
-    inside the row cap and (classic batcher, a model that sets one) the
-    token budget."""
+    inside the row cap and (a model that sets one) the token budget."""
+    from code2vec_tpu.serving.batcher import DynamicBatcher
     from code2vec_tpu.serving.batcher import bucket_for
     predict_fn = _HeldCall(lambda lines: list(lines))
     kwargs = {}
@@ -701,7 +563,7 @@ def test_requests_behind_a_held_call_are_cut_as_one_batch(kind, budget):
         sent = [["x" * 100], ["y" * 100], ["z" * 200], ["w" * 100]]
         # 3 rows x the 256 bucket would pass 512 tokens; 2 x 256 fits
         want = [["x" * 100, "y" * 100], ["z" * 200, "w" * 100]]
-    batcher = _make_batcher(kind, predict_fn, max_batch_rows=4, **kwargs)
+    batcher = DynamicBatcher(predict_fn, max_batch_rows=4, **kwargs)
     try:
         held = predict_fn.hold(batcher)
         futures = [batcher.submit(lines) for lines in sent]
@@ -725,9 +587,11 @@ def test_request_expiring_behind_a_held_call_settles_504(kind):
     predict_fn = _HeldCall()
     batcher = _make_batcher(kind, predict_fn, max_batch_rows=8)
     try:
-        held = predict_fn.hold(batcher)
-        doomed = batcher.submit(["late"], deadline=Deadline(0.05))
-        alive = batcher.submit(["fine"], deadline=Deadline(30.0))
+        held = predict_fn.hold(batcher, _rows(kind, "hold"))
+        doomed = batcher.submit(_rows(kind, "late"),
+                                deadline=Deadline(0.05))
+        alive = batcher.submit(_rows(kind, "fine"),
+                               deadline=Deadline(30.0))
         time.sleep(0.15)                        # the budget runs out
         predict_fn.release()
         assert held.result(timeout=10) == ["HOLD"]
@@ -753,9 +617,9 @@ def test_cut_idle_ratio_tells_straight_through_from_behind_a_call(
     predict_fn = _HeldCall()
     batcher = _make_batcher(kind, predict_fn, max_batch_rows=8)
     try:
-        held = predict_fn.hold(batcher)
+        held = predict_fn.hold(batcher, _rows(kind, "hold"))
         assert (cut_idle.sum, cut_idle.count) == (1.0, 1)
-        behind = [batcher.submit([f"b{i}"]) for i in range(3)]
+        behind = [batcher.submit(_rows(kind, f"b{i}")) for i in range(3)]
         predict_fn.release()
         for f in [held] + behind:
             f.result(timeout=10)
@@ -763,7 +627,8 @@ def test_cut_idle_ratio_tells_straight_through_from_behind_a_call(
         # once the dispatcher is back from the call and free again,
         # the next one goes straight through
         time.sleep(0.05)
-        assert batcher.submit(["again"]).result(timeout=10) == ["AGAIN"]
+        assert batcher.submit(_rows(kind, "again")).result(timeout=10) \
+            == ["AGAIN"]
         assert (cut_idle.sum, cut_idle.count) == (2.0, 3)
     finally:
         predict_fn.release()
@@ -1229,6 +1094,18 @@ def test_http_end_to_end(server):
     assert _post(server.port, "predict", "BOOM_ALWAYS")[0] == 422
     assert _post(server.port, "nope", "x")[0] == 404
     assert _post(server.port, "predict", "CRASH_ALWAYS f(")[0] == 503
+
+
+def test_healthz_batcher_block_names_the_one_batcher(server):
+    """`/healthz`'s `batcher` block: the row cap and the batches
+    dispatched so far, and nothing that told two batchers apart."""
+    assert _post(server.port, "predict",
+                 "class A { int f(int n) { return n; } }")[0] == 200
+    with urllib.request.urlopen(
+            f"http://127.0.0.1:{server.port}/healthz", timeout=30) as r:
+        block = json.loads(r.read())["batcher"]
+    assert block == {"max_batch_rows": server.config.serve_batch_size,
+                     "batches_dispatched": 1}
 
 
 def test_http_coalesces_concurrent_requests(server, monkeypatch):
@@ -1877,3 +1754,17 @@ def test_serve_cli_flags_parse():
         config_from_args(["serve", "--load", "/tmp/x",
                           "--serve_max_delay_ms", "2.5"])
     assert not hasattr(config2, "serve_max_delay_ms")
+
+
+@pytest.mark.parametrize("flag", [
+    ["--serve_continuous"], ["--serve_inflight_steps", "2"],
+    ["--serve_mips_nprobe", "8"], ["--serve_mips_nlist", "64"],
+    ["--serve_mips_crossover", "4"]])
+def test_flags_of_the_second_batcher_and_head_are_refused(flag):
+    """One dispatcher and one head serve: the options that chose another
+    are rejected by the parser, not ignored, and `Config` holds no such
+    field."""
+    from code2vec_tpu.cli import config_from_args
+    with pytest.raises(SystemExit):
+        config_from_args(["serve", "--load", "/tmp/x"] + flag)
+    assert not hasattr(Config(), flag[0].lstrip("-"))

@@ -625,66 +625,6 @@ def test_hot_swap_under_live_traffic_single_fingerprint_responses(
     assert hz["model"]["swap_status"]["swapped_fingerprint"] == "fpB"
 
 
-def test_hot_swap_under_continuous_batching_single_fingerprint(
-        chaos_server):
-    """The continuous dispatcher (--serve_continuous) under a live
-    hot-swap: every response still carries exactly ONE model
-    fingerprint (old or new, never a mix) and none is malformed.
-    FakeModel lacks the zero-copy slot surface, so the backend's
-    supports_rows guard degrades every slot to the lines path — the
-    slot/chaining machinery is exercised end to end and the
-    one-fingerprint law must hold either way."""
-    from code2vec_tpu.serving.batcher import ContinuousBatcher
-    from code2vec_tpu.serving.swap import SwapManager
-
-    srv, _ = chaos_server(serve_cache_entries=0, serve_continuous=True,
-                          serve_inflight_steps=2)
-    assert isinstance(srv.batcher, ContinuousBatcher)
-
-    def build_b(artifact_dir):
-        assert artifact_dir == "artifact-b"
-        time.sleep(0.3)  # overlap the load: old model keeps serving
-        return FakeModel(srv.config, fingerprint="fpB")
-
-    srv.swap = SwapManager(srv, build_model=build_b)
-    seen, malformed = [], []
-    stop_load = threading.Event()
-
-    def load(ci):
-        i = 0
-        while not stop_load.is_set():
-            status, body, _ = _post(
-                srv.port, "predict",
-                f"class C{ci}x{i} {{ int m{ci}x{i}() {{ return 1; }} }}")
-            assert status == 200
-            try:
-                seen.append(json.loads(body)["model_fingerprint"])
-            except Exception:
-                malformed.append(body)
-            i += 1
-
-    threads = [threading.Thread(target=load, args=(ci,))
-               for ci in range(3)]
-    for t in threads:
-        t.start()
-    try:
-        time.sleep(0.1)
-        status, _, _ = _post(srv.port, "admin/reload",
-                             json.dumps({"artifact": "artifact-b"}),
-                             headers={"Content-Type":
-                                      "application/json"})
-        assert status == 202
-        assert _wait_swap_state(srv, {"ready"}) == "ready"
-        time.sleep(0.2)  # post-swap traffic
-    finally:
-        stop_load.set()
-        for t in threads:
-            t.join(timeout=30)
-    assert not malformed, malformed[:3]
-    assert set(seen) <= {"fpA", "fpB"}, f"mixed fingerprints: {set(seen)}"
-    assert seen[-1] == "fpB" and "fpB" in seen
-
-
 def test_swap_validation_failure_leaves_old_model_serving(chaos_server):
     """A candidate with a mismatched output schema (narrower top-k) is
     REJECTED: swap status failed + visible in /healthz, old fingerprint
@@ -895,69 +835,6 @@ def test_drain_timeout_exits_nonzero_with_abandoned_count(
         hb = json.loads(hb_path.read_text())
     assert hb["status"] == "error"
     assert hb["abandoned_requests"] >= 1
-
-
-def test_sigterm_drain_under_continuous_batching_exits_zero(
-        tmp_path, fake_extractor, monkeypatch):
-    """serve_main with --serve_continuous: SIGTERM (the stop event the
-    signal handler sets) lands while a request is in flight — the drain
-    flushes the dispatcher's forming slots and in-flight steps, the
-    in-flight response completes well-formed, and the exit code is 0."""
-    from code2vec_tpu.serving.server import serve_main
-
-    monkeypatch.setenv("C2V_FAKE_SLEEP", "0.4")
-    hb_path = tmp_path / "serve.heartbeat.json"
-    config = _chaos_config(tmp_path, serve_port=0,
-                           serve_continuous=True,
-                           serve_inflight_steps=2,
-                           serve_drain_timeout_s=15.0,
-                           serve_heartbeat_interval_s=0.1,
-                           heartbeat_file=str(hb_path))
-    model = FakeModel(config)
-    stop = threading.Event()
-    rc_holder, results = {}, {}
-
-    def run():
-        rc_holder["rc"] = serve_main(config, model=model, stop=stop,
-                                     install_signals=False)
-
-    serve_thread = threading.Thread(target=run)
-    serve_thread.start()
-    slow = None
-    try:
-        deadline = time.time() + 10
-        port = None
-        while port is None and time.time() < deadline:
-            try:
-                port = json.loads(hb_path.read_text()).get("port")
-            except (OSError, ValueError):
-                time.sleep(0.02)
-        assert port, "server heartbeat never reported a port"
-
-        def slow_post():
-            results["slow"] = _post(
-                port, "predict",
-                "class S { int inflight() { return 1; } } SLOW_MARKER")
-
-        slow = threading.Thread(target=slow_post)
-        slow.start()
-        deadline = time.time() + 5
-        while time.time() < deadline:
-            try:
-                if json.loads(hb_path.read_text()).get("inflight", 0):
-                    break
-            except (OSError, ValueError):
-                pass
-            time.sleep(0.02)
-    finally:
-        stop.set()
-    serve_thread.join(timeout=30)
-    if slow is not None:
-        slow.join(timeout=30)
-    assert rc_holder["rc"] == 0
-    status, body, _ = results["slow"]
-    assert status == 200
-    assert json.loads(body)["model_fingerprint"] == "fpA"
 
 
 def test_total_phase_histogram_records_every_terminal_status(
