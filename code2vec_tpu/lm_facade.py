@@ -24,13 +24,16 @@ cache is the module's own: a tuple with one entry a layer, each entry an
 array or a tuple of arrays (two kinds of state a token); the facade
 holds it, hands it to the steps, donates it to registration and counts
 its bytes, and reads nothing inside it. A module says what a
-slot IS: rows of the cache a token (the default), or with `CACHE_KIND =
-"state"` one state of fixed size whatever the context's length
-(models/retention_lm.py): registration then CARRIES the slot's state from
-chunk to chunk (a chunk that starts at 0 starts from zeros, so a reused
-slot never leaks what it held), `tokens_per_slot` is only the longest
-context admitted, and the gauges read slots held over slots. A
-context is registered once (`register_context`: chunk by chunk under
+slot IS with its `CACHE_KIND`, and `CACHE_KINDS` below is the ONE place
+that says what each kind means: rows of the cache a token (the
+default), one state of fixed size whatever the context's length
+(`"state"`, models/retention_lm.py: registration then CARRIES the
+slot's state from chunk to chunk, a chunk that starts at 0 starting from
+zeros so that a reused slot never leaks what it held; `tokens_per_slot`
+is only the longest context admitted, and the gauges read slots held
+over slots), either of them with a list of PAGES of a pool beside it
+(`"paged"`, models/window_moe_lm.py; `"state+pages"`,
+models/delta_moe_lm.py). A context is registered once (`register_context`: chunk by chunk under
 one compiled shape, into a free or the least recently used slot,
 serving/context_cache.py), and a request may then name it: its tokens
 are scored as the continuation of the context's. The cache arrays are
@@ -63,14 +66,14 @@ from code2vec_tpu.model_facade import (
     _H_FILL, _device_part, _stage, head_sorted_columns_gauge,
 )
 from code2vec_tpu.models import (
-    hybrid_lm, latent_moe_lm, lm_common, retention_lm, sparse_gqa_moe_lm,
-    window_moe_lm,
+    delta_moe_lm, hybrid_lm, latent_moe_lm, lm_common, retention_lm,
+    sparse_gqa_moe_lm, window_moe_lm,
 )
 from code2vec_tpu.ops.sparse_attn import unpack_bits
 from code2vec_tpu.ops.topk import sorted_columns
 from code2vec_tpu.serving.batcher import bucket_for, parse_buckets
 from code2vec_tpu.serving.context_cache import (
-    ContextSlots, PoolTooSmall, chunks, context_id,
+    ContextSlots, PoolTooSmall, TooLong, chunks, context_id, extended_id,
 )
 from code2vec_tpu.training import checkpoint as ckpt_mod
 from code2vec_tpu.utils.device import describe_devices
@@ -138,6 +141,14 @@ _C_KEYS_SELECTED = obs.counter(
     "keys the selection kept (the step's own count, fetched with the "
     "answer), summed over a scoring step's rows, real queries and layers")
 
+_C_SCORE_STATES = obs.counter(
+    "score_states_read_total",
+    "(row, layer) recurrent states a scoring or extending step's rows "
+    "read from their contexts' cache slots, whatever the module")
+_C_SCORE_STATE_BYTES = obs.counter(
+    "score_state_bytes_read_total",
+    "bytes of the states (and what a slot keeps beside them) that a "
+    "scoring or extending step's rows read from the cache, every layer")
 _C_STATES = obs.counter(
     "retention_states_read_total",
     "(row, layer) retention states a scoring step's rows read from "
@@ -170,12 +181,51 @@ _C_PAGES_VISITED = obs.counter(
     "page list among them, times the full layers (a row rides the "
     "longest row's trips)")
 
+_C_TURNS = obs.counter(
+    "context_extend_turns_total",
+    "kept turns: score requests with `keep` whose step extended their "
+    "context")
+_C_TURN_TOKENS = obs.counter(
+    "context_extend_tokens_total",
+    "tokens by which kept turns extended their contexts")
+_C_TURN_PAGES = obs.counter(
+    "context_extend_pages_appended_total",
+    "pages kept turns took from the pool as their contexts crossed page "
+    "boundaries")
+_C_TURN_WAITED = obs.counter(
+    "context_extend_waited_total",
+    "kept rows held back for the next step because their context had a "
+    "kept row in the step before it")
+_H_EXTEND = obs.histogram(
+    "context_extend_seconds",
+    "the book's part of one step of kept turns: lookups, pages taken, "
+    "the new ids put in the old ones' place (the device's part is "
+    "serving_predict_device_seconds)",
+    buckets=(1e-5, 3e-5, 1e-4, 3e-4, 1e-3, 3e-3, 0.01, 0.03, 0.1))
+
 # the configuration file's `model_type` -> the module that runs it
 MODEL_MODULES = {"nemotron_h": hybrid_lm, "glm4_moe_lite": latent_moe_lm,
                  "KeyeVL2": sparse_gqa_moe_lm, "brumby": retention_lm,
-                 "afmoe": window_moe_lm}
-# what a module's `CACHE_KIND` may say a context's slot is
-CACHE_KINDS = ("tokens", "state", "paged")
+                 "afmoe": window_moe_lm, "solar_open2": delta_moe_lm}
+
+
+class SlotKind(NamedTuple):
+    """What a context holds in a module's cache."""
+    fixed_size: bool    # its slot is ONE state whatever the tokens behind
+    #                     it; else rows (or a ring) of the cache, a token
+    pages: bool         # and every token in pages of a pool beside it
+    longest: str        # what bounds a context's length, for the 4xx
+
+
+_BY_POSITIONS = "the model's positions less the longest question"
+# what a module's `CACHE_KIND` may say, and the ONE place that says what
+# each means (`ScoringModel.slot` is the held module's)
+CACHE_KINDS = {
+    "tokens": SlotKind(False, False, "a cache slot's tokens"),
+    "state": SlotKind(True, False, _BY_POSITIONS),
+    "paged": SlotKind(False, True, _BY_POSITIONS),
+    "state+pages": SlotKind(True, True, _BY_POSITIONS),
+}
 
 
 def cache_kind(module) -> str:
@@ -204,6 +254,7 @@ class ScoreRequest(NamedTuple):
     ids: np.ndarray         # (length,) int32
     top_k: int
     context: Optional[str] = None   # a registered context's id
+    keep: bool = False      # the context is left extended by `ids`
 
 
 class ScoreResult(NamedTuple):
@@ -222,6 +273,10 @@ class ScoreResult(NamedTuple):
     #                             the keys the last position attended,
     #                             packed (`selected_positions`); None for
     #                             a model whose attention selects nothing
+    kept_as: Optional[str] = None   # a kept turn: the id its context has
+    #                             now (the one the request named is gone)
+    refused: Optional[str] = None   # set INSTEAD of an answer: why a kept
+    #                             turn could not be held (which limit)
 
 
 def row_counts(bucket: int, budget: int) -> Tuple[int, ...]:
@@ -232,6 +287,65 @@ def row_counts(bucket: int, budget: int) -> Tuple[int, ...]:
         out.append(rows)
         rows *= 2
     return tuple(out)
+
+
+class _Turns:
+    """The book's part of one step of kept turns, under the cache's
+    lock: before the step is dispatched each row's context is looked up
+    and the free pages its new tokens need are taken (`take`); once it
+    is dispatched the longer contexts take the old ones' places under
+    their new ids (`commit`); a step that failed gives the pages back
+    (`undo`)."""
+
+    def __init__(self, book: ContextSlots):
+        self.book = book
+        self.rows: Dict[int, Tuple] = {}    # row -> (old id, its tokens,
+        #                                     new id, its tokens, its
+        #                                     pages, those of them taken)
+        self.kept: Dict[int, str] = {}
+        self.refused: Dict[int, str] = {}
+
+    def take(self, row: int, context: str, ids: np.ndarray):
+        """What the row's step reads and writes: the context as held,
+        its page list grown by the pages taken; None for a context that
+        is gone; the reason (a str) for a turn that cannot be held."""
+        with obs.span("context.extend", hist=_H_EXTEND):
+            try:
+                found = self.book.extend_pages(context, len(ids))
+            except (TooLong, PoolTooSmall) as e:
+                self.refused[row] = str(e)
+                return self.refused[row]
+            if found is None:
+                return None
+            held, taken = found
+            pages = held.pages + taken
+            self.rows[row] = (context, held.tokens,
+                              extended_id(context, ids),
+                              held.tokens + len(ids), pages, taken)
+            return held._replace(pages=pages)
+
+    def commit(self) -> Dict[int, str]:
+        """-> {row: its context's id} for rows whose context went
+        between `take` and now (a registration evicted it): the step
+        wrote into a slot that is no longer theirs, behind which the
+        new owner's registration writes."""
+        gone = {}
+        with obs.span("context.extend", hist=_H_EXTEND):
+            for row, (old, was, new, tokens, pages, taken
+                      ) in self.rows.items():
+                if self.book.replace(old, new, tokens, pages):
+                    self.kept[row] = new
+                    _C_TURNS.inc()
+                    _C_TURN_TOKENS.inc(tokens - was)
+                    _C_TURN_PAGES.inc(len(taken))
+                else:
+                    self.book.release(None, taken)
+                    gone[row] = old
+        return gone
+
+    def undo(self) -> None:
+        for *_, taken in self.rows.values():
+            self.book.release(None, taken)
 
 
 class ScoringModel:
@@ -287,79 +401,72 @@ class ScoringModel:
         self._predict_steps: Dict[Tuple[int, int], object] = {}
         self._fingerprint: Optional[str] = None
         self.contexts: Optional[ContextSlots] = None
-        self.state_cache = False    # a slot is one state, not token rows
-        self.paged_cache = False    # a ring slot and a list of pages
+        self.slot = CACHE_KINDS["tokens"]   # what a context holds
+        self.extends = hasattr(self.module, "ctx_extend_step")
         held = serve.get("context_cache")
         if held and hasattr(self.module, "init_cache"):
-            kind = cache_kind(self.module)
-            self.state_cache = kind == "state"
-            self.paged_cache = kind == "paged"
+            slot = self.slot = CACHE_KINDS[cache_kind(self.module)]
             self.register_chunk = int(held["register_chunk"])
             self.contexts = ContextSlots(
                 held["slots"], held["tokens_per_slot"],
-                fixed_size=self.state_cache,
-                pages=held["pages"] if self.paged_cache else 0,
+                fixed_size=slot.fixed_size,
+                pages=held["pages"] if slot.pages else 0,
                 page_tokens=self.register_chunk)
-            if self.state_cache or self.paged_cache:
+            if slot.fixed_size or slot.pages:
                 most = self.lm.max_position_embeddings - self._buckets[-1]
                 if self.contexts.capacity > most:
                     raise ValueError(
                         f"{config.model_config}: tokens_per_slot admits "
-                        f"contexts past the model's positions less the "
-                        f"longest question ({most})")
-            if (not self.state_cache
+                        f"contexts past {slot.longest} ({most})")
+            if (not slot.fixed_size
                     and self.contexts.capacity % self.register_chunk):
                 raise ValueError(
                     f"{config.model_config}: tokens_per_slot must be a "
                     f"multiple of register_chunk")
-            if self.paged_cache:
-                # a row's page list is as long as the longest context
-                self.list_pages = self.contexts.pages_for(
-                    self.contexts.capacity)
-                self.cache = self.module.init_cache(
-                    self.lm, self.contexts.slots, self.contexts.pages,
-                    self.register_chunk)
-            else:
-                self.cache = self.module.init_cache(
-                    self.lm, self.contexts.slots, self.contexts.capacity)
+            # a row's page list is as long as the longest context
+            self.list_pages = (self.contexts.pages_for(self.contexts.capacity)
+                               if slot.pages else 0)
+            self.cache = self.module.init_cache(
+                self.lm, self.contexts.slots,
+                *((self.contexts.pages, self.register_chunk) if slot.pages
+                  else (self.contexts.capacity,)))
             self._cache_lock = threading.Lock()
             self._register_lock = threading.Lock()
             self._register_step = None
             self.served_endpoints = ("score", "contexts")
             arrays = jax.tree.leaves(self.cache)
+            pool = [a for a in arrays if slot.pages
+                    and a.shape[0] == self.contexts.pages]
             total = sum(a.nbytes for a in arrays)
-            # a slot's share of every array (its leading dimension counts
-            # the slots, and whatever spare ones the module keeps)
-            self.slot_bytes = sum(a.nbytes // a.shape[0] for a in arrays)
-            _G_SLOT_BYTES.set(self.slot_bytes if self.state_cache else 0)
-            if self.paged_cache:
-                rings = sum(a.nbytes for a in arrays
-                            if a.shape[0] == self.contexts.slots)
-                self.log(f"Context cache: {self.contexts.slots} ring slots "
-                         f"of {self.register_chunk} tokens "
-                         f"({rings:,} bytes) and a pool of "
-                         f"{self.contexts.pages} pages of "
-                         f"{self.register_chunk} tokens "
-                         f"({total - rings:,} bytes), pattern "
-                         f"{self.lm.pattern}; contexts of up to "
-                         f"{self.contexts.capacity} tokens "
-                         f"({self.list_pages} pages); registration in "
-                         f"chunks of {self.register_chunk}")
-            elif self.state_cache:
-                self.log(f"Context cache: {self.contexts.slots} slots, one "
-                         f"state of {self.slot_bytes:,} bytes a context "
-                         f"({self.lm.layers} layers; contexts of up to "
-                         f"{self.contexts.capacity} tokens) = {total:,} "
-                         f"bytes; registration in chunks of "
-                         f"{self.register_chunk}")
-            else:
-                self.log(f"Context cache: {self.contexts.slots} slots x "
-                         f"{self.contexts.capacity} tokens x "
-                         f"{self.lm.layers} layers x {self.lm.cache_width} "
-                         f"values = {total:,} bytes; registration in "
-                         f"chunks of {self.register_chunk}")
+            pooled = sum(a.nbytes for a in pool)
+            # a slot's share of every array but the pool's (its leading
+            # dimension counts the slots, and whatever spare ones the
+            # module keeps)
+            self.slot_bytes = sum(a.nbytes // a.shape[0] for a in arrays
+                                  if all(a is not p for p in pool))
+            _G_SLOT_BYTES.set(self.slot_bytes if slot.fixed_size else 0)
+            what = (f"one state of {self.slot_bytes:,} bytes a context"
+                    if slot.fixed_size else
+                    f"{self.register_chunk if slot.pages else self.contexts.capacity}"
+                    f" tokens each")
+            self.log(
+                f"Context cache: {self.contexts.slots} "
+                f"{'slots' if slot.fixed_size or not slot.pages else 'ring slots'}"
+                f", {what} ({total - pooled:,} bytes)"
+                + (f", and a pool of {self.contexts.pages} pages of "
+                   f"{self.register_chunk} tokens ({pooled:,} bytes)"
+                   if slot.pages else "")
+                + f"; pattern {self.lm.pattern}; contexts of up to "
+                  f"{self.contexts.capacity} tokens"
+                + (f" ({self.list_pages} pages)" if slot.pages else "")
+                + f"; registration in chunks of {self.register_chunk}"
+                + ("; a scored turn can be kept" if self.extends else ""))
         self.log(f"Model created: {lm_common.count_leaves(specs):,} "
                  f"parameters; {self.describe_devices()}")
+
+    # the names the older modules' tests read; `slot` says both
+    state_cache = property(lambda self: self.slot.fixed_size)
+    paged_cache = property(lambda self: self.slot.pages)
 
     # ------------------------------------------------------ the contract
 
@@ -420,8 +527,11 @@ class ScoringModel:
 
     # ------------------------------------------------------------ scoring
 
-    def _step(self, rows: int, length: int):
-        key = (rows, length)
+    def _step(self, rows: int, length: int, keep: bool = False):
+        """The jitted step of one shape: scoring, or with `keep` the
+        module's extending step (the cache donated; -> (cache,
+        outputs))."""
+        key = (rows, length) + ((True,) if keep else ())
         step = self._predict_steps.get(key)
         if step is None:
             cfg, k, module = self.lm, self.top_k, self.module
@@ -429,7 +539,14 @@ class ScoringModel:
             head_sorted_columns_gauge("score").set(
                 sorted_columns(rows, block, min(k, cfg.vocab_rows)))
 
-            if self.contexts is None:
+            if keep:
+                def ctx_extend_step(params, cache, ids, lengths, slot, held,
+                                    pages):
+                    return module.ctx_extend_step(
+                        cfg, k, block, params, cache, ids, lengths, slot,
+                        held, pages)
+                step = jax.jit(ctx_extend_step, donate_argnums=(1,))
+            elif self.contexts is None:
                 def lm_score_step(params, ids, lengths):
                     return module.lm_score_step(cfg, k, block, params, ids,
                                                 lengths)
@@ -443,22 +560,29 @@ class ScoringModel:
                                                 *more)
                 step = jax.jit(ctx_score_step)
             self._predict_steps[key] = step
-            self.log(f"Compiling scoring step for shape (rows={rows}, "
-                     f"length={length}) [{len(self._predict_steps)} of "
-                     f"{len(self.shapes())}]")
+            # with kept turns: every shape twice, and registration's
+            programs = len(self.shapes()) * (1 + self.extends) + self.extends
+            self.log(f"Compiling {'extending' if keep else 'scoring'} step "
+                     f"for shape (rows={rows}, length={length}) "
+                     f"[{len(self._predict_steps)} of {programs}]")
         return step
 
     def _run_step(self, rows: int, length: int, ids: np.ndarray,
-                  lengths: np.ndarray, contexts: Sequence[Optional[str]] = ()):
+                  lengths: np.ndarray, contexts: Sequence[Optional[str]] = (),
+                  keep: bool = False):
         """Dispatch one scoring step, row i after context `contexts[i]`;
         the answer is fetched by the caller. -> (the step's outputs, the
         tokens each row's context holds, {row: its context's id} for
         the rows whose context is gone: they run as padding, `lengths`
         zeroed IN PLACE for them). With a cache the slots are looked up
         and the step dispatched under the cache's lock (module
-        docstring). The first parts of the device stage
-        (`serving_predict_device_seconds`): the arguments are put on the
-        device here, not inside the call, so that each part is timed."""
+        docstring). With `keep` the step EXTENDS the rows' contexts
+        (`_Turns` books it, under the same lock) and two more come
+        back: {row: the id its context has now} and {row: why the turn
+        could not be held} (such a row too runs as padding). The first
+        parts of the device stage (`serving_predict_device_seconds`):
+        the arguments are put on the device here, not inside the call,
+        so that each part is timed."""
         gone: Dict[int, str] = {}
         cached = self.contexts is not None
         with contextlib.ExitStack() as locked:
@@ -468,17 +592,22 @@ class ScoringModel:
                     slot = np.zeros((rows,), np.int32)
                     held = np.zeros((rows,), np.int32)
                     more = ()
-                    if self.paged_cache:
+                    if self.slot.pages:
                         more = (np.zeros((rows, self.list_pages), np.int32),)
+                    turns = _Turns(self.contexts) if keep else None
                     for i, context in enumerate(contexts):
                         if context is None:
                             continue
-                        found = self.contexts.lookup(context)
+                        found = (turns.take(i, context, ids[i, :lengths[i]])
+                                 if keep else self.contexts.lookup(context))
                         if found is None:
                             gone[i], lengths[i] = context, 0
                             continue
+                        if isinstance(found, str):      # the turn refused
+                            lengths[i] = 0
+                            continue
                         slot[i], held[i] = found.slot, found.tokens
-                        if self.paged_cache:
+                        if self.slot.pages:
                             more[0][i, :len(found.pages)] = found.pages
             with _device_part("put"):
                 if not cached:
@@ -487,6 +616,15 @@ class ScoringModel:
                     (ids, lengths, slot, held) + more if cached
                     else (ids, lengths))
             with _device_part("enqueue"):
+                if keep:
+                    try:
+                        self.cache, out = self._step(rows, length, True)(
+                            self.params, self.cache, *on_device)
+                    except BaseException:
+                        turns.undo()
+                        raise
+                    gone.update(turns.commit())
+                    return out, held, gone, turns.kept, turns.refused
                 out = self._step(rows, length)(
                     self.params, *on_device[:2],
                     *((self.cache,) if cached else ()), *on_device[2:])
@@ -502,7 +640,15 @@ class ScoringModel:
                 n, length, np.zeros((n, length), np.int32),
                 np.ones((n,), np.int32))
             jax.block_until_ready(out.topk_values)
-        if self.contexts is not None:
+        if self.extends and self.contexts is not None:
+            # rows of no real token: each writes back what it read
+            for n, length in self.shapes():
+                jax.block_until_ready(self._extend_rows(
+                    n, length, np.zeros((n, length), np.int32),
+                    np.zeros((n,), np.int32)).topk_values)
+            self._register_chunk(
+                np.zeros((self.register_chunk,), np.int32), 0, 0, 0, ())
+        elif self.contexts is not None:
             # a chunk of no real token into slot 0: what it writes there
             # lies behind the length of whatever the slot holds (a state
             # takes nothing from it and comes back as it was)
@@ -510,9 +656,9 @@ class ScoringModel:
             # a chunk of none writes nothing)
             self._register_chunk(
                 np.zeros((self.register_chunk,), np.int32), 0, 0,
-                0 if self.paged_cache
+                0 if self.slot.pages
                 else self.contexts.capacity - self.register_chunk,
-                () if self.paged_cache else None)
+                () if self.slot.pages else None)
 
     def _token_ids(self, ids: Sequence[int], most: int) -> np.ndarray:
         try:
@@ -527,13 +673,22 @@ class ScoringModel:
         return arr.astype(np.int32)
 
     def validate(self, ids: Sequence[int], top_k: int,
-                 context: Optional[str] = None) -> ScoreRequest:
+                 context: Optional[str] = None, keep: bool = False
+                 ) -> ScoreRequest:
         """A request's ids as an array, or ValueError saying what is
         wrong with them; UnknownContext for a context that is not
         held."""
         arr = self._token_ids(ids, self.token_budget)
         if not 1 <= int(top_k) <= self.top_k:
             raise ValueError(f"top_k must lie in [1, {self.top_k}]")
+        if keep and not (self.extends and self.contexts is not None):
+            raise ValueError(
+                f"keep: {self.model_name} cannot extend a registered "
+                f"context in place; register the longer context (POST "
+                f"/contexts)")
+        if keep and context is None:
+            raise ValueError("keep extends a registered context: name it "
+                             "(context)")
         if context is not None:
             if self.contexts is None:
                 raise ValueError("this model keeps no contexts: send the "
@@ -544,14 +699,43 @@ class ScoringModel:
                     f"context {context!r} is unknown or evicted: register "
                     f"it again (POST /contexts)")
             context = str(context)
-        return ScoreRequest(arr, int(top_k), context)
+        return ScoreRequest(arr, int(top_k), context, bool(keep))
 
     # ----------------------------------------------------------- contexts
+
+    def _extend_rows(self, rows: int, length: int, ids: np.ndarray,
+                     lengths: np.ndarray, slot: Optional[np.ndarray] = None,
+                     held: Optional[np.ndarray] = None,
+                     pages: Optional[np.ndarray] = None):
+        """Dispatch one extending step on rows whose slots, lengths held
+        and page lists the caller knows (zeros where None), under the
+        cache's lock; the book is the caller's. -> the step's outputs
+        (`self.cache` is the extended cache)."""
+        zeros = np.zeros((rows,), np.int32)
+        if pages is None:
+            pages = np.zeros((rows, self.list_pages), np.int32)
+        with self._cache_lock:
+            self.cache, out = self._step(rows, length, True)(
+                self.params, self.cache, ids, lengths,
+                zeros if slot is None else slot,
+                zeros if held is None else held, pages)
+        return out
 
     def _register_chunk(self, ids: np.ndarray, real: int, slot: int,
                         start: int, pages: Optional[Sequence[int]] = None
                         ) -> None:
         """`pages`: with a paged cache, the context's page list."""
+        if self.extends:
+            # the empty context extended: the extending step at one row
+            listed = np.zeros((1, self.list_pages), np.int32)
+            listed[0, :len(pages)] = pages
+            with obs.span("context.register.chunk", hist=_H_REGISTER_CHUNK):
+                self._extend_rows(
+                    1, self.register_chunk, ids[None, :],
+                    np.array([real], np.int32), np.array([slot], np.int32),
+                    np.array([start], np.int32), listed)
+                jax.block_until_ready(self.cache)
+            return
         if self._register_step is None:
             cfg, module = self.lm, self.module
 
@@ -585,10 +769,8 @@ class ScoringModel:
         except ValueError as e:
             if "ids must hold" not in str(e):
                 raise
-            limit = ("the model's positions less the longest question"
-                     if self.state_cache or self.paged_cache
-                     else "a cache slot's tokens")
-            raise ValueError(f"{e} (the longest context admitted: {limit})")
+            raise ValueError(f"{e} (the longest context admitted: "
+                             f"{self.slot.longest})")
         context = context_id(arr)
         with self._register_lock, obs.span("context.register",
                                            hist=_H_REGISTER):
@@ -596,7 +778,7 @@ class ScoringModel:
                 return {"context": context, "tokens": int(arr.size),
                         "evicted": None, "held": True}
             pages, also = None, {}
-            if self.paged_cache:
+            if self.slot.pages:
                 try:
                     slot, pages, gone = self.contexts.acquire_pages(arr.size)
                 except PoolTooSmall as e:
@@ -627,16 +809,24 @@ class ScoringModel:
     def score_batch(self, requests: Sequence[ScoreRequest]
                     ) -> List[ScoreResult]:
         """One result a request, in order. The batcher hands over what
-        fits one step; a longer list is cut into steps here."""
+        fits one step; a longer list is cut into steps here. A step
+        either keeps all its rows or none, and never two kept rows of
+        one context: the second waits for the next step, where the id
+        it names is gone (the first one's turn replaced it)."""
         out: List[ScoreResult] = []
         pending = list(requests)
         while pending:
-            take, deepest = 0, 0
+            take, deepest, kept = 0, 0, set()
             for r in pending:
                 b = bucket_for(len(r.ids), self._buckets)
-                if take and (take + 1) * max(deepest, b) > self.token_budget:
+                if take and ((take + 1) * max(deepest, b) > self.token_budget
+                             or r.keep != pending[0].keep):
+                    break
+                if r.keep and r.context in kept:
+                    _C_TURN_WAITED.inc()
                     break
                 take, deepest = take + 1, max(deepest, b)
+                kept.add(r.context)
             out.extend(self._score_step(pending[:take], deepest))
             pending = pending[take:]
         return out
@@ -654,9 +844,11 @@ class ScoringModel:
                 lengths[i] = len(r.ids)
             _H_FILL["rows"].observe(n / rows)
             contexts = [r.context for r in requests]
+            keep = bool(requests[0].keep)
         with _stage("device"):
-            got, held, gone = self._run_step(rows, length, ids, lengths,
-                                             contexts)
+            got, held, gone, *turns = self._run_step(
+                rows, length, ids, lengths, contexts, keep)
+            kept, refused = turns or ({}, {})
             with _device_part("wait"):
                 answer = jax.block_until_ready(
                     (got.topk_values, got.topk_indices, got.lse, got.stats))
@@ -664,15 +856,19 @@ class ScoringModel:
                 values, indices, lse, stats = jax.device_get(answer)
         _H_TOKEN_FILL.observe(float(lengths.sum()) / (rows * length))
         _C_UNKNOWN.inc(len(gone))
-        if self.state_cache:
+        if self.slot.fixed_size:
             # a row reads its context's state, the same bytes whatever
             # the context's length: nothing here counts tokens
             reading = int(((held > 0) & (lengths > 0)).sum())
-            _C_STATES.inc(reading * self.lm.layers)
-            _C_STATE_BYTES.inc(reading * self.slot_bytes)
-        elif self.paged_cache:
+            layers = getattr(self.lm, "state_layers", self.lm.layers)
+            _C_SCORE_STATES.inc(reading * layers)
+            _C_SCORE_STATE_BYTES.inc(reading * self.slot_bytes)
+            if not self.slot.pages:
+                _C_STATES.inc(reading * layers)
+                _C_STATE_BYTES.inc(reading * self.slot_bytes)
+        if self.slot.pages:
             self._count_paged(held, lengths)
-        elif self.contexts is not None:
+        elif self.contexts is not None and not self.slot.fixed_size:
             q = lengths.astype(np.int64)
             pairs = int((q * held + q * (q + 1) // 2).sum())
             _C_KEYS.inc(int((held + q).sum()))
@@ -695,7 +891,8 @@ class ScoringModel:
                     np.exp(values[i, :k] - lse[i]), int(lengths[i]),
                     stats.chosen_last[i], int(held[i]), gone.get(i),
                     None if stats.selected_last is None
-                    else stats.selected_last[i]))
+                    else stats.selected_last[i], kept.get(i),
+                    refused.get(i)))
             return results
 
     def _count_paged(self, held: np.ndarray, lengths: np.ndarray) -> None:
@@ -703,7 +900,8 @@ class ScoringModel:
         the ring rows and the page tokens its real rows may see, the
         pages they hold and the pages the full layers' loop walks (every
         real row rides the longest list's trips)."""
-        window, full = self.lm.window_layers, self.lm.full_layers
+        window = getattr(self.lm, "window_layers", 0)
+        full = self.lm.full_layers
         seen = held[lengths > 0].astype(np.int64)
         if not seen.size:
             return

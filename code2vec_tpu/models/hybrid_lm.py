@@ -54,6 +54,7 @@ from code2vec_tpu.models.lm_common import (  # noqa: F401  (re-exported)
 )
 from code2vec_tpu.ops import moe, ssd
 from code2vec_tpu.ops.attention import causal_gqa_attention
+from code2vec_tpu.ops.delta_rule import conv_carried
 from code2vec_tpu.ops.topk import blockwise_matmul_top_k
 
 KINDS = "M*E"
@@ -208,18 +209,6 @@ def abstract_params(cfg: LMConfig) -> Dict[str, jax.ShapeDtypeStruct]:
 
 # ---------------------------------------------------------------- the layers
 
-def causal_conv1d(x: jax.Array, w: jax.Array, b: jax.Array) -> jax.Array:
-    """Depthwise, causal: y[t] = sum_j w[:, j] x[t - (K-1) + j] + b, as a
-    conv1d with left padding K-1 computes it. x (b, l, c) -> float32."""
-    k = w.shape[1]
-    length = x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    y = b.astype(jnp.float32)
-    for j in range(k):
-        y = y + xp[:, j:j + length] * w[:, j].astype(jnp.float32)
-    return y
-
-
 def mamba_mixer(cfg: LMConfig, p: Dict[str, jax.Array], u: jax.Array
                 ) -> jax.Array:
     """u (b, l, hidden) bfloat16 -> (b, l, hidden) bfloat16."""
@@ -233,7 +222,7 @@ def mamba_mixer(cfg: LMConfig, p: Dict[str, jax.Array], u: jax.Array
         xbc = zxbcdt[..., di:di + cfg.conv_dim].astype(jnp.bfloat16)
         dt = jax.nn.softplus(zxbcdt[..., di + cfg.conv_dim:]
                              + p["dt_bias"])                # float32
-        xbc = jax.nn.silu(causal_conv1d(xbc, p["conv_w"], p["conv_b"])
+        xbc = jax.nn.silu(conv_carried(xbc, p["conv_w"], bias=p["conv_b"])[0]
                           ).astype(jnp.bfloat16)
     x = xbc[..., :di].reshape(bsz, length, nh, hd)
     b_in = xbc[..., di:di + g * n].reshape(bsz, length, g, n)

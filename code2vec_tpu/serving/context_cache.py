@@ -20,8 +20,17 @@ layers). `acquire_pages` takes both at once and evicts least recently
 used contexts WHOLE, ring slot and pages, until the new one fits; a
 context that no eviction could make room for is refused, never cut.
 
-An id is the content's own hash, so registering the same tokens twice
-finds the slot already filled. A slot being filled belongs to no id: the
+With `fixed_size` AND pages (models/delta_moe_lm.py) a slot is one
+state and the pages hold every token beside it; such a context can GROW:
+`extend_pages` takes the free pages its new tokens need (it evicts
+nothing: a turn never costs another session its place) and `replace`
+puts the longer context under a NEW id in the old one's place, in one
+act under the book's lock.
+
+An id is the content's own hash (a grown context's: `extended_id`, a
+hash of the id it had and the tokens it grew by, so that nothing
+rehashes what the context already holds), so registering the same tokens
+twice finds the slot already filled. A slot being filled belongs to no id: the
 id it held is gone the moment the slot is taken, and the new id appears
 only when `commit` says every chunk is written. A lookup therefore
 never sees a half-written slot, and a request for an evicted id gets
@@ -48,7 +57,8 @@ _G_TOKENS = obs.gauge(
 _G_FILL = obs.gauge(
     "latent_cache_fill_ratio",
     "real tokens held over the capacity of all slots; for a cache of "
-    "fixed-size states, slots held over slots")
+    "fixed-size states, slots held over slots; for rings with a page "
+    "pool, pages held over pages")
 _C_EVICTED = obs.counter(
     "latent_cache_evictions_total",
     "contexts that lost their slot to a newer one (least recently used)")
@@ -79,13 +89,29 @@ class HeldPages(NamedTuple):
 
 
 class PoolTooSmall(ValueError):
-    """A context needs more pages than the whole pool has."""
+    """A context needs more pages than the whole pool has, or a turn
+    more than are free."""
+
+
+class TooLong(ValueError):
+    """A turn would take its context past the longest one admitted."""
 
 
 def context_id(ids: np.ndarray) -> str:
     """The id of a context: a hash of its token ids."""
     return hashlib.sha256(
         np.ascontiguousarray(ids, dtype=np.int32).tobytes()).hexdigest()[:16]
+
+
+def extended_id(context: str, ids: np.ndarray) -> str:
+    """The id of the context `context` extended by the tokens `ids`:
+    sha256 over the old id's 16 hexadecimal characters (ASCII) followed
+    by the tokens as little-endian int32, its first 16 hexadecimal
+    characters. A client that knows a session's id and what it sent can
+    compute the id the session has next."""
+    return hashlib.sha256(
+        context.encode("ascii") + np.ascontiguousarray(
+            ids, dtype="<i4").tobytes()).hexdigest()[:16]
 
 
 class ContextSlots:
@@ -118,9 +144,10 @@ class ContextSlots:
             held = self.pages - len(self._free_pages)
             _G_PAGES.set(held)
             _G_PAGE_FILL.set(held / self.pages)
-            _G_RINGS.set(len(self._held))
-            _G_FILL.set(held / self.pages)
-            return
+            if not self.fixed_size:     # a slot is a ring of tokens
+                _G_RINGS.set(len(self._held))
+                _G_FILL.set(held / self.pages)
+                return
         _G_FILL.set(len(self._held) / self.slots if self.fixed_size
                     else tokens / (self.slots * self.capacity))
 
@@ -190,12 +217,60 @@ class ContextSlots:
             _C_REGISTERED.inc()
             self._publish()
 
-    def release(self, slot: int, pages: Sequence[int] = ()) -> None:
-        """A slot (and pages) taken by `acquire` whose filling failed."""
+    def release(self, slot: Optional[int], pages: Sequence[int] = ()
+                ) -> None:
+        """A slot (and pages) taken by `acquire` whose filling failed;
+        `slot` None: pages alone, taken by `extend_pages` for a turn
+        that did not happen."""
         with self._lock:
-            self._free.append(slot)
+            if slot is not None:
+                self._free.append(slot)
             self._free_pages.extend(reversed(tuple(pages)))
             self._publish()
+
+    def extend_pages(self, context: str, more: int
+                     ) -> Optional[Tuple[HeldPages, Tuple[int, ...]]]:
+        """What `context` holds, now the most recently used, and the
+        FREE pages that `more` further tokens need beyond its own (none
+        while they fit its last page), taken here and now. None for an
+        id that is unknown or evicted; TooLong past the longest context
+        admitted; PoolTooSmall where too few pages are free (nothing is
+        evicted for a turn, and nothing was taken)."""
+        with self._lock:
+            held = self._held.get(context)
+            if held is None:
+                return None
+            self._held.move_to_end(context)
+            tokens = held.tokens + int(more)
+            if tokens > self.capacity:
+                raise TooLong(
+                    f"{held.tokens} tokens held and {int(more)} more pass "
+                    f"the longest context admitted, {self.capacity} "
+                    f"(positions)")
+            need = self.pages_for(tokens) - len(held.pages)
+            if need > len(self._free_pages):
+                raise PoolTooSmall(
+                    f"{int(more)} more tokens need {need} more pages of "
+                    f"{self.page_tokens} tokens; {len(self._free_pages)} "
+                    f"of the pool's {self.pages} are free (pool)")
+            pages = tuple(self._free_pages.pop() for _ in range(need))
+            self._publish()
+            return held, pages
+
+    def replace(self, old: str, new: str, tokens: int,
+                pages: Sequence[int]) -> bool:
+        """The context `old` has grown: it is now `new`, of `tokens`
+        tokens on `pages` (its own and those `extend_pages` took), in
+        the same slot; `old` is gone. False, and nothing changed, where
+        `old` is no longer held (evicted since the lookup): the caller
+        gives the pages it took back."""
+        with self._lock:
+            held = self._held.pop(old, None)
+            if held is None:
+                return False
+            self._held[new] = HeldPages(held.slot, int(tokens), tuple(pages))
+            self._publish()
+            return True
 
     def held(self) -> Dict[str, Held]:
         with self._lock:

@@ -624,8 +624,12 @@ class PredictionServer:
         normalized = normalize_source(code)
         key = cache_key_normalized(normalized, endpoint=endpoint,
                                    topk=self.topk, model=fp, **knobs)
+        # a kept turn changes what it names: it is never answered from,
+        # nor stored in, the cache of answers
+        kept_turn = bool(score_request is not None
+                         and getattr(score_request, "keep", False))
         with trace.span("cache_lookup") as sp:
-            cached = self.cache.get(key)
+            cached = None if kept_turn else self.cache.get(key)
             sp.attrs["hit"] = cached is not None
         if cached is not None:
             # Cache hits serve BEFORE admission and breakers: graceful
@@ -695,7 +699,8 @@ class PredictionServer:
                                            endpoint=endpoint,
                                            topk=self.topk,
                                            model=result_fp, **knobs)
-            self.cache.put(key, body)
+            if not kept_turn:
+                self.cache.put(key, body)
             # `respond` ends where `total` does: handle_request takes
             # one reading for both
             phases["respond_from"] = t_back
@@ -722,9 +727,12 @@ class PredictionServer:
             raise _HTTPError(400, 'JSON body must be {"ids": [...], '
                                   '"top_k": N}')
         try:
+            # `keep` only where it is asked for: a model's `validate`
+            # that knows no kept turns is called as it always was
+            keep = {"keep": True} if params.get("keep") else {}
             return model.validate(params["ids"],
                                   params.get("top_k", model.top_k),
-                                  params.get("context"))
+                                  params.get("context"), **keep)
         except ValueError as e:
             raise _HTTPError(400, str(e))
         except LookupError as e:        # a context that is not held
@@ -814,12 +822,18 @@ class PredictionServer:
             [r] = raw
             if r.unknown_context is not None:
                 raise _HTTPError(
-                    404, f"context {r.unknown_context!r} was evicted "
-                         f"before this request's step: register it again "
-                         f"(POST /contexts)")
+                    404, f"context {r.unknown_context!r} was evicted, or "
+                         f"extended by a kept turn, before this request's "
+                         f"step: name the id that turn answered with, or "
+                         f"register it again (POST /contexts)")
+            if getattr(r, "refused", None) is not None:
+                raise _HTTPError(
+                    409, f"this turn cannot be kept: {r.refused}")
             out = {"model": self._model_ref[0].model_name,
                    "model_fingerprint": fingerprint,
                    "tokens": r.tokens, "context_tokens": r.context_tokens,
+                   **({} if getattr(r, "kept_as", None) is None
+                      else {"context": r.kept_as}),
                    "top": [{"id": int(i), "logit": float(v),
                             "probability": float(p)}
                            for i, v, p in zip(r.token_ids, r.logits,

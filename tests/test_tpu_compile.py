@@ -316,3 +316,58 @@ def test_the_window_and_page_models_steps_compile_at_published_widths(
         assert "copy(" not in "".join(
             line for line in compiled.as_text().splitlines()
             if "bf16[160,1024,2048]" in line.split("=")[0])
+
+
+@pytest.mark.parametrize("shape", ["1x1024", "4x256", "register_2048"])
+def test_the_delta_rule_models_extending_step_compiles_at_published_widths(
+        one_chip, shape):
+    """`ctx_extend_step` of `solar-open2-ep8` at its published widths: a
+    one-row and a four-row kept turn and the 2,048-token registration
+    shape, each row with a 96-page list (a 196,608-token session),
+    against the states and the whole page pool, both donated. Each
+    program's temporaries stay under 1.5 GB: a chunk's `(2048 queries, 64
+    heads, 131072 keys)` float32 scores at once would be 69 GB, and the
+    delta rule's sub-block factors of every chunk at once 1.07 GB a
+    layer, which is why the one folds keys in blocks of 512 and the other
+    scans its chunks."""
+    import json
+    import os
+    from code2vec_tpu.models import delta_moe_lm as lm
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmarks", "configs",
+                           "solar-open2-ep8.json")) as f:
+        raw = json.load(f)
+    cfg = lm.LMConfig.from_dict(raw)
+    held = raw["serve"]["context_cache"]
+    chunk = held["register_chunk"]
+    listed = held["tokens_per_slot"] // chunk
+
+    def spec(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    params = {leaf.name: spec(leaf.shape, jnp.dtype(leaf.dtype))
+              for leaf in lm.leaf_specs(cfg)}
+    cache = jax.tree.map(
+        lambda a: spec(a.shape, a.dtype), jax.eval_shape(
+            lambda: lm.init_cache(cfg, held["slots"], held["pages"], chunk)))
+    assert listed == 96 and cache[0].shape == (416, 2048, 2048)
+    assert [a.shape for a in cache[1]] == [(25, 64, 128, 128),
+                                           (25, 3, 24576)]
+    rows, length = ((1, chunk) if shape == "register_2048"
+                    else (int(n) for n in shape.split("x")))
+    compiled = jax.jit(
+        lambda p, c, ids, n, slot, at, pages: lm.ctx_extend_step(
+            cfg, 10, 4096, p, c, ids, n, slot, at, pages),
+        donate_argnums=(1,)).lower(
+        params, cache, spec((rows, length), jnp.int32),
+        spec((rows,), jnp.int32), spec((rows,), jnp.int32),
+        spec((rows,), jnp.int32), spec((rows, listed), jnp.int32)).compile()
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 1.5e9, memory.temp_size_in_bytes
+    # weights (6.62 GB), states and pool are the program's arguments ...
+    assert 10.4e9 < memory.argument_size_in_bytes < 10.5e9
+    # ... and the donated states and pool are updated in place, not copied
+    assert memory.alias_size_in_bytes > 3.8e9
+    held_arrays = ("bf16[416,2048,2048]", "f32[25,64,128,128]")
+    assert "copy(" not in "".join(
+        line for line in compiled.as_text().splitlines()
+        if any(a in line.split("=")[0] for a in held_arrays))
