@@ -1,5 +1,5 @@
 """Dense Adam of one embedding table whose gradient is a sorted list of
-rows, not a table.
+rows, or several such lists, not a table.
 
 A train batch touches a few per cent of a table's rows (java14m: ~180K
 live entries against 1,301,136 token rows), yet dense Adam moves every
@@ -12,31 +12,43 @@ operation in float32 (training/state.py `_scale_by_adam_nu_dtype`,
 `optax.scale(-lr)`, `optax.apply_updates`), fed by what the lookup's
 backward has BEFORE its scatter (ops/embed.py `sorted_row_list`): the
 sorted keys, dead entries behind a key past the table's end, and the
-cotangent rows in that order, in the compute dtype.
+cotangent rows in that order, in the compute dtype. A data mesh's chips
+each make such a list and all-gather them: the update then takes `runs`
+lists laid end to end, each sorted, and adds every run's rows, run by
+run (on every chip in the same order, so the replicas stay bit-equal).
 
 On a TPU it is one Pallas kernel that walks the table once, 16 bytes a
 parameter. The grid runs over ITEMS, a (tile of table rows, chunk of
-the list) pair each, tile by tile and within a tile chunk by chunk
-(`_schedule`: a tile's entries are those between its edges in the sorted
-keys; a tile no entry falls into still has one item). The three blocks
+the list) pair each, tile by tile, within a tile run by run and within
+a run chunk by chunk (`_schedule`: a tile's entries of a run are those
+between its edges in the run's sorted keys; a tile has one item of a run
+none of whose entries fall into it). The three blocks
 of a tile stay where they are while its items pass, so the pipeline
 moves each once. A tile's float32 gradient is built in VMEM, a PIECE of
-128 entries at a time, by one-hot `(128, 128) @ (128, width)` products,
+128 entries at a time, by a one-hot `(rows, 128) @ (128, width)` product,
 exact in one bfloat16 pass because the rows ARE in the compute dtype and
 the accumulator is float32; a key of another tile (or a dead one)
-matches no row. The piece's keys are sorted, so they lie between its
-first key and the next piece's (`_piece_edges`), and the product runs
-over the 128-row BANDS of the tile that span holds and no others. The
+matches no row. A run is whole chunks, so a piece lies within one run
+and its keys are sorted: they lie between its first key and its last
+(`_piece_edges`), and ONE product takes the piece in, over the fewest
+rows of `LEVELS` that hold that span, from the span's first 128-row BAND
+on. The
 tile's last item forms the update and writes parameters and moments in
 place. The ids are Zipf: more than half of a batch's entries name the
 first thousand rows, and a row hit by thousands of entries is many
-items of one tile and ONE band a piece, nothing else. (Timed on a v5e
-against an add a row at a dynamic sublane, and against one product over
-the whole tile: PERF.md section 6, PR 43.)
+items of one tile and ONE band a piece, nothing else; a piece of the
+tail spans half a tile, and a product costs the piece's way into the MXU
+before the rows that stream past it, so one product over a thousand rows
+beats nine over a band each. (Timed on a v5e against an add a row at a
+dynamic sublane and against the whole tile for every piece, PERF.md
+section 6, PR 43; against a product a band and other levels, PR 46: with
+four runs the band loop took 10.3 ms for java14m's token table, this
+7.6.)
 
-Everywhere else it is the plain form: the scatter-add into zeros and the
-same arithmetic, which is also what the kernel is tested against
-(tests/test_adam_rows.py runs it through the Pallas interpreter).
+Everywhere else it is the plain form: the scatter-add into zeros (sorted
+only where the list is ONE run) and the same arithmetic, which is also
+what the kernel is tested against (tests/test_adam_rows.py runs it
+through the Pallas interpreter).
 """
 
 from __future__ import annotations
@@ -57,6 +69,9 @@ TILE = 2048
 CHUNK = 1024
 PIECE = 128
 BAND = 128
+# Table rows a piece's one product may cover, ascending; the tile itself
+# is always the last.
+LEVELS = (128, 512, 1024, 2048)
 _LANES = 128
 
 
@@ -73,16 +88,17 @@ def adam_rows_into_table(table: jax.Array, mu: jax.Array, nu: jax.Array,
                          keys: jax.Array, rows: jax.Array,
                          bias1: jax.Array, bias2: jax.Array, *,
                          lr: float, b1: float, b2: float, eps: float,
-                         name: str = "adam_rows"
+                         runs: int = 1, name: str = "adam_rows"
                          ) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One Adam step of `table` `(R, W)` float32 with moments `mu`, `nu`
     (any float storage dtype) under the gradient `zeros.at[keys].add(
-    rows)`: `keys` `(L,)` int32 SORTED, an entry with a key of `R` or
-    more is dead; `rows` `(L, W)` in that order. `bias1` / `bias2` are
+    rows)`: `keys` `(runs * L,)` int32, `runs` lists of `L` laid end to
+    end and each SORTED, an entry with a key of `R` or more is dead;
+    `rows` `(runs * L, W)` in that order. `bias1` / `bias2` are
     `1 - b1**t` / `1 - b2**t` of the step being taken. Returns the new
     (table, mu, nu); donated inputs are updated in place. `name` is the
     kernel's in a profile."""
-    hyper = dict(lr=lr, b1=b1, b2=b2, eps=eps)
+    hyper = dict(lr=lr, b1=b1, b2=b2, eps=eps, runs=runs)
     if not kernel_takes(table.shape[1], rows.dtype):
         return _plain(table, mu, nu, keys, rows, bias1, bias2, **hyper)
     return jax.lax.platform_dependent(
@@ -101,9 +117,11 @@ def _update(p, m, v, g, bias1, bias2, *, lr, b1, b2, eps):
     return p + (-lr) * step, mean.astype(m.dtype), square.astype(v.dtype)
 
 
-def _plain(table, mu, nu, keys, rows, bias1, bias2, **hyper):
+def _plain(table, mu, nu, keys, rows, bias1, bias2, *, runs: int = 1,
+           **hyper):
+    # several sorted runs end to end are not one sorted list
     grad = jnp.zeros_like(table).at[keys].add(
-        rows.astype(table.dtype), indices_are_sorted=True, mode="drop")
+        rows.astype(table.dtype), indices_are_sorted=runs == 1, mode="drop")
     return _update(table, mu, nu, grad, bias1, bias2, **hyper)
 
 
@@ -122,45 +140,55 @@ def _entries_below(keys: jax.Array, edges: jax.Array, chunk: int):
     return at * chunk + within
 
 
-def _schedule(keys: jax.Array, table_rows: int, tile: int, chunk: int):
+def _schedule(keys: jax.Array, table_rows: int, tile: int, chunk: int,
+              runs: int = 1):
     """The grid's items, all int32: `tile_of[i]` and `chunk_of[i]` of
-    item `i` (`tiles + chunks` of them, the static bound; the ones past
-    `total` repeat the last and do nothing), and `offsets[t]`, the first
-    list entry whose key is in tile `t` or later (`offsets[tiles]`: the
-    live entries)."""
-    tiles, chunks = -(-table_rows // tile), keys.shape[0] // chunk
+    item `i` (`runs * tiles + chunks` of them, the static bound; the
+    ones past `total` repeat the last and do nothing), and
+    `offsets[r * (tiles + 1) + t]`, the first list entry of run `r`
+    whose key is in tile `t` or later (at `t = tiles`: the end of the
+    run's live entries). A tile's items are its chunks of the first run,
+    then of the second, and so on; chunks and entries count from the
+    head of the whole list."""
+    tiles, chunks = -(-table_rows // tile), keys.shape[0] // chunk // runs
     edges = jnp.minimum(jnp.arange(tiles + 1, dtype=jnp.int32) * tile,
                         table_rows)
-    offsets = _entries_below(keys, edges, chunk)
-    first = jnp.minimum(offsets[:-1] // chunk, chunks - 1)
-    last = jnp.maximum(first, (offsets[1:] - 1) // chunk)
+    offsets = jax.vmap(lambda run: _entries_below(run, edges, chunk))(
+        keys.reshape(runs, -1))
+    first = jnp.minimum(offsets[:, :-1] // chunk, chunks - 1)
+    last = jnp.maximum(first, (offsets[:, 1:] - 1) // chunk)
+    run_head = jnp.arange(runs, dtype=jnp.int32)[:, None] * chunks
+    # by (tile, run) pair, in the order the items go
+    first, last = ((x + run_head).T.reshape(-1) for x in (first, last))
     counts = last - first + 1
     starts = jnp.cumsum(counts) - counts
-    item = jnp.arange(tiles + chunks, dtype=jnp.int32)
-    # the tile whose items hold item `i`: the last that starts at or
+    item = jnp.arange(runs * (tiles + chunks), dtype=jnp.int32)
+    # the pair whose items hold item `i`: the last that starts at or
     # before it
-    tile_of = jnp.sum(starts[None, :] <= item[:, None], axis=1,
+    pair_of = jnp.sum(starts[None, :] <= item[:, None], axis=1,
                       dtype=jnp.int32) - 1
-    chunk_of = jnp.minimum(first[tile_of] + item - starts[tile_of],
-                           last[tile_of])
-    return tile_of, chunk_of, (starts[-1] + counts[-1])[None], offsets
+    chunk_of = jnp.minimum(first[pair_of] + item - starts[pair_of],
+                           last[pair_of])
+    return (jax.lax.div(pair_of, runs), chunk_of,
+            (starts[-1] + counts[-1])[None],
+            (offsets + run_head * chunk).reshape(-1))
 
 
-def _piece_edges(keys: jax.Array, table_rows: int) -> jax.Array:
-    """The first key of every `PIECE` entries, and one past the end for
-    the last piece's upper edge: the keys are sorted, so piece `j`'s lie
-    in `[edges[j], edges[j + 1]]`."""
-    return jnp.concatenate([keys[::PIECE],
-                            jnp.full((1,), table_rows, keys.dtype)])
+def _piece_edges(keys: jax.Array) -> jax.Array:
+    """The first key of every `PIECE` entries, then the last of each: a
+    piece lies within one run, whose keys are sorted, so piece `j`'s lie
+    in `[edges[j], edges[pieces + j]]`."""
+    return jnp.concatenate([keys[::PIECE], keys[PIECE - 1::PIECE]])
 
 
 def _kernel(tile_of, chunk_of, total, offsets, edges, bias_ref, keys_ref,
             rows_ref, p_ref, mu_ref, nu_ref, p_out, mu_out, nu_out, grad_ref,
-            *, hyper):
+            *, hyper, tiles, run_chunks, levels):
     from jax.experimental import pallas as pl
     item, items = pl.program_id(0), pl.num_programs(0)
     tile, chunk = p_ref.shape[0], rows_ref.shape[0]
     t, c = tile_of[item], chunk_of[item]
+    pieces = edges.shape[0] // 2
     live = item < total[0]
     opens = (item == 0) | (tile_of[jnp.maximum(item - 1, 0)] != t)
     closes = ((item == total[0] - 1)
@@ -174,31 +202,37 @@ def _kernel(tile_of, chunk_of, total, offsets, edges, bias_ref, keys_ref,
         row = keys_ref[0, pl.ds(n, 1), :] - t * tile               # (1, PIECE)
         piece = rows_ref[pl.ds(pl.multiple_of(n * PIECE, PIECE), PIECE), :]
 
-        def add(band, _):
-            # the piece's rows into one band of the tile, one-hot: a key
-            # outside the band, of another tile or a dead one matches no
-            # row
-            at_row = pl.multiple_of(band * BAND, BAND)
-            hit = at_row + jax.lax.broadcasted_iota(
-                jnp.int32, (BAND, PIECE), 0) == row
-            grad_ref[pl.ds(at_row, BAND), :] += jnp.dot(
-                jnp.where(hit, 1.0, 0.0).astype(piece.dtype), piece,
-                preferred_element_type=jnp.float32)
-
         # the piece's keys are sorted: they lie between its first key and
-        # the next piece's, so only those bands can be hit (a piece of one
-        # hot row is one band; one with no key of this tile names no band
-        # or one it adds nothing to)
+        # its last, so only the bands of that span can be hit (a piece of
+        # one hot row is one band; one with no key of this tile names
+        # none). ONE product takes the piece in, over the fewest rows of
+        # `levels` that hold the span: a product's cost is the piece's way
+        # into the MXU before it is the rows that stream past it
         j = c * (chunk // PIECE) + n
-        lowest = jax.lax.div(jnp.maximum(edges[j] - t * tile, 0), BAND)
-        highest = jax.lax.div(
-            jnp.minimum(edges[j + 1] - t * tile, tile - 1), BAND)
-        jax.lax.fori_loop(lowest, highest + 1, add, None)
+        first = jnp.maximum(edges[j] - t * tile, 0)
+        last = jnp.minimum(edges[pieces + j] - t * tile, tile - 1)
+        lowest = jax.lax.div(first, BAND)
+        bands = jax.lax.div(last, BAND) - lowest + 1
+
+        for under, rows_at_once in zip((0,) + levels, levels):
+            @pl.when((bands > under // BAND) & (bands <= rows_at_once // BAND))
+            def _(rows_at_once=rows_at_once):
+                # one-hot: a key outside these rows, of another tile or
+                # a dead one matches no row
+                at_row = pl.multiple_of(
+                    jnp.minimum(lowest * BAND, tile - rows_at_once), BAND)
+                hit = at_row + jax.lax.broadcasted_iota(
+                    jnp.int32, (rows_at_once, PIECE), 0) == row
+                grad_ref[pl.ds(at_row, rows_at_once), :] += jnp.dot(
+                    jnp.where(hit, 1.0, 0.0).astype(piece.dtype), piece,
+                    preferred_element_type=jnp.float32)
 
     @pl.when(live)
     def _():
-        # the chunk's pieces that hold an entry of this tile
-        mine = [jnp.clip(offsets[at] - c * chunk, 0, chunk)
+        # the chunk's pieces that hold an entry of this tile, among its
+        # run's
+        run = jax.lax.div(c, run_chunks) * (tiles + 1)
+        mine = [jnp.clip(offsets[run + at] - c * chunk, 0, chunk)
                 for at in (t, t + 1)]
         jax.lax.fori_loop(jax.lax.div(mine[0], PIECE),
                           jax.lax.div(mine[1] + PIECE - 1, PIECE),
@@ -212,24 +246,30 @@ def _kernel(tile_of, chunk_of, total, offsets, edges, bias_ref, keys_ref,
 
 
 def _pallas(table, mu, nu, keys, rows, bias1, bias2, *, name: str,
-            interpret: bool = False, tile: int = TILE, chunk: int = CHUNK,
-            **hyper):
+            runs: int = 1, interpret: bool = False, tile: int = TILE,
+            chunk: int = CHUNK, levels: Tuple[int, ...] = LEVELS, **hyper):
     # imported where a step is traced around the kernel (ops/head_ce.py)
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
     # a table under one tile (a toy's) is one ragged tile of whole bands
     tile = min(tile, -(-table.shape[0] // BAND) * BAND)
-    length = -(-keys.shape[0] // chunk) * chunk
-    if length != keys.shape[0]:     # whole chunks, the filling dead
-        fill = length - keys.shape[0]
-        keys = jnp.pad(keys, (0, fill), constant_values=table.shape[0])
-        rows = jnp.pad(rows, ((0, fill), (0, 0)))
-    schedule = _schedule(keys, table.shape[0], tile, chunk)
     width = table.shape[1]
+    fill = -(keys.shape[0] // runs) % chunk
+    if fill:    # every run whole chunks, the filling dead: no chunk, and
+        # so no piece, holds entries of two runs
+        keys = jnp.pad(keys.reshape(runs, -1), ((0, 0), (0, fill)),
+                       constant_values=table.shape[0]).reshape(-1)
+        rows = jnp.pad(rows.reshape(runs, -1, width),
+                       ((0, 0), (0, fill), (0, 0))).reshape(-1, width)
+    schedule = _schedule(keys, table.shape[0], tile, chunk, runs)
     by_tile = pl.BlockSpec((tile, width),
                            lambda i, tile_of, *_: (tile_of[i], 0))
+    # the last level is the tile, whatever the table
+    levels = tuple(sorted({min(level, tile) for level in levels + (tile,)}))
     return pl.pallas_call(
-        functools.partial(_kernel, hyper=hyper),
+        functools.partial(_kernel, hyper=hyper, levels=levels,
+                          tiles=-(-table.shape[0] // tile),
+                          run_chunks=keys.shape[0] // chunk // runs),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(schedule[0].shape[0],),
@@ -249,6 +289,6 @@ def _pallas(table, mu, nu, keys, rows, bias1, bias2, *, name: str,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         name=name, interpret=interpret,
-    )(*schedule, _piece_edges(keys, table.shape[0]),
+    )(*schedule, _piece_edges(keys),
       jnp.stack([bias1, bias2]).astype(jnp.float32),
       keys.reshape(-1, chunk // PIECE, PIECE), rows, table, mu, nu)

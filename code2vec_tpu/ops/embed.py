@@ -18,14 +18,16 @@ to each row's `depth` (its deepest valid context), under static shapes:
   corner of the grid is not (200 = 8 x 25), and what consumes the rows
   (ops/encode_live.py) runs over the filled slots and no others.
 - backward: the ids are sorted once, the dead ones last
-  (`_sorted_entries`). One chip alone stops there: its train step hands
-  the sorted `(key, row)` list to the table's Adam (`sorted_row_list`,
-  ops/adam_rows.py) and no table-shaped gradient exists. A mesh of
-  chips needs the table for its all-reduce: `_embed_bwd`, the lookup's
-  VJP, is the mesh's path, and ONE sorted scatter-add takes the list in.
-  Either moves the rows of the shortest of `SCATTER_SIZES` static
-  prefixes of the list that holds every live entry (`_embed_bwd` says
-  why not a loop).
+  (`_sorted_entries`). Where the table's Adam takes lists
+  (training/step.py `adam_row_list_tables`) a chip stops there: the
+  train step hands the sorted `(key, row)` list to the table's Adam
+  (`sorted_row_list`, ops/adam_rows.py), on a data mesh after an
+  all-gather has laid every chip's list end to end, and no table-shaped
+  gradient exists. Any other step differentiates through the lookup:
+  `_embed_bwd`, its VJP, takes the list in by ONE sorted scatter-add
+  into a table, which a data mesh then all-reduces. Either moves the
+  rows of the shortest of `SCATTER_SIZES` static prefixes of the list
+  that holds every live entry (`_embed_bwd` says why not a loop).
 """
 
 from __future__ import annotations
@@ -187,9 +189,10 @@ def _over_live_prefix(entries, total: int, of_length):
 
 def _embed_bwd(dtype, residuals, cotangents):
     """Scatter-add of the live entries' cotangent rows into one
-    table-shaped gradient: what a mesh of chips needs, whose all-reduce
-    sums tables (one chip alone hands the list itself to its Adam,
-    `sorted_row_list`). On the chip a scatter is either unsorted and
+    table-shaped gradient: what a step needs whose Adam takes tables
+    (a width the row-list kernel does not take, float32 rows; every
+    other step hands the list itself to its Adam, `sorted_row_list`).
+    On the chip a scatter is either unsorted and
     pays the memory's latency for every row (75 ns on a v5e), or sorted:
     one pass over the table (2.1 ms for java14m's token table) plus 11 ns
     a row, which is what XLA makes of `jnp.take`'s transpose. A loop of
@@ -222,7 +225,8 @@ def live_rows_and_entries(table: jax.Array, ids: Tuple[jax.Array, ...],
     """`embed_live_rows`'s outputs, and the `entries` that
     `sorted_row_list` needs beside their cotangents: the lookup taken
     OUT of the differentiated function, for a step whose optimizer
-    takes the table's gradient as a list (training/step.py, one chip)."""
+    takes the table's gradient as a list (training/step.py
+    `_make_row_list_train_step`)."""
     return _embed_fwd(table, ids, depth, dtype)
 
 
@@ -231,7 +235,9 @@ def sorted_row_list(entries, cotangents) -> Tuple[jax.Array, jax.Array]:
     `_embed_bwd`: the keys of ALL entries sorted, a dead entry's a key
     past the table's end, and each key's cotangent row (the compute
     dtype, as the outputs were), moved by one gather over the shortest
-    static prefix that holds every live entry; zero rows behind it."""
+    static prefix that holds every live entry; zero rows behind it, so
+    the list is whole at one static length whatever the batch (what an
+    all-gather of several chips' lists needs)."""
     keys, source, updates, live = _sorted_entries(entries, cotangents)
     total = keys.shape[0]
 

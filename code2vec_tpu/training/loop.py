@@ -36,6 +36,7 @@ from code2vec_tpu.ops.head_ce import target_shards
 from code2vec_tpu.training.state import TrainState
 from code2vec_tpu.training.step import (
     adam_row_list_tables, async_collective_count, gathers_live_rows,
+    row_list_runs,
 )
 from code2vec_tpu.utils.device import describe_devices, shard_layout
 from code2vec_tpu.utils.prefetch import DevicePrefetcher
@@ -241,8 +242,15 @@ class Trainer:
             "tables whose gradient the train step hands to Adam as the "
             "backward's sorted (key, row) list and never builds as a "
             "table (training/step.py adam_row_list_tables): the token "
-            "and path tables where one chip holds them whole, else 0"
+            "and path tables where every chip holds them whole, else 0"
             ).set(row_list_tables)
+        list_runs = row_list_runs(config, self.mesh)
+        reg.gauge(
+            "train_row_list_runs",
+            "chips whose sorted lists one table's Adam takes in a step "
+            "(training/step.py row_list_runs): every chip of a data "
+            "mesh, all-gathered; 1 on one chip; 0 where no list is made"
+            ).set(list_runs)
 
         batch_num = 0              # batches this run
         trace_active = False       # profiler trace in flight
@@ -554,7 +562,7 @@ class Trainer:
                         f"{first.seconds:.2f}s; {said_async}"
                         f"head over {head_shards} target shard(s); "
                         f"Adam takes {row_list_tables} table(s)' gradient "
-                        f"as a row list; "
+                        f"as a row list, {list_runs} chip(s)' lists each; "
                         f"batch {tuple(arrays[0].shape)}: "
                         f"{shard_layout(arrays[0])}")
                     obs.log_compiles_from_now(log)

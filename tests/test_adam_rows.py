@@ -4,9 +4,10 @@ a table of zeros, then `_scale_by_adam_nu_dtype` + `optax.scale(-lr)` +
 `optax.apply_updates` on that table. A row no entry touches must come
 out BIT-equal (`b1 * mu + (1 - b1) * 0` is `b1 * mu`); a touched row
 within float32 summation order. The TPU kernel runs here through the
-Pallas interpreter, at a small tile. And the one-chip train step built
-around it (training/step.py) against the table-shaped step a mesh of
-chips keeps."""
+Pallas interpreter, at a small tile, for one sorted list and for several
+laid end to end. And the train step built around it (training/step.py),
+on one chip and on a data mesh whose chips all-gather their lists,
+against the step that builds table-shaped gradients."""
 
 import functools
 
@@ -88,16 +89,34 @@ def _under_one_tile(rng):
     return rows, _keys(rng, rows, rng.integers(0, rows, 60), 128)
 
 
+def _zipf_runs(runs, rng):
+    """`runs` sorted lists end to end, as many chips' lists arrive: Zipf
+    keys, one hot row hit in every run, a tile no run touches, a ragged
+    last tile whose last row is hit, and (of four) one run all dead.
+    Each run is no multiple of the chunk."""
+    rows, per_run = 3 * TILE + 37, 3 * CHUNK + 50
+    lists = []
+    for run in range(runs):
+        live = np.minimum(rng.zipf(1.3, 2 * CHUNK + 17 * run), rows - 1)
+        live = live[(live < TILE) | (live >= 2 * TILE)]
+        live = np.concatenate([live, np.full(CHUNK // 2, 77), [rows - 1]])
+        lists.append(_keys(rng, rows, [] if run == 2 else live, per_run))
+    return rows, np.concatenate(lists), runs
+
+
 CASES = {f.__name__.lstrip("_"): f for f in (
     _heavy_duplicates, _tile_edges, _ragged_last_tile, _mostly_dead,
     _empty_list, _not_whole_chunks, _under_one_tile)}
+CASES.update({f"zipf_runs_{n}": functools.partial(_zipf_runs, n)
+              for n in (1, 2, 4)})
 
 
 def _what_it_replaces(table, mu, nu, keys, rows):
-    """The table-shaped gradient through the optimizer's own transform,
-    from a state whose count stands at `STEP - 1`."""
+    """The table-shaped gradient of the entries, whatever their order,
+    through the optimizer's own transform, from a state whose count
+    stands at `STEP - 1`."""
     grad = jnp.zeros_like(table).at[keys].add(
-        rows.astype(jnp.float32), indices_are_sorted=True, mode="drop")
+        rows.astype(jnp.float32), mode="drop")
     optimizer = optax.chain(
         _scale_by_adam_nu_dtype(HYPER["b1"], HYPER["b2"], HYPER["eps"],
                                 mu.dtype, nu.dtype),
@@ -109,26 +128,28 @@ def _what_it_replaces(table, mu, nu, keys, rows):
     return optax.apply_updates(table, updates), state[0].mu, state[0].nu
 
 
-def _the_kernel(*args):
+def _the_kernel(*args, runs=1):
     return adam_rows._pallas(*args, name="adam_rows_test", interpret=True,
-                             tile=TILE, chunk=CHUNK, **HYPER)
+                             tile=TILE, chunk=CHUNK, runs=runs, **HYPER)
 
 
-def _the_plain_form(*args):
-    return adam_rows._plain(*args, **HYPER)
+def _the_plain_form(*args, runs=1):
+    return adam_rows._plain(*args, runs=runs, **HYPER)
 
 
-def _against_the_optimizer(*args):
+def _against_the_optimizer(*args, runs):
     """Primitive by primitive, as written: under one `jit` each the
     CPU's compiler contracts `a * b + c` in one program and not in the
     other, and a few elements in ten thousand move by one step of
     float32."""
     with jax.disable_jit():
-        return _the_plain_form(*args), _what_it_replaces(*args[:5])
+        return (_the_plain_form(*args, runs=runs),
+                _what_it_replaces(*args[:5]))
 
 
-def _kernel_against_the_plain_form(*args):
-    return jax.jit(_the_kernel)(*args), jax.jit(_the_plain_form)(*args)
+def _kernel_against_the_plain_form(*args, runs):
+    return (jax.jit(functools.partial(_the_kernel, runs=runs))(*args),
+            jax.jit(functools.partial(_the_plain_form, runs=runs))(*args))
 
 
 PAIRS = {"plain_form_and_optimizer": _against_the_optimizer,
@@ -142,9 +163,10 @@ def test_the_row_list_adam_is_the_scatter_and_the_optimizers_update(
         case, pair, moments):
     """Two links of one chain: the plain form against the optimizer's
     own transform on the scattered table, and the kernel (through the
-    interpreter) against the plain form."""
+    interpreter) against the plain form. The `zipf_runs` cases hand
+    both several sorted runs end to end."""
     rng = np.random.default_rng(sorted(CASES).index(case))
-    table_rows, keys = CASES[case](rng)
+    table_rows, keys, *runs = CASES[case](rng)
     table = jnp.asarray(rng.normal(size=(table_rows, WIDTH)), jnp.float32)
     mu = jnp.asarray(rng.normal(size=table.shape) * 0.1, moments)
     nu = jnp.asarray(rng.random(size=table.shape) * 0.01, moments)
@@ -153,7 +175,8 @@ def test_the_row_list_adam_is_the_scatter_and_the_optimizers_update(
     count = jnp.asarray(STEP, jnp.float32)
     got, want = PAIRS[pair](
         table, mu, nu, keys, rows,
-        1.0 - HYPER["b1"] ** count, 1.0 - HYPER["b2"] ** count)
+        1.0 - HYPER["b1"] ** count, 1.0 - HYPER["b2"] ** count,
+        runs=runs[0] if runs else 1)
     touched = np.zeros(table_rows, bool)
     touched[np.asarray(keys)[np.asarray(keys) < table_rows]] = True
     for name, g, w in zip(("table", "mu", "nu"), got, want):
@@ -169,6 +192,43 @@ def test_the_row_list_adam_is_the_scatter_and_the_optimizers_update(
                                    atol=1e-6, err_msg=f"{name}, touched")
     if case == "empty_list":
         assert not touched.any()
+
+
+@pytest.mark.parametrize("runs", [1, 2])
+def test_a_piece_goes_in_by_one_product_at_every_level(runs):
+    """At the chip's own tile and chunk: pieces whose keys span one band
+    (a hot row), a few, half a tile and a whole one each take the level
+    of `LEVELS` that holds their span, pieces at the tile's end take it
+    from the end back, and the table is the plain form's."""
+    rng = np.random.default_rng(11)
+    tile, table_rows = adam_rows.TILE, 2 * adam_rows.TILE + 300
+    assert adam_rows.LEVELS[-1] == tile and len(adam_rows.LEVELS) > 2
+    spans = [1, 3 * 128, 7 * 128, tile, 128, 5 * 128]
+    lists = []
+    for run in range(runs):
+        live = [np.full(128, 5 + run)]
+        for n, span in enumerate(spans):
+            start = (n * 700 + 130 * run) % (table_rows - span)
+            live.append(np.sort(rng.integers(start, start + span, 128)))
+        live.append(np.sort(rng.integers(table_rows - 200, table_rows, 100)))
+        lists.append(_keys(rng, table_rows, np.concatenate(live),
+                           2 * adam_rows.CHUNK))
+    keys = jnp.asarray(np.concatenate(lists))
+    table = jnp.asarray(rng.normal(size=(table_rows, WIDTH)), jnp.float32)
+    mu = jnp.asarray(rng.normal(size=table.shape) * 0.1, jnp.bfloat16)
+    nu = jnp.asarray(rng.random(size=table.shape) * 0.01, jnp.bfloat16)
+    rows = jnp.asarray(rng.normal(size=(len(keys), WIDTH)), jnp.bfloat16)
+    count = jnp.asarray(STEP, jnp.float32)
+    args = (table, mu, nu, keys, rows, 1.0 - HYPER["b1"] ** count,
+            1.0 - HYPER["b2"] ** count)
+    got = jax.jit(lambda *a: adam_rows._pallas(
+        *a, name="adam_rows_test", interpret=True, runs=runs, **HYPER))(*args)
+    want = jax.jit(functools.partial(_the_plain_form, runs=runs))(*args)
+    for name, g, w in zip(("table", "mu", "nu"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(g, np.float32), np.asarray(w, np.float32),
+            rtol=2.0 ** -8 if name != "table" else 1e-5, atol=1e-6,
+            err_msg=name)
 
 
 @pytest.mark.parametrize("rows, width, dtype, kernel", [
@@ -202,30 +262,82 @@ def test_one_test_says_what_the_kernel_takes(monkeypatch, rows, width,
                                rtol=1e-5, atol=1e-6)
 
 
-def test_the_schedule_gives_every_tile_its_chunks_once():
-    """Every tile has at least one item, its items' chunks cover its
-    entries, tiles and chunks never go back, and the items past the
-    total repeat the last."""
+def _schedule_of_one_list(keys, table_rows, tile, chunk):
+    """`_schedule` as it stood while the kernel took ONE sorted list (PR
+    43), word for word: what one run's must still be."""
+    tiles, chunks = -(-table_rows // tile), keys.shape[0] // chunk
+    edges = jnp.minimum(jnp.arange(tiles + 1, dtype=jnp.int32) * tile,
+                        table_rows)
+    offsets = adam_rows._entries_below(keys, edges, chunk)
+    first = jnp.minimum(offsets[:-1] // chunk, chunks - 1)
+    last = jnp.maximum(first, (offsets[1:] - 1) // chunk)
+    counts = last - first + 1
+    starts = jnp.cumsum(counts) - counts
+    item = jnp.arange(tiles + chunks, dtype=jnp.int32)
+    tile_of = jnp.sum(starts[None, :] <= item[:, None], axis=1,
+                      dtype=jnp.int32) - 1
+    chunk_of = jnp.minimum(first[tile_of] + item - starts[tile_of],
+                           last[tile_of])
+    return tile_of, chunk_of, (starts[-1] + counts[-1])[None], offsets
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_one_runs_schedule_is_the_one_lists(case):
+    """With one run the grid's items are, array for array, those the
+    kernel had when it took one list."""
+    table_rows, keys, *_ = CASES[case](np.random.default_rng(7))
+    keys = np.concatenate([keys, np.full(-len(keys) % CHUNK, table_rows,
+                                         np.int32)])
+    tile = min(TILE, -(-table_rows // 128) * 128)
+    for got, want in zip(
+            adam_rows._schedule(jnp.asarray(keys), table_rows, tile, CHUNK),
+            _schedule_of_one_list(jnp.asarray(keys), table_rows, tile,
+                                  CHUNK)):
+        assert got.dtype == want.dtype
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("runs", [1, 2, 4])
+def test_the_schedule_gives_every_tile_its_chunks_once(runs):
+    """Every tile has at least one item a run, its items go run by run
+    and their chunks cover its entries of each run, tiles never go back
+    and chunks go back only where the next tile starts again at the
+    first run, and the items past the total repeat the last."""
     rng = np.random.default_rng(1)
-    rows = 5 * TILE + 9
-    keys = _keys(rng, rows, np.concatenate(
-        [np.full(500, 3), rng.integers(4 * TILE, rows, 40)]), 1024)
+    rows, per_run = 5 * TILE + 9, 1024
+    chunks = per_run // CHUNK
+    keys = np.concatenate([_keys(rng, rows, np.concatenate(
+        [np.full(500 - 100 * run, 3),
+         rng.integers(4 * TILE, rows, 40 + run)]), per_run)
+        for run in range(runs)])
     tile_of, chunk_of, total, offsets = (
         np.asarray(x) for x in adam_rows._schedule(
-            jnp.asarray(keys), rows, TILE, CHUNK))
+            jnp.asarray(keys), rows, TILE, CHUNK, runs))
     total = int(total[0])
-    assert len(tile_of) == 6 + 1024 // CHUNK and total <= len(tile_of)
+    assert len(tile_of) == runs * (6 + chunks) and total <= len(tile_of)
     assert sorted(set(tile_of[:total])) == list(range(6))
-    assert (np.diff(tile_of) >= 0).all() and (np.diff(chunk_of) >= 0).all()
+    assert (np.diff(tile_of) >= 0).all()
     assert (tile_of[total:] == 5).all()
     assert (chunk_of[total:] == chunk_of[total - 1]).all()
-    assert offsets[0] == 0 and offsets[-1] == 540
+    offsets = offsets.reshape(runs, 7)
+    for run in range(runs):
+        live = 540 - 99 * run
+        assert offsets[run, 0] == run * per_run
+        assert offsets[run, -1] == run * per_run + live
+        assert (np.diff(offsets[run]) >= 0).all()
+    run_of = chunk_of // chunks
     for t in range(6):
-        mine = chunk_of[:total][tile_of[:total] == t]
-        np.testing.assert_array_equal(mine, np.arange(mine[0], mine[-1] + 1))
-        if offsets[t + 1] > offsets[t]:
-            assert mine[0] * CHUNK <= offsets[t]
-            assert offsets[t + 1] <= (mine[-1] + 1) * CHUNK
+        mine = tile_of[:total] == t
+        np.testing.assert_array_equal(
+            np.unique(run_of[:total][mine]), np.arange(runs))
+        assert (np.diff(run_of[:total][mine]) >= 0).all()
+        for run in range(runs):
+            its = chunk_of[:total][mine & (run_of[:total] == run)]
+            np.testing.assert_array_equal(
+                its, np.arange(its[0], its[-1] + 1))
+            if offsets[run, t + 1] > offsets[run, t]:
+                assert its[0] * CHUNK <= offsets[run, t]
+                assert offsets[run, t + 1] <= (its[-1] + 1) * CHUNK
 
 
 # ------------------------------------------------------------- the step
@@ -244,8 +356,9 @@ def toy_blocks(monkeypatch):
 
 
 def _toy(mesh=None, **overrides):
-    config = Config(train_data_path_prefix="unused", train_batch_size=B,
-                    max_contexts=M, dropout_keep_rate=1.0, **overrides)
+    config = Config(**{**dict(
+        train_data_path_prefix="unused", train_batch_size=B, max_contexts=M,
+        dropout_keep_rate=1.0), **overrides})
     module = Code2VecModule(dims=DIMS, dropout_keep_rate=1.0,
                             compute_dtype=jnp.dtype(config.compute_dtype))
     optimizer = make_optimizer(config)
@@ -275,6 +388,29 @@ def _through_the_interpreter(*args, name, **hyper):
                              chunk=CHUNK, **hyper)
 
 
+def _three_steps_agree(step, state, table_step, table_state, batch_of):
+    """Three steps of both from one seed each: every loss, Adam's first
+    moment after step one and the parameters after step three agree
+    within float32 summation order. Returns `step`'s last state."""
+    for n in range(3):
+        batch, rng = batch_of(n), jax.random.PRNGKey(n)
+        state, loss = step(state, *batch, rng)
+        table_state, table_loss = table_step(table_state, *batch, rng)
+        np.testing.assert_allclose(float(loss), float(table_loss), rtol=1e-6)
+        pairs = {"mu": (_first_moment(state), _first_moment(table_state))
+                 } if n == 0 else {}
+        if n == 2:
+            pairs["parameters"] = (state.params, table_state.params)
+        for what, (got, want) in pairs.items():
+            assert set(got) == set(want)
+            for key in want:
+                np.testing.assert_allclose(
+                    np.asarray(got[key], np.float32),
+                    np.asarray(want[key], np.float32), rtol=2.0 ** -7,
+                    atol=1e-6, err_msg=f"step {n + 1} {what} {key}")
+    return state
+
+
 @pytest.mark.parametrize("moments", ["bfloat16", "float32"])
 @pytest.mark.parametrize("form", ["plain", "kernel"])
 def test_three_one_chip_steps_equal_the_table_shaped_steps(
@@ -296,22 +432,8 @@ def test_three_one_chip_steps_equal_the_table_shaped_steps(
     table_step = table_builder.make_train_step(table_state)
     tree = jax.tree.structure(state)
     kinds = [(x.shape, x.dtype) for x in jax.tree.leaves(state)]
-    for n in range(3):
-        batch, rng = _toy_batch(seed=n), jax.random.PRNGKey(n)
-        state, loss = step(state, *batch, rng)
-        table_state, table_loss = table_step(table_state, *batch, rng)
-        np.testing.assert_allclose(float(loss), float(table_loss), rtol=1e-6)
-        pairs = {"mu": (_first_moment(state), _first_moment(table_state))
-                 } if n == 0 else {}
-        if n == 2:
-            pairs["parameters"] = (state.params, table_state.params)
-        for what, (got, want) in pairs.items():
-            assert set(got) == set(want)
-            for key in want:
-                np.testing.assert_allclose(
-                    np.asarray(got[key], np.float32),
-                    np.asarray(want[key], np.float32), rtol=2.0 ** -7,
-                    atol=1e-6, err_msg=f"step {n + 1} {what} {key}")
+    state = _three_steps_agree(step, state, table_step, table_state,
+                               lambda n: _toy_batch(seed=n))
     assert jax.tree.structure(state) == tree
     assert [(x.shape, x.dtype) for x in jax.tree.leaves(state)] == kinds
     assert int(state.step) == 3
@@ -319,70 +441,126 @@ def test_three_one_chip_steps_equal_the_table_shaped_steps(
 
 
 def _refuse(*args, **kwargs):
-    raise AssertionError("the one-chip step's alone")
+    raise AssertionError("the row-list step's alone")
+
+
+def _mesh_batch(seed, chips):
+    """`chips` toy batches, one a chip, row slices of one global batch."""
+    return tuple(np.concatenate(x) for x in zip(
+        *(_toy_batch(seed=chips * seed + chip) for chip in range(chips))))
+
+
+@pytest.mark.parametrize("form", ["plain", "kernel"])
+def test_three_steps_of_a_data_mesh_equal_the_table_shaped_steps(
+        monkeypatch, toy_blocks, form):
+    """On four (forced host) devices under `dp`: each step's loss,
+    Adam's first moment after step one and the parameters after step
+    three of the step that all-gathers the chips' lists equal those of
+    the step whose chips all-reduce table-shaped gradients, within
+    float32 summation order. And a drift between replicas is a wrong
+    result: after the three steps every chip's copy of every table and
+    moment is BIT-equal to chip 0's."""
+    if form == "kernel":
+        monkeypatch.setattr(step_mod, "adam_rows_into_table",
+                            _through_the_interpreter)
+    mesh = make_mesh(MeshPlan(4, 1, 1))
+    builder, state = _toy(mesh=mesh, dp=4, train_batch_size=4 * B)
+    assert step_mod.adam_row_list_tables(builder.config, mesh) == 2
+    assert step_mod.row_list_runs(builder.config, mesh) == 4
+    step = builder.make_train_step(state)
+    monkeypatch.setattr(step_mod, "adam_row_list_tables", lambda c, m: 0)
+    table_builder, table_state = _toy(mesh=mesh, dp=4,
+                                      train_batch_size=4 * B)
+    table_step = table_builder.make_train_step(table_state)
+    state = _three_steps_agree(step, state, table_step, table_state,
+                               lambda n: _mesh_batch(n, 4))
+    assert int(state.step) == 3
+    moments = step_mod._adam_moments(state.opt_state)
+    for name in ("token_embedding", "path_embedding", "target_embedding"):
+        for what, leaf in (("table", state.params[name]),
+                           ("mu", moments.mu[name]),
+                           ("nu", moments.nu[name])):
+            copies = [np.asarray(shard.data)
+                      for shard in leaf.addressable_shards]
+            assert len(copies) == 4 and copies[0].shape == leaf.shape
+            for chip, copy in enumerate(copies[1:], 1):
+                np.testing.assert_array_equal(
+                    copy.view(np.uint8), copies[0].view(np.uint8),
+                    err_msg=f"{name} {what}: chip {chip} against chip 0")
 
 
 @pytest.mark.parametrize("plan", [(2, 1, 1), (2, 2, 1)],
                          ids=["dp2", "dp2_tp2"])
-def test_a_mesh_of_two_chips_still_builds_table_shaped_gradients(
+def test_a_mesh_that_shards_a_table_still_builds_table_shaped_gradients(
         monkeypatch, toy_blocks, plan):
-    """The choice is the mesh's: on a data mesh of two (forced host)
-    devices the step lowers without the list or its Adam, the token
-    table's gradient a scatter into a table; one chip's does not lower
-    without them."""
+    """The choice is the mesh's: where `model` shards the tables the
+    step lowers without the list or its Adam; one chip's, and a data
+    mesh's of two (forced host) devices, do not lower without them."""
     for op in ("sorted_row_list", "live_rows_and_entries",
                "adam_rows_into_table"):
         monkeypatch.setattr(step_mod, op, _refuse)
     mesh = make_mesh(MeshPlan(*plan))
     builder, state = _toy(mesh=mesh, dp=plan[0], tp=plan[1])
-    text = builder.make_train_step(state).lower(
-        state, *_toy_batch(), jax.random.PRNGKey(1)).as_text()
+    lower = lambda: builder.make_train_step(state).lower(
+        state, *_toy_batch(), jax.random.PRNGKey(1))
     if plan[1] == 1:
-        assert "stablehlo.scatter" in text
+        with pytest.raises(AssertionError, match="row-list step's alone"):
+            lower()
+    else:
+        lower()
     builder, state = _toy()
-    with pytest.raises(AssertionError, match="one-chip step's alone"):
+    with pytest.raises(AssertionError, match="row-list step's alone"):
         builder.make_train_step(state).lower(
             state, *_toy_batch(), jax.random.PRNGKey(1))
 
 
-@pytest.mark.parametrize("plan, overrides, tables", [
-    (None, {}, 2), ((1, 1, 1), {}, 2),
-    ((2, 1, 1), {}, 0), ((4, 1, 1), {}, 0),
-    ((1, 2, 1), {}, 0), ((2, 1, 2), {}, 0),
-    (None, {"use_sparse_embedding_update": True}, 0),
-    (None, {"adam_nu_dtype": "float32"}, 0),
-    (None, {"adam_nu_dtype": "float32", "adam_mu_dtype": "float32"}, 2),
-    (None, {"compute_dtype": "float32"}, 0),
-    (None, {"default_embeddings_size": 64}, 0),
-    (None, {"path_embeddings_size": 256}, 0),
+@pytest.mark.parametrize("plan, overrides, tables, runs", [
+    (None, {}, 2, 1), ((1, 1, 1), {}, 2, 1),
+    ((2, 1, 1), {}, 2, 2), ((4, 1, 1), {}, 2, 4),
+    ((1, 2, 1), {}, 0, 0), ((2, 1, 2), {}, 0, 0),
+    (None, {"use_sparse_embedding_update": True}, 0, 0),
+    (None, {"adam_nu_dtype": "float32"}, 0, 0),
+    ((4, 1, 1), {"adam_nu_dtype": "float32"}, 0, 0),
+    (None, {"adam_nu_dtype": "float32", "adam_mu_dtype": "float32"}, 2, 1),
+    (None, {"compute_dtype": "float32"}, 0, 0),
+    ((4, 1, 1), {"compute_dtype": "float32"}, 0, 0),
+    (None, {"default_embeddings_size": 64}, 0, 0),
+    (None, {"path_embeddings_size": 256}, 0, 0),
 ], ids=["no_mesh", "mesh_of_one", "dp2", "dp4", "tp2", "dp2_cp2", "sparse",
-        "stock_adam_bf16_mu", "stock_adam_float32", "float32_rows",
-        "narrow_tables", "wide_path_table"])
-def test_which_steps_hand_adam_a_row_list(plan, overrides, tables):
-    """One chip with whole tables, no mesh of more chips; tables the
-    kernel takes (128 wide, rows in bfloat16), so the gauge never says
-    "a row list" over a scatter; and not the one optimizer whose
-    arithmetic the list's Adam does not follow (stock optax.adam over a
-    bfloat16 first moment)."""
+        "stock_adam_bf16_mu", "dp4_stock_adam_bf16_mu", "stock_adam_float32",
+        "float32_rows", "dp4_float32_rows", "narrow_tables",
+        "wide_path_table"])
+def test_which_steps_hand_adam_a_row_list(plan, overrides, tables, runs):
+    """Whole tables on every chip, one chip or a data mesh of any size,
+    and no mesh that shards anything else; tables the kernel takes (128
+    wide, rows in bfloat16), so the gauge never says "a row list" over a
+    scatter; and not the one optimizer whose arithmetic the list's Adam
+    does not follow (stock optax.adam over a bfloat16 first moment). One
+    Adam then takes every chip's list."""
     config = Config(train_data_path_prefix="unused", **overrides)
     mesh = None if plan is None else make_mesh(MeshPlan(*plan))
     assert step_mod.adam_row_list_tables(config, mesh) == tables
+    assert step_mod.row_list_runs(config, mesh) == runs
 
 
-@pytest.mark.parametrize("plan, tables", [
-    (None, 2), ((2, 1, 1), 0), ((1, 2, 1), 0),
-], ids=["no_mesh", "dp2", "tp2"])
+@pytest.mark.parametrize("plan, tables, runs", [
+    (None, 2, 1), ((2, 1, 1), 2, 2), ((4, 1, 1), 2, 4), ((1, 2, 1), 0, 0),
+], ids=["no_mesh", "dp2", "dp4", "tp2"])
 def test_the_trainer_says_how_many_tables_adam_takes_as_a_row_list(
-        tiny_config, plan, tables):
-    """`train_adam_row_list_tables`, and the same number in the first
-    step's log line."""
+        tiny_config, plan, tables, runs):
+    """`train_adam_row_list_tables` and `train_row_list_runs`, and the
+    same numbers in the first step's log line."""
     lines = []
     tiny_config.verbose_mode = 0
     tiny_config.log = lines.append
     mesh = None if plan is None else make_mesh(MeshPlan(*plan))
-    gauge = obs.default_registry().gauge("train_adam_row_list_tables")
-    gauge.set(-1)
+    registry = obs.default_registry()
+    gauges = [registry.gauge(name) for name in (
+        "train_adam_row_list_tables", "train_row_list_runs")]
+    for gauge in gauges:
+        gauge.set(-1)
     _train_an_epoch(tiny_config, _plain_step, batches=1, rows=8, mesh=mesh)
-    assert gauge.value == tables
+    assert [gauge.value for gauge in gauges] == [tables, runs]
     first, = [ln for ln in lines if ln.startswith("First train step")]
-    assert f"Adam takes {tables} table(s)' gradient as a row list" in first
+    assert (f"Adam takes {tables} table(s)' gradient as a row list, "
+            f"{runs} chip(s)' lists each") in first

@@ -105,12 +105,13 @@ _RESULT_TYPE = re.compile(
     r"(?:-start)?\(")
 
 
-def _dp_train_step_lowered(dp: int):
+def _dp_train_step_lowered(dp: int, dims: ModelDims = DP_DIMS):
     config = Config(train_data_path_prefix="unused",
                     compute_dtype="bfloat16", dp=dp,
+                    default_embeddings_size=dims.token_dim,
                     train_batch_size=DP_ROWS, max_contexts=M)
     mesh = make_mesh(MeshPlan(dp=dp, tp=1, cp=1)) if dp > 1 else None
-    module = Code2VecModule(dims=DP_DIMS, compute_dtype=jnp.bfloat16)
+    module = Code2VecModule(dims=dims, compute_dtype=jnp.bfloat16)
     opt = make_optimizer(config)
     state = create_train_state(module, opt, jax.random.PRNGKey(0),
                                mesh=mesh, config=config)
@@ -156,6 +157,68 @@ def test_the_dp_steps_target_gradient_is_gathered_not_reduced():
         f"-> tensor<{filled}x{dim}xbf16>"), lowered
     logits = re.compile(rf"\[{DP_ROWS},(?:{rows}|{filled})\]")
     assert not [r for r, _ in results if logits.search(r)]
+
+
+# Tables the row-list Adam's kernel takes (ops/adam_rows.py: 128 wide
+# under bfloat16 rows), so the data mesh's step exchanges LISTS.
+LIST_DIMS = ModelDims(token_vocab_size=64, path_vocab_size=40,
+                      target_vocab_size=30, token_dim=128, path_dim=128)
+
+
+def _adam_without_a_scatter(table, mu, nu, keys, rows, bias1, bias2, **_):
+    """Stands where the TPU's kernel does (off the chip the row-list
+    Adam is a scatter into a table of zeros): reads the whole list and
+    builds no table from it."""
+    read = jnp.sum(keys) + jnp.sum(rows.astype(jnp.float32))
+    return table + 0.0 * read, mu, nu
+
+
+@pytest.mark.parametrize("lists", [True, False],
+                         ids=["row_lists", "table_shaped"])
+def test_the_dp_step_exchanges_the_chips_lists_and_sums_no_table(
+        monkeypatch, lists):
+    """On the 4-device data mesh, with tables the kernel takes: the
+    compiled step holds no all-reduce of an array of the token or the
+    path table's shape and no scatter into one, and does hold the four
+    all-gathers that lay the chips' sorted lists end to end (keys int32;
+    rows in the compute dtype, read in the lowered text as above).
+    Differential: the table-shaped step of the same mesh (the one a
+    width the kernel does not take keeps) HAS both all-reduces and both
+    scatters, and gathers no list."""
+    from code2vec_tpu.ops import embed
+    from code2vec_tpu.training import step as step_mod
+    monkeypatch.setattr(step_mod, "adam_rows_into_table",
+                        _adam_without_a_scatter)
+    if not lists:
+        monkeypatch.setattr(step_mod, "adam_row_list_tables",
+                            lambda config, mesh: 0)
+    step = _dp_train_step_lowered(DP, LIST_DIMS)
+    text = step.compile().as_text()
+    tables = [f"[{rows},{LIST_DIMS.token_dim}]" for rows in (
+        LIST_DIMS.token_vocab_size, LIST_DIMS.path_vocab_size)]
+    reduced = [m.group(1) for ln in _collective_lines(text)
+               if "all-reduce" in ln and (m := _RESULT_TYPE.search(ln))]
+    summed = [t for t in tables if any(t in r for r in reduced)]
+    scattered = [t for t in tables if re.search(
+        rf"= f32{re.escape(t)}\S* scatter\(", text)]
+    # a chip's lists: every entry of its slots, of two id arrays or one
+    slots = embed.slot_count(DP_ROWS // DP, M)
+    entries = slots * embed.BLOCK_ROWS * embed.BLOCK_CONTEXTS
+    lengths = [DP * 2 * entries, DP * entries]
+    gathered = [ln for ln in step.as_text().splitlines()
+                if "all_gather" in ln]
+    keys = [n for n in lengths
+            if any(ln.rstrip().endswith(f"-> tensor<{n}xi32>")
+                   for ln in gathered)]
+    rows = [n for n in lengths if any(
+        ln.rstrip().endswith(f"-> tensor<{n}x{LIST_DIMS.token_dim}xbf16>")
+        for ln in gathered)]
+    if lists:
+        assert not summed and not scattered
+        assert keys == lengths and rows == lengths
+    else:
+        assert summed == tables and scattered == tables
+        assert not keys and not rows
 
 
 def test_the_one_device_step_holds_no_collective():

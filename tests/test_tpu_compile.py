@@ -72,26 +72,31 @@ def test_the_heads_pass_b_kernel_compiles_at_published_widths(
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("table_rows,entries", [
-    (1301136, 409600),      # java14m's token table, source + target ids
-    (911417, 204800),       # ... its path table
-    (1301136, 1024000),     # java14m-ctx500's 500 contexts
-    (300, 2048),            # a toy's table, under one tile
+@pytest.mark.parametrize("table_rows,entries,runs", [
+    (1301136, 409600, 1),   # java14m's token table, source + target ids
+    (911417, 204800, 1),    # ... its path table
+    (1301136, 1024000, 1),  # java14m-ctx500's 500 contexts
+    (300, 2048, 1),         # a toy's table, under one tile
+    (1301136, 409600, 4),   # `java14m.train_dp4`: four chips' lists
+    (911417, 204800, 4),
+    (300, 2000, 2),         # runs that are no whole chunks
 ])
 def test_the_row_list_adam_compiles_at_published_widths(
-        one_chip, table_rows, entries):
+        one_chip, table_rows, entries, runs):
     """Adam of a table from the backward's sorted row list
     (ops/adam_rows.py) at the tables java14m runs: rows no tile divides,
-    bfloat16 moments, a batch's entries. Lowered for the TPU the op
-    picks the kernel, the state is updated in place, and nothing
-    table-shaped is left among the program's temporaries."""
+    bfloat16 moments, a batch's entries, of one chip or of the four of
+    a data mesh laid end to end. Lowered for the TPU the op picks the
+    kernel, the state is updated in place, and nothing table-shaped is
+    left among the program's temporaries."""
     from code2vec_tpu.ops.adam_rows import adam_rows_into_table
+    entries *= runs
 
     def shape(dims, dtype):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
     compiled = jax.jit(
         lambda *args: adam_rows_into_table(
-            *args, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8,
+            *args, lr=1e-3, b1=0.9, b2=0.999, eps=1e-8, runs=runs,
             name="adam_token_rows"),
         donate_argnums=(0, 1, 2)).lower(
             shape((table_rows, 128), jnp.float32),
@@ -107,22 +112,30 @@ def test_the_row_list_adam_compiles_at_published_widths(
     assert memory.temp_size_in_bytes < 16e6 + entries * 128 * 2
 
 
-def test_the_one_chip_step_compiles_without_a_table_shaped_gradient(
-        one_chip):
-    """`java14m.train_hostfed`'s step for one described chip: both
-    tables' Adam is the row-list kernel, and no op of the program makes
-    a float32 array of either table's shape (the zeroed table, its
-    scatter, a gradient operand: each was one; the kernels' own results
-    are the donated state)."""
+@pytest.mark.parametrize("chips", [1, 4], ids=["one_chip", "dp4"])
+def test_the_row_list_step_compiles_without_a_table_shaped_gradient(
+        topo, chips):
+    """`java14m.train_hostfed`'s step for one described chip and
+    `java14m.train_dp4`'s for the described 2x2 host: both tables' Adam
+    is the row-list kernel, and no op of the program makes a float32
+    array of either table's shape (the zeroed table, its scatter, a
+    gradient operand, an all-reduce: each was one; the kernels' own
+    results are the donated state). Across four chips the lists cross
+    by all-gathers at the step's top level, not inside a branch."""
     import re
+    from jax.sharding import NamedSharding
     from code2vec_tpu.config import Config
     from code2vec_tpu.models.code2vec import Code2VecModule, ModelDims
+    from code2vec_tpu.parallel.mesh import MeshPlan, make_mesh
     from code2vec_tpu.training.state import (
-        TrainState, init_params, make_optimizer)
-    from code2vec_tpu.training.step import TrainStepBuilder
-    rows, contexts = 1024, 200
+        TrainState, init_params, make_optimizer, state_spec_tree)
+    from code2vec_tpu.training.step import (
+        TrainStepBuilder, _batch_spec_tuple)
+    rows, contexts = 1024 * chips, 200
     config = Config(train_data_path_prefix="unused", train_batch_size=rows,
-                    max_contexts=contexts)
+                    max_contexts=contexts, dp=chips)
+    mesh = (make_mesh(MeshPlan(dp=chips, tp=1, cp=1), devices=topo.devices)
+            if chips > 1 else None)
     dims = ModelDims(token_vocab_size=1301136, path_vocab_size=911417,
                      target_vocab_size=261245, token_dim=128, path_dim=128)
     module = Code2VecModule(dims=dims,
@@ -135,8 +148,11 @@ def test_the_one_chip_step_compiles_without_a_table_shaped_gradient(
         return TrainState(step=jnp.zeros((), jnp.int32), params=params,
                           opt_state=optimizer.init(params))
 
-    def placed(x):
-        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    def placed(x, spec=None):
+        return jax.ShapeDtypeStruct(
+            x.shape, x.dtype,
+            sharding=(SingleDeviceSharding(topo.devices[0]) if mesh is None
+                      else NamedSharding(mesh, spec)))
     abstract = jax.eval_shape(init, jax.random.PRNGKey(0))
     batch = [jax.ShapeDtypeStruct(s, d) for s, d in
              [((rows, contexts), jnp.int32)] * 3
@@ -144,20 +160,32 @@ def test_the_one_chip_step_compiles_without_a_table_shaped_gradient(
                 ((rows,), jnp.bool_)]]
     key = jax.eval_shape(lambda: jax.random.key(
         0, impl=config.dropout_prng_impl))
-    step = TrainStepBuilder(module, optimizer, config).make_train_step(
-        abstract)
-    compiled = step.lower(jax.tree.map(placed, abstract),
-                          *map(placed, batch), placed(key)).compile()
+    step = TrainStepBuilder(module, optimizer, config,
+                            mesh=mesh).make_train_step(abstract)
+    whole = jax.sharding.PartitionSpec()
+    compiled = step.lower(
+        jax.tree.map(placed, abstract, state_spec_tree(abstract)),
+        *map(placed, batch, _batch_spec_tuple()),
+        placed(key, whole)).compile()
     text = compiled.as_text()
     for kernel in ("adam_token_rows", "adam_path_rows"):
         assert re.search(rf"%{kernel}\S* = .*tpu_custom_call", text)
     made = re.findall(
         r"= f32\[(?:1301136|911417),128\]\S* ([a-z-]+)\(", text)
     assert set(made) <= {"parameter", "get-tuple-element"}, set(made)
+    entry = text[text.index("\nENTRY "):]
+    gathered = set(re.findall(
+        r"= (s32\[\d+\]|bf16\[\d+,128\])\S* all-gather\(", entry))
+    # (the head gathers every row's label too: `s32[4096]`)
+    assert gathered - {"s32[4096]"} == (
+        {"s32[1638400]", "bf16[1638400,128]", "s32[819200]",
+         "bf16[819200,128]"} if chips > 1 else set())
     # the target table's Adam, which reads the float32 logits, is held
-    # before the encoder's backward: the step with table-shaped gradients
-    # compiled to 1,711,592,960 B of temporaries (PR 42's tree)
-    assert compiled.memory_analysis().temp_size_in_bytes < 1.70e9
+    # before the encoder's backward: the one-chip step with table-shaped
+    # gradients compiled to 1,711,592,960 B of temporaries (PR 42's
+    # tree), the dp4 step with them to 2,670,745,088 B (PR 45's)
+    assert compiled.memory_analysis().temp_size_in_bytes < (
+        1.70e9 if chips == 1 else 1.80e9)
 
 
 @pytest.mark.parametrize("rows,vocab,dim,dtype,block", [

@@ -157,25 +157,6 @@ def _toy_batch(rows=8, contexts=4):
             np.ones((rows,), np.int32), np.ones((rows,), bool))
 
 
-@pytest.mark.parametrize("name, tied", [("no_mesh", False), ("dp4", True)])
-def test_only_a_data_mesh_holds_the_all_reduces_behind_both_scatters(
-        monkeypatch, name, tied):
-    """Under `shard_map` over `data` the encoder's cotangents leave
-    through one `optimization_barrier` (no table's all-reduce starts
-    beside the other's scatter); the one-chip step has none, as
-    before."""
-    monkeypatch.setitem(MESHES, name, STAGED[name] + (False, False))
-    builder, state = _builder_and_state(name, False, False)
-    abstract = jax.tree.map(
-        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)
-    batch = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                  for a in _toy_batch())
-    rng = jax.ShapeDtypeStruct((2,), jnp.uint32)
-    text = builder.make_train_step(state).lower(
-        abstract, *batch, rng).as_text()
-    assert ("optimization_barrier" in text) == tied
-
-
 def test_the_async_collective_count_is_0_without_a_mesh_and_compiles_nothing():
     """After the first call the count reads the executable that call
     compiled: no second backend compile; a step with no collective
